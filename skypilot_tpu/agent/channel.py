@@ -36,7 +36,6 @@ import time
 from typing import Dict, Optional, Tuple
 
 from skypilot_tpu import tpu_logging
-from skypilot_tpu.agent import constants as agent_constants
 from skypilot_tpu.agent import rpc as agent_rpc
 
 logger = tpu_logging.init_logger(__name__)
@@ -70,8 +69,7 @@ class RpcChannel:
         self._lock = threading.Lock()
 
     def _start(self) -> None:
-        cmd = (f'{agent_constants.control_plane_env_prefix()}'
-               f'{shlex.quote(self._runner.remote_python)} '
+        cmd = (f'{shlex.quote(self._runner.remote_python)} '
                f'-m {self._module} --serve')
         self._proc = self._runner.popen_interactive(cmd)
         self._lines = queue_mod.Queue()

@@ -77,11 +77,6 @@ def run_job(job_id: int) -> int:
         rank, runner = rank_runner
         env = build_rank_env(cluster_info, rank, job_id)
         env.update(user_env)
-        if not spec.get('control_plane'):
-            # Only data-plane (user) jobs get the accelerator-runtime
-            # env back; controller/LB service processes must not
-            # initialize the TPU runtime or claim the chip.
-            constants.restore_accelerator_env(env)
         log_path = os.path.join(log_dir,
                                 constants.RANK_LOG_FMT.format(rank=rank))
         cmd = run_cmd

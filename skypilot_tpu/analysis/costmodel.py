@@ -71,7 +71,7 @@ import json
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 # ------------------------------------------------------------- classes
-# Operand-class taxonomy. Priority (below) decides the class of a
+# Operand classes. Priority (below) decides the class of a
 # mixed-operand elementwise result: KV beats weights beats tables
 # beats activations, so a dequant chain (codes x scale) keeps its
 # weight class until a matmul turns it into an activation.

@@ -151,8 +151,8 @@ TPU hot-path hygiene (GC2xx), applied to the compute layer
   :func:`skypilot_tpu.utils.host.host_sync` helper (bare
   ``np.asarray(x)``, ``.item()``, ``jax.device_get``,
   ``block_until_ready``, ``float(x)``). One accidental sync in the
-  decode loop costs a dispatch round trip (~100 ms through a remote
-  PJRT tunnel) *per step*. ``np.asarray(x, dtype)`` — the explicit
+  decode loop stalls the host on device completion *per step*.
+  ``np.asarray(x, dtype)`` — the explicit
   host-side conversion idiom — is allowed; the bare one-argument form
   is the classic accidental-sync spelling.
 

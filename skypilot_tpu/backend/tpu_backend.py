@@ -7,7 +7,7 @@ Role of reference ``CloudVmRayBackend``
   (:mod:`skypilot_tpu.agent.driver`) over every host.
 - Provisioning failover: zone loop with blocklisting + re-optimize
   (reference ``RetryingVmProvisioner.provision_with_retries`` ``:1979``),
-  consuming the :class:`exceptions.ProvisionError` taxonomy
+  consuming the :class:`exceptions.ProvisionError` classification
   (``blocklist_scope``) instead of parsing cloud stdout.
 - Client<->head control is the JSON RPC (:mod:`skypilot_tpu.agent.rpc`),
   replacing codegen-over-SSH.
@@ -462,9 +462,7 @@ class TpuVmBackend(backend_lib.Backend[TpuVmResourceHandle]):
         import shlex
         req = {'op': 'tail', 'job_id': job_id, 'follow': follow}
         runner = handle.head_runner()
-        from skypilot_tpu.agent import constants as agent_constants
-        cmd = (f'{agent_constants.control_plane_env_prefix()}'
-               f'{shlex.quote(runner.remote_python)} '
+        cmd = (f'{shlex.quote(runner.remote_python)} '
                f'-m skypilot_tpu.agent.rpc '
                f'{shlex.quote(json_lib.dumps(req))}')
         runner.run(cmd, stream_logs=True, log_path=os.devnull)

@@ -1,9 +1,9 @@
 """Exception hierarchy for skypilot_tpu.
 
-Mirrors the role of the reference's ``sky/exceptions.py`` (error taxonomy that
+Mirrors the role of the reference's ``sky/exceptions.py`` (error classification that
 the failover loop keys on), re-designed around TPU provisioning semantics:
 queued-resource timeouts and slice preemption are first-class failover signals
-(see reference failure taxonomy at
+(see reference failure classification at
 ``sky/backends/cloud_vm_ray_backend.py:1031-1086``).
 """
 from __future__ import annotations

@@ -69,9 +69,6 @@ def handle(request: Dict[str, Any]) -> Dict[str, Any]:
                         f'--job-id {job_id}'),
                 'env': {},
                 'workdir_target': None,
-                # Controller process is control plane: no accelerator
-                # runtime env (it must not claim the chip).
-                'control_plane': True,
             })
         agent_job_lib.schedule_step()
         return _ok(job_id=job_id, agent_job_id=agent_job_id)

@@ -377,53 +377,12 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-_probe_broken_warned = False
-
-
 def _ambient_mesh():
-    """The active mesh context's mesh, or None.
-
-    Tries the PUBLIC accessor first (``jax.sharding.get_mesh`` sees
-    ``jax.sharding.use_mesh``/``set_mesh`` contexts), then probes the
-    private locations that back the legacy ``with mesh:`` context (no
-    public accessor exists for it) and fails open (None → no
-    constraint) so a jax upgrade degrades perf, not correctness — but
-    warns ONCE when every probe RAISED (probe broken ≠ no mesh), since
-    silently disabled pipelining/sharding constraints would otherwise
-    degrade with no signal. ``tests/test_aux_subsystems.py::
-    test_ambient_mesh_probe`` additionally turns probe breakage into a
-    visible CI failure on the pinned jax."""
-    global _probe_broken_warned
-    try:
-        from jax.sharding import get_mesh
-        m = get_mesh()
-        if isinstance(m, jax.sharding.Mesh) and not m.empty:
-            return m
-    except Exception:  # pylint: disable=broad-except
-        pass
-    probe_healthy = False
-    for probe in ('jax._src.mesh', 'jax.interpreters.pxla'):
-        try:
-            import importlib
-            mod = importlib.import_module(probe)
-            m = mod.thread_resources.env.physical_mesh
-            probe_healthy = True
-            if not m.empty:
-                return m
-            break   # both probes back the SAME context; one healthy
-                    # read of an empty mesh settles it (and skipping
-                    # the pxla probe avoids its DeprecationWarning)
-        except Exception:  # pylint: disable=broad-except
-            continue
-    if not probe_healthy and not _probe_broken_warned:
-        _probe_broken_warned = True
-        import warnings
-        warnings.warn(
-            'skypilot_tpu: ambient-mesh probe failed (jax internals '
-            'changed?); mesh-context detection is DISABLED — pipeline '
-            'parallelism and activation sharding constraints will '
-            'silently not apply inside `with mesh:` contexts.')
-    return None
+    """The (abstract) mesh of the enclosing ``jax.set_mesh`` context, or
+    None. ``get_abstract_mesh`` because this is read while tracing,
+    where ``jax.sharding.get_mesh`` refuses to answer."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _in_mesh_context() -> bool:
@@ -437,31 +396,12 @@ def _in_multidevice_mesh() -> bool:
     return m is not None and m.size > 1
 
 
-_pp_probe_warned = False
-
-
 def _pp_mesh():
-    """The ambient mesh iff its pp axis is > 1 (else None).
-
-    Rides ``_ambient_mesh`` (public accessor first, then the private
-    legacy-context probe — which itself warns once when broken); the
-    probe-works-at-all guarantee is pinned by
-    ``tests/test_aux_subsystems.py::test_ambient_mesh_probe``."""
-    global _pp_probe_warned
-    try:
-        env_mesh = _ambient_mesh()
-        if env_mesh is None:
-            return None
-        return env_mesh if env_mesh.shape.get('pp', 1) > 1 else None
-    except Exception:  # pylint: disable=broad-except
-        if not _pp_probe_warned:
-            _pp_probe_warned = True
-            import warnings
-            warnings.warn(
-                'skypilot_tpu: ambient-mesh probe failed (jax internals '
-                'changed?); pipeline parallelism is DISABLED and pp-'
-                'sharded params will be all-gathered every step.')
+    """The ambient mesh iff its pp axis is > 1 (else None)."""
+    env_mesh = _ambient_mesh()
+    if env_mesh is None:
         return None
+    return env_mesh if env_mesh.shape.get('pp', 1) > 1 else None
 
 
 import threading as _threading

@@ -275,6 +275,19 @@ def ring_decode_attention(
     return out.reshape(b, 1, h, d)
 
 
+def flash_selected(impl: str, sq: int, head_dim: int, *,
+                   causal: bool = True, masked: bool = False) -> bool:
+    """Whether :func:`attention` runs the Pallas flash kernel for these
+    shapes ('flash' forces it; 'auto' picks it on TPU where the shape
+    fits the kernel's tiling; else the XLA reference). The entry points
+    print this, so which attention ran is not a guess."""
+    if impl == 'flash':
+        return True
+    return (impl == 'auto' and jax.default_backend() == 'tpu' and causal
+            and sq >= 256 and sq % 128 == 0 and head_dim % 128 == 0
+            and not masked)
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -316,15 +329,9 @@ def attention(
         return reference_attention(q, k, v, causal=causal)
     if impl not in ('auto', 'xla', 'flash'):
         raise ValueError(f'unknown attention impl {impl!r}')
-    use_flash = False
-    if impl == 'flash':
-        use_flash = True
-    elif impl == 'auto':
-        sq = q.shape[1]
-        on_tpu = jax.default_backend() == 'tpu'
-        use_flash = (on_tpu and causal and sq >= 256 and sq % 128 == 0
-                     and q.shape[-1] % 128 == 0 and q_offset is None
-                     and kv_len is None)
+    use_flash = flash_selected(
+        impl, q.shape[1], q.shape[-1], causal=causal,
+        masked=q_offset is not None or kv_len is not None)
     if use_flash:
         from skypilot_tpu.ops import flash_attention
         return flash_attention.flash_attention(q, k, v, causal=causal)

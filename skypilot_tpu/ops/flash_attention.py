@@ -32,11 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x;
-# resolve whichever the pinned jax ships.
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
-
 
 # --------------------------------------------------------------------------
 # Forward kernel
@@ -145,7 +140,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
@@ -270,7 +265,7 @@ def _fwd_chunk(q, k, v, cache_len, *, scale, split, block_q, block_k,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
@@ -432,7 +427,7 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
@@ -453,7 +448,7 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g):
                                lambda ib, ih, a, b_: (ib, ih, a, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,

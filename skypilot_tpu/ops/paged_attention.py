@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x.
 # Cost-model annotation (analysis/costmodel.py): these KERNEL BODIES
 # (the names pallas_call eqns carry) take the FULL layer-stacked pool
 # ([L, n_pages, ...]) with the layer as a scalar-prefetch block index
@@ -55,9 +54,6 @@ COST_KERNEL_KV_TRAFFIC = {
     '_kernel_manual': 'one_layer_per_call',
     '_kernel_fused': 'one_layer_per_call',    # ..._fused (cross-layer)
 }
-
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
 
 _NEG_INF = -1e30
 
@@ -618,7 +614,7 @@ def paged_decode_attention(
             # MHA shapes (hq=32, d=128, K-page blocks) put outputs +
             # double buffers a few MB past Mosaic's default 16M scoped
             # vmem; the v5e has 128M physical VMEM.
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=48 * 1024 * 1024),
         )(*args)
         return acc, m[..., 0], l[..., 0]
@@ -766,7 +762,7 @@ def paged_decode_attention_all_layers(
             jax.ShapeDtypeStruct((L, slots, hq, LANES), jnp.float32),
             jax.ShapeDtypeStruct((L, slots, hq, LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
     )(*args)
@@ -865,7 +861,7 @@ def paged_decode_attention_fused(
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((slots, hq, d), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
     )(*args)[0]

@@ -40,13 +40,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-import warnings as _warnings
-with _warnings.catch_warnings():
-    # jax >= 0.8 renames this to jax.shard_map but changes the kwarg
-    # surface (check_rep -> check_vma); keep the stable experimental
-    # import until the minimum jax is bumped.
-    _warnings.simplefilter('ignore', DeprecationWarning)
-    from jax.experimental.shard_map import shard_map
 
 _NEG_INF = -1e30
 
@@ -380,12 +373,12 @@ def seq_parallel_call(q, k, v, mesh, body, *, axis_name: str = 'sp',
     qspec = spec_for(('batch', 'seq', 'heads', 'head_dim'), rules)
     kspec = (qspec if k.shape[2] == q.shape[2] else
              spec_for(('batch', 'seq', 'kv_heads', 'head_dim'), rules))
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qspec, kspec, kspec),
         out_specs=qspec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)
 
@@ -437,12 +430,8 @@ def ring_attention(
                              rules=rules)
 
 
-def current_mesh() -> Optional[jax.sharding.Mesh]:
-    """The active mesh context, if any. Delegates to llama's probe:
-    public ``jax.sharding.get_mesh`` first, then the private
-    legacy-context locations, warning ONCE if every probe RAISES (a jax
-    bump silently disabling sequence parallelism would otherwise have
-    no signal; ``tests/test_aux_subsystems.py::test_ambient_mesh_probe``
-    pins probe health on the in-repo jax)."""
+def current_mesh():
+    """The (abstract) mesh of the enclosing ``jax.set_mesh`` context, if
+    any."""
     from skypilot_tpu.models.llama import _ambient_mesh
     return _ambient_mesh()

@@ -9,7 +9,7 @@ in-tree).
 
 Transport contract: ``transport(method, url, body_dict_or_None) ->
 (status_code, response_dict)``. The default transport attaches a gcloud
-access token. HTTP errors are mapped onto the exception taxonomy here so
+access token. HTTP errors are mapped onto the exception classification here so
 every caller sees blocklist-scoped ProvisionErrors, not raw HTTP.
 """
 from __future__ import annotations
@@ -80,7 +80,7 @@ def _default_transport(method: str, url: str,
             payload = {'error': {'message': str(e)}}
         return e.code, payload
     except (urllib.error.URLError, TimeoutError, OSError) as e:
-        # Network-level failures must enter the taxonomy too, or they
+        # Network-level failures must enter the classification too, or they
         # bypass gang cleanup and the failover loop entirely.
         err = exceptions.ProvisionError(
             f'GCP API unreachable ({method} {url.split("?")[0]}): {e}')
@@ -103,7 +103,7 @@ def _error_message(payload: Dict[str, Any]) -> str:
 
 def raise_for_status(status: int, payload: Dict[str, Any], *,
                      zone: Optional[str] = None) -> None:
-    """Map a GCP error onto the blocklist-scoped exception taxonomy
+    """Map a GCP error onto the blocklist-scoped exception classification
     (reference error discrimination:
     ``sky/backends/cloud_vm_ray_backend.py:1031-1086``)."""
     if status < 400:

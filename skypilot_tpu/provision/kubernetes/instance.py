@@ -13,7 +13,7 @@ gke-tpu-accelerator`` / ``gke-tpu-topology`` at ``:340-390``,
   pool via the accelerator+topology node selectors; the ``google.com/
   tpu`` resource request claims the chips of each host.
 - Gang semantics: a slice that cannot fully schedule is torn down and
-  the error enters the blocklist-scoped taxonomy so the failover loop
+  the error enters the blocklist-scoped classification so the failover loop
   moves on (Unschedulable == stockout).
 - A headless Service per cluster gives pods stable DNS names
   (``<pod>.<cluster>``) for the jax.distributed coordinator.

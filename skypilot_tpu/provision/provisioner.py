@@ -19,7 +19,6 @@ from typing import Dict, Optional
 from skypilot_tpu import exceptions
 from skypilot_tpu import provision
 from skypilot_tpu import tpu_logging
-from skypilot_tpu.agent import constants as agent_constants
 from skypilot_tpu.agent import rpc as agent_rpc
 from skypilot_tpu.provision import common
 from skypilot_tpu.utils import subprocess_utils
@@ -105,8 +104,7 @@ def post_provision_runtime_setup(
         'kill -0 $(cat ~/.skytpu_agent/agentd.pid) 2>/dev/null; then '
         '  echo "agentd already running"; '
         'else '
-        f'  {agent_constants.control_plane_env_prefix()}'
-        f'setsid {shlex.quote(head.remote_python)} -m '
+        f'  setsid {shlex.quote(head.remote_python)} -m '
         'skypilot_tpu.agent.agentd >> ~/.skytpu_agent/agentd.log 2>&1 '
         '< /dev/null & '
         'fi')
@@ -168,8 +166,7 @@ def agent_request(head_runner, request: Dict,
             channel_lib.disable(head_runner, module)
             logger.debug(f'RPC channel unavailable '
                          f'({e}); falling back to one-shot exec')
-    cmd = (f'{agent_constants.control_plane_env_prefix()}'
-           f'{shlex.quote(head_runner.remote_python)} '
+    cmd = (f'{shlex.quote(head_runner.remote_python)} '
            f'-m {module} '
            f'{shlex.quote(json.dumps(request))}')
     # Bounded like the channel path (graftcheck GC103 discipline): a

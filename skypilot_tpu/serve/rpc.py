@@ -103,10 +103,6 @@ def handle(request: Dict[str, Any]) -> Dict[str, Any]:
                         f'--service-name {name}'),
                 'env': {},
                 'workdir_target': None,
-                # The service (controller+LB) process is control plane:
-                # it must NOT get the accelerator-runtime env restored,
-                # or it initializes the TPU runtime / claims the chip.
-                'control_plane': True,
             })
         serve_state.set_service_agent_job(name, agent_job_id)
         agent_job_lib.schedule_step()

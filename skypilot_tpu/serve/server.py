@@ -67,6 +67,7 @@ from skypilot_tpu.serve import faults as faults_lib
 from skypilot_tpu.serve import gang as gang_lib
 from skypilot_tpu.serve import scheduler as scheduler_lib
 from skypilot_tpu.serve import wire
+from skypilot_tpu.telemetry import device as device_lib
 from skypilot_tpu.telemetry import tracing
 
 logger = tpu_logging.init_logger(__name__)
@@ -258,6 +259,10 @@ class ModelServer:
         # reflect CURRENT traffic, not its lifetime).
         reg = telemetry.get_registry()
         self._reg = reg
+        # Every XLA compile of the process, from before the engine
+        # loads: /metrics?format=json reports the count, so a caller
+        # can see that steady state over repeated shapes adds none.
+        self._compiles = device_lib.get_compile_watch()
         self._m_served = reg.counter(
             'skytpu_requests_served_total',
             'Requests completed and returned to a client')
@@ -470,8 +475,13 @@ class ModelServer:
                     f'Warm boot from {self.checkpoint_path} failed '
                     f'({type(e).__name__}: {e}); serving cold')
         self._ready.set()
+        device = device_lib.device_identity()
         logger.info(f'Engine ready: model={self.cfg_name} '
-                    f'max_batch={self.max_batch} max_seq={self.max_seq}')
+                    f'max_batch={self.max_batch} max_seq={self.max_seq} '
+                    f'on {device["device_count"]} x '
+                    f'{device["device_kind"]} ({device["platform"]}), '
+                    f'decode_impl='
+                    f'{getattr(engine, "decode_impl", None)}')
 
     def _engine_loop(self) -> None:
         try:
@@ -1471,6 +1481,24 @@ class ModelServer:
             'tokens_free': 0, 'preemptions': 0, 'kv_token_bytes': 0,
         }
 
+    def _engine_path(self) -> Dict[str, Any]:
+        """The JSON ``engine`` block: what the engine resolved at
+        construction (``decode_impl`` after ``auto``, pool pages, whether
+        the pool was sized from live device memory) and where its bytes
+        sit. Same keys before the engine loads and on a slot engine
+        (``decode_impl`` None: it has no paged decode path)."""
+        eng = self.engine
+        if eng is not None and hasattr(eng, 'resolved_path'):
+            return dict(eng.resolved_path(), kv_cache=self.kv_cache)
+        return {
+            'decode_impl': None, 'decode_interpret': False,
+            'prefill_attn': None, 'page_size': 0, 'kv_pool_pages': 0,
+            'pool_auto_sized': False,
+            'bytes_by_device': {'params': {}, 'kv_pool': {}},
+            'jit_first_calls': 0, 'jit_first_call_seconds': 0.0,
+            'kv_cache': self.kv_cache,
+        }
+
     def _lora_stats(self) -> Dict[str, Any]:
         """The JSON ``lora`` block with a stable all-zeros fallback
         before the engine loads (or with the adapter bank off) — same
@@ -1547,6 +1575,15 @@ class ModelServer:
             # replica view and the adaptive-TP policy read this.
             'mesh': dict(self._mesh_axes(),
                          devices=self.tp * self.dp),
+            # The device as JAX reports it (platform, device_kind,
+            # device_count, per-device memory_stats), the engine's
+            # resolved path, and the process's XLA compile count: a
+            # replica that came up on the CPU, dropped to the gather
+            # path or recompiles in steady state shows from outside.
+            'device': dict(device_lib.device_identity(),
+                           memory=device_lib.device_memory()),
+            'engine': self._engine_path(),
+            'compiles': self._compiles.stats(),
             # Disaggregation block (stable schema: role + every handoff
             # outcome and transfer direction, zeros when idle). The
             # phase-aware LB policy routes and picks handoff targets
@@ -1715,6 +1752,8 @@ class ModelServer:
                     elif server._ready.is_set():
                         self._json(200, {'status': 'ready',
                                          'model': server.cfg_name,
+                                         'device': device_lib.
+                                         device_identity(),
                                          'gang': server.gang_status()})
                     else:
                         self._json(503, {'status': 'loading'})

@@ -88,6 +88,20 @@ def main(argv=None) -> None:
                                  attn_impl=args.attn_impl,
                                  mu_dtype=args.mu_dtype))
 
+    # First line: where this runs and which attention the step traces
+    # (a trainer that came up on the CPU must not pass for one on the
+    # chip). chip_smoke.py reads it.
+    from skypilot_tpu.ops.attention import flash_selected
+    from skypilot_tpu.telemetry import device as device_lib
+    attention = args.attn_impl
+    if attention in ('auto', 'xla', 'flash'):
+        attention = ('flash' if flash_selected(args.attn_impl, args.seq,
+                                               cfg.head_dim) else 'xla')
+    print('[train] ' + json.dumps({
+        'device': device_lib.device_identity(),
+        'mesh': mesh_lib.mesh_axis_sizes(trainer.mesh),
+        'attention': attention}), flush=True)
+
     data_axis = mesh_lib.data_axis_size(trainer.mesh)
     if args.batch % data_axis:
         raise SystemExit(
@@ -127,9 +141,11 @@ def main(argv=None) -> None:
     for step in range(start_step, args.steps):
         state, metrics = trainer.step(state, _to_jnp(next(it)))
         if (step + 1) % args.log_every == 0 or step + 1 == args.steps:
+            # The readback is what waits for the device: taken before
+            # the clock is read, or tok_s times the enqueue.
+            m = host_scalars(metrics)   # explicit readback (GC202)
             dt = time.time() - t0
             window = step + 1 - last_logged     # actual steps elapsed
-            m = host_scalars(metrics)   # explicit readback (GC202)
             print(json.dumps({
                 'step': step + 1,
                 'loss': round(m['loss'], 4),
@@ -148,6 +164,8 @@ def main(argv=None) -> None:
     if args.lora_rank > 0 and args.adapter_out:
         trainer.save_adapter(os.path.abspath(args.adapter_out), state)
         print(f'[train] adapter saved: {args.adapter_out}', flush=True)
+    print('[train] memory ' + json.dumps(device_lib.device_memory()),
+          flush=True)
     print(f'[train] done at step {int(state.step)}', flush=True)
 
 

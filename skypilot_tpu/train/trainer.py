@@ -222,7 +222,7 @@ class Trainer:
                           opt_state=new_opt), metrics
 
     def init(self, rng: jax.Array) -> TrainState:
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._init_jit(rng)
 
     def init_from_pretrained(self, path: str) -> TrainState:
@@ -244,7 +244,7 @@ class Trainer:
                               opt_state=self.optimizer.init(
                                   self._trainable(p)))
 
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return jax.jit(init_opt,
                            out_shardings=self.state_shardings)(params)
 
@@ -253,7 +253,7 @@ class Trainer:
         if 'mask' not in batch:
             batch = dict(batch,
                          mask=jnp.ones_like(batch['targets'], jnp.float32))
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._step_jit(state, batch)
 
     def fit(self, state: TrainState, data_iter, num_steps: int,

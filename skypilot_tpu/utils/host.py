@@ -13,8 +13,8 @@ copy). Two reasons:
   sync inside the decode hot loop becomes a failing test, not a silent
   5.5s TTFT regression.
 - **Explicitness**: a call spelled ``host_sync(x)`` tells the reader
-  the host is about to stall on device completion (100 ms+ through a
-  remote PJRT tunnel); ``np.asarray(x)`` says nothing.
+  the host is about to stall on device completion; ``np.asarray(x)``
+  says nothing.
 
 The helpers are dependency-light: jax is imported lazily so the
 orchestration layer can import ``skypilot_tpu.utils`` without the

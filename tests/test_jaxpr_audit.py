@@ -5,9 +5,8 @@ slot/paged engines' steady-state decode + chunked-prefill loops perform
 ZERO device->host transfers outside the sanctioned host_sync readback,
 and compile exactly once per (horizon, sample, kv_bucket) key —
 repeated same-shaped calls never grow the jit caches. A regression here
-is a silent multi-ms-per-step tax in production (100 ms+ through a
-remote PJRT tunnel), which is why it hard-fails in CI instead of
-waiting for a bench round to notice."""
+is a silent per-step tax in production, which is why it hard-fails in
+CI instead of waiting for a bench round to notice."""
 import jax.numpy as jnp
 import numpy as np
 import pytest

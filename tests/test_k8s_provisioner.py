@@ -1,6 +1,6 @@
 """Kubernetes (GKE-TPU) provisioner against a fake kubectl: the same
 hermetic matrix the GCP provisioner passes (create/query/terminate,
-multi-slice gangs, stockout->failover taxonomy, partial-failure cleanup)
+multi-slice gangs, stockout->failover classification, partial-failure cleanup)
 — proving the cloud abstraction holds a third implementation
 (VERDICT r2 item 6; reference ``sky/provision/kubernetes/``).
 """
@@ -186,7 +186,7 @@ def test_unschedulable_maps_to_capacity_error(fake):
     assert ei.value.blocklist_scope == 'zone'
 
 
-def test_quota_error_taxonomy(fake):
+def test_quota_error_classification(fake):
     fake.fail_next_apply = (1, 'pods "x" is forbidden: exceeded quota')
     with pytest.raises(exceptions.QuotaExceededError):
         k8s_instance.run_instances('kubernetes', None, 'kq', _config())

@@ -47,7 +47,7 @@ def test_forward_matches_sequential(pp, n_micro):
     params = _toy_stack()
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 16))
     ref = _sequential(params, x)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(functools.partial(
             pipeline_layers, stage_fn=_stage_fn, mesh=mesh,
             num_microbatches=n_micro))(params, x)
@@ -67,7 +67,7 @@ def test_gradients_match_sequential():
     def loss_seq(p):
         return jnp.sum(_sequential(p, x) ** 2)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         g_pipe = jax.jit(jax.grad(loss_pipe))(params)
     g_seq = jax.grad(loss_seq)(params)
     for key in ('w', 'b'):
@@ -80,7 +80,8 @@ def test_batch_divisibility_enforced():
     mesh = _mesh(pp=2)
     params = _toy_stack()
     x = jnp.zeros((3, 4, 16))
-    with mesh, pytest.raises(ValueError, match='microbatch'):
+    with jax.set_mesh(mesh), pytest.raises(
+            ValueError, match='microbatch'):
         pipeline_layers(params, x, _stage_fn, mesh, num_microbatches=2)
 
 
@@ -127,7 +128,7 @@ def test_with_aux_plumbs_scalar():
     def stage_aux(p, xx):
         return _stage_fn(p, xx), jnp.float32(2.5)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(functools.partial(
             pipeline_layers, stage_fn=stage_aux, mesh=mesh,
             with_aux=True))(params, x)
@@ -169,7 +170,7 @@ class TestMoePP:
                               return_aux=True)[2] for i in (0, 2)]
         aux_ref = jnp.mean(jnp.stack(auxs))
         mesh = _mesh(pp=2)
-        with mesh:
+        with jax.set_mesh(mesh):
             shardings = mesh_lib.tree_shardings(
                 llama.param_logical_axes(cfg), mesh, shapes=params)
             sharded = jax.device_put(params, shardings)
@@ -252,7 +253,7 @@ def test_pp_x_fsdp_bubble_skip_no_deadlock():
     params = _toy_stack()
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 16))
     ref = _sequential(params, x)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(functools.partial(
             pipeline_layers, stage_fn=_stage_fn, mesh=mesh,
             num_microbatches=2, skip_bubbles=True))(params, x)
@@ -278,7 +279,7 @@ def test_bubble_skip_saves_compute_pp_x_fsdp():
         fn = jax.jit(functools.partial(
             pipeline_layers, stage_fn=_stage_fn, mesh=mesh,
             num_microbatches=1, skip_bubbles=skip))
-        with mesh:
+        with jax.set_mesh(mesh):
             jax.block_until_ready(fn(params, x))      # compile
             best = float('inf')
             for _ in range(5):

@@ -36,7 +36,7 @@ def test_matches_reference(sp, causal):
     mesh = _mesh(sp)
     q, k, v = _rand_qkv()
     ref = reference_attention(q, k, v, causal=causal)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda q, k, v: ring_attention(
             q, k, v, mesh, causal=causal))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -47,7 +47,7 @@ def test_gqa_grouped_heads():
     mesh = _mesh(sp=4)
     q, k, v = _rand_qkv(h=8, hkv=2)
     ref = reference_attention(q, k, v, causal=True)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = ring_attention(q, k, v, mesh, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -56,7 +56,7 @@ def test_gqa_grouped_heads():
 def test_sp1_falls_back_to_reference():
     mesh = _mesh(sp=1)
     q, k, v = _rand_qkv()
-    with mesh:
+    with jax.set_mesh(mesh):
         out = ring_attention(q, k, v, mesh, causal=True)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -70,7 +70,7 @@ def test_sharded_inputs_inside_jit():
     qs = jax.device_put(q, jax.sharding.NamedSharding(
         mesh, mesh_lib.spec_for(('batch', 'seq', 'heads', 'head_dim'))))
     ref = reference_attention(q, k, v, causal=True)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda q, k, v: ring_attention(
             q, k, v, mesh, causal=True))(qs, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -112,7 +112,7 @@ class TestZigzag:
         mesh = _mesh(sp)
         q, k, v = _rand_qkv(s=32 * (sp // 2) if sp > 2 else 32)
         ref = reference_attention(q, k, v, causal=True)
-        with mesh:
+        with jax.set_mesh(mesh):
             out = jax.jit(lambda q, k, v: ring_attention(
                 q, k, v, mesh, causal=True, layout='zigzag',
                 block_impl='einsum'))(q, k, v)
@@ -132,7 +132,7 @@ class TestZigzag:
             return jnp.sum(reference_attention(q, k, v,
                                                causal=True) ** 2)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             g_ring = jax.jit(jax.grad(loss_ring, (0, 1, 2)))(q, k, v)
         g_ref = jax.grad(loss_ref, (0, 1, 2))(q, k, v)
         for a, b, name in zip(g_ring, g_ref, 'qkv'):
@@ -165,7 +165,7 @@ class TestFlashBlockBody:
         mesh = _mesh(2)
         # 128-aligned halves + d=128 so the kernel tiles.
         q, k, v = _rand_qkv(b=4, s=512, h=2, hkv=2, d=128)
-        with mesh:
+        with jax.set_mesh(mesh):
             ref = jax.jit(lambda q, k, v: ring_attention(
                 q, k, v, mesh, causal=True, layout=layout,
                 block_impl='einsum'))(q, k, v)
@@ -190,7 +190,7 @@ class TestFlashBlockBody:
             return jnp.sum(reference_attention(q, k, v,
                                                causal=True) ** 2)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             g_ring = jax.jit(jax.grad(loss_ring, (0, 1, 2)))(q, k, v)
         g_ref = jax.grad(loss_ref, (0, 1, 2))(q, k, v)
         for a, b, name in zip(g_ring, g_ref, 'qkv'):

@@ -56,6 +56,35 @@ def test_serving_mesh_shapes(tp_devices):
         mesh_lib.serving_mesh(tp=1024)
 
 
+@pytest.mark.parametrize('engine_cls, cache_cls', [
+    (PagedInferenceEngine, 'skypilot_tpu.inference.paged.PagedKVCache'),
+    (InferenceEngine, 'skypilot_tpu.models.llama.KVCache')])
+def test_sharded_cache_is_born_sharded(tp_devices, setup, monkeypatch,
+                                       engine_cls, cache_cls):
+    """The tp=4 server's first run on four real chips died here: the
+    pool is sized per device, and it was built whole on the first device
+    before being resharded. Its zeros must be made inside a program with
+    out_shardings (traced), never eagerly — a virtual CPU device has no
+    memory limit to say so."""
+    import importlib
+    module, name = cache_cls.rsplit('.', 1)
+    cls = getattr(importlib.import_module(module), name)
+    traced = []
+    create = cls.create.__func__
+
+    def watched(klass, *args, **kwargs):
+        cache = create(klass, *args, **kwargs)
+        traced.append(isinstance(cache[0], jax.core.Tracer))
+        return cache
+
+    monkeypatch.setattr(cls, 'create', classmethod(watched))
+    cfg, params = setup
+    eng = engine_cls(cfg, params, max_batch=4, max_seq=128,
+                     mesh=mesh_lib.serving_mesh(tp=2))
+    assert traced and all(traced), traced
+    assert len(eng.cache[0].sharding.device_set) == 2
+
+
 def test_serving_spec_from_env(monkeypatch):
     monkeypatch.setenv('SKYTPU_TP', '2')
     monkeypatch.setenv('SKYTPU_DP', '3')

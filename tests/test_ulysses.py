@@ -32,7 +32,7 @@ def test_matches_reference(causal):
     b, s, h, d = 2, 64, 8, 16
     q, k, v = _rand(b, s, h, d)
     mesh = _mesh(sp=4)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = ulysses_attention(q, k, v, mesh, causal=causal)
     ref = reference_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -46,7 +46,7 @@ def test_gqa_grouping_preserved():
     k = jax.random.normal(ks[0], (b, s, hkv, d), jnp.float32)
     v = jax.random.normal(ks[1], (b, s, hkv, d), jnp.float32)
     mesh = _mesh(sp=4)            # hkv % sp == 0: grouped form survives
-    with mesh:
+    with jax.set_mesh(mesh):
         out = ulysses_attention(q, k, v, mesh, causal=True)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -60,7 +60,7 @@ def test_mqa_expands_kv():
     k = jax.random.normal(ks[0], (b, s, 1, d), jnp.float32)
     v = jax.random.normal(ks[1], (b, s, 1, d), jnp.float32)
     mesh = _mesh(sp=4)            # hkv=1 < sp: expansion path
-    with mesh:
+    with jax.set_mesh(mesh):
         out = ulysses_attention(q, k, v, mesh, causal=True)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -72,7 +72,7 @@ def test_rejects_indivisible_heads():
     q, k, v = _rand(b, s, h, d)
     mesh = _mesh(sp=4)
     with pytest.raises(ValueError, match='n_heads'):
-        with mesh:
+        with jax.set_mesh(mesh):
             ulysses_attention(q, k, v, mesh, causal=True)
 
 
@@ -101,7 +101,7 @@ def test_custom_scale_honored():
     b, s, h, d = 2, 32, 8, 16
     q, k, v = _rand(b, s, h, d, seed=4)
     mesh = _mesh(sp=4)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = ulysses_attention(q, k, v, mesh, causal=True, scale=2.0)
     ref = reference_attention(q, k, v, causal=True, scale=2.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
