@@ -201,10 +201,8 @@ def kv_token_bytes(cfg, quantized: bool, mesh=None) -> int:
 
 
 # Telemetry series every engine registers at construction (zeros from
-# the first scrape): the decode step's KV read traffic and the
-# attention-impl attribution of its wall time.
+# the first scrape): the decode step's KV read traffic.
 KV_READ_METRIC = 'skytpu_kv_read_bytes_per_step'
-ATTN_MS_METRIC = 'skytpu_attn_kernel_ms'
 
 
 def _ring_horizon_cap(cfg, batch: int, param_bytes: int,
@@ -300,12 +298,11 @@ class _EngineBase:
         self._prof = (profiler_lib.StepProfiler(
             engine=type(self).__name__) if self.telemetry_enabled
             else profiler_lib.NullProfiler())
-        # KV-round-two gauges, registered AT CONSTRUCTION so both
-        # series sit on the very first scrape (zeros) — the stable-
-        # schema contract: dashboards never join against a series that
+        # KV-round-two gauge, registered AT CONSTRUCTION so the series
+        # sits on the very first scrape (zero) — the stable-schema
+        # contract: dashboards never join against a series that
         # appears only after the first decode.
         self._kv_read_gauge = None
-        self._attn_ms_gauges: Dict[str, Any] = {}
         if self.telemetry_enabled:
             from skypilot_tpu.telemetry import registry as registry_lib
             reg = registry_lib.get_registry()
@@ -314,31 +311,17 @@ class _EngineBase:
                 'KV-cache bytes one decode substep streams from HBM '
                 '(live context rows x per-token stored cost, per '
                 'shard) — the bandwidth-wall numerator')
-            for impl in ('per_layer', 'cross_layer'):
-                self._attn_ms_gauges[impl] = reg.gauge(
-                    ATTN_MS_METRIC,
-                    'Host wall ms per decode substep attributed to '
-                    'the attention impl serving the dispatch',
-                    impl=impl)
 
-    def _note_decode_step(self, live_tokens: int, substeps: int,
-                          dt_s: float) -> None:
-        """Per-dispatch attribution behind the two KV-round-two
-        gauges: the HBM bytes the step's attention reads stream (live
-        context rows x the same per-token cost every capacity decision
-        uses) and host wall ms per device substep, labeled by the
-        attention impl that served it (per_layer | cross_layer — the
-        phase split the cross-layer fusion is supposed to flip). Host
+    def _note_decode_step(self, live_tokens: int) -> None:
+        """Per-dispatch attribution behind the KV-round-two gauge: the
+        HBM bytes the step's attention reads stream (live context rows
+        x the same per-token cost every capacity decision uses). Host
         arithmetic only; nothing here touches the device."""
         if self._kv_read_gauge is None:
             return
         per_tok = kv_token_bytes(self.cfg, self.kv_cache_dtype,
                                  mesh=getattr(self, 'mesh', None))
         self._kv_read_gauge.set(live_tokens * per_tok)
-        impl = ('cross_layer'
-                if getattr(self, 'decode_impl', None) == 'cross_layer'
-                else 'per_layer')
-        self._attn_ms_gauges[impl].set(dt_s / max(1, substeps) * 1e3)
 
     # ------------------------------------------ cost-model boundary
     # Operand-class annotation at the decode program boundary: the
@@ -365,6 +348,13 @@ class _EngineBase:
         THIS engine (the bench and ``/debug`` surface)."""
         return self._prof.phase_stats()
 
+    @property
+    def profiler(self):
+        """The step-phase profiler: the serve layer's engine loop times
+        its own phases (lock wait, fill, event routing) through it, so
+        one profiler holds the whole loop."""
+        return self._prof
+
     def _trace_finish(self, req: 'Request', **meta: Any) -> None:
         """Complete a request's trace and publish it to the process
         ring buffer (the ``/debug/requests`` surface)."""
@@ -382,6 +372,20 @@ class _EngineBase:
         if req.trace is not None:
             req.trace.end('queue')
             req.trace.begin('prefill')
+
+    def _trace_first_token(self, req: 'Request') -> None:
+        """Prefill -> decode transition, at the readback of the token
+        the last chunk sampled. The prefill span ends where that
+        chunk's dispatch returned; what the async pipeline added until
+        the host read the token is ``first_token_lag``."""
+        trace = req.trace
+        if trace is None:
+            return
+        dispatched = trace.last_end('prefill_chunk')
+        trace.end('prefill', at=dispatched)
+        if dispatched is not None:
+            trace.add('first_token_lag', dispatched, clock.monotonic())
+        trace.begin('decode')
 
     def _init_slots(self, max_batch: int) -> None:
         if not hasattr(self, '_prof'):       # engines call _init_telemetry
@@ -546,20 +550,25 @@ class _EngineBase:
 
     def adopt_trace_context(self, request_id: int,
                             trace_id: Optional[str] = None,
-                            parent_span: Optional[str] = None
+                            parent_span: Optional[str] = None,
+                            submitted_at: Optional[float] = None
                             ) -> Optional[str]:
         """Join a queued/running request to a wire-supplied trace
         context (the LB's ``X-Skytpu-Trace`` hop header). Returns the
         request's effective 128-bit trace id — locally minted when no
         wire context arrived — or None when the request is unknown or
-        telemetry is off. Caller holds the engine lock (same contract
-        as ``add_request``)."""
+        telemetry is off. ``submitted_at`` (wall clock) is when the
+        serve scheduler took the request: its wait there becomes the
+        trace's ``sched_wait`` span. Caller holds the engine lock
+        (same contract as ``add_request``)."""
         for req in list(self._queue) + [r for r in self._slots
                                         if r is not None]:
             if req.request_id == request_id:
                 if req.trace is None:
                     return None
                 req.trace.adopt_wire_context(trace_id, parent_span)
+                if submitted_at is not None:
+                    req.trace.prepend('sched_wait', submitted_at)
                 return req.trace.trace_id
         return None
 
@@ -1787,7 +1796,8 @@ class InferenceEngine(SpeculativeMixin, _EngineBase):
         vm_d = rest.pop(0) if vm_h is not None else None
         prefill = self._get_chunk_prefill(n, chunk_w, kv_bucket, sample)
         chunk_t0 = clock.monotonic()
-        with self._prof.phase('prefill_chunk'), \
+        with self._prof.phase('prefill_chunk', prompts=n, width=chunk_w,
+                              kv_bucket=kv_bucket), \
                 self._prof.jit_key('chunk_prefill',
                                    (n, chunk_w, kv_bucket, sample)):
             first, self.cache = prefill(
@@ -2158,12 +2168,23 @@ class InferenceEngine(SpeculativeMixin, _EngineBase):
                 vm_h[i] = req._vocab_mask
         adp_d = jnp.asarray(adp_h) if adp_h is not None else None
         vm_d = jnp.asarray(vm_h) if vm_h is not None else None
-        with self._prof.phase('prefill_chunk'), \
+        # Queue -> slot happens here, before the dispatch, so that the
+        # whole-prompt prefill is one ``prefill_chunk`` span inside the
+        # ``prefill`` span, as a chunk is in chunked mode.
+        for _, req in batch:
+            self._trace_sched(req)
+        chunk_t0 = clock.monotonic()
+        with self._prof.phase('prefill_chunk', prompts=n, width=bucket), \
                 self._prof.jit_key('prefill', (bucket, n)):
             next_tokens, self.cache = prefill(
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(true_lens), jnp.asarray(slots),
                 adp_d, vm_d)
+        chunk_t1 = clock.monotonic()
+        for _, req in batch:
+            if req.trace is not None:
+                req.trace.add('prefill_chunk', chunk_t0, chunk_t1,
+                              offset=0, tokens=len(req.prompt))
         # Async: reserve the slots NOW (so the next admission wave and
         # _enqueue_decode see them taken) but defer the token readback —
         # the prefill result rides the pipeline and its events surface
@@ -2176,7 +2197,6 @@ class InferenceEngine(SpeculativeMixin, _EngineBase):
         for slot, req in batch:
             self._slots[slot] = req
             self._slot_len[slot] = len(req.prompt)
-            self._trace_sched(req)
         self._meta_dirty = True
         self._pending.append({'kind': 'prefill', 'toks': next_tokens,
                               'batch': [(slot, req, i) for i, (slot, req)
@@ -2247,17 +2267,18 @@ class InferenceEngine(SpeculativeMixin, _EngineBase):
         # Per-substep attribution: one dispatch covers ``horizon``
         # decode substeps (the multi-step amortization the profiler's
         # per_substep_ms split makes visible).
-        self._prof.note_substeps('decode_enqueue', horizon)
-        t0 = clock.monotonic()
+        self._prof.note_substeps('decode_enqueue', horizon,
+                                 live_rows=int(active.sum()))
+        self._prof.tag(horizon=horizon, kv_bucket=kv_bucket)
         with self._prof.jit_key('decode', (horizon, sample, kv_bucket)):
             toks, self.cache = self._decode_fn(
                 self.params, self.cache, self._tok_dev, rng,
                 temps_d, topks_d, topps_d, active_d, self._adp_dev,
                 self._vmask_dev, horizon, sample, kv_bucket)
-        live = int(sum(self._slot_len[s] + self._inflight_steps
-                       for s in range(self.max_batch)
-                       if ready[s] is not None))
-        self._note_decode_step(live, horizon, clock.monotonic() - t0)
+        self._note_decode_step(
+            int(sum(self._slot_len[s] + self._inflight_steps
+                    for s in range(self.max_batch)
+                    if ready[s] is not None)))
         self._tok_dev = toks[:, -1]
         self._inflight_steps += horizon
         self._pending.append({'kind': 'decode', 'toks': toks,
@@ -2289,9 +2310,7 @@ class InferenceEngine(SpeculativeMixin, _EngineBase):
                     events.append(self._evict_nonfinite(slot, req))
                     continue
                 req.first_token_time = now
-                if req.trace is not None:
-                    req.trace.end('prefill')
-                    req.trace.begin('decode')
+                self._trace_first_token(req)
                 req.output.append(token)
                 finished = self._finish_req(slot, req, token)
                 events.append((req.request_id, token, finished))

@@ -1285,9 +1285,16 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 pages_per_block=self.pages_per_block,
                 mlora_idx=adp, vocab_mask=vmask)
 
-        merge = jax.jit(functools.partial(merge_ring_into_pool,
-                                          mesh=self.mesh),
-                        donate_argnums=(0,), **merge_kwargs)
+        # A named function, not a jitted ``functools.partial`` (which
+        # has no name: the program was ``jit__unknown`` in every device
+        # trace): the program is ``jit_merge_ring_into_pool``.
+        mesh = self.mesh
+
+        def merge_ring(cache, ring_k, ring_v, table_p, lengths, active):
+            return merge_ring_into_pool(cache, ring_k, ring_v, table_p,
+                                        lengths, active, mesh=mesh)
+        merge_ring.__name__ = merge_ring_into_pool.__name__
+        merge = jax.jit(merge_ring, donate_argnums=(0,), **merge_kwargs)
 
         def decode_and_merge(params, cache, table_p, tokens, lengths,
                              rng, temps, topks, topps, active, adp,
@@ -1727,9 +1734,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # operand: each separate jnp.asarray is its own dispatch round
         # trip, nine of them per admission otherwise.
         extras = tuple(x for x in (adp_h, vm_h) if x is not None)
-        uploaded = device_upload(
-            (table_p, tokens, lengths, valid, want, temps, topks,
-             topps) + extras)
+        with self._prof.phase('admit_upload'):
+            uploaded = device_upload(
+                (table_p, tokens, lengths, valid, want, temps, topks,
+                 topps) + extras)
         (table_d, tokens_d, lengths_d, valid_d, want_d, temps_d,
          topks_d, topps_d) = uploaded[:8]
         rest = list(uploaded[8:])
@@ -1743,7 +1751,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                      for i, s in enumerate(batch) if want[i] >= 0)
         prefill = self._get_prefill(n, P, sample, chunk_w)
         chunk_t0 = clock.monotonic()
-        with self._prof.phase('prefill_chunk'), \
+        with self._prof.phase('prefill_chunk', prompts=n, pages=P,
+                              width=chunk_w, sample=sample), \
                 self._prof.jit_key('prefill', (n, P, sample, chunk_w)):
             first, self.cache = prefill(
                 self.params, self.cache, table_d, tokens_d, lengths_d,
@@ -1788,9 +1797,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             slots_p = np.full(n, self.max_batch, np.int32)
             for j, (i, slot) in enumerate(done_rows):
                 rows_p[j], slots_p[j] = i, slot
-            rows_d, slots_d = device_upload((rows_p, slots_p))
-            self._tok_dev = self._merge_tokens_drop(
-                self._tok_dev, slots_d, jnp.take(first, rows_d))
+            with self._prof.phase('admit_token_merge'):
+                rows_d, slots_d = device_upload((rows_p, slots_p))
+                self._tok_dev = self._merge_tokens_drop(
+                    self._tok_dev, slots_d, jnp.take(first, rows_d))
             self._meta_dirty = True          # slots become decodable
             self._pending.append({
                 'kind': 'prefill', 'toks': first,
@@ -2505,16 +2515,17 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # Per-substep attribution: one dispatch covers ``horizon``
         # decode substeps (multi-step amortization; the profiler's
         # per_substep_ms split makes it visible).
-        self._prof.note_substeps('decode_enqueue', horizon)
-        t0 = clock.monotonic()
+        self._prof.note_substeps('decode_enqueue', horizon,
+                                 live_rows=len(active_slots))
+        self._prof.tag(horizon=horizon, pages=P)
         with self._prof.jit_key('decode', (horizon, sample, P)):
             toks, self.cache = self._decode_fn(
                 self.params, self.cache, table_dd,
                 self._tok_dev, lengths_dd, rng,
                 temps_d, topks_d, topps_d, active_d, self._adp_dev,
                 self._vmask_dev, horizon, sample)
-        live = int(sum(int(lengths[s]) for s in active_slots))
-        self._note_decode_step(live, horizon, clock.monotonic() - t0)
+        self._note_decode_step(
+            int(sum(int(lengths[s]) for s in active_slots)))
         self._tok_dev = toks[:, -1]
         # Snapshot the epochs BEFORE any early free below bumps them:
         # the entry must record the epochs its tokens were produced
@@ -2567,9 +2578,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                     self._await_first.discard(slot)
                 if req.first_token_time is None:  # not on re-admission
                     req.first_token_time = now
-                if req.trace is not None:
-                    req.trace.end('prefill')
-                    req.trace.begin('decode')
+                self._trace_first_token(req)
                 req.output.append(token)
                 finished = self._finish_req(slot, req, token)
                 events.append((req.request_id, token, finished))

@@ -150,7 +150,7 @@ class ScheduledRequest:
     __slots__ = ('tier', 'prompt', 'max_new_tokens', 'sampling', 'seq',
                  'submit_time', 'admit_time', 'outbox', 'request_id',
                  'result', 'first_token_time', 'cancelled', 'handoff',
-                 'trace_ctx')
+                 'trace_ctx', 'first_flush_time', 'sse_write_s')
 
     def __init__(self, tier: str, prompt: List[int],
                  max_new_tokens: int, sampling: Dict[str, Any],
@@ -167,6 +167,13 @@ class ScheduledRequest:
         self.request_id: Optional[int] = None
         self.result: Optional[Any] = None
         self.first_token_time: Optional[float] = None
+        # Stamped by the ONE handler thread that streams this request
+        # (monotonic): when the flush of the first token's SSE line
+        # returned, and the seconds spent in SSE writes so far. Folded
+        # into the trace and the registry once, when the request is
+        # recorded as finished — never per token.
+        self.first_flush_time: Optional[float] = None
+        self.sse_write_s = 0.0
         self.cancelled = False
         # Wire-supplied trace context ({'trace_id', 'parent_span'}) —
         # the X-Skytpu-Trace hop header this request arrived with. On
@@ -571,9 +578,13 @@ class RequestScheduler:
                 # written back so every downstream hop — KV handoff,
                 # gang op-log, migration legs — carries the same id.
                 ctx = sr.trace_ctx or {}
+                # The wait in the tier queues, submit -> add_request,
+                # becomes the trace's ``sched_wait`` span: the engine's
+                # own ``queue`` span opens only at add_request.
                 tid = engine.adopt_trace_context(
                     rid, trace_id=ctx.get('trace_id'),
-                    parent_span=ctx.get('parent_span'))
+                    parent_span=ctx.get('parent_span'),
+                    submitted_at=sr.submit_time)
                 if tid is not None:
                     sr.trace_ctx = dict(ctx, trace_id=tid)
             if self.on_admit is not None:

@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import contextlib
 import http.server
 import json
 import os
@@ -67,7 +68,9 @@ from skypilot_tpu.serve import faults as faults_lib
 from skypilot_tpu.serve import gang as gang_lib
 from skypilot_tpu.serve import scheduler as scheduler_lib
 from skypilot_tpu.serve import wire
+from skypilot_tpu.telemetry import clock
 from skypilot_tpu.telemetry import device as device_lib
+from skypilot_tpu.telemetry import profiler as profiler_lib
 from skypilot_tpu.telemetry import tracing
 
 logger = tpu_logging.init_logger(__name__)
@@ -276,7 +279,33 @@ class ModelServer:
             'Mean time per output token after the first (ms)')
         self._h_queue_wait = reg.histogram(
             'skytpu_request_queue_wait_ms',
-            'Time from submit to slot assignment (ms)')
+            'Time in the engine queue: add_request to slot '
+            'assignment (ms); the wait before add_request is the '
+            'sched_wait stage')
+        # The time to first token as stages that add up (the spans of
+        # tracing.TTFT_STAGES, folded in when a request is recorded as
+        # finished) — registered here, zeros from the first scrape.
+        self._h_ttft_stage = {
+            stage: reg.histogram(
+                'skytpu_request_ttft_stage_ms',
+                'Time to first token by stage (ms): sched_wait + queue '
+                '+ prefill + first_token_lag + emit_first = submit to '
+                "the first token's flushed SSE line", stage=stage)
+            for stage in tracing.TTFT_STAGES}
+        self._h_sse_write = reg.histogram(
+            'skytpu_request_sse_write_ms',
+            "A streamed request's total time in SSE writes and flushes "
+            '(ms), observed once at its finish')
+        # The engine thread's own account of the engine lock, stamped
+        # around its hold in _engine_loop: against wall time, the share
+        # of it a handler cannot have the engine.
+        self._m_lock_held = reg.counter(
+            'skytpu_engine_lock_held_seconds_total',
+            'Seconds the engine loop held the engine lock (fill + '
+            'step, its blocking readback included)')
+        self._m_lock_wait = reg.counter(
+            'skytpu_engine_lock_wait_seconds_total',
+            'Seconds the engine loop waited to acquire the engine lock')
         self._httpd: Optional[http.server.ThreadingHTTPServer] = None
         self._stopping = False
         self._engine_thread: Optional[threading.Thread] = None
@@ -566,14 +595,16 @@ class ModelServer:
                     # Stale results (a slot turned over meanwhile) are
                     # revalidated and recomputed inside step().
                     self.engine.prepare_proposals()
-                with self._lock:
+                prof = self.engine.profiler
+                with self._engine_lock_timed(prof):
                     # Top the engine up from the scheduler's tier
                     # queues (priority + SRW order, tier budget
                     # split), then step. The scheduler holds the
                     # backlog; the engine queue stays empty, so
                     # admission ORDER is decided here every step, not
                     # at submit time.
-                    self.sched.fill_engine(self.engine)
+                    with prof.phase('fill_engine'):
+                        self.sched.fill_engine(self.engine)
                     # has_runnable_work: a prefill worker whose only
                     # live slots are HELD (awaiting their KV handoff)
                     # parks here instead of spinning — release_hold /
@@ -629,7 +660,8 @@ class ModelServer:
                 # Outbox routing runs OUTSIDE the lock: puts are
                 # lock-free and a slow SSE consumer can never hold the
                 # engine step hostage.
-                self.sched.on_events(self.engine, events)
+                with prof.phase('route_events'):
+                    self.sched.on_events(self.engine, events)
                 # NaN blast-radius escalation: isolated poisoned
                 # requests are evicted per-request above, but repeated
                 # hits mean the REPLICA is sick (bad HBM, corrupted
@@ -659,6 +691,22 @@ class ModelServer:
         if self._error is None:
             self._error = 'server stopped'
         self.sched.fail_all(self._error)
+
+    @contextlib.contextmanager
+    def _engine_lock_timed(self, prof):
+        """The engine thread's hold of the engine lock: the wait for it
+        is the profiler's ``lock_wait`` phase, and wait and hold go on
+        the two cumulative counters (two clock reads a loop turn)."""
+        t_want = clock.monotonic()
+        with prof.phase('lock_wait'):
+            self._lock.acquire()
+        t_have = clock.monotonic()
+        try:
+            yield
+        finally:
+            self._lock.release()
+            self._m_lock_wait.inc(t_have - t_want)
+            self._m_lock_held.inc(clock.monotonic() - t_have)
 
     def _fatal(self, e: Exception) -> None:
         """Engine died: drop readiness (the serve probe then pulls this
@@ -866,7 +914,7 @@ class ModelServer:
             raise RuntimeError(
                 f'engine failed: {sr.outbox.error or self._error}')
         req = sr.result
-        self._record_finished(req)
+        self._record_finished(req, sr)
         hit_eos = (req.eos_id is not None and req.output
                    and req.output[-1] == req.eos_id)
         return {
@@ -916,14 +964,14 @@ class ModelServer:
         slot stops generating tokens nobody will read — and count it
         as aborted, not served."""
         if sr.result is not None:
-            self._record_finished(sr.result)
+            self._record_finished(sr.result, sr)
             return
         if self.sched.cancel(sr):
             self._m_aborted.inc()
         elif sr.result is not None:
             # Finished during the cancel race: cancel() popped the
             # finished request into sr.result instead of aborting.
-            self._record_finished(sr.result)
+            self._record_finished(sr.result, sr)
 
     # ------------------------------------------------------------ handoff
     def handoff_target(self, header_value: Optional[str]
@@ -1356,10 +1404,14 @@ class ModelServer:
             while len(self._completed_keys) > self._max_completed_keys:
                 self._completed_keys.popitem(last=False)
 
-    def _record_finished(self, req) -> None:
+    def _record_finished(self, req, sr) -> None:
         """Fold one finished request into the registry: served counter
         plus the TTFT / TPOT / queue-wait latency decomposition (the
-        queue-wait span comes off the request's telemetry trace)."""
+        queue-wait span comes off the request's telemetry trace), and
+        the stages of its time to first token. ``sr`` is the streamed
+        request's scheduler record: what its handler thread stamped
+        (first flush, SSE write time) is folded in HERE, once, when the
+        engine thread is done with the trace."""
         self._m_served.inc()
         if req.ttft_ms is not None:
             self._h_ttft.observe(req.ttft_ms)
@@ -1369,11 +1421,26 @@ class ModelServer:
             self._h_tpot.observe(
                 (req.finish_time - req.first_token_time) * 1e3
                 / (len(req.output) - 1))
-        trace = tracing.get_trace_buffer().find(req.request_id)
-        if trace is not None:
-            queue_ms = trace.span_ms('queue')
-            if queue_ms is not None:
-                self._h_queue_wait.observe(queue_ms)
+        streamed = sr.first_flush_time is not None
+        if streamed:
+            self._h_sse_write.observe(sr.sse_write_s * 1e3)
+        # By the request's own 128-bit id (written back on admission),
+        # never by the engine-local request id: with telemetry off
+        # that found another request's trace.
+        trace_id = (sr.trace_ctx or {}).get('trace_id')
+        trace = (tracing.get_trace_buffer().find_trace(trace_id)
+                 if trace_id else None)
+        if trace is None:
+            return
+        queue_ms = trace.span_ms('queue')
+        if queue_ms is not None:
+            self._h_queue_wait.observe(queue_ms)
+        surfaced = trace.first_token_at()
+        if streamed and surfaced is not None:
+            trace.add('emit_first', surfaced,
+                      max(surfaced, sr.first_flush_time))
+        for stage, ms in trace.ttft_stages().items():
+            self._h_ttft_stage[stage].observe(ms)
 
     # ----------------------------------------------------------- metrics
     def _update_gauges(self) -> None:
@@ -1516,6 +1583,12 @@ class ModelServer:
             'last_load_ms': 0.0, 'loaded': [], 'pinned': {},
         }
 
+    def _counter_value(self, name: str) -> float:
+        """A registry counter another module owns (0 before it
+        registers: the engine's profiler does at engine load)."""
+        metric = self._reg.get(name)
+        return metric.value if metric is not None else 0.0
+
     def _metrics_json_payload(self) -> Dict[str, Any]:
         """The PR-3 stable-schema JSON gauge block, now sourced from
         the telemetry registry (every key ALWAYS present and numeric;
@@ -1554,6 +1627,26 @@ class ModelServer:
                 self._h_queue_wait.quantile(0.5), 1),
             'queue_wait_ms_p90': round(
                 self._h_queue_wait.quantile(0.9), 1),
+            # The time to first token by stage (stable schema: every
+            # stage present, zeros before traffic), over the
+            # registry's rolling window.
+            'ttft_stages': {
+                stage: {'p50': round(h.quantile(0.5), 3),
+                        'p95': round(h.quantile(0.95), 3),
+                        'n': h.window_len}
+                for stage, h in self._h_ttft_stage.items()},
+            # Cumulative counters of the engine loop, and the monotonic
+            # clock they were read on: a scraper takes shares and
+            # means from the difference of two scrapes.
+            'engine_loop': {
+                'clock_s': clock.monotonic(),
+                'lock_held_seconds_total': self._m_lock_held.value,
+                'lock_wait_seconds_total': self._m_lock_wait.value,
+                'decode_substeps_total': self._counter_value(
+                    profiler_lib.SUBSTEP_METRIC),
+                'decode_live_rows_total': self._counter_value(
+                    profiler_lib.LIVE_ROWS_METRIC),
+            },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),
             'spec_accept_rate': round(
@@ -1957,6 +2050,18 @@ class ModelServer:
                       'failed_upstream': ho['target'],
                       'tokens_so_far': list(tokens)})
 
+            def _sse_token(self, sr, line: bytes) -> None:
+                """Write and flush one token's SSE line, on the
+                request's own tally: two clock reads, no lock and no
+                registry call (folded in once, at the finish)."""
+                t0 = clock.monotonic()
+                self.wfile.write(line)
+                self.wfile.flush()
+                t1 = clock.monotonic()
+                sr.sse_write_s += t1 - t0
+                if sr.first_flush_time is None:
+                    sr.first_flush_time = t1
+
             def _stream_loop(self, sr, tokens, is_text, tok,
                              key=None, pre=None) -> None:
                 pending = [] if pre is None else [pre]
@@ -1978,9 +2083,8 @@ class ModelServer:
                     event = {'token': int(token)}
                     if is_text:
                         event['text'] = sanitize_text(tok.decode([int(token)]))
-                    self.wfile.write(
-                        f'data: {json.dumps(event)}\n\n'.encode())
-                    self.wfile.flush()
+                    self._sse_token(
+                        sr, f'data: {json.dumps(event)}\n\n'.encode())
                     if finished:
                         done = {'done': True,
                                 'request_id': sr.request_id,
@@ -2190,7 +2294,9 @@ class ModelServer:
                         else:
                             choice = {'index': 0, 'text': piece,
                                       'finish_reason': None}
-                        emit(json.dumps(chunk_of(choice)))
+                        self._sse_token(
+                            sr, ('data: ' + json.dumps(chunk_of(choice))
+                                 + '\n\n').encode())
                         if finished:
                             # Terminal chunk: empty delta/text with the
                             # real finish_reason, then [DONE] — the

@@ -24,6 +24,14 @@ per-engine latency decomposition) and into the process registry
 (``skypilot_tpu_engine_step_phase_seconds{phase=...}`` — the
 ``/metrics`` surface). :class:`NullProfiler` is the telemetry-off
 no-op twin with the same API.
+
+One timeline with the device: while a ``jax.profiler`` trace of the
+process runs, every phase (and every first call per jit key) is also a
+``jax.profiler.TraceAnnotation`` named ``skytpu:<phase>``, so it lands
+on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the device
+operations, in the same nanoseconds. While no trace runs, nothing is
+constructed: one read of the profiler's flag, cheaper than the clock
+pair beside it.
 """
 from __future__ import annotations
 
@@ -37,6 +45,8 @@ from skypilot_tpu.telemetry import registry as registry_lib
 PHASE_METRIC = 'skytpu_engine_step_phase_seconds'
 COMPILE_METRIC = 'skytpu_jit_first_call_seconds'
 SUBSTEP_METRIC = 'skytpu_engine_decode_substeps_total'
+LIVE_ROWS_METRIC = 'skytpu_engine_decode_live_rows_total'
+ANNOTATION_PREFIX = 'skytpu:'
 
 
 class NullProfiler:
@@ -45,8 +55,8 @@ class NullProfiler:
     compile_events: List[Dict[str, Any]] = []
 
     @contextlib.contextmanager
-    def phase(self, name: str):
-        del name
+    def phase(self, name: str, **key: Any):
+        del name, key
         yield
 
     @contextlib.contextmanager
@@ -54,8 +64,11 @@ class NullProfiler:
         del fn, key
         yield
 
-    def note_substeps(self, name: str, n: int) -> None:
-        del name, n
+    def tag(self, **key: Any) -> None:
+        del key
+
+    def note_substeps(self, name: str, n: int, live_rows: int = 0) -> None:
+        del name, n, live_rows
 
     def phase_stats(self) -> Dict[str, Any]:
         return {}
@@ -85,9 +98,21 @@ class StepProfiler:
             SUBSTEP_METRIC,
             'Device decode substeps covered by enqueued dispatches '
             '(k per call under multi-step decode)')
+        self._live_rows_counter = self._reg.counter(
+            LIVE_ROWS_METRIC,
+            'Batch rows that carried a request, summed over the decode '
+            'substeps of enqueued dispatches (over the substeps '
+            'counter: the mean live batch of a step)')
         self._hists: Dict[str, registry_lib.Histogram] = {}
         self._seen_keys: Dict[str, set] = {}
         self.compile_events: List[Dict[str, Any]] = []
+        # Bound here, not at import: the control plane imports
+        # ``telemetry`` without JAX; only an engine builds a profiler.
+        from jax import profiler as jax_profiler
+        self._annotation = jax_profiler.TraceAnnotation
+        # The annotations now open, innermost last (engine thread only;
+        # empty while no trace runs).
+        self._open: List[Any] = []
 
     def _phase_hist(self, name: str) -> registry_lib.Histogram:
         hist = self._hists.get(name)
@@ -101,13 +126,32 @@ class StepProfiler:
             self._hists[name] = hist
         return hist
 
+    def _annotate(self, name: str, key: Dict[str, Any]) -> bool:
+        """Open ``skytpu:<name>`` on the running ``jax.profiler`` trace;
+        False, with nothing constructed, while none runs."""
+        if not self._annotation.is_enabled():
+            return False
+        note = self._annotation(ANNOTATION_PREFIX + name, **key)
+        note.__enter__()
+        self._open.append(note)
+        return True
+
+    def tag(self, **key: Any) -> None:
+        """Attach a program key (prompts, pages, horizon...) that is
+        only known inside a phase to that phase's annotation."""
+        if self._open:
+            self._open[-1].set_metadata(**key)
+
     @contextlib.contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, **key: Any):
+        annotated = self._annotate(name, key)
         t0 = clock.monotonic()
         try:
             yield
         finally:
             dt = clock.monotonic() - t0
+            if annotated:
+                self._open.pop().__exit__(None, None, None)
             self._phase_hist(name).observe(dt)
             with self._lock:
                 acc = self._acc.setdefault(name, [0, 0.0, 0.0])
@@ -123,11 +167,15 @@ class StepProfiler:
         if key in seen:
             yield
             return
+        annotated = self._annotate('first_call',
+                                   {'fn': fn, 'key': repr(key)})
         t0 = clock.monotonic()
         try:
             yield
         finally:
             dt = clock.monotonic() - t0
+            if annotated:
+                self._open.pop().__exit__(None, None, None)
             seen.add(key)
             self._reg.histogram(
                 COMPILE_METRIC,
@@ -140,13 +188,15 @@ class StepProfiler:
                     {'fn': fn, 'key': repr(key),
                      'seconds': round(dt, 6)})
 
-    def note_substeps(self, name: str, n: int) -> None:
+    def note_substeps(self, name: str, n: int, live_rows: int = 0) -> None:
         """Record that the NEXT/current ``name`` dispatch covers ``n``
-        device substeps (multi-step decode's per-substep attribution).
-        Host-side counter bump only — nothing touches the device."""
+        device substeps (multi-step decode's per-substep attribution),
+        ``live_rows`` of its batch rows carrying a request. Host-side
+        counter bumps only — nothing touches the device."""
         if n <= 0:
             return
         self._substep_counter.inc(n)
+        self._live_rows_counter.inc(n * live_rows)
         with self._lock:
             self._substeps[name] = self._substeps.get(name, 0) + n
 

@@ -2,12 +2,18 @@
 
 A :class:`RequestTrace` is minted when a request enters an engine
 (``add_request``) and carried on the ``Request`` object through its
-whole life: queue-wait → prefill (one span per chunk in chunked mode) →
-decode → speculative propose/verify rounds → finish or cancel. Spans
-are HOST-DISPATCH-ALIGNED: a span covers the host-side time of the
-stage (the device executes asynchronously behind the dispatch
-pipeline), which is exactly the latency a client observes and what the
-"where did this request's latency go" question needs.
+whole life: scheduler wait (stamped by the serve scheduler, which held
+the request before the engine saw it) → queue-wait → prefill (one span
+per chunk in chunked mode) → first-token lag (the async pipeline
+between the last chunk's dispatch and the token's readback) → first
+emit (the handler's flush of that token's SSE line) → decode →
+speculative propose/verify rounds → finish or cancel. Spans are
+HOST-DISPATCH-ALIGNED: a span covers the host-side time of the stage
+(the device executes asynchronously behind the dispatch pipeline),
+which is exactly the latency a client observes and what the "where did
+this request's latency go" question needs. The first five are the
+stages of the time to first token (:data:`TTFT_STAGES`): contiguous,
+each stamped where it happens, so they add up to submit → first flush.
 
 Completed traces land in a bounded ring buffer (:class:`TraceBuffer`,
 default 256 — a long-lived replica keeps CURRENT traffic, memory
@@ -22,7 +28,6 @@ threads) is locked.
 from __future__ import annotations
 
 import collections
-import itertools
 import os
 import re
 import threading
@@ -32,7 +37,9 @@ from skypilot_tpu.telemetry import clock
 
 DEFAULT_BUFFER = int(os.environ.get('SKYTPU_TRACE_BUFFER', '256'))
 
-_trace_seq = itertools.count(1)
+# The time to first token, in the order a request passes through them.
+TTFT_STAGES = ('sched_wait', 'queue', 'prefill', 'first_token_lag',
+               'emit_first')
 
 # ------------------------------------------------- cross-process trace ids
 # The wire header every skytpu process propagates on outbound hops
@@ -107,11 +114,6 @@ class RequestTrace:
                  trace_id: Optional[str] = None,
                  parent_span: Optional[str] = None):
         self.request_id = request_id
-        # The process-local id survives one release as ``legacy_id``
-        # (pids recycle across replica restarts, so it is NOT unique
-        # fleet-wide — the controller keys its trace store by the
-        # 128-bit ``trace_id`` only).
-        self.legacy_id = f'{os.getpid():x}-{next(_trace_seq):x}'
         self.trace_id = trace_id or mint_trace_id()
         self.parent_span = parent_span
         self.t0 = clock.monotonic()
@@ -136,13 +138,34 @@ class RequestTrace:
         self.spans.append(span)
         return span
 
-    def end(self, name: str) -> None:
+    def end(self, name: str, at: Optional[float] = None) -> None:
         """Close the most recent still-open span named ``name``
-        (no-op when none is open — re-admission paths may re-begin)."""
+        (no-op when none is open — re-admission paths may re-begin),
+        now or at the monotonic time ``at``."""
         for span in reversed(self.spans):
             if span.name == name and span.t1 is None:
-                span.t1 = clock.monotonic()
+                span.t1 = clock.monotonic() if at is None else at
                 return
+
+    def last_end(self, name: str) -> Optional[float]:
+        """Monotonic end of the most recent completed span ``name``."""
+        for span in reversed(self.spans):
+            if span.name == name and span.t1 is not None:
+                return span.t1
+        return None
+
+    def prepend(self, name: str, wall_start: float, **meta: Any) -> None:
+        """Record a stage that ENDED where this trace begins and began
+        at the wall-clock time ``wall_start`` (the scheduler held the
+        request before the engine minted the trace). The trace's
+        origin moves back to it, so ``submitted_at`` is the request's
+        real submission and every ``start_ms`` stays non-negative."""
+        t1 = self.t0
+        self.t0 -= max(0.0, self.wall0 - wall_start)
+        self.wall0 = min(self.wall0, wall_start)
+        span = Span(name, self.t0, self.wall0, meta or None)
+        span.t1 = t1
+        self.spans.insert(0, span)
 
     def add(self, name: str, t0: float, t1: float, **meta: Any) -> Span:
         """Record a pre-timed span (monotonic endpoints)."""
@@ -175,6 +198,29 @@ class RequestTrace:
                 return span.dur_ms
         return None
 
+    def first_token_at(self) -> Optional[float]:
+        """Monotonic time the first token was read back: the end of
+        the first ``first_token_lag`` span (a re-admission after a
+        preemption records a later one)."""
+        return next((s.t1 for s in self.spans
+                     if s.name == 'first_token_lag'), None)
+
+    def ttft_stages(self) -> Dict[str, float]:
+        """Milliseconds in each of :data:`TTFT_STAGES` this trace holds,
+        up to the first token ({} when none surfaced). A request
+        preempted before its first token queues and prefills twice: a
+        stage sums every span of its name that began before the first
+        token was read back."""
+        t_first = self.first_token_at()
+        if t_first is None:
+            return {}
+        out: Dict[str, float] = {}
+        for span in self.spans:
+            if span.name in TTFT_STAGES and span.t1 is not None and (
+                    span.t0 < t_first or span.name == 'emit_first'):
+                out[span.name] = out.get(span.name, 0.0) + span.dur_ms
+        return out
+
     def to_dict(self) -> Dict[str, Any]:
         spans = []
         for span in self.spans:
@@ -188,7 +234,6 @@ class RequestTrace:
                 d['meta'] = dict(span.meta)
             spans.append(d)
         d = {'trace_id': self.trace_id,
-             'legacy_id': self.legacy_id,
              'request_id': self.request_id,
              'submitted_at': self.wall0,
              'done': self.done,
@@ -267,10 +312,10 @@ class TraceBuffer:
         return None
 
     def find_trace(self, trace_id: str) -> Optional[RequestTrace]:
-        """Lookup by 128-bit trace id (or, for one release, the old
-        ``pid-seq`` legacy id)."""
+        """Lookup by 128-bit trace id, newest first (a retried leg
+        shares its id with the leg before it)."""
         for t in reversed(self.snapshot()):
-            if t.trace_id == trace_id or t.legacy_id == trace_id:
+            if t.trace_id == trace_id:
                 return t
         return None
 

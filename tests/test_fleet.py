@@ -437,9 +437,8 @@ def test_trace_header_roundtrip_and_garbage():
         'parent_span'] is None
 
 
-def test_request_trace_keeps_legacy_id_and_adopts_wire_context():
+def test_request_trace_adopts_wire_context():
     trace = tracing.RequestTrace(9)
-    assert trace.legacy_id and '-' in trace.legacy_id
     original = trace.trace_id
     assert len(original) == 32
     trace.adopt_wire_context(trace_id='ab' * 16,
@@ -449,7 +448,7 @@ def test_request_trace_keeps_legacy_id_and_adopts_wire_context():
     trace.finish()
     d = trace.to_dict()
     assert d['trace_id'] == 'ab' * 16
-    assert d['legacy_id'] == trace.legacy_id
+    assert 'legacy_id' not in d
     assert d['parent_span'] == 'lb.dispatch'
 
 

@@ -367,9 +367,8 @@ def test_step_phase_profiler_and_compile_events():
 
 def test_kv_round2_series_registered_at_construction():
     """KV-round-two stable schema: constructing an engine alone puts
-    the KV read-traffic gauge and BOTH attention-impl attribution
-    series in the registry — zeros from the first scrape, before any
-    decode dispatch."""
+    the KV read-traffic gauge in the registry — zero from the first
+    scrape, before any decode dispatch."""
     from skypilot_tpu import telemetry
     from skypilot_tpu.inference.engine import InferenceEngine
     from skypilot_tpu.models import configs
@@ -382,17 +381,17 @@ def test_kv_round2_series_registered_at_construction():
         registry_lib.reset_registry()
     assert '# TYPE skytpu_kv_read_bytes_per_step gauge' in prom
     assert 'skytpu_kv_read_bytes_per_step 0' in prom
-    assert '# TYPE skytpu_attn_kernel_ms gauge' in prom
-    for impl in ('per_layer', 'cross_layer'):
-        assert f'skytpu_attn_kernel_ms{{impl="{impl}"}} 0' in prom, impl
+    # The live-rows counter sits beside the substeps counter from the
+    # first scrape too.
+    assert 'skytpu_engine_decode_live_rows_total 0' in prom
+    assert 'skytpu_engine_decode_substeps_total 0' in prom
 
 
 @pytest.mark.parametrize('kind', ['slot', 'paged'])
 def test_kv_round2_series_updated_by_decode(kind):
     """After decode traffic the KV read gauge carries live-context x
-    per-token bytes and exactly the attention impl that served the
-    dispatches is non-zero (per_layer here — the slot engine has no
-    cross-layer path and the paged engine defaults off it on CPU)."""
+    per-token bytes, and live rows / substeps is the mean live batch
+    of a step (one request in two slots: exactly 1)."""
     from skypilot_tpu import telemetry
     from skypilot_tpu.inference.engine import kv_token_bytes
     from skypilot_tpu.models import configs
@@ -410,37 +409,13 @@ def test_kv_round2_series_updated_by_decode(kind):
         eng.run_to_completion(horizon=4)
         reg = telemetry.get_registry()
         kv_gauge = reg.get('skytpu_kv_read_bytes_per_step')
-        per_layer = reg.get('skytpu_attn_kernel_ms', impl='per_layer')
-        cross = reg.get('skytpu_attn_kernel_ms', impl='cross_layer')
         assert kv_gauge is not None and kv_gauge.value > 0
         # live context x per-token stored cost: bounded by the full
         # sequence capacity of the whole batch.
         assert kv_gauge.value <= kv_token_bytes(cfg, None) * 2 * 64
-        assert per_layer is not None and per_layer.value > 0
-        assert cross is not None and cross.value == 0
-    finally:
-        registry_lib.reset_registry()
-
-
-def test_kv_round2_cross_layer_attribution():
-    """decode_impl='cross_layer' routes the wall-time attribution to
-    the cross_layer series — the per_layer series stays zero."""
-    from skypilot_tpu import telemetry
-    from skypilot_tpu.inference.paged import PagedInferenceEngine
-    from skypilot_tpu.models import configs
-    registry_lib.reset_registry()
-    try:
-        eng = PagedInferenceEngine(configs.get_config('tiny'),
-                                   max_batch=2, max_seq=64,
-                                   decode_impl='cross_layer')
-        eng.add_request([1, 2, 3, 4, 5], max_new_tokens=4)
-        eng.run_to_completion(horizon=4)
-        reg = telemetry.get_registry()
-        assert reg.get('skytpu_attn_kernel_ms',
-                       impl='cross_layer').value > 0
-        assert reg.get('skytpu_attn_kernel_ms',
-                       impl='per_layer').value == 0
-        assert reg.get('skytpu_kv_read_bytes_per_step').value > 0
+        substeps = reg.get('skytpu_engine_decode_substeps_total').value
+        rows = reg.get('skytpu_engine_decode_live_rows_total').value
+        assert substeps > 0 and rows == substeps
     finally:
         registry_lib.reset_registry()
 
@@ -826,3 +801,314 @@ def test_server_prometheus_metrics_and_debug_requests():
         assert ours['done']
     finally:
         server.stop()
+
+
+# ---------------------------------------------------------------------------
+# One timeline: engine-loop phases on the device trace's clock, and the
+# time to first token as stages that add up
+# ---------------------------------------------------------------------------
+def _profile(trace_dir, body):
+    """Run ``body`` under a jax.profiler trace (host and device events,
+    no Python tracer: what the benchmark's ``--trace 1`` records);
+    returns (plane name, line name, event name, stats) of every event."""
+    import glob
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    found = sorted(glob.glob(str(
+        trace_dir / 'plugins' / 'profile' / '*' / '*.xplane.pb')))
+    data = jax.profiler.ProfileData.from_file(found[-1])
+    return [(p.name, ln.name, e.name, dict(e.stats))
+            for p in data.planes for ln in p.lines for e in ln.events]
+
+
+def _tiny_paged():
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
+    from skypilot_tpu.models import configs
+    return PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                                max_seq=64, prefill_chunk_tokens=8)
+
+
+def test_profiled_engine_phases_on_the_trace_clock(tmp_path):
+    """A profiled engine run holds the profiler's phases as ``skytpu:``
+    events on the host plane of the SAME trace as the device
+    operations, the chunk and decode dispatches with their program
+    keys, and the ring merge under its own program name."""
+    eng = _tiny_paged()
+    eng.add_request([1, 2, 3] * 7, max_new_tokens=5)
+    eng.run_to_completion(horizon=4)            # compile outside
+
+    def body():
+        eng.add_request([4, 5, 6] * 7, max_new_tokens=5)
+        eng.run_to_completion(horizon=4)
+
+    events = _profile(tmp_path, body)
+    host = [(name, stats) for plane, _, name, stats in events
+            if plane.startswith('/host:')]
+    names = {name for name, _ in host}
+    for phase in ('admit', 'admit_upload', 'admit_token_merge',
+                  'prefill_chunk', 'decode_enqueue', 'readback'):
+        assert f'skytpu:{phase}' in names, (phase, sorted(
+            n for n in names if n.startswith('skytpu:')))
+    chunk = next(st for n, st in host if n == 'skytpu:prefill_chunk')
+    assert chunk['prompts'] == 1 and chunk['width'] == 8 \
+        and chunk['pages'] >= 1
+    # (a decode_enqueue that found no slot to decode carries no key)
+    decode = next(st for n, st in host
+                  if n == 'skytpu:decode_enqueue' and st)
+    assert decode['horizon'] == 4 and decode['pages'] >= 1
+    # The programs the device ran, by the name their operations carry.
+    modules = {st['hlo_module'] for _, _, _, st in events
+               if 'hlo_module' in st}
+    assert 'jit_merge_ring_into_pool' in modules, modules
+    assert 'jit_decode_steps' in modules and 'jit_prefill' in modules
+    assert not any('unknown' in m for m in modules), modules
+    # No trace running: the same phases construct no annotation.
+    assert not eng.profiler._open
+
+
+def test_annotation_costs_nothing_without_a_trace(monkeypatch):
+    """While no jax.profiler trace runs, a phase reads the profiler's
+    flag and constructs nothing (the jaxpr audit's ``telemetry`` preset
+    proves the rest: no sync, no transfer, no compile)."""
+    from skypilot_tpu.telemetry import profiler as profiler_lib
+    prof = profiler_lib.StepProfiler(
+        engine='t', registry=registry_lib.MetricsRegistry())
+
+    class Boom:
+        built = 0
+
+        def __init__(self, *a, **kw):
+            Boom.built += 1
+
+        @staticmethod
+        def is_enabled():
+            return False
+
+    monkeypatch.setattr(prof, '_annotation', Boom)
+    with prof.phase('admit'), prof.phase('prefill_chunk', prompts=2):
+        prof.tag(pages=4)
+    with prof.jit_key('decode', (8, False, 1)):
+        pass
+    assert Boom.built == 0 and not prof._open
+    assert set(prof.phase_stats()['phases']) == {'admit', 'prefill_chunk'}
+
+
+def test_request_trace_stage_arithmetic():
+    """``prepend`` moves the trace's origin back to the scheduler's
+    submit; ``ttft_stages`` sums a preempted request's repeated queue
+    and prefill spans, and stops at the first token."""
+    trace = tracing.RequestTrace(3)
+    wall_submit = trace.wall0 - 0.25
+    t_engine = trace.t0
+    trace.prepend('sched_wait', wall_submit)
+    assert trace.wall0 == wall_submit
+    assert abs((t_engine - trace.t0) - 0.25) < 1e-9
+    t = t_engine
+    trace.add('queue', t, t + 0.010)
+    trace.add('prefill', t + 0.010, t + 0.030)          # preempted
+    trace.add('queue', t + 0.030, t + 0.050, preempted=True)
+    trace.add('prefill', t + 0.050, t + 0.100)
+    trace.add('prefill_chunk', t + 0.060, t + 0.100)
+    trace.add('first_token_lag', t + 0.100, t + 0.300)
+    trace.add('queue', t + 0.400, t + 0.500, preempted=True)   # later
+    trace.add('first_token_lag', t + 0.600, t + 0.700)  # re-admission
+    trace.add('emit_first', t + 0.300, t + 0.302)
+    stages = trace.ttft_stages()
+    assert list(stages) == list(tracing.TTFT_STAGES)
+    want = {'sched_wait': 250.0, 'queue': 30.0, 'prefill': 70.0,
+            'first_token_lag': 200.0, 'emit_first': 2.0}
+    for stage, ms in want.items():
+        assert abs(stages[stage] - ms) < 1e-6, (stage, stages)
+    assert abs(sum(stages.values()) - 552.0) < 1e-6
+    d = trace.to_dict()
+    assert d['spans'][0]['name'] == 'sched_wait'
+    assert d['spans'][0]['start_ms'] == 0.0
+    assert d['submitted_at'] == wall_submit
+    # No first token, no stages.
+    assert tracing.RequestTrace(4).ttft_stages() == {}
+
+
+def _stream_generate(port, prompt, max_new_tokens):
+    """POST /generate streamed; (events, seconds from send to the
+    first SSE line)."""
+    import time
+    body = json.dumps({'prompt': prompt, 'stream': True,
+                       'max_new_tokens': max_new_tokens}).encode()
+    req = urllib.request.Request(
+        f'http://127.0.0.1:{port}/generate', data=body,
+        headers={'Content-Type': 'application/json'})
+    t0, first, events = time.monotonic(), None, []
+    with urllib.request.urlopen(req, timeout=120) as r:
+        for raw in r:
+            if raw.startswith(b'data:'):
+                if first is None:
+                    first = time.monotonic() - t0
+                events.append(json.loads(raw[5:]))
+    return events, first
+
+
+def _get_json(port, path):
+    with urllib.request.urlopen(
+            f'http://127.0.0.1:{port}{path}', timeout=10) as r:
+        return json.loads(r.read())
+
+
+@pytest.fixture(scope='module')
+def stage_server():
+    """One ModelServer on a fresh registry for the stage tests; what
+    ``/metrics?format=json`` said before any traffic rides along."""
+    from skypilot_tpu.serve.server import ModelServer
+    from skypilot_tpu.utils import common_utils
+    registry_lib.reset_registry()
+    port = common_utils.find_free_port(18940)
+    server = ModelServer('tiny', max_batch=2, max_seq=64, port=port)
+    recorded = []
+    finish_stream = server.finish_stream
+
+    def finish_and_keep(sr):
+        finish_stream(sr)
+        recorded.append(sr)
+
+    server.finish_stream = finish_and_keep
+    server.start(block=False)
+    try:
+        _wait_ready(port)
+        yield server, port, _get_json(port, '/metrics?format=json'), \
+            recorded
+    finally:
+        server.stop()
+        registry_lib.reset_registry()
+
+
+def test_ttft_stages_present_and_zero_before_traffic(stage_server):
+    _, _, before, _ = stage_server
+    assert list(before['ttft_stages']) == list(tracing.TTFT_STAGES)
+    for stage, block in before['ttft_stages'].items():
+        assert block == {'p50': 0.0, 'p95': 0.0, 'n': 0}, stage
+    loop = before['engine_loop']
+    assert set(loop) == {'clock_s', 'lock_held_seconds_total',
+                         'lock_wait_seconds_total',
+                         'decode_substeps_total',
+                         'decode_live_rows_total'}
+    assert all(isinstance(v, (int, float)) for v in loop.values())
+    # The boot's warm-up request ran on the engine directly: substeps
+    # are counted, but no engine-loop turn has taken the lock yet.
+    assert loop['lock_held_seconds_total'] == 0.0
+    assert loop['decode_substeps_total'] > 0
+
+
+def test_sse_request_ttft_stages_add_up(stage_server):
+    """A request served over SSE carries all five stage spans on one
+    trace, in order and not overlapping, and they add up to what they
+    claim to split: first flush - submit."""
+    server, port, before, recorded = stage_server
+    events, client_first_s = _stream_generate(
+        port, [3, 1, 4, 1, 5] * 4, 6)
+    done = events[-1]
+    assert done['done'] and len(done['tokens']) == 6
+    import time
+    deadline = time.time() + 10
+    while not recorded and time.time() < deadline:
+        time.sleep(0.01)                 # the handler's finally clause
+    sr = recorded[-1]
+    traces = _get_json(port, '/debug/requests?limit=8')['requests']
+    ours = next(t for t in traces
+                if t['request_id'] == done['request_id'])
+    assert len(ours['trace_id']) == 32
+    spans = {s['name']: s for s in ours['spans']
+             if s['name'] in tracing.TTFT_STAGES}
+    assert set(spans) == set(tracing.TTFT_STAGES)
+    ordered = [spans[name] for name in tracing.TTFT_STAGES]
+    assert ordered[0]['start_ms'] == 0.0
+    for a, b in zip(ordered, ordered[1:]):
+        end = a['start_ms'] + a['dur_ms']
+        # contiguous: no overlap, and no hole beyond two clock reads
+        # (a thread switch between them, at worst)
+        assert -0.05 <= b['start_ms'] - end <= 20.0, (a, b)
+    total = sum(s['dur_ms'] for s in ordered)
+    trace = tracing.get_trace_buffer().find(done['request_id'])
+    flush_minus_submit = (sr.first_flush_time - trace.t0) * 1e3
+    assert abs(total - flush_minus_submit) <= 0.1 * flush_minus_submit
+    # The client saw its first line no earlier than the flush returned
+    # less the connection's own set-up, which no stage claims.
+    assert total <= client_first_s * 1e3 + 1.0
+    # The span list's lifecycle order still holds with the new stages.
+    names = [s['name'] for s in ours['spans']]
+    assert names.index('sched_wait') < names.index('queue') \
+        < names.index('prefill') < names.index('first_token_lag') \
+        < names.index('decode')
+    after = _get_json(port, '/metrics?format=json')
+    for stage in tracing.TTFT_STAGES:
+        assert after['ttft_stages'][stage]['n'] == 1, stage
+        assert abs(after['ttft_stages'][stage]['p50']
+                   - spans[stage]['dur_ms']) < 0.01, stage
+    loop0, loop1 = before['engine_loop'], after['engine_loop']
+    held = (loop1['lock_held_seconds_total']
+            - loop0['lock_held_seconds_total'])
+    assert 0 < held <= loop1['clock_s'] - loop0['clock_s']
+    rows = (loop1['decode_live_rows_total']
+            - loop0['decode_live_rows_total'])
+    substeps = (loop1['decode_substeps_total']
+                - loop0['decode_substeps_total'])
+    assert substeps > 0 and rows == substeps    # one request live
+
+
+def test_stage_and_loop_series_in_prometheus_text(stage_server):
+    _, port, _, _ = stage_server
+    with urllib.request.urlopen(
+            f'http://127.0.0.1:{port}/metrics', timeout=10) as r:
+        prom = r.read().decode()
+    for needle in ('# TYPE skytpu_request_ttft_stage_ms histogram',
+                   '# TYPE skytpu_request_sse_write_ms histogram',
+                   '# TYPE skytpu_engine_lock_held_seconds_total counter',
+                   '# TYPE skytpu_engine_lock_wait_seconds_total counter',
+                   '# TYPE skytpu_engine_decode_live_rows_total counter',
+                   'phase="lock_wait"', 'phase="fill_engine"',
+                   'phase="route_events"'):
+        assert needle in prom, needle
+    for stage in tracing.TTFT_STAGES:
+        assert ('skytpu_request_ttft_stage_ms_bucket'
+                f'{{le="+Inf",stage="{stage}"}}') in prom, stage
+    assert 'skytpu_attn_kernel_ms' not in prom
+
+
+def test_telemetry_off_no_annotation_no_stage(monkeypatch, tmp_path):
+    """``SKYTPU_TELEMETRY=0``: a profiled request through the server
+    enters no annotation, mints no trace and stamps no stage."""
+    from skypilot_tpu.serve.server import ModelServer
+    from skypilot_tpu.telemetry import profiler as profiler_lib
+    from skypilot_tpu.utils import common_utils
+    monkeypatch.setenv('SKYTPU_TELEMETRY', '0')
+    registry_lib.reset_registry()
+    port = common_utils.find_free_port(18960)
+    server = ModelServer('tiny', max_batch=2, max_seq=64, port=port)
+    server.start(block=False)
+    try:
+        _wait_ready(port)
+        assert isinstance(server.engine.profiler,
+                          profiler_lib.NullProfiler)
+        traces_before = len(tracing.get_trace_buffer())
+        out = {}
+
+        def body():
+            out['events'], _ = _stream_generate(
+                port, [2, 7, 1, 8] * 4, 4)
+
+        events = _profile(tmp_path, body)
+        assert out['events'][-1]['done']
+        assert not [name for _, _, name, _ in events
+                    if name.startswith('skytpu:')]
+        assert len(tracing.get_trace_buffer()) == traces_before
+        m = _get_json(port, '/metrics?format=json')
+        assert all(block['n'] == 0
+                   for block in m['ttft_stages'].values())
+        assert m['requests_served'] == 1
+    finally:
+        server.stop()
+        registry_lib.reset_registry()
