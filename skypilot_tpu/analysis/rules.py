@@ -128,15 +128,17 @@ TPU hot-path hygiene (GC2xx), applied to the compute layer
   journal; a write it didn't see is state it cannot rebuild.
 - **GC121 per-layer-pool-read** — a per-layer pool slice
   (``lax.dynamic_index_in_dim`` over a ``[L, ...]`` KV pool, or a
-  scalar layer subscript) or a ``_gather_layer`` call inside a
-  decode-scoped function in ``inference/``. The paged decode path is
-  KV-bandwidth-bound: slicing the stacked pool makes XLA materialize
-  that layer's whole pool as a fresh operand, and gather-per-layer
-  materializes a full KV copy per layer per step — exactly the
-  traffic the paged-attention kernels (scalar-prefetch layer index,
-  cross-layer fused variant) exist to avoid. Decode code hands the
-  FULL stacked pool to the kernels; prefill/verify-shaped functions
-  (compute-bound, need contiguous rows) are exempt.
+  scalar layer subscript) in ANY function of ``inference/``, or a
+  ``_gather_layer`` call inside a decode-scoped one. Slicing the
+  stacked pool makes XLA materialize that layer's whole pool as a
+  fresh operand — a read of the entire KV pool per program, growing
+  with the pool and not with the work (decode: the traffic the
+  paged-attention kernels exist to avoid; prefill: 19 % of the 7B
+  chunk program before PR 28). Consumers take the FULL stacked pool
+  and the layer as an index (the kernels' scalar prefetch,
+  ``_gather_layer``'s flat gather). Prefill/verify-shaped functions
+  may call ``_gather_layer`` (they need contiguous rows); decode
+  reads go through the kernels.
 - **GC122 unbounded-lb-map-growth** — a growth mutation on a
   ``self.*`` container (``self.x[k] = v``, ``.append``, ``.add``,
   ``.setdefault``, ``.update``, ...) in
@@ -255,13 +257,14 @@ RULES: Dict[str, str] = {
              'reconciliation is only sound if the journal can never '
              'drift from what the state machines actually did',
     'GC121': 'per-layer-pool-read: per-layer KV-pool slice '
-             '(dynamic_index_in_dim / scalar layer subscript) or '
-             '_gather_layer call in a decode-scoped inference '
-             'function — the paged decode read goes through the '
-             'paged-attention kernels (scalar-prefetch layer index, '
-             'or the cross-layer fused kernel), never a materialized '
-             'per-layer pool copy; prefill/verify-shaped functions '
-             'are exempt (compute-bound, need contiguous rows)',
+             '(dynamic_index_in_dim / scalar layer subscript) in any '
+             'inference function, or a _gather_layer call in a '
+             'decode-scoped one — consumers take the FULL stacked '
+             'pool and the layer as an index (paged-attention '
+             'kernels: scalar prefetch; _gather_layer: flat gather), '
+             'never a materialized per-layer pool copy; '
+             'prefill/verify-shaped functions may call _gather_layer '
+             '(they need contiguous rows)',
     'GC122': 'unbounded-lb-map-growth: growth mutation on a self.* '
              'container (subscript-assign / append / add / setdefault '
              '/ update / ...) in serve/load_balancing_policies.py '
@@ -312,16 +315,17 @@ _INT4_DTYPE_STRINGS = {'int4', 'uint4'}
 _NIBBLE_SCOPE_MARKERS = ('quantize', 'pack_int4', 'unpack_int4')
 
 # --------------------------------------------------------------------- GC121
-# The paged decode hot path is KV-bandwidth-bound: a per-layer pool
-# slice forces XLA to materialize that layer's whole pool as a fresh
-# operand of the consumer, and a gather-per-layer materializes a full
-# KV copy per layer per step. Decode-scoped functions in inference/
-# hand the FULL stacked pool to the paged-attention kernels
-# (ops/paged_attention.py: the layer rides scalar prefetch; the
-# cross-layer variant runs every layer in one pallas_call). Exempt
-# scopes are the prefill/verify-shaped functions (compute-bound — they
-# legitimately materialize contiguous rows for cached_attention) and
-# the gather helper's own body; the one legacy gather fallback inside
+# A per-layer pool slice forces XLA to materialize that layer's whole
+# pool as a fresh operand of the consumer, in every program shape: the
+# KV-bandwidth-bound decode step, and the prefill chunk too (19 % of
+# the 7B chunk program on the chip; PERF.md, PR 28). So the slice half
+# applies to every function in inference/: consumers take the FULL
+# stacked pool and the layer as an index (ops/paged_attention.py: the
+# layer rides scalar prefetch; the cross-layer variant runs every layer
+# in one pallas_call; _gather_layer folds it into the gather's row
+# index). The gather-per-layer half stays decode-scoped:
+# prefill/verify-shaped functions need contiguous rows for
+# cached_attention; the one legacy gather fallback inside
 # paged_decode_horizon is suppressed inline, so any NEW site
 # hard-fails.
 _POOL_SLICE_FNS = {'lax.dynamic_index_in_dim',
@@ -329,7 +333,7 @@ _POOL_SLICE_FNS = {'lax.dynamic_index_in_dim',
                    'dynamic_index_in_dim'}
 _GATHER_LAYER_FNS = {'_gather_layer', 'gather_layer'}
 _POOL_SCALE_NAMES = {'k_scale', 'v_scale'}
-_GC121_EXEMPT_SCOPE_MARKERS = ('prefill', 'verify', '_gather_layer')
+_GC121_GATHER_SCOPE_MARKERS = ('prefill', 'verify')
 
 # --------------------------------------------------------------------- GC114
 # KV transfer paths: the disaggregated-serving wire codec and handoff
@@ -1056,33 +1060,30 @@ class _Checker(ast.NodeVisitor):
         seg = dotted.rsplit('.', 1)[-1]
         return 'pool' in seg or seg in _POOL_SCALE_NAMES
 
-    def _gc121_applies(self) -> bool:
-        """GC121 polices DECODE-scoped inference functions only:
-        prefill/verify-shaped scopes legitimately materialize
-        contiguous rows (compute-bound), and the gather helper is the
-        one sanctioned materializer."""
+    def _gc121_decode_scope(self) -> bool:
+        """GC121's gather half polices DECODE-scoped functions only:
+        prefill/verify-shaped scopes legitimately gather contiguous
+        rows for ``cached_attention``."""
         if any(m in s for s in self._scope
-               for m in _GC121_EXEMPT_SCOPE_MARKERS):
+               for m in _GC121_GATHER_SCOPE_MARKERS):
             return False
         return any('decode' in s for s in self._scope)
 
     def _check_pool_slice_call(self, node: ast.Call, name: str) -> None:
         """GC121 (call half): ``lax.dynamic_index_in_dim(pool, li)``
-        or ``_gather_layer(...)`` in a decode scope — a materialized
-        per-layer pool read on the KV-bandwidth-bound path."""
-        if not self._gc121_applies():
-            return
+        anywhere in inference/, or ``_gather_layer(...)`` in a decode
+        scope — a materialized per-layer pool read."""
         short = name.rsplit('.', 1)[-1]
         if (name in _POOL_SLICE_FNS and node.args
                 and self._is_pool_named(node.args[0])):
             self._add('GC121', node,
-                      'per-layer pool slice on the paged decode path '
-                      '— dynamic_index_in_dim materializes a copy of '
-                      'the layer\'s whole pool per step; hand the '
-                      'FULL stacked pool to the paged-attention '
-                      'kernels (layer via scalar prefetch, or the '
-                      'cross-layer fused kernel)')
-        elif short in _GATHER_LAYER_FNS:
+                      'per-layer pool slice — dynamic_index_in_dim '
+                      'materializes a copy of the layer\'s whole pool '
+                      'per layer step; hand the consumer the FULL '
+                      'stacked pool and the layer as an index '
+                      '(paged-attention kernels: scalar prefetch; '
+                      '_gather_layer: flat gather)')
+        elif short in _GATHER_LAYER_FNS and self._gc121_decode_scope():
             self._add('GC121', node,
                       'gather-per-layer on the paged decode path — '
                       '_gather_layer materializes a full KV copy per '
@@ -1091,11 +1092,10 @@ class _Checker(ast.NodeVisitor):
 
     def visit_Subscript(self, node):
         """GC121 (subscript half): a scalar layer subscript of a pool
-        (``pool_k[li]`` / ``pool_k[0]`` / ``pool_k[li, ...]``) in a
-        decode scope — the same materialized per-layer read as the
+        (``pool_k[li]`` / ``pool_k[0]`` / ``pool_k[li, ...]``) anywhere
+        in inference/ — the same materialized per-layer read as the
         dynamic_index_in_dim spelling."""
-        if (self.is_inference and self._gc121_applies()
-                and self._is_pool_named(node.value)):
+        if self.is_inference and self._is_pool_named(node.value):
             idx = node.slice
             if isinstance(idx, ast.Tuple) and idx.elts:
                 idx = idx.elts[0]
@@ -1104,10 +1104,10 @@ class _Checker(ast.NodeVisitor):
                           and isinstance(idx.value, int)))
             if scalar:
                 self._add('GC121', node,
-                          'scalar layer subscript of a KV pool on the '
-                          'paged decode path — a materialized '
-                          'per-layer pool read; hand the FULL stacked '
-                          'pool to the paged-attention kernels')
+                          'scalar layer subscript of a KV pool — a '
+                          'materialized per-layer pool read; hand the '
+                          'consumer the FULL stacked pool and the '
+                          'layer as an index')
         self.generic_visit(node)
 
     def _check_wire_dtype(self, node: ast.Call, name: str,

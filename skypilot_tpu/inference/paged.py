@@ -331,24 +331,51 @@ def _maybe_quantize_rows(new_kv, quantized):
     return (quant(k_rows), quant(v_rows))
 
 
-def _gather_layer(pool_layer: jax.Array, scale_layer, table_p: jax.Array):
-    """pool_layer [n_pages, hkv, page, d] -> ([slots, P*page, hkv, d],
-    scales or None): contiguous token-major view of each slot's first P
-    pages (the XLA attention ops are token-major; the permute fuses
-    into the gather's copy — this is the fallback path, the Pallas
-    kernel reads the head-major pool directly). int8 pools return
-    CODES + gathered scales — the gathered copy stays int8 (half the
-    write+read traffic of a dequantized gather) and the attention op
-    folds the scales into logits/probs."""
-    g = pool_layer[table_p]                     # [slots, P, hkv, page, d]
-    slots, P, hkv, page = g.shape[:4]
+def _gather_layer(pool: jax.Array, scale_pool, li, table_p: jax.Array):
+    """Layer ``li``'s pages of ``table_p`` out of the STACKED pool
+    [L, n_pages, hkv, page, d] -> ([slots, P*page, hkv, d], scales or
+    None): contiguous token-major view of each slot's first P pages
+    (the XLA attention ops are token-major; the permute fuses into the
+    gather's copy — the Pallas decode kernel reads the head-major pool
+    directly). int8 / packed-int4 pools return CODES + gathered scales
+    — the gathered copy stays quantized (half the write+read traffic
+    of a dequantized gather) and the attention op folds the scales
+    into logits/probs.
+
+    ``li`` (traced, from the layer scan) rides in the gather's INDEX:
+    the two major axes merge (a bitcast — no layout changes, and the
+    head axis a tp mesh shards is untouched) and page ``p`` of layer
+    ``li`` is row ``li * n_pages + p``. A layer sliced out first
+    (``dynamic_index_in_dim``) is a copy of that layer's WHOLE pool in
+    every layer step: the entire KV pool read once per program, growing
+    with the pool and not with the prompt (GC121; ``PERF.md``, PR 28).
+    Every id in ``table_p`` is an in-bounds page id (padding is page
+    0), so the flat row stays inside layer ``li``."""
+    n_layers, n_pages = pool.shape[:2]
+    slots, P = table_p.shape
+    rows = li * n_pages + table_p               # [slots, P] flat page rows
+    # Never a ONE-row gather: XLA turns it into a dynamic-slice, fuses
+    # that into the p.v dot and gives the dot's transposed layout to the
+    # operand — a transposed copy of the whole V pool in every program
+    # run (2.66 GB of temp, 8.8 ms a chunk on the 7B; PERF.md, PR 28).
+    # A second copy of the row keeps it a gather, which reads the pool
+    # as it lies; the extra page is dropped before the permute.
+    if rows.size == 1:
+        rows = jnp.concatenate([rows, rows], axis=1)
+
+    def pages(stacked):
+        flat = stacked.reshape((n_layers * n_pages,) + stacked.shape[2:])
+        return flat[rows][:, :P]
+
+    g = pages(pool)                             # [slots, P, hkv, page, d]
+    hkv, page = g.shape[2:4]
     g = g.transpose(0, 1, 3, 2, 4).reshape(
         (slots, P * page, hkv) + g.shape[4:])
-    if scale_layer is not None:
-        s = scale_layer[table_p]                # [slots, P, hkv, page]
-        s = s.transpose(0, 1, 3, 2).reshape(slots, P * page, hkv, 1)
-        return g, s
-    return g, None
+    if scale_pool is None:
+        return g, None
+    s = pages(scale_pool)                       # [slots, P, hkv, page]
+    s = s.transpose(0, 1, 3, 2).reshape(slots, P * page, hkv, 1)
+    return g, s
 
 
 def paged_decode_horizon(
@@ -449,22 +476,12 @@ def paged_decode_horizon(
             else:
                 # The ONE grandfathered per-layer gather on the decode
                 # path (GC121): the XLA-only fallback for backends /
-                # head_dims the kernels don't cover. Every suppression
+                # head_dims the kernels don't cover. The suppression
                 # below is deliberate — a new gather-per-layer site
                 # anywhere else on the decode path hard-fails
                 # graftcheck.
-                pk = lax.dynamic_index_in_dim(pool_k, li, 0,  # graftcheck: disable=GC121
-                                              keepdims=False)
-                pv = lax.dynamic_index_in_dim(pool_v, li, 0,  # graftcheck: disable=GC121
-                                              keepdims=False)
-                sk = (lax.dynamic_index_in_dim(ks_pool, li, 0,  # graftcheck: disable=GC121
-                                               keepdims=False)
-                      if cache.quantized else None)
-                sv = (lax.dynamic_index_in_dim(vs_pool, li, 0,  # graftcheck: disable=GC121
-                                               keepdims=False)
-                      if cache.quantized else None)
-                ck, sck = _gather_layer(pk, sk, table_p)  # graftcheck: disable=GC121
-                cv, scv = _gather_layer(pv, sv, table_p)  # graftcheck: disable=GC121
+                ck, sck = _gather_layer(pool_k, ks_pool, li, table_p)  # graftcheck: disable=GC121
+                cv, scv = _gather_layer(pool_v, vs_pool, li, table_p)  # graftcheck: disable=GC121
 
                 def attn_fn(q, k, v):
                     return ring_decode_attention(q, k, v, ck, cv, len0,
@@ -567,14 +584,8 @@ def paged_prefill_chunk(
 
     def layer_body(xc, layer_and_idx):
         layer, li = layer_and_idx
-        pk = lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
-        pv = lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
-        sk = (lax.dynamic_index_in_dim(ks_pool, li, 0, keepdims=False)
-              if cache.quantized else None)
-        sv = (lax.dynamic_index_in_dim(vs_pool, li, 0, keepdims=False)
-              if cache.quantized else None)
-        ck, sck = _gather_layer(pk, sk, table_p)
-        cv, scv = _gather_layer(pv, sv, table_p)
+        ck, sck = _gather_layer(pool_k, ks_pool, li, table_p)
+        cv, scv = _gather_layer(pool_v, vs_pool, li, table_p)
 
         def attn_fn(q, k, v):
             return cached_attention(q, k, v, ck, cv, len0,
@@ -661,14 +672,8 @@ def paged_spec_verify(
 
     def layer_body(xc, layer_and_idx):
         layer, li = layer_and_idx
-        pk = lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
-        pv = lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
-        sk = (lax.dynamic_index_in_dim(ks_pool, li, 0, keepdims=False)
-              if cache.quantized else None)
-        sv = (lax.dynamic_index_in_dim(vs_pool, li, 0, keepdims=False)
-              if cache.quantized else None)
-        ck, sck = _gather_layer(pk, sk, table_p)
-        cv, scv = _gather_layer(pv, sv, table_p)
+        ck, sck = _gather_layer(pool_k, ks_pool, li, table_p)
+        cv, scv = _gather_layer(pool_v, vs_pool, li, table_p)
 
         def attn_fn(q, kk, vv):
             return cached_attention(q, kk, vv, ck, cv, len0,

@@ -6,6 +6,8 @@ host sync in the decode loop fails HERE, not in a bench regression
 three rounds later."""
 import textwrap
 
+import pytest
+
 from skypilot_tpu.analysis import lint as lint_lib
 from skypilot_tpu.analysis import rules as rules_lib
 from skypilot_tpu.analysis.cli import main as graftcheck_main
@@ -434,27 +436,46 @@ def test_gc121_scalar_pool_subscript_in_decode_flagged():
         ['GC121', 'GC121', 'GC121']
 
 
-def test_gc121_prefill_verify_and_helper_scopes_exempt():
-    # Prefill/verify-shaped functions are compute-bound and
-    # legitimately materialize contiguous rows; the gather helper is
-    # the sanctioned materializer; non-pool slices stay legal in
-    # decode scopes (the ring is per-horizon, not the pool).
+def test_gc121_prefill_verify_may_gather_but_not_slice():
+    # Prefill/verify-shaped functions need contiguous rows, so they may
+    # CALL the gather helper — handing it the stacked pool and the
+    # layer; the helper's flat gather is no layer subscript; non-pool
+    # slices stay legal in decode scopes (the ring is per-horizon, not
+    # the pool).
     src = '''
     from jax import lax
     def paged_prefill_chunk(cache, li, table_p):
-        pk = lax.dynamic_index_in_dim(cache.pool_k, li, 0)
-        return _gather_layer(pk, None, table_p)
+        return _gather_layer(cache.pool_k, cache.k_scale, li, table_p)
     def paged_spec_verify(cache, li, table_p):
-        pv = cache.pool_v[li]
-        return _gather_layer(pv, None, table_p)
-    def _gather_layer(pool_layer, scale_layer, table_p):
-        return pool_layer[table_p], scale_layer
+        return _gather_layer(cache.pool_v, None, li, table_p)
+    def _gather_layer(pool, scale_pool, li, table_p):
+        rows = li * pool.shape[1] + table_p
+        return pool.reshape((-1,) + pool.shape[2:])[rows], scale_pool
     def paged_decode_horizon(ring_k, li, lengths):
         rk = lax.dynamic_index_in_dim(ring_k, li, 0)
         n = lengths[li]
         return rk, n
     '''
     assert rule_ids(src, 'skypilot_tpu/inference/paged.py') == []
+
+
+@pytest.mark.parametrize('scope', ['paged_prefill_chunk',
+                                   'paged_spec_verify', '_gather_layer',
+                                   'merge_rows'])
+def test_gc121_pool_slice_flagged_in_every_inference_scope(scope):
+    # The slice was once exempt in prefill/verify scopes as
+    # "compute-bound"; the device trace said 19 % of the prefill
+    # program (PERF.md, PR 28). No scope of inference/ may slice a
+    # layer's pool out, in either spelling.
+    src = f'''
+    from jax import lax
+    def {scope}(cache, li, table_p):
+        pk = lax.dynamic_index_in_dim(cache.pool_k, li, 0)
+        sv = cache.v_scale[li]
+        return pk, sv
+    '''
+    assert rule_ids(src, 'skypilot_tpu/inference/paged.py') == \
+        ['GC121', 'GC121']
 
 
 def test_gc121_outside_inference_and_suppressions_clean():

@@ -2,17 +2,20 @@
 caching, chunked prefill, pool accounting (VERDICT r4 task 3; reference
 capability anchor: vLLM paged attention, llm/vllm/README.md:10)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from skypilot_tpu.inference.engine import InferenceEngine
-from skypilot_tpu.inference.paged import (PageAllocator,
-                                          PagedInferenceEngine)
+from skypilot_tpu.inference.paged import (PageAllocator, PagedKVCache,
+                                          PagedInferenceEngine,
+                                          _gather_layer,
+                                          paged_prefill_chunk)
 from skypilot_tpu.models import configs, llama
 
-# Compile-heavy (jit of full models): slow tier — the fast sweep is
-# the orchestration layer (SURVEY §4 offline tier analog).
-pytestmark = pytest.mark.slow
+# Compile-heavy (jit of full models): the engine classes ride the slow
+# tier — the fast sweep is the orchestration layer (SURVEY §4 offline
+# tier analog) plus the pool-gather tests at the file's end.
 
 
 @pytest.fixture(scope='module')
@@ -30,6 +33,7 @@ def _greedy_slot_engine(cfg, params, prompts, n_new, **kw):
     return [done[r].output for r in rids]
 
 
+@pytest.mark.slow
 class TestPagedEquivalence:
 
     def test_greedy_matches_slot_engine(self, setup):
@@ -97,6 +101,7 @@ class TestPagedEquivalence:
         assert req.stop_hit and req.output == full[:2]
 
 
+@pytest.mark.slow
 class TestPrefixCache:
 
     def test_shared_prefix_reuses_pages(self, setup):
@@ -199,6 +204,7 @@ class TestPrefixCache:
                 == s2['n_pages'] - 1)
 
 
+@pytest.mark.slow
 class TestAllocator:
 
     def test_exhaustion_and_lru_eviction(self):
@@ -230,6 +236,7 @@ class TestAllocator:
         assert p in a.free                     # unregistered -> free list
 
 
+@pytest.mark.slow
 class TestPallasDecodeKernel:
     """Paged-attention Pallas kernel (interpret mode on CPU): the
     engine's pallas decode path matches the gather path exactly."""
@@ -260,6 +267,7 @@ class TestPallasDecodeKernel:
         assert len(done[rid].output) == 4
 
 
+@pytest.mark.slow
 class TestContinuousAdmission:
     """Round-5: admission interleaves prefill chunks with decode (the
     wave-synchronous form stalled running requests for a whole wave)."""
@@ -328,6 +336,7 @@ class TestContinuousAdmission:
             assert streamed[-len(out):] == out
 
 
+@pytest.mark.slow
 class TestEarlyRecycle:
     """Host-known completion frees slots at ENQUEUE: a budget-bound
     request's slot recycles while its tail tokens are still riding the
@@ -381,3 +390,90 @@ class TestEarlyRecycle:
         assert any(r is not None for r in eng._slots)
         done = eng.run_to_completion(horizon=8)
         assert len(done[rid].output) == 4
+
+
+# ---------------------------------------------------------------------
+# The pool gather (tier-1): the layer rides in the gather's index.
+# ---------------------------------------------------------------------
+def _random_pool(kv_dtype, rng, shape):
+    """A stacked pool [L, n_pages, hkv, page, d] (+ scales) whose every
+    (layer, page) holds values of its own."""
+    if kv_dtype == 'bf16':
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16), None
+    if kv_dtype == 'int4':          # two nibble codes a byte along d
+        pool = rng.integers(0, 256, shape[:-1] + (shape[-1] // 2,),
+                            np.uint8)
+    else:
+        pool = rng.integers(-127, 128, shape, np.int8)
+    return jnp.asarray(pool), jnp.asarray(
+        rng.random(shape[:-1], np.float32))
+
+
+@pytest.mark.parametrize('kv_dtype', ['bf16', 'int8', 'int4'])
+def test_gather_layer_equals_numpy_take(kv_dtype):
+    """``_gather_layer`` under jit with a TRACED layer equals, bit for
+    bit, a plain take of that layer's pages (token-major) — for every
+    layer, with padding (page 0), repeated ids and the last page in the
+    table, and for a table of one page."""
+    n_layers, n_pages, hkv, page, d = 3, 5, 2, 4, 8
+    pool, scales = _random_pool(kv_dtype, np.random.default_rng(0),
+                                (n_layers, n_pages, hkv, page, d))
+    gather = jax.jit(_gather_layer)     # li is an argument: traced
+
+    def take(stacked, li, table):
+        g = np.asarray(stacked)[li][table]      # [slots, P, hkv, page, ..]
+        g = np.moveaxis(g, 2, 3)                # token-major
+        return g.reshape(table.shape[:1] + (-1, hkv) + g.shape[4:])
+
+    # The second table is the one-row gather the helper never emits.
+    for table in ([[1, 3, 0, 0], [4, 4, 2, 0]], [[4]]):
+        table = np.array(table, np.int32)
+        for li in range(n_layers):
+            g, s = gather(pool, scales, jnp.int32(li), jnp.asarray(table))
+            assert g.dtype == pool.dtype
+            np.testing.assert_array_equal(np.asarray(g),
+                                          take(pool, li, table))
+            if scales is None:
+                assert s is None
+            else:
+                np.testing.assert_array_equal(
+                    np.asarray(s), take(scales, li, table)[..., None])
+
+
+@pytest.mark.parametrize('kv_dtype', ['bf16', 'int8'])
+def test_prefill_chunk_pages_at_pool_end(kv_dtype):
+    """The index arithmetic's edge: a prompt whose pages sit at the
+    pool's far end (so layer L-1 reads the pool's very last page) gives
+    bit for bit the first tokens and the cache rows of the same prompt
+    in the pool's first pages."""
+    cfg = configs.TINY
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    n_pages, page, chunk = 9, 8, 16
+    tokens = np.random.default_rng(1).integers(
+        0, cfg.vocab_size, (1, 2 * chunk)).astype(np.int32)
+    step = jax.jit(lambda cache, table, toks, lengths: paged_prefill_chunk(
+        params, cache, table, toks, lengths,
+        jnp.full((1,), chunk, jnp.int32),
+        jnp.full((1,), chunk - 1, jnp.int32), cfg))
+
+    def prefill(table):
+        cache = PagedKVCache.create(cfg, n_pages=n_pages, page_size=page,
+                                    kv_dtype=kv_dtype)
+        firsts = []
+        for i in range(2):          # the second chunk reads the first's
+            first, cache = step(
+                cache, jnp.asarray([table], jnp.int32),
+                jnp.asarray(tokens[:, i * chunk:(i + 1) * chunk]),
+                jnp.full((1,), i * chunk, jnp.int32))
+            firsts.append(int(first[0]))
+        return firsts, [np.asarray(leaf)[:, table] for leaf in cache
+                        if leaf is not None]
+
+    low, high = [1, 2, 3, 4], [n_pages - 1, n_pages - 3, n_pages - 2,
+                               n_pages - 4]
+    firsts_low, rows_low = prefill(low)
+    firsts_high, rows_high = prefill(high)
+    assert firsts_high == firsts_low
+    for a, b in zip(rows_low, rows_high):
+        assert a.any()
+        np.testing.assert_array_equal(a, b)

@@ -13,12 +13,15 @@ process at a time may load the TPU's library, and every xdist worker
 imports every test file), and everything compiles in the test's own
 process.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from skypilot_tpu.models import configs
+from skypilot_tpu.inference import paged
+from skypilot_tpu.models import configs, llama, quantization
 from skypilot_tpu.ops import flash_attention as fa
 from skypilot_tpu.ops import paged_attention as pa
 
@@ -140,6 +143,74 @@ def test_paged_decode_attention_compiles(one_chip, kv_dtype,
 @pytest.mark.parametrize('kv_dtype', ['bf16', 'int8'])
 def test_paged_decode_attention_fused_compiles(one_chip, kv_dtype):
     assert _has_kernel(_compile_fused(kv_dtype, one_chip))
+
+
+def test_layer_scan_gathers_pages_without_copying_a_pool_layer(one_chip):
+    """The paged prefill's read at the chat cell's size (Qwen2-7B: 28
+    layers, a 1451-page int8 pool, 4 KV heads): a layer scan that calls
+    ``_gather_layer`` with a traced layer must hand the gather the
+    stacked pool itself. Slicing the layer out first compiled to a
+    ``dynamic-slice_bitcast_fusion`` with an ``s8[1451,4,128,128]``
+    output — a copy of the layer's whole pool in every layer step, 19 %
+    of the prefill program on the chip (``PERF.md``, PR 28)."""
+    n_layers, n_pages, hkv, slots, pages = 28, 1451, 4, 2, 4
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(pool_k, pool_v, ks, vs, table):
+        def layer_body(acc, li):
+            ck, sck = paged._gather_layer(pool_k, ks, li, table)
+            cv, scv = paged._gather_layer(pool_v, vs, li, table)
+            return acc + jnp.sum(ck * sck, 1) + jnp.sum(cv * scv, 1), None
+        acc = jnp.zeros((slots, hkv, CFG.head_dim), jnp.float32)
+        return jax.lax.scan(layer_body, acc, jnp.arange(n_layers))[0]
+
+    pool = s((n_layers, n_pages, hkv, PAGE, CFG.head_dim), jnp.int8)
+    scale = s((n_layers, n_pages, hkv, PAGE), jnp.float32)
+    compiled = jax.jit(fn).lower(pool, pool, scale, scale,
+                                 s((slots, pages), jnp.int32)).compile()
+    assert f's8[{n_pages},{hkv},{PAGE},{CFG.head_dim}]' not in \
+        compiled.as_text()
+    # The compiler counts a loop's body once, int8 tiles as padded
+    # words: it read 11.7 MB here, and 404.8 MB with the slice.
+    gathered = n_layers * 2 * slots * pages * hkv * PAGE * (
+        CFG.head_dim + 4)
+    assert compiled.cost_analysis()['bytes accessed'] < 4 * gathered
+
+
+def test_one_page_prefill_chunk_leaves_the_pool_in_place(one_chip):
+    """The whole prefill-chunk program of the chat cell (Qwen2-7B, int8
+    weights, a 1451-page int8 pool) for ONE prompt of ONE page, chunk
+    128: the shape at which a one-row gather became a dynamic-slice
+    fused into the p.v dot, and the dot's transposed layout a copy of
+    the whole V pool at the program's start — 2.66 GB of temp beside
+    13.7 GB of arguments on a 16.9 GB chip (``PERF.md``, PR 28). Only
+    the real program shows it: a scan with a plain consumer does not."""
+    cfg = configs.QWEN2_7B
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda key: quantization.quantize_params(
+            llama.init_params(key, cfg=cfg)), jax.random.PRNGKey(0)))
+    cache = shaped(jax.eval_shape(lambda: paged.PagedKVCache.create(
+        cfg, n_pages=1451, page_size=PAGE, kv_dtype='int8')))
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill(params, cache, table_p, tokens, lengths, valid, want):
+        return paged.paged_prefill_chunk(params, cache, table_p, tokens,
+                                         lengths, valid, want, cfg)
+
+    compiled = prefill.lower(params, cache, vec(1, 1), vec(1, 128),
+                             vec(1), vec(1), vec(1)).compile()
+    pool_layer = cache.pool_k.size // cfg.n_layers          # 95.1 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_layer
 
 
 @pytest.mark.parametrize('kernel', ['per_layer', 'fused'])
