@@ -1,0 +1,17 @@
+"""Mean device time of one prefill chunk of the long-context cell: over
+the executions of the ``prefill`` program in the traced part, whatever
+each chunk held (``prefill_chunk_ms``'s reduction, for this cell)."""
+import statistics
+
+LAYER = 'engine step'
+UNIT = 'ms'
+MOVES = 'tpot_p95_ms'
+CELLS = ['glm-4.7-flash.longctx']
+SOURCE = 'device_trace'
+
+
+def read(run):
+    chunks = run['trace'].programs.get('prefill', [])
+    if not chunks:
+        return None
+    return statistics.fmean(e.duration_s for e in chunks) * 1e3

@@ -374,9 +374,10 @@ def _tiny_engine(kind: str, chunked: bool, speculate_k: int = 0,
                  quantize: Optional[str] = None,
                  decode_steps_per_call: Optional[int] = None,
                  decode_impl: Optional[str] = None,
-                 adapter_slots: int = 0, adapter_rank: int = 8):
+                 adapter_slots: int = 0, adapter_rank: int = 8,
+                 model: str = 'tiny'):
     from skypilot_tpu.models import configs
-    cfg = configs.get_config('tiny')
+    cfg = configs.get_config(model)
     chunk = 16 if chunked else 0
     extra: Dict[str, Any] = {}
     if quantize is not None:
@@ -596,7 +597,8 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
                  warmup_rounds: int = 1,
                  merge_all_gathers: int = 0,
                  quantize: Optional[str] = None,
-                 decode_impl: Optional[str] = None) -> AuditReport:
+                 decode_impl: Optional[str] = None,
+                 model: str = 'tiny') -> AuditReport:
     """Build a tiny engine, run one warmup wave (compiles allowed),
     then audit ``rounds`` identical same-shaped waves: every compile
     and every unsanctioned host transfer in those waves is a violation.
@@ -625,6 +627,7 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
     tp_tag = f' + tp={mesh_tp}' if mesh_tp else ''
     tp_tag += f' x dp={mesh_dp}' if mesh_dp else ''
     impl_tag = f' + decode_impl={decode_impl}' if decode_impl else ''
+    impl_tag += f' + model={model}' if model != 'tiny' else ''
     report = AuditReport(
         name=f'{kind} engine '
              f'({"chunked prefill + " if chunked else ""}decode'
@@ -632,7 +635,8 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
     engine = _tiny_engine(kind, chunked, speculate_k,
                           kv_cache_dtype=kv_cache_dtype,
                           mesh_tp=mesh_tp, mesh_dp=mesh_dp,
-                          quantize=quantize, decode_impl=decode_impl)
+                          quantize=quantize, decode_impl=decode_impl,
+                          model=model)
     if speculate_k:
         # Repetitive prompts: the n-gram proposer matches, acceptance
         # is nonzero AND per-slot variable — the masked-commit shapes
@@ -1292,6 +1296,12 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     # lands series AND completed traces in the aggregator.
     'fleet-obs': audit_fleet_obs,
     'llama': audit_llama_forward,
+    # Latent attention + dropless routed experts (tiny-glm) through the
+    # paged engine: the latent pool, the grouped-matmul kernel's
+    # dynamic grid and the experts-read count riding the token readback
+    # add zero unsanctioned d2h and zero steady-state recompiles.
+    'paged-latent-moe': lambda: audit_engine('paged', chunked=True,
+                                             model='tiny-glm'),
 }
 
 # Presets that need a multi-device backend: preset -> device count.

@@ -126,8 +126,8 @@ def _ring_row_bytes(cfg, batch: int, mesh=None) -> int:
     tp like the cache it merges into (batch sharding is NOT credited —
     the paged ring rides a replicated batch, so dividing by dp would
     under-reserve)."""
-    return (cfg.n_layers * batch * cfg.n_kv_heads * cfg.head_dim *
-            jnp.dtype(cfg.dtype).itemsize * 2
+    return (cfg.n_layers * batch * cfg.kv_spec.row_values *
+            jnp.dtype(cfg.dtype).itemsize
             ) // kv_shard_degree(cfg, mesh)
 
 
@@ -190,14 +190,60 @@ def kv_token_bytes(cfg, quantized: bool, mesh=None) -> int:
     ``quantized`` accepts the historical bool (True == int8) or a kv
     dtype string: int4 rows are PACKED — two nibble codes per byte
     (head_dim/2) plus the same fp32 row scale."""
+    spec = cfg.kv_spec          # a latent model: one row, not k+v heads
     if quantized == 'int4':
-        row_w = cfg.head_dim // 2 + 4
+        row_w = spec.k_dim // 2 + spec.v_dim // 2 + 8
     elif quantized and quantized != 'bf16':
-        row_w = cfg.head_dim + 4
+        row_w = spec.k_dim + spec.v_dim + 8
     else:
-        row_w = cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    return (cfg.n_layers * cfg.n_kv_heads * row_w * 2
+        row_w = (spec.k_dim + spec.v_dim) * jnp.dtype(cfg.dtype).itemsize
+    return (cfg.n_layers * spec.heads * row_w
             ) // kv_shard_degree(cfg, mesh)
+
+
+def refuse_unsupported(cfg, **asked) -> None:
+    """What a model's layer kinds cannot yet be combined with is refused
+    where the engine is built, with the reason, and never fails later or
+    silently. ``asked`` holds what the caller was asked for: ``engine``
+    ('slot' | 'paged'), ``quantize``, ``kv_cache_dtype`` (resolved),
+    ``speculate_k``, ``adapter_slots``, ``mesh``, ``decode_impl``."""
+    if not cfg.latent:
+        return
+    reasons = {
+        'engine': (asked.get('engine') == 'slot',
+                   'the slot engine reserves [max_seq, kv_heads, '
+                   "head_dim] rows a slot; a latent cache row has no "
+                   "head axis: serve it with kv_cache='paged'"),
+        'quantize': (asked.get('quantize') is not None,
+                     'quantize_params knows the dense GQA leaves only; '
+                     'the expert stacks need a grouped matmul that '
+                     'dequantizes (ROADMAP.md)'),
+        'kv_cache_dtype': (asked.get('kv_cache_dtype', 'bf16') != 'bf16',
+                           'a per-row scale of the normed latent is '
+                           'not implemented; the latent row is already '
+                           '17.8x smaller than expanded K/V'),
+        'speculate_k': (bool(asked.get('speculate_k')),
+                        'paged_spec_verify attends through '
+                        'cached_attention (GQA rows)'),
+        'adapter_slots': (bool(asked.get('adapter_slots')),
+                          'the LoRA bank targets wq/wk/wv/wo and the '
+                          'dense FFN, which this model does not have'),
+        'mesh': (asked.get('mesh') is not None,
+                 'the one shared latent row cannot shard over tp, and '
+                 'experts are not yet placed over a mesh'),
+        'decode_impl': (asked.get('decode_impl') in ('pallas',
+                                                     'cross_layer'),
+                        'ops/paged_attention.py contracts K and V rows '
+                        "of one width under per-head groups; 'auto' "
+                        'takes the absorbed XLA form over gathered '
+                        'pages'),
+    }
+    for name, (hit, why) in reasons.items():
+        if hit:
+            raise ValueError(
+                f'{cfg.name} (attn_kind={cfg.attn_kind!r}, '
+                f'ffn_kind={cfg.ffn_kind!r}) cannot be combined with '
+                f'{name}={asked[name]!r}: {why}')
 
 
 # Telemetry series every engine registers at construction (zeros from
@@ -1308,6 +1354,7 @@ class InferenceEngine(SpeculativeMixin, _EngineBase):
         self.decode_priority_ratio = decode_priority_ratio
         self._rng = jax.random.PRNGKey(rng_seed)
 
+        refuse_unsupported(cfg, engine='slot')
         cfg, self.params, quantize = prepare_params(
             cfg, params, quantize=quantize, mesh=mesh,
             donate_params=donate_params)

@@ -37,6 +37,7 @@ token capacity as well as halving the decode KV stream.
 from __future__ import annotations
 
 import functools
+import contextlib
 import hashlib
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -52,6 +53,9 @@ from skypilot_tpu.telemetry import clock
 from skypilot_tpu.utils.host import device_upload, host_sync
 
 Params = Dict[str, Any]
+
+
+_LANES = 128
 
 
 class PagedKVCache(NamedTuple):
@@ -96,8 +100,23 @@ class PagedKVCache(NamedTuple):
                kv_dtype: Optional[str] = None) -> 'PagedKVCache':
         if kv_dtype is None:
             kv_dtype = 'int8' if quantized else 'bf16'
-        shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size,
-                 cfg.head_dim)
+        spec = cfg.kv_spec
+        shape = (cfg.n_layers, n_pages, spec.heads, page_size, spec.k_dim)
+        if spec.v_dim != spec.k_dim:
+            # A latent cache: the normed latent rows in the K pool, the
+            # roped key part in the V pool; bf16 only (refuse_unsupported).
+            # Rope rows narrower than the 128 lanes lie several tokens to
+            # a lane row ([page * v_dim / 128, 128]: the same bytes in the
+            # same order). A [page, 64] page makes the row scatter relay
+            # the whole pool (a 341 MB copy in every chunk and ring merge,
+            # 16 s more to compile each; compiler, PR 30).
+            v_shape = shape[:-1] + (spec.v_dim,)
+            if _LANES % spec.v_dim == 0 and \
+                    (page_size * spec.v_dim) % _LANES == 0:
+                v_shape = shape[:-2] + (page_size * spec.v_dim // _LANES,
+                                        _LANES)
+            return cls(pool_k=jnp.zeros(shape, cfg.dtype),
+                       pool_v=jnp.zeros(v_shape, cfg.dtype))
         if kv_dtype == 'int4':
             if cfg.head_dim % 2:
                 raise ValueError('int4 KV needs an even head_dim')
@@ -164,6 +183,8 @@ def _scatter_rows(pool: jax.Array, rows: jax.Array,
     the scatter runs IN PLACE (0-byte temps, donation holds), at the
     price of a slower per-row scatter (~3-4x the token-major merge,
     bounded at ~3% of a decode-horizon program)."""
+    if pool.ndim == 5 and rows.shape[-1] != pool.shape[-1]:
+        return _scatter_rows_lane_packed(pool, rows, flat_idx)
     L, n_pages, hkv, page = pool.shape[:4]
     tail = pool.shape[4:]
     rows_per_layer = n_pages * hkv * page
@@ -177,6 +198,34 @@ def _scatter_rows(pool: jax.Array, rows: jax.Array,
     flat_rows = rows.reshape((idx.size,) + tail)
     flat_pool = flat_pool.at[idx].set(
         flat_rows.astype(flat_pool.dtype), mode='drop')
+    return flat_pool.reshape(pool.shape)
+
+
+def _scatter_rows_lane_packed(pool: jax.Array, rows: jax.Array,
+                              flat_idx: jax.Array) -> jax.Array:
+    """``_scatter_rows`` for a pool whose rows of ``w`` < 128 values lie
+    ``128 / w`` tokens to a lane row (``PagedKVCache.create``): pool [L,
+    n_pages, 1, page * w / 128, 128], rows [L, slots, n, 1, w]. Token
+    ``t`` of a page is lanes ``(t % per) * w ...`` of lane row ``t //
+    per``; the scatter's window is [w] at (row, lane). Flat and in place
+    like its twin."""
+    L, n_pages, hkv, lane_rows, lanes = pool.shape
+    w = rows.shape[-1]
+    per = lanes // w
+    assert hkv == 1, 'lane-packed rows have no head axis'
+    flat_pool = pool.reshape(L * n_pages * lane_rows, lanes)
+    f = flat_idx.reshape(-1)                            # [slots*n] tokens
+    row = (jnp.arange(L)[:, None] * (n_pages * lane_rows)
+           + (f // per)[None, :]).reshape(-1)
+    lane = jnp.broadcast_to(((f % per) * w)[None, :],
+                            (L, f.size)).reshape(-1)
+    flat_pool = lax.scatter(
+        flat_pool, jnp.stack([row, lane], axis=-1),
+        rows.reshape(-1, w).astype(flat_pool.dtype),
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0, 1)),
+        mode='drop')
     return flat_pool.reshape(pool.shape)
 
 
@@ -412,7 +461,7 @@ def paged_decode_horizon(
     the ring into the pool via ``merge_ring_into_pool`` in a separate
     donated program (see its docstring for why)."""
     b = tokens.shape[0]
-    n_layers, n_kv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    n_layers, spec = cfg.n_layers, cfg.kv_spec
     len0 = lengths
     pool_k, pool_v = cache.pool_k, cache.pool_v
     ks_pool, vs_pool = cache.k_scale, cache.v_scale
@@ -420,9 +469,12 @@ def paged_decode_horizon(
     # pool): the kernel DMAs them per page with no relayout — the old
     # token-major storage cost one full scale-pool relayout (~0.5 GB
     # on a 7B) per horizon program, scaling with pool capacity.
-    layer_params = params['layers']
-    ring_k = jnp.zeros((n_layers, b, horizon, n_kv, hd), cfg.dtype)
-    ring_v = jnp.zeros_like(ring_k)
+    ring_k = jnp.zeros((n_layers, b, horizon, spec.heads, spec.k_dim),
+                       cfg.dtype)
+    ring_v = jnp.zeros((n_layers, b, horizon, spec.heads, spec.v_dim),
+                       cfg.dtype)
+    live = (None if not cfg.latent or active is None
+            else active[:, None])
     if rngs is None:
         rngs = jnp.zeros((horizon, 2), jnp.uint32)
 
@@ -437,7 +489,22 @@ def paged_decode_horizon(
             rk = lax.dynamic_index_in_dim(ring_k, li, 0, keepdims=False)
             rv = lax.dynamic_index_in_dim(ring_v, li, 0, keepdims=False)
 
-            if decode_impl == 'pallas':
+            if cfg.latent:
+                # The absorbed form over the gathered latent and rope
+                # rows: one shared row a token under every query head
+                # (ops/latent_attention.py).
+                from skypilot_tpu.ops.latent_attention import (
+                    absorbed_ring_decode_attention)
+                with jax.named_scope('mla_attn'):   # the gather is its cost
+                    ck, _ = _gather_layer(pool_k, None, li, table_p)  # graftcheck: disable=GC121
+                    cv, _ = _gather_layer(pool_v, None, li, table_p)  # graftcheck: disable=GC121
+                cv = cv.reshape(cv.shape[0], -1, spec.v_dim)  # lane rows
+
+                def attn_fn(q_lat, q_rope, c, kr, scale):
+                    return absorbed_ring_decode_attention(
+                        q_lat, q_rope, c, kr, ck[:, :, 0], cv, len0,
+                        rk[:, :, 0], rv[:, :, 0], i, scale=scale)
+            elif decode_impl == 'pallas':
                 # The kernel takes the FULL stacked pool with the layer
                 # as a scalar-prefetch block index: slicing the pool
                 # here (dynamic_index_in_dim) would force XLA to
@@ -488,13 +555,13 @@ def paged_decode_horizon(
                                                  rk, rv, i, k_scale=sck,
                                                  v_scale=scv)
 
-            xc, new_kv, _ = llama._layer_core(layer, xc, cfg, positions,
-                                              attn_fn,
-                                              mlora_idx=mlora_idx)
-            return xc, new_kv
+            xc, new_kv, aux = llama._layer_core(
+                layer, xc, cfg, positions, attn_fn, mlora_idx=mlora_idx,
+                live=live)
+            return xc, (new_kv, aux)
 
-        x, (k_rows, v_rows) = lax.scan(
-            layer_body, x, (layer_params, jnp.arange(n_layers)))
+        x, ((k_rows, v_rows), aux) = llama.scan_layers(layer_body, x,
+                                                       params, cfg)
         ring_k = lax.dynamic_update_slice(
             ring_k, k_rows.astype(ring_k.dtype), (0, 0, i, 0, 0))
         ring_v = lax.dynamic_update_slice(
@@ -513,11 +580,17 @@ def paged_decode_horizon(
         # (host evicts exactly that request at readback; co-batched
         # slots continue) — see llama.mask_nonfinite_tokens.
         nxt = llama.mask_nonfinite_tokens(logits, nxt)
-        return (ring_k, ring_v, nxt), nxt
+        return (ring_k, ring_v, nxt), (nxt, jnp.sum(aux))
 
-    (ring_k, ring_v, _), toks = lax.scan(
+    (ring_k, ring_v, _), (toks, step_aux) = lax.scan(
         one_step, (ring_k, ring_v, tokens), (jnp.arange(horizon), rngs))
-    return toks.T, ring_k, ring_v
+    toks = toks.T
+    if cfg.ffn_kind == 'routed_shared':
+        # One more row under the slots' tokens: the distinct experts each
+        # step read, summed over its expert layers. It rides the one
+        # readback the call has (PagedInferenceEngine._process_one).
+        toks = jnp.concatenate([toks, step_aux.astype(toks.dtype)[None]])
+    return toks, ring_k, ring_v
 
 
 def merge_ring_into_pool(cache: PagedKVCache, ring_k, ring_v,
@@ -581,27 +654,42 @@ def paged_prefill_chunk(
     ks_pool, vs_pool = cache.k_scale, cache.v_scale
     x = llama._embed_tokens(params, tokens, cfg)
     positions = len0[:, None] + jnp.arange(chunk)[None, :]
+    # Padding rows of a piece route nowhere (a latent model's experts).
+    live = (jnp.arange(chunk)[None, :] < valid[:, None] if cfg.latent
+            else None)
 
     def layer_body(xc, layer_and_idx):
         layer, li = layer_and_idx
-        ck, sck = _gather_layer(pool_k, ks_pool, li, table_p)
-        cv, scv = _gather_layer(pool_v, vs_pool, li, table_p)
+        with (jax.named_scope('mla_attn') if cfg.latent
+              else contextlib.nullcontext()):       # the gather is its cost
+            ck, sck = _gather_layer(pool_k, ks_pool, li, table_p)
+            cv, scv = _gather_layer(pool_v, vs_pool, li, table_p)
 
-        def attn_fn(q, k, v):
-            return cached_attention(q, k, v, ck, cv, len0,
-                                    k_scale=sck, v_scale=scv)
+        if cfg.latent:
+            from skypilot_tpu.ops.latent_attention import (
+                absorbed_cached_attention)
+            cv = cv.reshape(n, -1, cfg.kv_spec.v_dim)   # lane rows
+
+            def attn_fn(q_lat, q_rope, c, kr, scale):
+                return absorbed_cached_attention(
+                    q_lat, q_rope, c, kr, ck[:, :, 0], cv, len0,
+                    scale=scale)
+        else:
+            def attn_fn(q, k, v):
+                return cached_attention(q, k, v, ck, cv, len0,
+                                        k_scale=sck, v_scale=scv)
 
         xc, new_kv, _ = llama._layer_core(layer, xc, cfg, positions,
-                                          attn_fn, mlora_idx=mlora_idx)
+                                          attn_fn, mlora_idx=mlora_idx,
+                                          live=live)
         # Quantize inside the scan: the stacked [L, n, chunk] ys stay
         # int8 (the bf16 stack is the 7B prefill's biggest transient).
         return xc, _maybe_quantize_rows(new_kv, cache.quant_mode)
 
-    import contextlib
     from skypilot_tpu.models.quantization import w8a8_region
     with (w8a8_region() if w8a8 else contextlib.nullcontext()):
-        x, (k_rows, v_rows) = lax.scan(
-            layer_body, x, (params['layers'], jnp.arange(cfg.n_layers)))
+        x, (k_rows, v_rows) = llama.scan_layers(layer_body, x, params,
+                                                cfg)
     x = llama.rms_norm(x, params['final_norm'], cfg.norm_eps,
                        cfg.norm_plus_one)
     idx = jnp.clip(want_idx, 0, chunk - 1)
@@ -889,8 +977,13 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                  adapter_rank: int = 8,
                  adapter_targets: Optional[Any] = None,
                  telemetry: bool = True):
-        from skypilot_tpu.inference.engine import prepare_params
+        from skypilot_tpu.inference.engine import (prepare_params,
+                                                   refuse_unsupported)
         from skypilot_tpu.parallel import mesh as mesh_lib
+        refuse_unsupported(cfg, engine='paged', quantize=quantize,
+                           speculate_k=speculate_k,
+                           adapter_slots=adapter_slots, mesh=mesh,
+                           decode_impl=decode_impl)
         self._init_telemetry(telemetry)
         self.max_batch = max_batch
         self.max_seq = max_seq
@@ -940,6 +1033,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         from skypilot_tpu.inference.engine import resolve_kv_cache_dtype
         self.kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype,
                                                      quantize)
+        refuse_unsupported(cfg, kv_cache_dtype=self.kv_cache_dtype,
+                           quantize=quantize)
         kv_int8 = self.kv_cache_dtype == 'int8'
         if page_size is None:
             page_size = self._auto_page_size(cfg, max_seq, kv_int8,
@@ -1014,8 +1109,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             from jax.sharding import NamedSharding
             self._ring_sh = NamedSharding(mesh, mesh_lib.spec_for(
                 ('layers', 'batch', None, 'kv_heads', 'head_dim'),
-                shape=(cfg.n_layers, max_batch, 1, cfg.n_kv_heads,
-                       cfg.head_dim),
+                shape=(cfg.n_layers, max_batch, 1, cfg.kv_spec.heads,
+                       cfg.kv_spec.k_dim),
                 mesh=mesh))
 
         on_tpu = jax.default_backend() == 'tpu'
@@ -1026,7 +1121,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # pools stay on the gather path: the packed uint8 page
             # blocks halve the minor dim below the 128-lane tile.
             decode_impl = ('pallas' if cfg.head_dim % 128 == 0
-                           and on_tpu
+                           and not cfg.latent and on_tpu
                            and self.kv_cache_dtype != 'int4'
                            and mesh is None else 'gather')
         elif (on_tpu and self.kv_cache_dtype == 'int4'
@@ -1102,6 +1197,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         for b in self._PREFILL_N_BUCKETS:
             if b <= n_fit:
                 self._prefill_n_max = b
+        # Expert layers a decode step runs (0: the model routes nothing),
+        # for the expert counters of the profiler.
+        self._moe_layers = (cfg.n_layers - cfg.n_dense_layers
+                            if cfg.ffn_kind == 'routed_shared' else 0)
         self.chunks_prefilled = 0          # diagnostics (prefix-hit wins)
         self.preemptions = 0               # pool-pressure recomputes
         # KV handoff programs (disaggregated serving): export page
@@ -1377,8 +1476,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             'decode_interpret': (
                 self.decode_impl in ('pallas', 'cross_layer')
                 and jax.default_backend() != 'tpu'),
-            # paged_prefill_chunk always takes cached_attention.
-            'prefill_attn': 'xla_two_block',
+            # paged_prefill_chunk always takes cached_attention, or a
+            # latent model its absorbed form: two-block XLA either way.
+            'prefill_attn': ('xla_absorbed_two_block' if self.cfg.latent
+                             else 'xla_two_block'),
             'page_size': self.page,
             'kv_pool_pages': self.alloc.n_pages,
             'pool_auto_sized': bool(self._pool_auto_sized),
@@ -1423,6 +1524,58 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 self.cfg, self.kv_cache_dtype, mesh=self.mesh),
             'kv_shards': kv_shard_degree(self.cfg, self.mesh),
         }
+
+    def _refuse_kv_transfer(self) -> None:
+        if self.cfg.latent:
+            raise NotImplementedError(
+                f'{self.cfg.name}: KV export/ingest (handoff, prefix '
+                'snapshots) moves [pages, kv_heads, head_dim] rows; the '
+                "wire format has no latent row yet (kv_transfer.py)")
+
+    # What one chunk program's transients may take beside the weights and
+    # the pool, and what a score element of its attention costs at the
+    # peak (the f32 logits with their exponentials and the cast
+    # probabilities: 0.378 GB for 84 M more elements between two
+    # compiles of GLM-4.7-Flash's program; compiler, PR 30). By this
+    # count qwen2-7b.chat's largest program (32 prompts x 16 pages: 2.11
+    # GB, which runs in the 2.9 GB that deployment leaves) is inside the
+    # budget and the 32 x 32 that does not fit (compiler, PR 28) outside.
+    _ATTN_SCORE_BYTES = 4.5
+    _CHUNK_TRANSIENT_BUDGET = int(2.2e9)
+
+    def _chunk_batch_cap(self, batch: List[int]) -> int:
+        """How many of ``batch``'s pieces one chunk program may hold. Its
+        attention scores are [prompts, heads, chunk, pages x page] at the
+        page bucket of the longest context in it and the prompt bucket
+        above the count, and nothing else capped them: 8k-token prompts
+        batched 32 wide ended the server with RESOURCE_EXHAUSTED. Routed
+        experts add their sorted rows (top-k copies of a token through
+        gate, up and down). Slot-parity pools (the CPU) were sized
+        against nothing and are not capped."""
+        if not self._pool_auto_sized:
+            return len(batch)
+        from skypilot_tpu.inference.engine import _bucket_len
+        cfg = self.cfg
+        page_bytes = (cfg.n_heads * self.chunk * self.page
+                      * self._ATTN_SCORE_BYTES)
+        row_bytes = 0
+        if cfg.ffn_kind == 'routed_shared':
+            row_bytes = self.chunk * cfg.n_experts_per_token * (
+                6 * cfg.dim + 8 * cfg.moe_ffn_dim)
+        pages, fit = 1, 1
+        for count, slot in enumerate(batch, 1):
+            req = self._slots[slot]
+            rest = (len(req._ctx) - req._n_matched * self.page
+                    - self._prefill_off[slot])
+            pages = max(pages, _bucket_len(self._pages_needed(
+                int(self._slot_len[slot]) + min(self.chunk, rest)),
+                minimum=1))
+            n = next(b for b in self._PREFILL_N_BUCKETS if b >= count)
+            if n * (pages * page_bytes + row_bytes) \
+                    > self._CHUNK_TRANSIENT_BUDGET:
+                break
+            fit = count
+        return fit
 
     # ---------------------------------------------------------- admission
     def _pages_needed(self, tokens: int) -> int:
@@ -1673,6 +1826,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         if not pending:
             return []
         batch = pending[:self._prefill_n_max]
+        batch = batch[:self._chunk_batch_cap(batch)]
         n = next(b for b in self._PREFILL_N_BUCKETS if b >= len(batch))
         # Chunk-width variant: when every pending piece fits 128
         # tokens (the common case with a prefix-cache hit — e.g. a
@@ -1739,7 +1893,14 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # operand: each separate jnp.asarray is its own dispatch round
         # trip, nine of them per admission otherwise.
         extras = tuple(x for x in (adp_h, vm_h) if x is not None)
-        with self._prof.phase('admit_upload'):
+        # Query-key pairs under the causal mask that the chunk needs, a
+        # layer: each valid row against its context and the piece up to
+        # itself. On the upload's annotation too, so that a trace holds
+        # the pairs of exactly the chunks it holds.
+        pairs = sum(int(v) * int(c) + int(v) * (int(v) + 1) // 2
+                    for v, c in zip(valid[:len(batch)],
+                                    lengths[:len(batch)]))
+        with self._prof.phase('admit_upload', pairs=pairs):
             uploaded = device_upload(
                 (table_p, tokens, lengths, valid, want, temps, topks,
                  topps) + extras)
@@ -1765,6 +1926,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 topps_d, prng)
         chunk_t1 = clock.monotonic()
         self.chunks_prefilled += 1
+        self._prof.note_prefill_pairs(pairs)
         for i, slot in enumerate(batch):
             r = self._slots[slot]
             if r.trace is not None:
@@ -2023,6 +2185,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         dequantized (the int8-on-the-wire contract GC114 gates)."""
         if P in self._export_fns:
             return self._export_fns[P]
+        self._refuse_kv_transfer()
         page = self.page
         quantized = self.cache.quantized
 
@@ -2071,6 +2234,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         key = (nb, P)
         if key in self._ingest_fns:
             return self._ingest_fns[key]
+        self._refuse_kv_transfer()
         quantized = self.cache.quantized
         mesh = self.mesh
 
@@ -2521,7 +2685,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # decode substeps (multi-step amortization; the profiler's
         # per_substep_ms split makes it visible).
         self._prof.note_substeps('decode_enqueue', horizon,
-                                 live_rows=len(active_slots))
+                                 live_rows=len(active_slots),
+                                 moe_layers=self._moe_layers,
+                                 top_k=self.cfg.n_experts_per_token)
         self._prof.tag(horizon=horizon, pages=P)
         with self._prof.jit_key('decode', (horizon, sample, P)):
             toks, self.cache = self._decode_fn(
@@ -2531,7 +2697,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 self._vmask_dev, horizon, sample)
         self._note_decode_step(
             int(sum(int(lengths[s]) for s in active_slots)))
-        self._tok_dev = toks[:, -1]
+        self._tok_dev = toks[:self.max_batch, -1]
         # Snapshot the epochs BEFORE any early free below bumps them:
         # the entry must record the epochs its tokens were produced
         # under, or a recycled slot's stale entry would pass the epoch
@@ -2588,6 +2754,11 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 finished = self._finish_req(slot, req, token)
                 events.append((req.request_id, token, finished))
             return events
+        if self._moe_layers:
+            # The row under the slots' tokens (paged_decode_horizon).
+            self._prof.note_distinct_experts(
+                int(vals[self.max_batch].sum()),
+                entry['horizon'] * self._moe_layers)
         for slot, req in enumerate(entry['snapshot']):
             if req is None:
                 continue

@@ -7,14 +7,33 @@ The reference ships *recipes* that launch external frameworks
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 
 
+class KVSpec(NamedTuple):
+    """The cache rows of one token of one layer: ``heads`` rows of
+    ``k_dim`` values in the K pool and as many of ``v_dim`` in the V
+    pool. GQA: (n_kv_heads, head_dim, head_dim). Latent attention: one
+    shared row, the normed latent in the K pool and the roped key part
+    in the V pool (no per-head K or V is ever stored)."""
+    heads: int
+    k_dim: int
+    v_dim: int
+
+    @property
+    def row_values(self) -> int:
+        return self.heads * (self.k_dim + self.v_dim)
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """A decoder-only transformer configuration (Llama-family)."""
+    """A decoder-only transformer configuration. ``attn_kind`` and
+    ``ffn_kind`` name what a layer is made of: grouped-query attention
+    with a dense gated FFN (the Llama family, the default), or latent
+    attention (MLA) with routed + shared experts behind
+    ``n_dense_layers`` leading dense layers (``models/latent_moe.py``)."""
     name: str
     vocab_size: int
     dim: int                    # model/embedding width
@@ -55,6 +74,30 @@ class ModelConfig:
     lora_rank: int = 0
     lora_alpha: float = 16.0
     lora_targets: tuple = ('wq', 'wk', 'wv', 'wo')
+    # Attention kind: 'gqa' | 'latent'. Latent (MLA) projects queries
+    # through ``q_lora_rank`` and caches one shared row a token a layer:
+    # ``kv_lora_rank`` latent values and ``qk_rope_head_dim`` rotary
+    # ones; a head's query/key is ``qk_nope_head_dim`` + rope wide, its
+    # value ``v_head_dim``.
+    attn_kind: str = 'gqa'
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # FFN kind of the layers after the first ``n_dense_layers`` (those
+    # are dense SwiGLU of width ``ffn_dim``): 'dense' | 'routed_shared'
+    # = ``n_routed_experts`` dropless experts of width ``moe_ffn_dim``,
+    # ``n_experts_per_token`` a token by sigmoid score (a selection-only
+    # bias, weights renormalised and scaled by
+    # ``routed_scaling_factor``), beside ``n_shared_experts`` experts
+    # every token takes.
+    ffn_kind: str = 'dense'
+    n_dense_layers: int = 0
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    moe_ffn_dim: int = 0
+    routed_scaling_factor: float = 1.0
 
     @property
     def lora_enabled(self) -> bool:
@@ -75,8 +118,22 @@ class ModelConfig:
         return self.n_experts is not None
 
     @property
+    def latent(self) -> bool:
+        return self.attn_kind == 'latent'
+
+    @property
+    def kv_spec(self) -> KVSpec:
+        """What one cached token of one layer is made of."""
+        if self.latent:
+            return KVSpec(1, self.kv_lora_rank, self.qk_rope_head_dim)
+        return KVSpec(self.n_kv_heads, self.head_dim, self.head_dim)
+
+    @property
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + head)."""
+        if self.latent:
+            from skypilot_tpu.models import latent_moe
+            return latent_moe.num_params(self)
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
         q_dim = self.n_heads * self.head_dim
         kv_dim = self.n_kv_heads * self.head_dim
@@ -92,6 +149,9 @@ class ModelConfig:
     def flops_per_token(self, training: bool = False) -> float:
         """~2*N matmul FLOPs per token fwd (6*N with backward)."""
         n = self.num_params
+        if self.latent:
+            from skypilot_tpu.models import latent_moe
+            n = latent_moe.num_params(self, active_only=True)
         if self.is_moe:
             # only active experts count
             d, f = self.dim, self.ffn_dim
@@ -155,10 +215,33 @@ TINY_QWEN = _cfg(name='tiny-qwen', vocab_size=256, dim=64, n_layers=2,
                  n_heads=4, n_kv_heads=2, ffn_dim=128, max_seq_len=128,
                  remat='none', qkv_bias=True)
 
+# zai-org/GLM-4.7-Flash (``glm4_moe_lite``) as published; its
+# multi-token-prediction block is a draft head outside the forward pass
+# and is not held.
+GLM_4_7_FLASH = _cfg(
+    name='glm-4.7-flash', vocab_size=154880, dim=2048, n_layers=47,
+    n_heads=20, n_kv_heads=20, ffn_dim=10240, max_seq_len=202752,
+    rope_theta=1000000.0, norm_eps=1e-5, attn_kind='latent',
+    q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+    qk_rope_head_dim=64, v_head_dim=256, ffn_kind='routed_shared',
+    n_dense_layers=1, n_routed_experts=64, n_experts_per_token=4,
+    n_shared_experts=1, moe_ffn_dim=1536, routed_scaling_factor=1.8)
+
+# Every size differs from its neighbour where a mix-up would hide:
+# nope != rope != v, the dense width != the expert width.
+TINY_GLM = _cfg(
+    name='tiny-glm', vocab_size=256, dim=64, n_layers=3, n_heads=4,
+    n_kv_heads=4, ffn_dim=160, max_seq_len=128, remat='none',
+    attn_kind='latent', q_lora_rank=48,
+    kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=8,
+    v_head_dim=16, ffn_kind='routed_shared', n_dense_layers=1,
+    n_routed_experts=8, n_experts_per_token=2, n_shared_experts=1,
+    moe_ffn_dim=96, routed_scaling_factor=1.8)
+
 PRESETS = {c.name: c for c in [
     LLAMA3_8B, LLAMA3_70B, LLAMA2_7B, LLAMA3_1B, MIXTRAL_8X7B,
     GEMMA_2B, GEMMA_7B, QWEN2_7B, TINY, TINY_MOE, TINY_GEMMA,
-    TINY_QWEN]}
+    TINY_QWEN, GLM_4_7_FLASH, TINY_GLM]}
 
 
 def get_config(name: str) -> ModelConfig:

@@ -43,6 +43,9 @@ def _dense_init(key, shape, dtype, fan_in):
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Initialize parameters. Layer params are stacked on a leading
     ``layers`` axis for lax.scan."""
+    if cfg.latent:
+        from skypilot_tpu.models import latent_moe
+        return latent_moe.init_params(rng, cfg)
     d, hd = cfg.dim, cfg.head_dim
     n_h, n_kv, f, L = cfg.n_heads, cfg.n_kv_heads, cfg.ffn_dim, cfg.n_layers
     keys = jax.random.split(rng, 8)
@@ -98,6 +101,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     """Same structure as ``init_params``, with logical-axis tuples as leaves.
 
     The leading scan axis is 'layers' (never sharded)."""
+    if cfg.latent:
+        from skypilot_tpu.models import latent_moe
+        return latent_moe.param_logical_axes(cfg)
     axes: Params = {
         'embed': ('vocab_in', 'embed'),
         'final_norm': ('norm',),
@@ -452,19 +458,66 @@ def _ffn(layer: Params, x: jax.Array, cfg: ModelConfig,
     return down
 
 
+def layer_stacks(params: Params, cfg: ModelConfig):
+    """[(stacked layer params, index of the stack's first layer)]: the
+    one ``layers`` stack, or a latent model's dense and routed stacks."""
+    if cfg.latent:
+        from skypilot_tpu.models import latent_moe
+        return latent_moe.layer_stacks(params, cfg)
+    return [(params['layers'], 0)]
+
+
+def scan_layers(body, x: jax.Array, params: Params, cfg: ModelConfig):
+    """``lax.scan`` of ``body(x, (layer, li))`` over every layer stack in
+    turn, ``li`` the layer's index in the model (its row of a stacked
+    cache); the stacks' per-layer outputs come back as one, layer-major.
+
+    A stack's ``experts`` are not scanned: a layer sliced out of the scan
+    input is a copy, and as a kernel's operand that copy is made in full
+    (604 MB of expert weights a layer step on GLM-4.7-Flash: every expert
+    read, whatever was routed; compiler, PR 30). The layer gets the whole
+    stack with ``expert_layer``, its row in it, and the grouped matmul
+    takes the row as a group offset."""
+    outs = []
+    for stack, first in layer_stacks(params, cfg):
+        held = {k: stack[k] for k in ('experts',) if k in stack}
+        scanned = ({k: v for k, v in stack.items() if k not in held}
+                   if held else stack)
+        idx = jnp.arange(jax.tree.leaves(scanned)[0].shape[0])
+
+        def step(carry, layer_idx, held=held, first=first):
+            layer, i = layer_idx
+            if held:
+                layer = dict(layer, expert_layer=i, **held)
+            return body(carry, (layer, i + first if first else i))
+
+        x, ys = lax.scan(step, x, (scanned, idx))
+        outs.append(ys)
+    if len(outs) == 1:
+        return x, outs[0]
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
+
+
 def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
                 positions: jax.Array, attn_fn,
-                mlora_idx: Optional[jax.Array] = None):
+                mlora_idx: Optional[jax.Array] = None,
+                live: Optional[jax.Array] = None):
     """One transformer layer, parameterized by the attention op so every
     path (training full-sequence, prefill/decode against a cache, the
     fused serving loop) shares ONE copy of the layer math. ``attn_fn``
-    maps roped (q, k, v) to the attention output.
+    maps roped (q, k, v) to the attention output (a latent model's maps
+    its absorbed queries and new cache rows: ``latent_moe.layer_core``,
+    which alone reads ``live``, the rows that carry a token).
 
     ``mlora_idx`` ([b] int32, -1 = none) gathers per-row adapters from
     the ``layer['mlora']`` bank slice (multi-tenant serving); None (the
     default, and every training/eval path) leaves the math untouched.
 
     Returns (x, (k, v) new kv rows, moe aux loss)."""
+    if cfg.latent:
+        from skypilot_tpu.models import latent_moe
+        return latent_moe.layer_core(layer, x, cfg, positions, attn_fn,
+                                     live=live)
     from jax.ad_checkpoint import checkpoint_name
     h = rms_norm(x, layer['attn_norm'], cfg.norm_eps,
                   cfg.norm_plus_one)
@@ -522,7 +575,9 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
 def _layer_fn(layer: Params, x: jax.Array, cfg: ModelConfig,
               positions: jax.Array,
               cache_kv, cache_len, attn_impl: str):
-    if cache_kv is None:
+    if cfg.latent:
+        attn_fn = None          # expanded causal form over the sequence
+    elif cache_kv is None:
         def attn_fn(q, k, v):
             return attention(q, k, v, causal=True, impl=attn_impl)
     else:
@@ -569,6 +624,10 @@ def forward(
     Returns (logits [b, s, vocab], new_cache or None), plus the mean MoE
     load-balancing aux loss when ``return_aux`` (0 for dense models).
     """
+    if cfg.latent and cache is not None:
+        raise NotImplementedError(
+            'a latent-attention model has no contiguous KVCache: it '
+            'decodes through the paged pool (inference/paged.py)')
     x = _embed_tokens(params, tokens, cfg)
     x = _shard(x, 'batch', 'seq', 'embed')
     b, s = tokens.shape
@@ -654,11 +713,11 @@ def forward(
                                           pp_mesh, with_aux=True)
             aux_layers = aux_mean[None]
         else:
-            def scan_body(carry, layer):
-                out, _, aux = body(carry, (layer, None))
+            def scan_body(carry, layer_idx):
+                out, _, aux = body(carry, (layer_idx[0], None))
                 return out, aux
 
-            x, aux_layers = lax.scan(scan_body, x, layer_params)
+            x, aux_layers = scan_layers(scan_body, x, params, cfg)
         new_cache = None
     else:
         # The cache is a loop INVARIANT (closed over, indexed per layer),
@@ -821,12 +880,17 @@ def prefill_rows(
             def attn_fn(q, k, v):
                 return attention(q, k, v, causal=True, impl=attn_impl)
 
-            xc, (k, v), _ = _layer_core(layer, carry, cfg, positions,
-                                        attn_fn, mlora_idx=mlora_idx)
+            xc, (k, v), _ = _layer_core(
+                layer, carry, cfg, positions,
+                None if cfg.latent else attn_fn, mlora_idx=mlora_idx)
             return xc, emit_rows(k, v)
 
-        xs = params['layers']
+        xs = None               # every layer stack in turn, below
     else:
+        if cfg.latent:
+            raise NotImplementedError(
+                'chunked prefill of a latent-attention model runs in '
+                'the paged engine (paged_prefill_chunk)')
         if len(cache_kv) == 4:
             ck_all, cv_all, ks_all, vs_all = cache_kv
         else:
@@ -856,7 +920,11 @@ def prefill_rows(
     ctx = (quantization.w8a8_region() if w8a8
            else contextlib.nullcontext())
     with ctx:
-        x, rows = lax.scan(body, x, xs)
+        if xs is None:
+            x, rows = scan_layers(lambda c, layer_idx: body(c, layer_idx[0]),
+                                  x, params, cfg)
+        else:
+            x, rows = lax.scan(body, x, xs)
     x = rms_norm(x, params['final_norm'], cfg.norm_eps,
                  cfg.norm_plus_one)
     if all_logits:

@@ -1646,6 +1646,14 @@ class ModelServer:
                     profiler_lib.SUBSTEP_METRIC),
                 'decode_live_rows_total': self._counter_value(
                     profiler_lib.LIVE_ROWS_METRIC),
+                'moe_layer_steps_total': self._counter_value(
+                    profiler_lib.MOE_LAYER_STEPS_METRIC),
+                'moe_distinct_experts_total': self._counter_value(
+                    profiler_lib.MOE_DISTINCT_METRIC),
+                'moe_assignments_total': self._counter_value(
+                    profiler_lib.MOE_ASSIGNMENTS_METRIC),
+                'prefill_attn_pairs_total': self._counter_value(
+                    profiler_lib.PREFILL_PAIRS_METRIC),
             },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),

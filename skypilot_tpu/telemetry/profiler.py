@@ -46,6 +46,12 @@ PHASE_METRIC = 'skytpu_engine_step_phase_seconds'
 COMPILE_METRIC = 'skytpu_jit_first_call_seconds'
 SUBSTEP_METRIC = 'skytpu_engine_decode_substeps_total'
 LIVE_ROWS_METRIC = 'skytpu_engine_decode_live_rows_total'
+# Expert layers (models/latent_moe.py) and prefill attention: what the
+# benchmark's roofline shares count the need from.
+MOE_LAYER_STEPS_METRIC = 'skytpu_moe_layer_steps_total'
+MOE_DISTINCT_METRIC = 'skytpu_moe_distinct_experts_total'
+MOE_ASSIGNMENTS_METRIC = 'skytpu_moe_assignments_total'
+PREFILL_PAIRS_METRIC = 'skytpu_prefill_attn_pairs_total'
 ANNOTATION_PREFIX = 'skytpu:'
 
 
@@ -67,8 +73,15 @@ class NullProfiler:
     def tag(self, **key: Any) -> None:
         del key
 
-    def note_substeps(self, name: str, n: int, live_rows: int = 0) -> None:
-        del name, n, live_rows
+    def note_substeps(self, name: str, n: int, live_rows: int = 0,
+                      moe_layers: int = 0, top_k: int = 0) -> None:
+        del name, n, live_rows, moe_layers, top_k
+
+    def note_distinct_experts(self, n: int, layer_steps: int) -> None:
+        del n, layer_steps
+
+    def note_prefill_pairs(self, n: int) -> None:
+        del n
 
     def phase_stats(self) -> Dict[str, Any]:
         return {}
@@ -103,6 +116,24 @@ class StepProfiler:
             'Batch rows that carried a request, summed over the decode '
             'substeps of enqueued dispatches (over the substeps '
             'counter: the mean live batch of a step)')
+        self._moe_layer_steps = self._reg.counter(
+            MOE_LAYER_STEPS_METRIC,
+            'Expert layers run, summed over the decode substeps of '
+            'enqueued dispatches')
+        self._moe_distinct = self._reg.counter(
+            MOE_DISTINCT_METRIC,
+            'Distinct experts a decode substep read, summed over its '
+            'expert layers (counted on the device, read back with the '
+            "call's tokens; over the layer steps: the mean an expert "
+            'layer reads)')
+        self._moe_assignments = self._reg.counter(
+            MOE_ASSIGNMENTS_METRIC,
+            'Token-to-expert assignments of live rows, summed over '
+            'expert layers and decode substeps')
+        self._prefill_pairs = self._reg.counter(
+            PREFILL_PAIRS_METRIC,
+            'Query-key pairs under the causal mask that enqueued '
+            'prefill chunks needed, per layer')
         self._hists: Dict[str, registry_lib.Histogram] = {}
         self._seen_keys: Dict[str, set] = {}
         self.compile_events: List[Dict[str, Any]] = []
@@ -188,17 +219,35 @@ class StepProfiler:
                     {'fn': fn, 'key': repr(key),
                      'seconds': round(dt, 6)})
 
-    def note_substeps(self, name: str, n: int, live_rows: int = 0) -> None:
+    def note_substeps(self, name: str, n: int, live_rows: int = 0,
+                      moe_layers: int = 0, top_k: int = 0) -> None:
         """Record that the NEXT/current ``name`` dispatch covers ``n``
         device substeps (multi-step decode's per-substep attribution),
-        ``live_rows`` of its batch rows carrying a request. Host-side
-        counter bumps only — nothing touches the device."""
+        ``live_rows`` of its batch rows carrying a request, each through
+        ``moe_layers`` expert layers of ``top_k`` experts a token.
+        Host-side counter bumps only — nothing touches the device."""
         if n <= 0:
             return
         self._substep_counter.inc(n)
         self._live_rows_counter.inc(n * live_rows)
+        if moe_layers:
+            self._moe_layer_steps.inc(n * moe_layers)
+            self._moe_assignments.inc(n * moe_layers * live_rows * top_k)
         with self._lock:
             self._substeps[name] = self._substeps.get(name, 0) + n
+
+    def note_distinct_experts(self, n: int, layer_steps: int) -> None:
+        """Distinct experts a decode dispatch read over its
+        ``layer_steps`` (substeps x expert layers), as read back with its
+        tokens; traced, an instant ``skytpu:moe_readback`` carries both,
+        so that a trace holds the counts of the calls it holds."""
+        self._moe_distinct.inc(n)
+        if self._annotate('moe_readback', {'distinct': n,
+                                           'layer_steps': layer_steps}):
+            self._open.pop().__exit__(None, None, None)
+
+    def note_prefill_pairs(self, n: int) -> None:
+        self._prefill_pairs.inc(n)
 
     def phase_stats(self) -> Dict[str, Any]:
         """Per-phase summary for THIS engine (bench's latency

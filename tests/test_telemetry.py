@@ -995,8 +995,16 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
     assert set(loop) == {'clock_s', 'lock_held_seconds_total',
                          'lock_wait_seconds_total',
                          'decode_substeps_total',
-                         'decode_live_rows_total'}
+                         'decode_live_rows_total',
+                         # expert layers and prefill attention (PR 30):
+                         # zeros where the model routes nothing
+                         'moe_layer_steps_total',
+                         'moe_distinct_experts_total',
+                         'moe_assignments_total',
+                         'prefill_attn_pairs_total'}
     assert all(isinstance(v, (int, float)) for v in loop.values())
+    assert loop['moe_layer_steps_total'] == 0
+    assert loop['prefill_attn_pairs_total'] > 0     # the warm-up's prompt
     # The boot's warm-up request ran on the engine directly: substeps
     # are counted, but no engine-loop turn has taken the lock yet.
     assert loop['lock_held_seconds_total'] == 0.0

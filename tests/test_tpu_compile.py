@@ -241,3 +241,40 @@ def test_engine_refuses_int4_kv_kernels_on_tpu(monkeypatch, decode_impl):
                              n_pages=8, page_size=8,
                              kv_cache_dtype='int4',
                              decode_impl=decode_impl)
+
+
+@pytest.mark.parametrize('rows', [128, 4096])    # a decode step (32 slots
+def test_grouped_expert_matmuls_compile_at_glm_widths(   # x top-4), a chunk
+        one_chip, monkeypatch, rows):
+    """The dropless expert layer of GLM-4.7-Flash at its published widths
+    (64 experts of 2048 x 1536 in a stack of 7 layers, bf16), through the
+    kernel and not interpret mode; the whole stacked weights are the
+    kernel's operand (no layer of them is sliced out as a copy), and the
+    compiled text holds the kernel three times."""
+    from skypilot_tpu.models import latent_moe
+    cfg = configs.GLM_4_7_FLASH
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    L, E, d, f, k = 7, cfg.n_routed_experts, cfg.dim, cfg.moe_ffn_dim, \
+        cfg.n_experts_per_token
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    experts = {'w_gate': s((L, E, d, f), jnp.bfloat16),
+               'w_up': s((L, E, d, f), jnp.bfloat16),
+               'w_down': s((L, E, f, d), jnp.bfloat16)}
+    T = rows // k
+
+    def fn(experts, layer, x, chosen, w, live):
+        return latent_moe.routed_experts(experts, layer, x, chosen, w,
+                                         live, cfg)
+
+    compiled = jax.jit(fn).lower(
+        experts, s((), jnp.int32), s((T, d), jnp.bfloat16),
+        s((T, k), jnp.int32), s((T, k), jnp.float32),
+        s((T,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 3
+    # nothing expert-stack sized is copied: temps stay far under one
+    # layer's experts (604 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
