@@ -310,12 +310,15 @@ def _sub_jaxprs(eqn) -> List[Any]:
 
 def _kernel_kv_layer_sliced(eqn) -> bool:
     """True when this pallas_call is one of the annotated layer-stacked
-    pool kernels (:data:`ops.paged_attention.COST_KERNEL_KV_TRAFFIC`):
-    the kernel takes the FULL stacked pool with the layer as a
-    scalar-prefetch index and DMAs one layer's pages per call."""
+    pool kernels (``COST_KERNEL_KV_TRAFFIC`` of ``ops.paged_attention``
+    and ``ops.latent_paged_attention``): the kernel takes the FULL
+    stacked pool with the layer as a scalar-prefetch index and DMAs one
+    layer's pages per call."""
     try:
+        from skypilot_tpu.ops import latent_paged_attention as lpa
         from skypilot_tpu.ops import paged_attention as pa
-        names = set(pa.COST_KERNEL_KV_TRAFFIC)
+        names = set(pa.COST_KERNEL_KV_TRAFFIC) | set(
+            lpa.COST_KERNEL_KV_TRAFFIC)
     except ImportError:     # ops unavailable in a lint-only install
         return False
     tag = str(eqn.params.get('name_and_src_info',
@@ -834,11 +837,15 @@ def engine_dispatch_costs(engine, fns: Sequence[Callable],
         # args[10]/args[11] are the adapter indices / vocab mask; the
         # merge consumes neither.
         horizon = args[12]
-        ring = jax.ShapeDtypeStruct(
-            (cfg.n_layers, engine.max_batch, horizon, cfg.n_kv_heads,
-             cfg.head_dim), cfg.dtype,
-            sharding=getattr(engine, '_ring_sh', None))
-        margs = (cache, ring, ring, table, lengths, active)
+        spec = cfg.kv_spec
+
+        def ring(width):
+            return jax.ShapeDtypeStruct(
+                (cfg.n_layers, engine.max_batch, horizon, spec.heads,
+                 width), cfg.dtype,
+                sharding=getattr(engine, '_ring_sh', None))
+        margs = (cache, ring(spec.k_dim), ring(spec.v_dim), table, lengths,
+                 active)
         mcls = (classify_cache(cache), ACTIVATION, ACTIVATION,
                 TABLE, TABLE, TABLE)
         out['merge'] = trace_dispatch(fn, margs, mcls, label='merge')

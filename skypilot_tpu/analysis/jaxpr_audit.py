@@ -1297,11 +1297,14 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     'fleet-obs': audit_fleet_obs,
     'llama': audit_llama_forward,
     # Latent attention + dropless routed experts (tiny-glm) through the
-    # paged engine: the latent pool, the grouped-matmul kernel's
-    # dynamic grid and the experts-read count riding the token readback
-    # add zero unsanctioned d2h and zero steady-state recompiles.
+    # paged engine as the chip runs it, decode through the latent paged
+    # kernel: the latent pool, the grouped-matmul kernel's dynamic grid
+    # and the experts-read count riding the token readback add zero
+    # unsanctioned d2h and zero steady-state recompiles, and the decode
+    # dispatch gathers no page of the pool.
     'paged-latent-moe': lambda: audit_engine('paged', chunked=True,
-                                             model='tiny-glm'),
+                                             model='tiny-glm',
+                                             decode_impl='pallas'),
 }
 
 # Presets that need a multi-device backend: preset -> device count.

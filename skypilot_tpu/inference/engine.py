@@ -231,12 +231,11 @@ def refuse_unsupported(cfg, **asked) -> None:
         'mesh': (asked.get('mesh') is not None,
                  'the one shared latent row cannot shard over tp, and '
                  'experts are not yet placed over a mesh'),
-        'decode_impl': (asked.get('decode_impl') in ('pallas',
-                                                     'cross_layer'),
-                        'ops/paged_attention.py contracts K and V rows '
-                        "of one width under per-head groups; 'auto' "
-                        'takes the absorbed XLA form over gathered '
-                        'pages'),
+        'decode_impl': (asked.get('decode_impl') == 'cross_layer',
+                        'the fused-merge kernel contracts K and V rows '
+                        "of one width under per-head groups; 'pallas' "
+                        'is the latent paged kernel, with the ring '
+                        'merged in XLA'),
     }
     for name, (hit, why) in reasons.items():
         if hit:

@@ -489,10 +489,33 @@ def paged_decode_horizon(
             rk = lax.dynamic_index_in_dim(ring_k, li, 0, keepdims=False)
             rv = lax.dynamic_index_in_dim(ring_v, li, 0, keepdims=False)
 
-            if cfg.latent:
-                # The absorbed form over the gathered latent and rope
-                # rows: one shared row a token under every query head
-                # (ops/latent_attention.py).
+            if cfg.latent and decode_impl == 'pallas':
+                # The cached rows through the latent paged kernel, each
+                # live slot's own pages DMA'd from the pools as they
+                # lie; ring and current token merged in XLA
+                # (ops/latent_paged_attention.py).
+                from skypilot_tpu.ops.latent_paged_attention import (
+                    latent_paged_decode_attention,
+                    merge_latent_partial_with_ring_self)
+                interp = jax.default_backend() != 'tpu'
+                # A slot the host has freed reads nothing, whatever
+                # length it was left with.
+                read_len = (len0 if active is None
+                            else jnp.where(active, len0, 0))
+
+                def attn_fn(q_lat, q_rope, c, kr, scale):
+                    partial = latent_paged_decode_attention(
+                        q_lat[:, 0], q_rope[:, 0], pool_k, pool_v,
+                        table_p, read_len, layer=li, scale=scale,
+                        interpret=interp)
+                    return merge_latent_partial_with_ring_self(
+                        partial, q_lat, q_rope, c, kr, rk[:, :, 0],
+                        rv[:, :, 0], i, scale=scale)
+            elif cfg.latent:
+                # The XLA fallback (CPU, a rope width that does not
+                # pack) and the kernel's oracle: the absorbed form over
+                # the gathered latent and rope rows, one shared row a
+                # token under every query head (ops/latent_attention.py).
                 from skypilot_tpu.ops.latent_attention import (
                     absorbed_ring_decode_attention)
                 with jax.named_scope('mla_attn'):   # the gather is its cost
@@ -1120,10 +1143,18 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # on a real TPU backend (tests opt in explicitly). int4
             # pools stay on the gather path: the packed uint8 page
             # blocks halve the minor dim below the 128-lane tile.
-            decode_impl = ('pallas' if cfg.head_dim % 128 == 0
-                           and not cfg.latent and on_tpu
-                           and self.kv_cache_dtype != 'int4'
-                           and mesh is None else 'gather')
+            # A latent cache has its own kernel
+            # (ops/latent_paged_attention.py): a bf16 pool of
+            # 128-lane latent rows beside the lane-packed rope pool.
+            if cfg.latent:
+                fits = (cfg.kv_lora_rank % _LANES == 0
+                        and self.cache.pool_k.dtype == jnp.bfloat16
+                        and self.cache.pool_v.shape[-1] == _LANES)
+            else:
+                fits = (cfg.head_dim % 128 == 0
+                        and self.kv_cache_dtype != 'int4')
+            decode_impl = ('pallas' if fits and on_tpu and mesh is None
+                           else 'gather')
         elif (on_tpu and self.kv_cache_dtype == 'int4'
               and decode_impl in ('pallas', 'cross_layer')):
             # Mosaic refuses the in-kernel nibble unpack of a packed
@@ -2688,6 +2719,12 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                                  live_rows=len(active_slots),
                                  moe_layers=self._moe_layers,
                                  top_k=self.cfg.n_experts_per_token)
+        if self.cfg.latent:
+            # What the latent paged kernel reads of the padded table.
+            self._prof.note_decode_attn_pages(
+                horizon * sum(self._pages_needed(int(lengths[s]))
+                              for s in active_slots),
+                horizon * self.max_batch * P)
         self._prof.tag(horizon=horizon, pages=P)
         with self._prof.jit_key('decode', (horizon, sample, P)):
             toks, self.cache = self._decode_fn(

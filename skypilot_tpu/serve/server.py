@@ -1654,6 +1654,10 @@ class ModelServer:
                     profiler_lib.MOE_ASSIGNMENTS_METRIC),
                 'prefill_attn_pairs_total': self._counter_value(
                     profiler_lib.PREFILL_PAIRS_METRIC),
+                'decode_attn_pages_live_total': self._counter_value(
+                    profiler_lib.ATTN_PAGES_LIVE_METRIC),
+                'decode_attn_pages_table_total': self._counter_value(
+                    profiler_lib.ATTN_PAGES_TABLE_METRIC),
             },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),

@@ -52,6 +52,10 @@ MOE_LAYER_STEPS_METRIC = 'skytpu_moe_layer_steps_total'
 MOE_DISTINCT_METRIC = 'skytpu_moe_distinct_experts_total'
 MOE_ASSIGNMENTS_METRIC = 'skytpu_moe_assignments_total'
 PREFILL_PAIRS_METRIC = 'skytpu_prefill_attn_pairs_total'
+# The latent paged decode kernel (ops/latent_paged_attention.py): pages
+# it reads against pages the padded table holds.
+ATTN_PAGES_LIVE_METRIC = 'skytpu_decode_attn_pages_live_total'
+ATTN_PAGES_TABLE_METRIC = 'skytpu_decode_attn_pages_table_total'
 ANNOTATION_PREFIX = 'skytpu:'
 
 
@@ -82,6 +86,9 @@ class NullProfiler:
 
     def note_prefill_pairs(self, n: int) -> None:
         del n
+
+    def note_decode_attn_pages(self, live: int, table: int) -> None:
+        del live, table
 
     def phase_stats(self) -> Dict[str, Any]:
         return {}
@@ -134,6 +141,16 @@ class StepProfiler:
             PREFILL_PAIRS_METRIC,
             'Query-key pairs under the causal mask that enqueued '
             'prefill chunks needed, per layer')
+        self._attn_pages_live = self._reg.counter(
+            ATTN_PAGES_LIVE_METRIC,
+            "Pages of live rows' own contexts (ceil(length / page) a "
+            'row) that the latent paged decode kernel read a layer, '
+            'summed over decode substeps')
+        self._attn_pages_table = self._reg.counter(
+            ATTN_PAGES_TABLE_METRIC,
+            'Pages the padded page table of those dispatches held (slots '
+            'x page bucket), summed over decode substeps (under the live '
+            'counter: the share of the table that is live)')
         self._hists: Dict[str, registry_lib.Histogram] = {}
         self._seen_keys: Dict[str, set] = {}
         self.compile_events: List[Dict[str, Any]] = []
@@ -248,6 +265,13 @@ class StepProfiler:
 
     def note_prefill_pairs(self, n: int) -> None:
         self._prefill_pairs.inc(n)
+
+    def note_decode_attn_pages(self, live: int, table: int) -> None:
+        """Pages a decode dispatch's attention reads a layer (``live``:
+        the live rows' own) and pages its padded table holds, each
+        times the dispatch's substeps. Host arithmetic at the enqueue."""
+        self._attn_pages_live.inc(live)
+        self._attn_pages_table.inc(table)
 
     def phase_stats(self) -> Dict[str, Any]:
         """Per-phase summary for THIS engine (bench's latency

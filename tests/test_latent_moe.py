@@ -105,7 +105,8 @@ class LogitTap:
         return seen
 
 
-def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE):
+def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE,
+                        decode_impl='gather'):
     """Chunked paged prefill of ``prompts`` in ONE batch, then ``n_new``
     decode steps in fused horizons with the ring merged in between.
     Returns per prompt (tokens generated, {position: logits}): the logits
@@ -123,6 +124,14 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE):
                                       page_size=PAGE)
     tap = LogitTap()
     got = [dict() for _ in prompts]
+    active = jnp.ones(n, bool)
+    # Jitted once each: a fresh lambda a call would compile it again.
+    prefill = jax.jit(
+        lambda c, *a: paged.paged_prefill_chunk(params, c, *a, cfg))
+    decode = jax.jit(lambda c, t, l: paged.paged_decode_horizon(
+        params, c, jnp.asarray(table), t, l, cfg, horizon=horizon,
+        active=active, decode_impl=decode_impl))
+    merge = jax.jit(paged.merge_ring_into_pool)
     with mock.patch.object(llama, 'mask_nonfinite_tokens', tap):
         first = np.zeros(n, np.int32)
         for off in range(0, max(map(len, prompts)), CHUNK):
@@ -136,10 +145,8 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE):
                 tokens[i, :len(piece)] = piece
                 if piece and off + len(piece) == len(p):
                     want[i] = len(piece) - 1
-            tok, cache = jax.jit(
-                lambda c, *a: paged.paged_prefill_chunk(params, c, *a, cfg)
-            )(cache, *map(jnp.asarray,
-                          (table, tokens, lengths, valid, want)))
+            tok, cache = prefill(cache, *map(
+                jnp.asarray, (table, tokens, lengths, valid, want)))
             (logits,) = tap.take()
             for i, p in enumerate(prompts):
                 if want[i] >= 0:
@@ -148,16 +155,10 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE):
         out = [[int(t)] for t in first]
         cur = jnp.asarray(first)
         lengths = np.array([len(p) for p in prompts], np.int32)
-        active = jnp.ones(n, bool)
         for _ in range(0, n_new - 1, horizon):
-            toks, ring_k, ring_v = jax.jit(
-                lambda c, t, l: paged.paged_decode_horizon(
-                    params, c, jnp.asarray(table), t, l, cfg,
-                    horizon=horizon, active=active, decode_impl='gather')
-            )(cache, cur, jnp.asarray(lengths))
-            cache = jax.jit(paged.merge_ring_into_pool)(
-                cache, ring_k, ring_v, jnp.asarray(table),
-                jnp.asarray(lengths), active)
+            toks, ring_k, ring_v = decode(cache, cur, jnp.asarray(lengths))
+            cache = merge(cache, ring_k, ring_v, jnp.asarray(table),
+                          jnp.asarray(lengths), active)
             steps = tap.take()
             toks = np.asarray(toks)
             assert toks.shape == (n + 1, horizon)     # + the experts row
@@ -170,6 +171,7 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE):
     return out, got
 
 
+@pytest.mark.parametrize('decode_impl', ['gather', 'pallas'])
 @pytest.mark.parametrize('dtype,first_lens,page', [
     ('float32', (37, 21), 8),   # chunks 16+16+5 and 16+5: chunk and page
     ('bfloat16', (37, 21), 8),  # boundaries crossed, unequal lengths
@@ -178,7 +180,10 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE):
     ('float32', (1, 1), 16),    # the lane-packed pool (the chip's form)
 ])
 def test_paged_prefill_then_decode_matches_reference(dtype, first_lens,
-                                                     page):
+                                                     page, decode_impl):
+    """``decode_impl``: the absorbed XLA form over gathered pages, and
+    the latent paged kernel (interpret mode here) with the ring merged
+    in XLA; both against the plain reference."""
     cfg, params = make(dtype)
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
@@ -188,7 +193,7 @@ def test_paged_prefill_then_decode_matches_reference(dtype, first_lens,
         assert paged.PagedKVCache.create(cfg, n_pages=3, page_size=16
                                          ).pool_v.shape == (3, 3, 1, 1, 128)
     out, got = serve_through_pages(cfg, params, prompts, n_new, horizon=4,
-                                   PAGE=page)
+                                   PAGE=page, decode_impl=decode_impl)
     rows, want = [], []
     for prompt, tokens, logits in zip(prompts, out, got):
         ref = reference_logits(params, prompt + tokens, cfg)
@@ -385,7 +390,6 @@ def test_wrong_mathematics_fails_the_tolerance(variant, dtype):
     ({'speculate_k': 2}, 'speculate_k'),
     ({'adapter_slots': 2}, 'adapter_slots'),
     ({'mesh': 'tp2'}, 'mesh'),
-    ({'decode_impl': 'pallas'}, 'decode_impl'),
     ({'decode_impl': 'cross_layer'}, 'decode_impl'),
     ({'engine': 'slot'}, 'engine'),
     ({'call': 'export'}, 'KV export/ingest'),
@@ -455,11 +459,19 @@ def test_llama_family_pool_shapes_and_program_keys_unchanged():
 
 
 def test_audit_preset_no_transfer_no_recompile():
-    """The engine's steady state with this model: the experts-read count
-    rides the one sanctioned token readback, and same-shaped waves
-    compile nothing."""
-    from skypilot_tpu.analysis import jaxpr_audit
+    """The engine's steady state with this model, decode through the
+    latent paged kernel as on the chip: the experts-read count rides the
+    one sanctioned token readback, same-shaped waves compile nothing,
+    and nothing of the decode dispatch but the kernel reads the pool: no
+    layer step gathers pages."""
+    from skypilot_tpu.analysis import costmodel, jaxpr_audit
     report = jaxpr_audit.run_preset('paged-latent-moe')
+    assert 'decode_impl=pallas' in report.name
     assert report.ok(), '\n' + report.format()
     assert not [t for t in report.transfers if not t.sanctioned]
     assert all(a == b for a, b in report.compile_counts.values())
+    assert not report.cost_error, report.cost_error
+    pool_reads = [e for e in report.dispatch_costs['decode'].eqns
+                  if e.read.get(costmodel.KV_POOL)]
+    assert pool_reads and {e.prim for e in pool_reads} == {'pallas_call'}
+    assert all('per layer' in e.note for e in pool_reads)

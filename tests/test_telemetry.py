@@ -1001,7 +1001,11 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          'moe_layer_steps_total',
                          'moe_distinct_experts_total',
                          'moe_assignments_total',
-                         'prefill_attn_pairs_total'}
+                         'prefill_attn_pairs_total',
+                         # the latent paged decode kernel (PR 31): zeros
+                         # for a GQA cache
+                         'decode_attn_pages_live_total',
+                         'decode_attn_pages_table_total'}
     assert all(isinstance(v, (int, float)) for v in loop.values())
     assert loop['moe_layer_steps_total'] == 0
     assert loop['prefill_attn_pairs_total'] > 0     # the warm-up's prompt

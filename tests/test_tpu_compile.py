@@ -278,3 +278,118 @@ def test_grouped_expert_matmuls_compile_at_glm_widths(   # x top-4), a chunk
     # nothing expert-stack sized is copied: temps stay far under one
     # layer's experts (604 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+# ------------------------------------------- the latent paged decode kernel
+GLM_SLOTS, GLM_PAGES = 32, 2818         # glm-4.7-flash.longctx's engine
+
+
+def _glm_cfg():
+    """GLM-4.7-Flash as the cell cuts it: 8 of its layers."""
+    import dataclasses
+    return dataclasses.replace(configs.GLM_4_7_FLASH, n_layers=8)
+
+
+def _glm_cache(one_chip, cfg):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: paged.PagedKVCache.create(
+            cfg, n_pages=GLM_PAGES, page_size=PAGE)))
+
+
+@pytest.mark.parametrize('table_p', [64, 128])   # contexts to 8k and to 16k
+def test_latent_paged_decode_attention_compiles(one_chip, table_p):
+    """The kernel at the cell's widths (32 slots, page 128, 20 heads,
+    ``kv_lora_rank`` 512, rope 64 two tokens to a lane row), one page a
+    loop iteration (the block the code keeps): Mosaic takes the one-hot
+    spread of the packed rope page and the lane mask."""
+    from skypilot_tpu.ops import latent_paged_attention as lpa
+    cfg = _glm_cfg()
+    cache = _glm_cache(one_chip, cfg)
+    assert cache.pool_v.shape[3:] == (64, 128)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q_lat, q_rope, pool_c, pool_r, table, lens, layer):
+        return lpa.latent_paged_decode_attention(
+            q_lat, q_rope, pool_c, pool_r, table, lens, layer=layer,
+            scale=0.0625)
+
+    compiled = jax.jit(fn).lower(
+        s((GLM_SLOTS, cfg.n_heads, cfg.kv_lora_rank), jnp.bfloat16),
+        s((GLM_SLOTS, cfg.n_heads, cfg.qk_rope_head_dim), jnp.bfloat16),
+        cache.pool_k, cache.pool_v, s((GLM_SLOTS, table_p), jnp.int32),
+        s((GLM_SLOTS,), jnp.int32), s((), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def _result_sizes(hlo_text):
+    """{opcode: elements of its largest result} over the instructions
+    of an optimized HLO module."""
+    import math
+    import re
+    sizes = {}
+    for line in hlo_text.splitlines():
+        m = re.match(r'\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z\-]+)\(', line)
+        if not m:
+            continue
+        for dims in re.findall(r'[a-z]+[0-9]*\[([0-9,]+)\]', m.group(1)):
+            n = math.prod(int(d) for d in dims.split(','))
+            sizes[m.group(2)] = max(sizes.get(m.group(2), 0), n)
+    return sizes
+
+
+@pytest.mark.parametrize('decode_impl', ['pallas', 'gather'])
+def test_latent_decode_program_reads_no_page_it_does_not_need(
+        one_chip, monkeypatch, decode_impl):
+    """The whole latent ``paged_decode_horizon`` of the ``longctx`` cell
+    (GLM-4.7-Flash cut to 8 layers, a 2818-page pool, 32 slots, a
+    64-page bucket) in the manner of
+    ``test_layer_scan_gathers_pages_without_copying_a_pool_layer``: with
+    the kernel no operation of the optimized HLO makes anything as large
+    as the rope rows of one gathered bucket, let alone of a layer of a
+    pool; only parameters and loop plumbing are that large. The
+    ``'gather'`` fallback is the witness that the check sees what it is
+    after: its gathers, transposes and fusions are. 0.31 GB of temp with
+    the gather, under 0.05 GB without (compiler, PR 31)."""
+    cfg = _glm_cfg()
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    table_p, horizon = 64, 8
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda key: llama.init_params(key, cfg),
+                       jax.random.PRNGKey(0)))
+    cache = _glm_cache(one_chip, cfg)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, cache, table, tokens, lengths, active):
+        return paged.paged_decode_horizon(
+            params, cache, table, tokens, lengths, cfg, horizon=horizon,
+            active=active, decode_impl=decode_impl)
+
+    compiled = jax.jit(decode).lower(
+        params, cache, vec((GLM_SLOTS, table_p)), vec((GLM_SLOTS,)),
+        vec((GLM_SLOTS,)), vec((GLM_SLOTS,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    gathered_rope = GLM_SLOTS * table_p * PAGE * cfg.qk_rope_head_dim
+    assert gathered_rope < cache.pool_v.size // cfg.n_layers
+    # Weights are as large; nothing computes one: a fusion of that size
+    # is a bitcast of the output head.
+    plumbing = {'parameter', 'get-tuple-element', 'tuple', 'while',
+                'bitcast', 'fusion'}
+    made = {op: n for op, n in _result_sizes(text).items()
+            if op not in plumbing and n >= gathered_rope}
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if decode_impl == 'pallas':
+        # the dense layer's and the expert layers' scans: 2 + 3 kernels
+        assert text.count('tpu_custom_call') == 5
+        assert not made, made
+        assert f'[{GLM_PAGES},' not in text.replace(
+            f'[{cfg.n_layers},{GLM_PAGES},', '')
+        assert temp < 50e6
+    else:
+        assert 'gather' in made and temp > 250e6
