@@ -7,7 +7,7 @@ plain XLA reference on the same chip:
 
   serve   ``python -m skypilot_tpu.serve.server --model-path <llama3-8b
           synthetic HF checkpoint, 16 of 32 layers> --quantize int8
-          --kv-cache paged --max-batch 32 --max-seq 2048`` answers
+          --max-batch 32 --max-seq 2048`` answers
           requests over HTTP (chunked prefill, prefix cache, Pallas paged
           decode), reports the device and the path it resolved, and does
           not recompile over repeated shapes.
@@ -297,7 +297,7 @@ class Smoke:
         base = f'http://127.0.0.1:{port}'
         argv = [sys.executable, '-m', 'skypilot_tpu.serve.server',
                 '--model-path', ckpt, '--quantize', 'int8',
-                '--kv-cache', 'paged', '--max-batch', str(sz['max_batch']),
+                '--max-batch', str(sz['max_batch']),
                 '--max-seq', str(sz['max_seq']), '--port', str(port)]
         if tp > 1:
             argv += ['--tp', str(tp)]

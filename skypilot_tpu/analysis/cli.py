@@ -6,9 +6,9 @@ Subcommands:
   exit 1 on violations not covered by the baseline or inline
   suppressions. ``--update-baseline`` rewrites the baseline from the
   current violations (review before committing).
-- ``graftcheck audit [--preset slot|slot-monolithic|paged|slot-spec|
-  paged-spec|telemetry|telemetry-paged|kv-int8|kv-int8-slot|llama]`` —
-  runtime jaxpr audit of the engines' hot loops, including the
+- ``graftcheck audit [--preset paged|paged-spec|telemetry|kv-int8|
+  llama|...]`` (``jaxpr_audit.PRESETS``) —
+  runtime jaxpr audit of the engine's hot loops, including the
   speculative propose→verify→commit steady state and the int8-KV
   (``kv_cache_dtype='int8'`` over bf16 weights) quantize-on-write path
   (requires jax); exit 1 on unsanctioned host transfers, steady-state
@@ -219,9 +219,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     from skypilot_tpu.analysis import jaxpr_audit
     p_audit.add_argument('--preset', action='append',
                          choices=sorted(jaxpr_audit.PRESETS),
-                         help='repeatable; default: slot, paged, '
-                              'slot-spec, paged-spec, telemetry, '
-                              'kv-int8, kv-int8-slot, llama')
+                         help='repeatable; default: '
+                              'jaxpr_audit.DEFAULT_PRESETS')
     p_audit.add_argument('--json', action='store_true',
                          help='machine-readable output')
 

@@ -13,11 +13,14 @@ and explicit collective bytes per mesh axis. Three consumers:
    *before* the matmul quadruple the ``weight_int4``-class read
    stream), an extra pool sweep, or a stray gather the collective
    census can only count, not weigh.
-2. **bench.py rooflines** — the ``param_bytes + live_kv`` arithmetic
-   and the kv_round2 7B projection come from here (abstract traces of
-   the real decode programs, no weights materialized), cross-checked
-   against the measured ``skytpu_kv_read_bytes_per_step`` gauge within
-   :data:`KV_TOLERANCE`.
+2. **Static step bytes** — ``abstract_decode_cost`` /
+   ``roofline_step_bytes`` price one decode substep of any config from
+   abstract traces of the real decode program (no weights
+   materialized); ``kv_static_check`` holds the stored bytes per token
+   to the runtime's ``kv_token_bytes`` (what the
+   ``skytpu_kv_read_bytes_per_step`` gauge multiplies) within
+   :data:`KV_TOLERANCE`. (The benchmark's own roofline arithmetic is
+   ``perfbench/roofline.py``.)
 3. **``graftcheck costmodel``** — prints the attribution table for any
    preset.
 
@@ -94,7 +97,7 @@ _PRIORITY = {KV_POOL: 8, KV_SCALE: 7, WEIGHT_INT4: 6, WEIGHT_INT8: 5,
              ACTIVATION: 1, CONST: 0}
 
 # Measured-vs-static tolerance for KV read bytes/step: the declared
-# contract bench and the tests hold the model to.
+# contract the tests hold the model to.
 KV_TOLERANCE = 0.10
 
 # Headroom the budget generator adds over the traced value. Wide
@@ -723,8 +726,8 @@ def classify_params(tree) -> Any:
 
 
 def classify_cache(cache) -> Any:
-    """KV-cache pytree -> class labels (slot KVCache or
-    PagedKVCache; scales optional)."""
+    """KV-cache pytree -> class labels (PagedKVCache, or the contiguous
+    ``llama.KVCache``; scales optional)."""
     f = _fields(cache)
     if f is not None and 'pool_k' in f:
         return type(cache)(
@@ -942,16 +945,6 @@ BYTE_BUDGETS: Dict[str, Dict[str, Dict[str, int]]] = {
             'table': 550,
         },
     },
-    'adapters-slot': {
-        'decode': {
-            'activation': 868800,
-            'adapter_bank': 1316160,
-            'const': 176040,
-            'kv_pool': 1556480,
-            'table': 11265,
-            'weight_bf16': 1985920,
-        },
-    },
     'digest': {
         'decode': {
             'activation': 653760,
@@ -1046,18 +1039,6 @@ BYTE_BUDGETS: Dict[str, Dict[str, Dict[str, int]]] = {
             'table': 520,
         },
     },
-    'int4-slot': {
-        'decode': {
-            'activation': 740800,
-            'const': 185160,
-            'kv_pool': 389120,
-            'kv_scale': 358400,
-            'table': 2025,
-            'weight_bf16': 105920,
-            'weight_int4': 450560,
-            'weight_scale': 19200,
-        },
-    },
     'kv-int4': {
         'decode': {
             'activation': 664000,
@@ -1074,16 +1055,6 @@ BYTE_BUDGETS: Dict[str, Dict[str, Dict[str, int]]] = {
             'table': 920,
         },
     },
-    'kv-int4-slot': {
-        'decode': {
-            'activation': 664000,
-            'const': 185160,
-            'kv_pool': 389120,
-            'kv_scale': 358400,
-            'table': 2025,
-            'weight_bf16': 1908160,
-        },
-    },
     'kv-int8': {
         'decode': {
             'activation': 664000,
@@ -1098,16 +1069,6 @@ BYTE_BUDGETS: Dict[str, Dict[str, Dict[str, int]]] = {
             'kv_pool': 84480,
             'kv_scale': 21120,
             'table': 920,
-        },
-    },
-    'kv-int8-slot': {
-        'decode': {
-            'activation': 664000,
-            'const': 182600,
-            'kv_pool': 778240,
-            'kv_scale': 358400,
-            'table': 2025,
-            'weight_bf16': 1908160,
         },
     },
     'llama': {
@@ -1196,33 +1157,6 @@ BYTE_BUDGETS: Dict[str, Dict[str, Dict[str, int]]] = {
             'table': 1960,
         },
     },
-    'slot': {
-        'decode': {
-            'activation': 653760,
-            'const': 176040,
-            'kv_pool': 1556480,
-            'table': 1985,
-            'weight_bf16': 1908160,
-        },
-    },
-    'slot-monolithic': {
-        'decode': {
-            'activation': 653760,
-            'const': 176040,
-            'kv_pool': 1556480,
-            'table': 1985,
-            'weight_bf16': 1908160,
-        },
-    },
-    'slot-spec': {
-        'spec_verify': {
-            'activation': 339400,
-            'const': 180,
-            'kv_pool': 245760,
-            'table': 930,
-            'weight_bf16': 277400,
-        },
-    },
     'spec-multistep': {
         'spec_fused': {
             'activation': 1021800,
@@ -1233,15 +1167,6 @@ BYTE_BUDGETS: Dict[str, Dict[str, Dict[str, int]]] = {
         },
     },
     'telemetry': {
-        'decode': {
-            'activation': 653760,
-            'const': 176040,
-            'kv_pool': 1556480,
-            'table': 1985,
-            'weight_bf16': 1908160,
-        },
-    },
-    'telemetry-paged': {
         'decode': {
             'activation': 653760,
             'const': 165800,
@@ -1314,8 +1239,7 @@ def abstract_decode_cost(cfg, *, batch: int, avg_ctx: int,
                          horizon: int = 1) -> DispatchCost:
     """Price ONE paged decode substep for an arbitrary config —
     abstractly (eval_shape + make_jaxpr on structs; nothing compiles,
-    no weights materialize). The source of truth behind bench.py's
-    rooflines and the kv_round2 7B projection."""
+    no weights materialize)."""
     import functools
 
     import jax
@@ -1353,10 +1277,9 @@ def roofline_step_bytes(cfg, *, batch: int, avg_ctx: int,
                         quantize: Optional[str] = None,
                         kv_cache_dtype: Optional[str] = None
                         ) -> Dict[str, float]:
-    """bench.py's roofline denominator, statically derived: the weight
-    stream + live-context KV read of one decode step, per token
-    position. Returns the full attribution so the bench detail blocks
-    can show where the bytes went."""
+    """A decode step's roofline denominator, statically derived: the
+    weight stream + live-context KV read of one decode step, per token
+    position, with the full attribution of where the bytes went."""
     cost = abstract_decode_cost(cfg, batch=batch, avg_ctx=avg_ctx,
                                 quantize=quantize,
                                 kv_cache_dtype=kv_cache_dtype)
@@ -1378,7 +1301,7 @@ def kv_static_check(cfg, kv_cache_dtype, measured_per_token: float,
                     mesh=None) -> Dict[str, Any]:
     """Cross-check the static stored-bytes/token against a measured
     (telemetry-derived) value within KV_TOLERANCE. Returns the
-    comparison record bench embeds in its detail output."""
+    comparison record."""
     cost = abstract_decode_cost(cfg, batch=1, avg_ctx=64,
                                 kv_cache_dtype=kv_cache_dtype)
     static = float(cost.kv_bytes_per_token)

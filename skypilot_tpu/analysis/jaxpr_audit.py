@@ -1,6 +1,6 @@
 """graftcheck part B: runtime jaxpr + host-transfer auditor.
 
-Proves, at runtime, the invariants the slot/paged engines' performance
+Proves, at runtime, the invariants the paged engine's performance
 depends on (SageServe/ThunderServe-class serving wins hinge on a
 sync-free, recompile-stable steady-state loop — PAPERS.md):
 
@@ -15,8 +15,8 @@ sync-free, recompile-stable steady-state loop — PAPERS.md):
    independent by construction.
 2. **Recompile stability** — the decode (and chunked-prefill) jit
    caches do not grow across repeated same-shaped calls; the observed
-   static keys (horizon, sample, kv_bucket) that form the recompile
-   key are reported.
+   static keys (horizon, sample) that form the recompile key are
+   reported.
 3. **Jaxpr hygiene** — the traced decode/prefill/forward jaxprs
    contain no host-callback primitives and no unexpected wide-dtype
    promotions (anything promoting to float64 on a TPU program is a
@@ -367,8 +367,7 @@ def _jit_fns(fn) -> List[Any]:
 
 
 # ------------------------------------------------------------------ presets
-def _tiny_engine(kind: str, chunked: bool, speculate_k: int = 0,
-                 telemetry: bool = True,
+def _tiny_engine(speculate_k: int = 0, telemetry: bool = True,
                  kv_cache_dtype: Optional[str] = None,
                  mesh_tp: int = 0, mesh_dp: int = 0,
                  quantize: Optional[str] = None,
@@ -378,7 +377,6 @@ def _tiny_engine(kind: str, chunked: bool, speculate_k: int = 0,
                  model: str = 'tiny'):
     from skypilot_tpu.models import configs
     cfg = configs.get_config(model)
-    chunk = 16 if chunked else 0
     extra: Dict[str, Any] = {}
     if quantize is not None:
         extra['quantize'] = quantize
@@ -388,8 +386,6 @@ def _tiny_engine(kind: str, chunked: bool, speculate_k: int = 0,
     if decode_steps_per_call is not None:
         extra['decode_steps_per_call'] = decode_steps_per_call
     if decode_impl is not None:
-        # Paged-only knob ('gather' | 'pallas' | 'cross_layer'); the
-        # slot engine rejects it, so only paged presets may set it.
         extra['decode_impl'] = decode_impl
     if mesh_tp and mesh_tp > 1:
         import jax
@@ -408,19 +404,14 @@ def _tiny_engine(kind: str, chunked: bool, speculate_k: int = 0,
         extra['mesh'] = mesh_lib.serving_mesh(tp=mesh_tp,
                                               dp=max(1, mesh_dp))
         extra['attn_impl'] = 'xla'
-    if kind == 'paged':
-        from skypilot_tpu.inference.paged import PagedInferenceEngine
-        return PagedInferenceEngine(cfg, max_batch=4, max_seq=128,
-                                    prefill_chunk_tokens=chunk or None,
-                                    speculate_k=speculate_k,
-                                    kv_cache_dtype=kv_cache_dtype,
-                                    telemetry=telemetry, **extra)
-    from skypilot_tpu.inference.engine import InferenceEngine
-    return InferenceEngine(cfg, max_batch=4, max_seq=128,
-                           prefill_chunk_tokens=chunk,
-                           speculate_k=speculate_k,
-                           kv_cache_dtype=kv_cache_dtype,
-                           telemetry=telemetry, **extra)
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
+    # Chunk 16: the presets' prompts are longer than one chunk, so the
+    # cursor chunks and the completing chunk both run.
+    return PagedInferenceEngine(cfg, max_batch=4, max_seq=128,
+                                prefill_chunk_tokens=16,
+                                speculate_k=speculate_k,
+                                kv_cache_dtype=kv_cache_dtype,
+                                telemetry=telemetry, **extra)
 
 
 def _drive(engine, prompts: List[List[int]], max_new: int = 8) -> None:
@@ -432,17 +423,13 @@ def _drive(engine, prompts: List[List[int]], max_new: int = 8) -> None:
 def _record_static_keys(engine, report: AuditReport,
                         capture: Optional[Dict[str, Any]] = None):
     """Shim the engine's decode fn to log the static args of each call
-    — the (horizon, sample[, kv_bucket]) tuple IS the recompile key the
-    scheduler must keep stable. The slot engine's decode takes
-    (..., horizon, sample, kv_bucket); the paged engine's
-    (..., horizon, sample) — both pass them as trailing positionals.
+    — the (horizon, sample) tuple IS the recompile key the scheduler
+    must keep stable; the engine passes them as trailing positionals.
     ``capture`` (optional dict) additionally records each call's full
     argument avals+shardings — what the mesh presets re-lower the
     steady-state decode chain from for the collective census."""
     inner = engine._decode_fn
-    names = (('horizon', 'sample')
-             if type(engine).__name__.startswith('Paged')
-             else ('horizon', 'sample', 'kv_bucket'))
+    names = ('horizon', 'sample')
 
     def shim(*args, **kwargs):
         key = {k: kwargs[k] for k in names if k in kwargs}
@@ -464,8 +451,8 @@ def _capture_spec_args(engine, capture: Dict[str, Any]) -> None:
     are captured for pricing: spec steady state never touches
     ``_decode_fn``, so the decode shim alone would leave speculative
     presets without dispatch costs. The spec jits take all-array args
-    (sample/kv_bucket are baked into the closure), so the capture is
-    (arg structs, jit fn) — directly traceable."""
+    (sample and the page bucket are baked into the closure), so the
+    capture is (arg structs, jit fn) — directly traceable."""
     for getter_name, label in (('_get_spec_verify', 'spec_verify'),
                                ('_get_spec_fused', 'spec_fused')):
         getter = getattr(engine, getter_name, None)
@@ -558,8 +545,7 @@ def _count_collectives(hlo_text: str) -> Dict[str, int]:
 def _decode_chain_collectives(engine, inner, captured
                               ) -> Dict[str, Dict[str, int]]:
     """Compile-and-census the steady-state decode chain from the last
-    captured call's arg structs: the slot engine's fused decode is one
-    jitted program; the paged engine's chain is (decode_steps, merge)
+    captured call's arg structs: the chain is (decode_steps, merge)
     behind a plain wrapper — the merge's ring operands are
     reconstructed at the pinned ``_ring_sh`` sharding (decode's output
     sharding IS merge's input sharding — the contract under test)."""
@@ -590,8 +576,7 @@ def _decode_chain_collectives(engine, inner, captured
     return out
 
 
-def audit_engine(kind: str = 'slot', chunked: bool = True,
-                 rounds: int = 2, speculate_k: int = 0,
+def audit_engine(rounds: int = 2, speculate_k: int = 0,
                  kv_cache_dtype: Optional[str] = None,
                  mesh_tp: int = 0, mesh_dp: int = 0,
                  warmup_rounds: int = 1,
@@ -603,13 +588,12 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
     then audit ``rounds`` identical same-shaped waves: every compile
     and every unsanctioned host transfer in those waves is a violation.
 
-    ``kind``: 'slot' | 'paged'. ``chunked``: prompts longer than one
-    chunk so the chunked-prefill path (cursor chunks + completing
-    chunk) is exercised, not just monolithic admission.
+    The prompts are longer than one chunk, so the chunked-prefill path
+    (cursor chunks + completing chunk) is exercised.
     ``speculate_k > 0`` drives the speculative propose→verify→commit
     steady state on REPETITIVE prompts (so proposals actually fire and
     acceptance varies per slot): the verify jit cache must stay bounded
-    by the observed (k, sample, kv_bucket) key set, and the only host
+    by the observed (k, sample, P) key set, and the only host
     readback per round is the sanctioned commit sync.
 
     ``mesh_tp >= 2`` audits the SHARDED serving path on a (tp,) CPU
@@ -629,10 +613,9 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
     impl_tag = f' + decode_impl={decode_impl}' if decode_impl else ''
     impl_tag += f' + model={model}' if model != 'tiny' else ''
     report = AuditReport(
-        name=f'{kind} engine '
-             f'({"chunked prefill + " if chunked else ""}decode'
+        name=f'paged engine (chunked prefill + decode'
              f'{spec_tag}{kv_tag}{q_tag}{tp_tag}{impl_tag})')
-    engine = _tiny_engine(kind, chunked, speculate_k,
+    engine = _tiny_engine(speculate_k,
                           kv_cache_dtype=kv_cache_dtype,
                           mesh_tp=mesh_tp, mesh_dp=mesh_dp,
                           quantize=quantize, decode_impl=decode_impl,
@@ -654,15 +637,10 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
     labels = {'decode': lambda: (sum(_cache_size(f)
                                      for f in decode_jits)
                                  if decode_jits else -1)}
-    chunk_fns = getattr(engine, '_chunk_prefill_fns', None)
-    if chunk_fns is not None:
-        labels['chunk_prefill'] = lambda: len(chunk_fns)
-    prefill_fns = getattr(engine, '_prefill_fns', None)
-    if prefill_fns is not None:
-        labels['prefill'] = lambda: len(prefill_fns)
-    spec_fns = getattr(engine, '_spec_verify_fns', None)
-    if spec_fns is not None and speculate_k:
-        # The verify program cache is keyed (k, sample, kv_bucket) —
+    labels['prefill'] = lambda: len(engine._prefill_fns)
+    spec_fns = engine._spec_verify_fns
+    if speculate_k:
+        # The verify program cache is keyed (k, sample, P) —
         # steady state must never grow it (per-slot acceptance rides
         # masked commits, not fresh shapes).
         labels['spec_verify'] = lambda: len(spec_fns)
@@ -671,9 +649,8 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
         for _ in range(rounds):
             _drive(engine, prompts)        # identical shapes: no compiles
     engine._decode_fn = inner
-    if spec_fns is not None and speculate_k:
-        names = ('k', 'sample',
-                 'P' if kind == 'paged' else 'kv_bucket')
+    if speculate_k:
+        names = ('k', 'sample', 'P')
         report.static_keys.extend(
             dict(zip(names, key)) for key in sorted(spec_fns))
     report.compile_counts = {
@@ -685,21 +662,6 @@ def audit_engine(kind: str = 'slot', chunked: bool = True,
             report.allowed_all_gathers_by_label['merge'] = \
                 merge_all_gathers
     _attach_costs(report, engine, inner, capture)
-    # Jaxpr of the fused decode step itself (the hot program).
-    try:
-        import jax
-        from skypilot_tpu.models import llama
-        cfg = engine.cfg
-        if kind == 'slot':
-            jx = jax.make_jaxpr(
-                lambda p, c, t: llama.decode_horizon(
-                    p, c, t, cfg, horizon=4, kv_bucket=64))(
-                        engine.params, engine.cache, engine._tok_dev)
-            report.callback_prims, report.promotions = walk_jaxpr(jx)
-            report.f64_promotions = [
-                p for p in report.promotions if 'float64' in p]
-    except Exception as e:  # pragma: no cover - trace-shape drift
-        report.promotions.append(f'<jaxpr trace failed: {e}>')
     return report
 
 
@@ -724,8 +686,7 @@ def audit_multistep(k: int = 4,
     q_tag = f', quantize={quantize}' if quantize else ''
     report = AuditReport(
         name=f'multi-step decode (decode_steps_per_call={k}{q_tag})')
-    engine = _tiny_engine('paged', chunked=True,
-                          quantize=quantize, decode_steps_per_call=k)
+    engine = _tiny_engine(quantize=quantize, decode_steps_per_call=k)
     prompts = [[3 + i, 5, 7, 9, 2, 4, 6, 8, 1, 3, 5, 7]
                for i in range(4)]               # equal shapes: lockstep
     max_new = 2 * k + 1
@@ -814,14 +775,14 @@ def audit_spec_multistep(k: int = 4, steps: int = 3) -> AuditReport:
 
     # Reference: identical wave on a single-round verify engine — its
     # dispatch count is the ground truth the fusion must divide.
-    ref = _tiny_engine('paged', chunked=True, speculate_k=k)
+    ref = _tiny_engine(speculate_k=k)
     single = [0]
     count_calls(ref, '_spec_verify_call', single)
     one_wave(ref)                                 # warmup: compiles
     single[0] = 0
     one_wave(ref)                                 # counted wave
 
-    engine = _tiny_engine('paged', chunked=True, speculate_k=k,
+    engine = _tiny_engine(speculate_k=k,
                           decode_steps_per_call=steps)
     fused, fallback = [0], [0]
     count_calls(engine, '_spec_fused_call', fused)
@@ -858,7 +819,7 @@ def audit_spec_multistep(k: int = 4, steps: int = 3) -> AuditReport:
     return report
 
 
-def audit_adapters(kind: str = 'paged') -> AuditReport:
+def audit_adapters() -> AuditReport:
     """Batched multi-LoRA decode under adapter-bank churn.
 
     A tiny engine with a 2-slot adapter bank serves waves where two
@@ -872,7 +833,7 @@ def audit_adapters(kind: str = 'paged') -> AuditReport:
     - zero unsanctioned d2h and zero jit-cache growth across the
       churn waves: load/evict re-uploads bank rows (donated
       ``set_bank_row`` updates), it NEVER recompiles — the bank lives
-      in params, so the (horizon, sample[, bucket]) jit key does not
+      in params, so the (horizon, sample) jit key does not
       grow an adapter dimension;
     - the expected load/evict counts actually happened (2 loads + 2
       evictions per audited wave) — a silent cache hit would mean the
@@ -886,10 +847,9 @@ def audit_adapters(kind: str = 'paged') -> AuditReport:
 
     from skypilot_tpu.models import multilora
     report = AuditReport(
-        name=f'{kind} engine (chunked prefill + decode + multi-LoRA '
-             f'bank churn, 2 slots x 4 adapters)')
-    engine = _tiny_engine(kind, chunked=True,
-                          adapter_slots=2, adapter_rank=4)
+        name='paged engine (chunked prefill + decode + multi-LoRA '
+             'bank churn, 2 slots x 4 adapters)')
+    engine = _tiny_engine(adapter_slots=2, adapter_rank=4)
     cfg = engine.cfg
     rng = np.random.default_rng(0)
     names = [f'ad{i}' for i in range(4)]
@@ -993,9 +953,8 @@ def audit_disagg() -> AuditReport:
     from skypilot_tpu.inference import kv_transfer
     report = AuditReport(
         name='disagg prefill→decode handoff (paged, int8 wire)')
-    prefill = _tiny_engine('paged', chunked=True,
-                           kv_cache_dtype='int8')
-    decode = _tiny_engine('paged', chunked=True, kv_cache_dtype='int8')
+    prefill = _tiny_engine(kv_cache_dtype='int8')
+    decode = _tiny_engine(kv_cache_dtype='int8')
     prompts = [[1, 2, 3] * 9, [4, 5] * 10, [7] * 21]
 
     def one_round() -> None:
@@ -1048,7 +1007,7 @@ def audit_disagg() -> AuditReport:
     return report
 
 
-def audit_telemetry_parity(kind: str = 'slot') -> AuditReport:
+def audit_telemetry_parity() -> AuditReport:
     """Prove telemetry is free at the device boundary: a
     telemetry-ENABLED engine run performs zero unsanctioned d2h
     transfers and compiles exactly the same set of programs as a
@@ -1056,23 +1015,19 @@ def audit_telemetry_parity(kind: str = 'slot') -> AuditReport:
     dispatches). Per-mode steady-state recompiles and the on-vs-off
     jit-cache-size comparison both land in ``compile_counts``, so a
     parity break fails ``ok()`` like any other recompile."""
-    report = AuditReport(name=f'telemetry parity ({kind} engine)')
+    report = AuditReport(name='telemetry parity (paged engine)')
     prompts = [[1, 2, 3] * 9, [4, 5] * 10, [7] * 21]
 
     def cache_total(engine) -> int:
         total = 0
-        for attr in ('_prefill_fns', '_chunk_prefill_fns',
-                     '_spec_verify_fns'):
-            fns = getattr(engine, attr, None)
-            if fns is not None:
-                total += len(fns)
+        total += len(engine._prefill_fns) + len(engine._spec_verify_fns)
         decode_jits = _jit_fns(engine._decode_fn)
         total += sum(max(0, _cache_size(f)) for f in decode_jits)
         return total
 
     totals: Dict[bool, int] = {}
     for mode in (False, True):
-        engine = _tiny_engine(kind, chunked=True, telemetry=mode)
+        engine = _tiny_engine(telemetry=mode)
         _drive(engine, prompts)                   # warmup: compiles
         before = cache_total(engine)
         label = 'telemetry-on' if mode else 'telemetry-off'
@@ -1112,7 +1067,7 @@ def audit_digest_export() -> AuditReport:
     compile-count mismatch so it fails ``ok()`` loudly."""
     report = AuditReport(
         name='hot-prefix digest export (paged probe path)')
-    engine = _tiny_engine('paged', chunked=True)
+    engine = _tiny_engine()
     prompts = [[1, 2, 3] * 9, [4, 5] * 10, [7] * 21]  # >= 1 full page
     _drive(engine, prompts)                       # warmup: compiles
     capture: Dict[str, Any] = {}
@@ -1159,7 +1114,7 @@ def audit_fleet_obs() -> AuditReport:
     from skypilot_tpu.telemetry import tracing
     report = AuditReport(
         name='fleet observability scrape (registry+trace -> aggregator)')
-    engine = _tiny_engine('paged', chunked=True, telemetry=True)
+    engine = _tiny_engine(telemetry=True)
     prompts = [[1, 2, 3] * 9, [4, 5] * 10, [7] * 21]
     _drive(engine, prompts)                       # warmup: compiles
     capture: Dict[str, Any] = {}
@@ -1202,44 +1157,31 @@ def audit_fleet_obs() -> AuditReport:
 
 
 PRESETS: Dict[str, Callable[[], AuditReport]] = {
-    'slot': lambda: audit_engine('slot', chunked=True),
-    'slot-monolithic': lambda: audit_engine('slot', chunked=False),
-    'paged': lambda: audit_engine('paged', chunked=True),
-    'slot-spec': lambda: audit_engine('slot', chunked=True,
-                                      speculate_k=4),
-    'paged-spec': lambda: audit_engine('paged', chunked=True,
-                                       speculate_k=4),
+    'paged': audit_engine,
+    'paged-spec': lambda: audit_engine(speculate_k=4),
     'telemetry': audit_telemetry_parity,
-    'telemetry-paged': lambda: audit_telemetry_parity('paged'),
     # int8 KV over bf16 weights — the DECOUPLED kv_cache_dtype path no
-    # other preset drives (the coupled int8+int8 case rides the bench):
+    # other preset drives (the coupled int8+int8 case is the chat cell):
     # quantize-on-write in every scan + fused-dequant reads must add
     # zero d2h transfers and zero steady-state jit-cache growth.
-    'kv-int8': lambda: audit_engine('paged', chunked=True,
-                                    kv_cache_dtype='int8'),
-    'kv-int8-slot': lambda: audit_engine('slot', chunked=True,
-                                         kv_cache_dtype='int8'),
+    'kv-int8': lambda: audit_engine(kv_cache_dtype='int8'),
     # int4 KV codes (packed nibble rows + absmax/7 scales): quantize-
     # on-write and fused in-kernel dequant reads must add zero d2h and
     # zero steady-state jit-cache growth — halving KV bytes must not
     # buy a single host round-trip.
-    'kv-int4': lambda: audit_engine('paged', chunked=True,
-                                    kv_cache_dtype='int4'),
-    'kv-int4-slot': lambda: audit_engine('slot', chunked=True,
-                                         kv_cache_dtype='int4'),
+    'kv-int4': lambda: audit_engine(kv_cache_dtype='int4'),
     # Cross-layer fused decode attention: the per-layer ring+current-
     # token merge folded into the kernel's final grid step. Same hot-
     # loop gates as 'paged' — the fusion must be free at the dispatch
     # boundary.
-    'fused-attn': lambda: audit_engine('paged', chunked=True,
-                                       decode_impl='cross_layer'),
+    'fused-attn': lambda: audit_engine(decode_impl='cross_layer'),
     # Sharded serving path (tp=2 CPU mesh): chunked prefill + decode +
     # ring merge over the head-sharded pool — zero steady-state
     # recompiles, zero unsanctioned d2h, and no resharding collectives
     # (no all-to-all; all-gathers bounded by the known sharded-argmax
     # pair). Needs >= 2 devices — the graftcheck CLI re-execs under a
     # forced host platform device count when short.
-    'paged-tp': lambda: audit_engine('paged', chunked=True, mesh_tp=2),
+    'paged-tp': lambda: audit_engine(mesh_tp=2),
     # Gang-shaped mesh: (tp=2, dp=2) over 4 devices stands in for a
     # 2-process gang x 2 chips/process — on a pod the dp axis crosses
     # process boundaries, and the compiled HLO (and therefore this
@@ -1252,12 +1194,10 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     # merge_all_gathers budgets the IN-BODY ring-row gathers the dp>1
     # shard_map merge performs by design (dp pool replicas must not
     # diverge).
-    'paged-gang': lambda: audit_engine('paged', chunked=True,
-                                       mesh_tp=2, mesh_dp=2,
+    'paged-gang': lambda: audit_engine(mesh_tp=2, mesh_dp=2,
                                        warmup_rounds=2,
                                        merge_all_gathers=6),
-    'paged-tp-int8': lambda: audit_engine('paged', chunked=True,
-                                          mesh_tp=2,
+    'paged-tp-int8': lambda: audit_engine(mesh_tp=2,
                                           kv_cache_dtype='int8'),
     # Disaggregated prefill→decode handoff: the decode worker's steady
     # state compiles ZERO prefill programs, and ingest adds zero
@@ -1265,11 +1205,8 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     'disagg': audit_disagg,
     # int4 fused-dequant weights (packed codes + int8 KV via auto):
     # the unpack-inside-qeinsum path must add zero d2h transfers and
-    # zero steady-state jit-cache growth on both engines' hot loops.
-    'int4': lambda: audit_engine('paged', chunked=True,
-                                 quantize='int4'),
-    'int4-slot': lambda: audit_engine('slot', chunked=True,
-                                      quantize='int4'),
+    # zero steady-state jit-cache growth on the hot loop.
+    'int4': lambda: audit_engine(quantize='int4'),
     # Multi-step on-device decode: exactly ONE dispatch per k tokens,
     # every dispatch at static horizon k, zero recompiles/d2h.
     'multistep': audit_multistep,
@@ -1283,7 +1220,6 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     # d2h; the gather matmul bills bank-rows-touched bytes (armed
     # byte budget on the adapter_bank class).
     'adapters': audit_adapters,
-    'adapters-slot': lambda: audit_adapters('slot'),
     # Prefix-digest export on the LB probe path: a hot_prefix_digest()
     # scrape after every wave adds zero unsanctioned d2h and zero
     # jit-cache growth (host-side heat tracker only), and every scrape
@@ -1302,8 +1238,7 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     # and the experts-read count riding the token readback add zero
     # unsanctioned d2h and zero steady-state recompiles, and the decode
     # dispatch gathers no page of the pool.
-    'paged-latent-moe': lambda: audit_engine('paged', chunked=True,
-                                             model='tiny-glm',
+    'paged-latent-moe': lambda: audit_engine(model='tiny-glm',
                                              decode_impl='pallas'),
 }
 
@@ -1317,12 +1252,10 @@ MULTI_DEVICE_PRESETS: Dict[str, int] = {
 }
 
 DEFAULT_PRESETS: List[str] = [
-    'slot', 'paged', 'slot-spec', 'paged-spec', 'telemetry',
-    'kv-int8', 'kv-int8-slot', 'kv-int4', 'kv-int4-slot',
+    'paged', 'paged-spec', 'telemetry', 'kv-int8', 'kv-int4',
     'fused-attn', 'paged-tp', 'paged-tp-int8',
     'paged-gang', 'disagg', 'int4', 'multistep', 'int4-multistep',
-    'spec-multistep', 'adapters', 'adapters-slot', 'digest',
-    'fleet-obs', 'llama']
+    'spec-multistep', 'adapters', 'digest', 'fleet-obs', 'llama']
 
 
 def run_preset(name: str) -> AuditReport:

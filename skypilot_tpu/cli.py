@@ -561,10 +561,6 @@ def serve_logs(service_name, no_follow):
               help='Data-parallel degree (decode batch over chip '
                    'groups; aggregate tok/s). Default: SKYTPU_DP env, '
                    'else 1.')
-@click.option('--kv-cache', default='paged',
-              type=click.Choice(['slot', 'paged']),
-              help='paged (default) = shared page pool with prefix '
-                   'caching; slot = fixed per-slot reservations.')
 @click.option('--kv-cache-dtype', default=None,
               type=click.Choice(['bf16', 'int8']),
               help='KV cache storage dtype; default follows --quantize. '
@@ -651,7 +647,7 @@ def serve_logs(service_name, no_follow):
 @click.option('--max-batch', type=int, default=8)
 @click.option('--max-seq', type=int, default=1024)
 @click.option('--port', type=int, default=8081)
-def model_server(model, model_path, quantize, tp, dp, kv_cache,
+def model_server(model, model_path, quantize, tp, dp,
                  kv_cache_dtype, page_size, prefill_chunk_tokens,
                  decode_priority_ratio, decode_steps_per_call,
                  prefill_w8a8, speculate_k,
@@ -666,9 +662,6 @@ def model_server(model, model_path, quantize, tp, dp, kv_cache,
     ``--gang-world N`` the replica is a gang of N processes: rank 0
     serves HTTP, nonzero ranks run follower loops and the whole gang
     launches, drains, checkpoints, and dies together."""
-    if kv_cache != 'paged' and page_size is not None:
-        raise click.UsageError(
-            '--page-size only applies with --kv-cache paged')
     from skypilot_tpu.serve import gang as gang_lib
     gang_spec = gang_lib.GangSpec.from_env(
         rank=gang_rank, world=gang_world, coordinator=gang_coordinator,
@@ -680,7 +673,7 @@ def model_server(model, model_path, quantize, tp, dp, kv_cache,
                    f'{gang_spec.world} -> {gang_spec.coordinator}')
         server_lib.run_follower(gang_spec, argparse.Namespace(
             model=model, model_path=model_path, quantize=quantize,
-            tp=tp, dp=dp, kv_cache=kv_cache,
+            tp=tp, dp=dp,
             kv_cache_dtype=kv_cache_dtype, page_size=page_size,
             prefill_w8a8=prefill_w8a8,
             prefill_chunk_tokens=prefill_chunk_tokens,
@@ -693,7 +686,6 @@ def model_server(model, model_path, quantize, tp, dp, kv_cache,
     server = ModelServer(model, max_batch=max_batch, max_seq=max_seq,
                          port=port, model_path=model_path,
                          quantize=quantize, tp=tp, dp=dp,
-                         kv_cache=kv_cache,
                          kv_cache_dtype=kv_cache_dtype,
                          page_size=page_size,
                          prefill_w8a8=prefill_w8a8,
@@ -713,7 +705,7 @@ def model_server(model, model_path, quantize, tp, dp, kv_cache,
                          gang=gang_spec,
                          step_watchdog_s=step_watchdog_s)
     click.echo(f'Model server on :{port} '
-               f'(kv_cache={kv_cache}, speculate_k={speculate_k}, '
+               f'(speculate_k={speculate_k}, '
                f'tp={server.tp}, dp={server.dp}, role={server.role}, '
                f'gang_world={server.gang.world})')
     server.start(block=True)

@@ -96,7 +96,7 @@ class AdapterRegistry:
             collections.OrderedDict()
         self._refs: Dict[str, int] = {}
         self._free: List[int] = list(range(self.slots))
-        # In-memory adapter source (tests/bench; checkpoint-less).
+        # In-memory adapter source (tests; checkpoint-less).
         self._store: Dict[str, Tuple[Any, float]] = {}
         self.loads_total = 0
         self.evictions_total = 0
@@ -131,7 +131,7 @@ class AdapterRegistry:
     def register(self, name: str, lora_tree: Any,
                  scale: Optional[float] = None) -> None:
         """In-memory adapter source (trainer-format tree, see
-        ``lora.split_lora``); checkpoint-less path for tests/bench."""
+        ``lora.split_lora``); checkpoint-less path for tests."""
         _check_name(name)
         if scale is None:
             first = next(iter(lora_tree.values()))
@@ -257,7 +257,7 @@ class AdapterRegistry:
         self._req_counter(label).inc()
 
     def stats(self) -> Dict[str, Any]:
-        """The JSON ``lora`` block (``/metrics?format=json``, bench)."""
+        """The JSON ``lora`` block (``/metrics?format=json``)."""
         return {
             'slots': self.slots,
             'used': len(self._loaded),

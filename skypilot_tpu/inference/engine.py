@@ -1,33 +1,31 @@
-"""Continuous-batching inference engine (JetStream-class serving core).
+"""Continuous-batching inference engine: the host-side request lifecycle.
 
 The reference serves models by launching external engines (vLLM/SGLang/
 JetStream recipes under ``llm/``); this is the in-tree TPU engine those
-recipes become. Design:
+recipes become. This module holds what does not depend on how the cache
+is stored: the request record, the queue and slot table, finish/cancel
+bookkeeping, KV handoff validation, parameter preparation, sampling and
+the per-token byte accounting. The cache (a shared page pool), its
+compiled programs and the step loop are ``inference/paged.py``'s
+``PagedInferenceEngine``, the one engine.
 
-- **Slot-based continuous batching**: a fixed decode batch of ``max_batch``
-  slots over one batched KV cache ([layers, slots, max_seq, kv_heads, d],
-  per-slot lengths). Finished slots are immediately refilled from the queue
-  — the decode step shape never changes, so XLA compiles exactly two
-  programs (prefill per length-bucket, decode) and the MXU sees a fixed
-  [slots, 1] batch every step.
-- **Prefill/decode split**: prefill runs per-request at bucketed lengths
-  (powers of two — bounded compile count), writes its KV rows into the
-  slot; decode advances all active slots one token per step.
+- **Continuous batching**: a fixed decode batch of ``max_batch`` slots;
+  finished slots are immediately refilled from the queue — the decode
+  step shape never changes.
+- **Prefill/decode split**: prompts prefill in chunks interleaved with
+  decode horizons; decode advances all active slots together.
 - **Sampling**: greedy / temperature / top-k / top-p (nucleus), jitted
   with the decode step; per-request stop sequences checked host-side.
 - **Sharding**: with a mesh, params shard by their logical axes (tp for
-  serving) and the KV cache by ``cache_logical_axes`` — batch over data
-  axes, kv heads over tp.
+  serving) and the pool's kv heads over tp.
 
-The cache-capacity contract (llama.forward docstring) is enforced here:
-requests whose prompt+max_new_tokens exceed ``max_seq`` are rejected, and
+Requests whose prompt+max_new_tokens exceed ``max_seq`` are rejected, and
 decode stops at capacity.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -35,13 +33,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from skypilot_tpu import telemetry
-from skypilot_tpu.inference.speculative import SpeculativeMixin
 from skypilot_tpu.models import llama
 from skypilot_tpu.models.configs import ModelConfig
 from skypilot_tpu.parallel import mesh as mesh_lib
 from skypilot_tpu.telemetry import clock
 from skypilot_tpu.telemetry import tracing
-from skypilot_tpu.utils.host import device_upload, host_sync
+from skypilot_tpu.utils.host import device_upload
 
 
 @dataclasses.dataclass
@@ -204,16 +201,12 @@ def kv_token_bytes(cfg, quantized: bool, mesh=None) -> int:
 def refuse_unsupported(cfg, **asked) -> None:
     """What a model's layer kinds cannot yet be combined with is refused
     where the engine is built, with the reason, and never fails later or
-    silently. ``asked`` holds what the caller was asked for: ``engine``
-    ('slot' | 'paged'), ``quantize``, ``kv_cache_dtype`` (resolved),
-    ``speculate_k``, ``adapter_slots``, ``mesh``, ``decode_impl``."""
+    silently. ``asked`` holds what the caller was asked for:
+    ``quantize``, ``kv_cache_dtype`` (resolved), ``speculate_k``,
+    ``adapter_slots``, ``mesh``, ``decode_impl``."""
     if not cfg.latent:
         return
     reasons = {
-        'engine': (asked.get('engine') == 'slot',
-                   'the slot engine reserves [max_seq, kv_heads, '
-                   "head_dim] rows a slot; a latent cache row has no "
-                   "head axis: serve it with kv_cache='paged'"),
         'quantize': (asked.get('quantize') is not None,
                      'quantize_params knows the dense GQA leaves only; '
                      'the expert stacks need a grouped matmul that '
@@ -267,10 +260,10 @@ def _ring_horizon_cap(cfg, batch: int, param_bytes: int,
 
 def prepare_params(cfg: ModelConfig, params, *, quantize=None, mesh=None,
                    donate_params: bool = False):
-    """Shared param preparation for the slot and paged engines:
-    LoRA merge, init-if-absent, optional int8 quantization, mesh
-    sharding. Returns (cfg, params, effective_quantize) — cfg changes
-    when a LoRA checkpoint is folded (lora_rank drops to 0).
+    """Param preparation for an engine: LoRA merge, init-if-absent,
+    optional int8 / int4 quantization, mesh sharding. Returns (cfg,
+    params, effective_quantize) — cfg changes when a LoRA checkpoint is
+    folded (lora_rank drops to 0).
 
     Ordering matters twice: LoRA adapters fold BEFORE quantization
     (folding into an int8 base is refused), and on a mesh the bf16 tree
@@ -325,12 +318,11 @@ def prepare_params(cfg: ModelConfig, params, *, quantize=None, mesh=None,
 
 
 class _EngineBase:
-    """Host-side request lifecycle shared by the slot engine (below) and
-    the paged engine (``inference/paged.py``): queue, slot table,
-    finish/cancel bookkeeping, the async step loop. Subclasses implement
-    ``_admit()``, ``_enqueue_decode(horizon)`` and ``_process_one()``
-    (the compiled paths + their lagged readback) and may override
-    ``_free_slot``/``_validate_request``."""
+    """Host-side request lifecycle of the paged engine
+    (``inference/paged.py``, the one subclass): queue, slot table,
+    finish/cancel bookkeeping, KV handoff and gang entries. The subclass
+    holds the cache, the compiled paths, ``step()`` and the lagged
+    readback (``_process_one()``)."""
 
     def _init_telemetry(self, enabled: bool = True) -> None:
         """Engine telemetry: the step-phase profiler + per-request
@@ -373,24 +365,15 @@ class _EngineBase:
     # static cost model (analysis/costmodel.py) prices each dispatch
     # by attributing every jaxpr input to a byte stream — weights
     # (codes/scales split out for quantized trees), the KV pool, and
-    # the per-call control tables. Both engines share the calling
-    # convention (args[0]=params, args[1]=cache, control after), so
-    # the base annotation covers them.
+    # the per-call control tables (args[0]=params, args[1]=cache,
+    # control after).
     def decode_operand_classes(self, args):
         from skypilot_tpu.analysis import costmodel
         return costmodel.classify_decode_args(args)
 
-    def kv_token_capacity(self) -> int:
-        """Token rows the resident KV arrays physically hold (the
-        divisor that turns pool avals into stored bytes/token — the
-        cost model's telemetry-comparable KV unit). The slot cache
-        reserves every row up front; the paged pool overrides with
-        its page count."""
-        return self.max_batch * self.max_seq
-
     def phase_stats(self) -> Dict[str, Any]:
         """Step-phase latency decomposition + first-compile events for
-        THIS engine (the bench and ``/debug`` surface)."""
+        THIS engine (the ``/debug`` surface)."""
         return self._prof.phase_stats()
 
     @property
@@ -448,16 +431,13 @@ class _EngineBase:
         # results have not been read back yet, oldest first. Each entry
         # is {'kind': 'prefill'|'decode', 'toks': device array, ...}.
         self._pending: 'collections.deque[dict]' = collections.deque()
-        self._inflight_steps = 0     # sum of horizons of pending decodes
         self._meta_dirty = True      # slot table changed since upload
         self._meta_dev: Optional[Tuple[Any, ...]] = None
         # Device-resident current token per slot: decode call N+1 is
         # fed call N's last-token COLUMN without a host round trip (the
         # async pipeline's data path). Prefill tokens scatter in via
-        # _merge_tokens.
+        # _merge_tokens_drop.
         self._tok_dev = jnp.zeros((max_batch,), jnp.int32)
-        self._merge_tokens = jax.jit(
-            lambda tok, slots, vals: tok.at[slots].set(vals))
         # Multi-LoRA / grammar per-slot state: device adapter indices
         # ([b] int32, -1 = base) and vocab masks ([b, vocab] bool),
         # rebuilt with the slot-meta tuple. _vmask_any is STICKY: once
@@ -660,12 +640,6 @@ class _EngineBase:
                 return True
         return False
 
-    # Pool-pressure recompute requeues. The slot engine reserves
-    # max_seq rows per slot up front so it never preempts; the paged
-    # engine overrides this with a live counter. One spelling so the
-    # telemetry/bench surfaces read the same attribute off either.
-    preemptions = 0
-
     # Requests evicted because their logits row went non-finite (the
     # device-side NaN sentinel, llama.NONFINITE_TOKEN). The serve
     # layer watches the delta to escalate repeated hits to a
@@ -704,12 +678,6 @@ class _EngineBase:
     def queue_depth(self) -> int:
         """Requests waiting for a slot (the serve metrics surface)."""
         return len(self._queue)
-
-    def _slot_remaining_prefill(self, slot: int) -> int:
-        """Prompt tokens this slot still has to prefill (0 once
-        decodable). Chunked engines override with their cursor."""
-        del slot
-        return 0
 
     def _remaining_decode(self, req: 'Request') -> int:
         """Decode tokens this request may still emit (budget- and
@@ -788,11 +756,6 @@ class _EngineBase:
                 f'decode_steps_per_call must be >= 1, got {k}')
         return k
 
-    def _pinned_horizon(self, horizon: int) -> int:
-        """The fused horizon ``step()`` should run: the pinned k when
-        the multi-step knob is set, else the caller's horizon."""
-        return self.decode_steps_per_call or horizon
-
     # Depth of the async dispatch pipeline: device calls kept in flight
     # before the host reads results back. Depth 2 overlaps the per-call
     # dispatch round trip with device compute: the next decode is
@@ -801,33 +764,6 @@ class _EngineBase:
     # (The depth was chosen where a dispatch cost 100-600 ms: not
     # measured on the current chip, ROADMAP C8.)
     _PIPELINE_DEPTH = 2
-
-    def step(self, horizon: int = 1) -> List[Tuple[int, int, bool]]:
-        """Admit waiting requests into free slots (prefill), enqueue up
-        to ``horizon`` fused decode steps. Returns
-        [(request_id, token, finished), ...] in emission order.
-
-        Results lag enqueues by up to ``_PIPELINE_DEPTH`` calls — a
-        request's tokens surface one or two step() calls after the
-        device produced them; callers that need everything drained use
-        run_to_completion()."""
-        events: List[Tuple[int, int, bool]] = []
-        # Make room in the pipeline (sync the oldest call) BEFORE
-        # admitting: processing frees finished slots, so admission sees
-        # the freshest slot table.
-        with self._prof.phase('readback'):
-            while len(self._pending) >= self._PIPELINE_DEPTH:
-                events.extend(self._process_one())
-        with self._prof.phase('admit'):
-            events.extend(self._admit())
-        with self._prof.phase('decode_enqueue'):
-            enqueued = self._enqueue_decode(self._pinned_horizon(horizon))
-        if not enqueued and self._pending:
-            # Nothing to enqueue (no active slots, or capacity pinned
-            # until in-flight calls land): drain one instead.
-            with self._prof.phase('readback'):
-                events.extend(self._process_one())
-        return events
 
     def run_to_completion(self, horizon: int = 32) -> Dict[int, Request]:
         """Drive until queue + slots + in-flight calls drain. Returns
@@ -894,8 +830,8 @@ class _EngineBase:
     # worker exports a live request's context rows in the cache's
     # STORED dtype (int8 codes+scales stay int8 — the wire codec never
     # dequantizes); a decode worker ingests them and resumes decoding
-    # at the exact original bytes. Engine-specific gather/land live in
-    # the subclasses (_gather_kv_rows / _land_kv_rows).
+    # at the exact original bytes. The pool's gather and scatter are
+    # the subclass's (_gather_kv_rows / _land_kv_rows).
 
     def export_kv_snapshot(self, request_id: int):
         """Resumable handoff snapshot of a live DECODING request:
@@ -944,12 +880,6 @@ class _EngineBase:
         }
         return snapshot, events
 
-    def _gather_kv_rows(self, slot: int, n_rows: int):
-        """Engine-specific: the slot's first ``n_rows`` context rows as
-        host numpy (k, v, k_scale|None, v_scale|None), token-major
-        [L, n, hkv, d] (scales [L, n, hkv])."""
-        raise NotImplementedError
-
     def decoding_request_ids(self) -> List[int]:
         """Request ids currently seated in decode slots (the set
         ``export_kv_snapshot`` can snapshot). Callers serialize engine
@@ -986,50 +916,6 @@ class _EngineBase:
         if prepared and getattr(self, 'speculate_k', 0):
             self.prepare_proposals()
         return self.step(horizon=horizon)
-
-    # ---------------------------------------------- prefix checkpoint
-    # Spot resilience: on a preemption warning the serve layer
-    # checkpoints the engine's hottest prefix-cache page chains (plus
-    # in-flight request snapshots) through the SKKV/SKPF wire codec,
-    # and a replacement replica lands them via warm_prefix BEFORE it
-    # enters LB rotation — post-recovery TTFT is near-warm instead of
-    # cold. The slot engine has no prefix cache, so the base
-    # implementations are honest no-ops; the paged engine overrides
-    # both.
-
-    def export_prefix_snapshots(self, max_entries: int = 8):
-        """Hottest prefix-cache page chains as prefix entries
-        (``kv_transfer.encode_prefix_chain`` input dicts), plus any
-        events drained from the async pipeline (routed by the caller
-        exactly like ``step()`` events). Base: no prefix cache —
-        ``([], [])``."""
-        del max_entries
-        return [], []
-
-    def warm_prefix(self, entry: Dict[str, Any]) -> int:
-        """Land a prefix entry (or a request snapshot viewed as one)
-        into the prefix cache WITHOUT seating a request; returns the
-        number of KV rows landed. Base: no prefix cache — 0 rows (the
-        warmup endpoint reports it; callers must not treat 0 as an
-        error)."""
-        del entry
-        return 0
-
-    def hot_prefix_digest(self, max_entries: int = 16):
-        """Bounded (chain-hash, token-length, hits) digest of the
-        hottest cached prefix chains, for the LB's prefix-affinity
-        routing. Host-side state only — the probe path ships it on
-        every /metrics scrape, so it must never touch the device.
-        Base: no prefix cache — empty."""
-        del max_entries
-        return []
-
-    def export_prefix_entry(self, hash_hex: str):
-        """One digest-named hot chain as ``(entry_or_None, events)``
-        — the proactive affinity-migration export. Base: no prefix
-        cache — ``(None, [])``."""
-        del hash_hex
-        return None, []
 
     def _validate_kv_entry(self, entry: Dict[str, Any],
                            n_rows: int) -> None:
@@ -1114,9 +1000,9 @@ class _EngineBase:
             output=list(snap['output']),
             submit_time=clock.now())
         # The first token happened on the prefill worker; set the
-        # timestamp so per-token bookkeeping (and the slot engine's
-        # readback guard) treats the slot as live. The serve layer
-        # skips TTFT observation for handoff continuations.
+        # timestamp so per-token bookkeeping treats the slot as live.
+        # The serve layer skips TTFT observation for handoff
+        # continuations.
         req.first_token_time = req.submit_time
         req._enq_out = len(req.output)
         if self.telemetry_enabled:
@@ -1160,13 +1046,6 @@ class _EngineBase:
         self._meta_dirty = True
         return req.request_id
 
-    def _land_kv_rows(self, slot: int, req: Request,
-                      snap: Dict[str, Any]) -> None:
-        """Engine-specific: write the snapshot's rows into this slot's
-        cache storage (raises ``HandoffCapacityError`` on pool
-        pressure)."""
-        raise NotImplementedError
-
     def get_finished(self, request_id: int) -> Optional[Request]:
         return self._finished.get(request_id)
 
@@ -1182,16 +1061,12 @@ class _EngineBase:
         self._slot_len[slot] = 0
         self._meta_dirty = True      # async engines re-upload slot meta
 
-    def _maybe_finish(self, slot: int, token: int) -> bool:
-        return self._finish_req(slot, self._slots[slot], token)
-
     def _finish_req(self, slot: int, req, token: int) -> bool:
-        """Request-scoped finish check. Distinct from _maybe_finish so
-        the paged engine's EARLY-RECYCLED tenancies (slot already freed
-        or re-assigned, tail tokens still surfacing through the async
-        pipeline) can finish their request without touching whoever
-        holds the slot now — it is only freed when ``req`` still owns
-        it."""
+        """Request-scoped finish check, so that EARLY-RECYCLED
+        tenancies (slot already freed or re-assigned, tail tokens still
+        surfacing through the async pipeline) can finish their request
+        without touching whoever holds the slot now — it is only freed
+        when ``req`` still owns it."""
         # Stop sequences first: a stop completing exactly on the
         # max_new_tokens/max_seq boundary must still be trimmed.
         done = False
@@ -1227,1173 +1102,12 @@ class _EngineBase:
             registry.release(req.adapter)
 
 
-def _slot_spec_verify(params, big_cache, tokens, proposals, n_prop,
-                      temps, topks, topps, active, rng, *, cfg,
-                      attn_impl, kv_bucket, max_seq, k, sample,
-                      mlora_idx=None, vocab_mask=None):
-    """One speculative verify round over the slot cache — the traced
-    body shared by the single-round jit (``_get_spec_verify``) and the
-    fused in-scan rounds (``_get_spec_fused``): one forward over the
-    k+1 positions [t0, d1..dk] per slot, device acceptance, and a
-    MASKED sentinel scatter of the accepted rows. Returns
-    ``(commit, n_commit, new_tok, new_cache)``."""
-    from skypilot_tpu.inference import speculative
-    b = tokens.shape[0]
-    len0 = big_cache.length
-    # Length-aware cache read, same policy as decode_horizon: slice
-    # only when it at least halves the stream (the sliced prefix
-    # materializes as a program temp).
-    ck = big_cache.k[:, :, :kv_bucket]
-    cv = big_cache.v[:, :, :kv_bucket]
-    if big_cache.quantized:
-        cache_kv = (ck, cv, big_cache.k_scale[:, :, :kv_bucket],
-                    big_cache.v_scale[:, :, :kv_bucket])
-    else:
-        cache_kv = (ck, cv)
-    seq = jnp.concatenate([tokens[:, None], proposals], axis=1)
-    logits, rows = llama.prefill_rows(
-        params, seq, jnp.full((b,), k + 1, jnp.int32), cfg,
-        attn_impl=attn_impl,
-        quantize_rows=('int4' if big_cache.packed
-                       else big_cache.quantized),
-        cache_kv=cache_kv, cache_len=len0, all_logits=True,
-        mlora_idx=mlora_idx)
-    # Grammar masks constrain verification too — the [n, k+1, vocab]
-    # logits mask broadcasts over the k+1 verify positions, so a
-    # proposal outside the grammar is rejected exactly like any other
-    # mismatching draft.
-    logits = llama.apply_vocab_mask(logits, vocab_mask)
-    commit, n_commit = speculative.verify_tokens(
-        logits, proposals, n_prop, rng, temps, topks, topps,
-        sample=sample)
-    n_commit = jnp.where(active, n_commit, 0)
-    # Masked commit: rows past each slot's accepted count (and every
-    # row of inactive slots) scatter to the max_seq sentinel and drop.
-    pos = len0[:, None] + jnp.arange(k + 1)[None, :]
-    pos = jnp.where(jnp.arange(k + 1)[None, :]
-                    < n_commit[:, None], pos, max_seq)
-    slots = jnp.arange(b)
-    length = len0 + n_commit
-
-    def scatter(c, r):
-        return c.at[:, slots[:, None], pos].set(
-            r.astype(c.dtype), mode='drop')
-
-    if big_cache.quantized:
-        kq, vq, ks, vs = rows
-        new_cache = llama.KVCache(
-            k=scatter(big_cache.k, kq),
-            v=scatter(big_cache.v, vq), length=length,
-            k_scale=scatter(big_cache.k_scale, ks),
-            v_scale=scatter(big_cache.v_scale, vs))
-    else:
-        k_rows, v_rows = rows
-        new_cache = llama.KVCache(
-            k=scatter(big_cache.k, k_rows),
-            v=scatter(big_cache.v, v_rows), length=length)
-    # Next round's t0 = the last committed token per slot.
-    nxt = jnp.take_along_axis(
-        commit, jnp.maximum(n_commit - 1, 0)[:, None],
-        axis=1)[:, 0]
-    new_tok = jnp.where(active, nxt, tokens)
-    return commit, n_commit, new_tok, new_cache
-
-
-class InferenceEngine(SpeculativeMixin, _EngineBase):
-    """Slot-cache engine core: callers drive ``step()``; the serve layer
-    wraps it in an HTTP loop. Decode/prefill calls dispatch through the
-    async pipeline (``_EngineBase.step``): results are read back one
-    call behind the enqueue, so per-call dispatch latency overlaps
-    device compute and short fused horizons stop paying a round trip
-    each. ``speculate_k > 0`` switches decode to the speculative
-    propose→verify→commit loop (``inference/speculative.py``): up to
-    k+1 tokens per slot per weight-stream pass."""
-
-    def __init__(self, cfg: ModelConfig, params: Optional[Any] = None,
-                 *, max_batch: int = 8, max_seq: int = 1024,
-                 mesh: Optional[Any] = None, rng_seed: int = 0,
-                 attn_impl: str = 'auto',
-                 quantize: Optional[str] = None,
-                 kv_cache_dtype: Optional[str] = None,
-                 donate_params: bool = False,
-                 prefill_w8a8: bool = False,
-                 prefill_chunk_tokens: Optional[int] = 256,
-                 decode_priority_ratio: Optional[float] = None,
-                 decode_steps_per_call: Optional[int] = None,
-                 speculate_k: int = 0,
-                 adapter_slots: int = 0,
-                 adapter_dir: Optional[str] = None,
-                 adapter_rank: int = 8,
-                 adapter_targets: Optional[Any] = None,
-                 telemetry: bool = True):
-        self._init_telemetry(telemetry)
-        self.max_batch = max_batch
-        self.max_seq = max_seq
-        self.mesh = mesh
-        self.attn_impl = attn_impl
-        # Multi-step on-device decode (see _EngineBase): pin every
-        # decode call at exactly k fused steps.
-        self.decode_steps_per_call = self._validate_decode_steps(
-            decode_steps_per_call)
-        # Opt-in: quantize prefill activations to int8 (2x MXU rate on
-        # the compute-bound prefill; decode unaffected). Off by default
-        # — W8A8 adds activation quantization noise to the KV rows.
-        self.prefill_w8a8 = prefill_w8a8
-        # Chunked prefill (on by default): prompts prefill in
-        # ``prefill_chunk_tokens``-sized chunks interleaved with decode
-        # horizons, bounding how long running requests stall behind a
-        # long prompt (the monolithic admit measured 5.5 s median burst
-        # TTFT — head-of-line blocking, BENCH_r05). 0/None falls back
-        # to monolithic whole-prompt admission waves (bench baseline).
-        # ``decode_priority_ratio`` splits the interleaved token budget
-        # (see _EngineBase._interleave_horizon); None = 0.5.
-        chunk = prefill_chunk_tokens or 0
-        self.chunk = _bucket_len(chunk, minimum=8) if chunk else 0
-        self.chunked = self.chunk > 0
-        self.decode_priority_ratio = decode_priority_ratio
-        self._rng = jax.random.PRNGKey(rng_seed)
-
-        refuse_unsupported(cfg, engine='slot')
-        cfg, self.params, quantize = prepare_params(
-            cfg, params, quantize=quantize, mesh=mesh,
-            donate_params=donate_params)
-        self.cfg = cfg
-        # Actual PER-DEVICE stored parameter bytes (int8 leaves count
-        # 1B/elem; sharded leaves count their local shard) — sizes the
-        # decode-horizon ring cap against the true per-chip weight
-        # stream: under tp both the weight stream and the ring rows
-        # split, so the cap stays put instead of drifting with mesh
-        # shape.
-        from skypilot_tpu.models import quantization
-        self._param_bytes = quantization.per_device_bytes(self.params)
-
-        # KV storage dtype is its OWN knob (decoupled from the weight
-        # quantize mode; None follows it for backward compatibility):
-        # the cache's quantized flag drives every downstream write site
-        # (prefill scatter, chunk prefill, spec verify, decode merge)
-        # and the fused-dequant attention reads.
-        self.kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype,
-                                                     quantize)
-        create_cache = functools.partial(
-            llama.KVCache.create, cfg, batch=max_batch, max_seq=max_seq,
-            kv_dtype=self.kv_cache_dtype)
-        # Pre-partitioned cache + pinned output shardings: the cache is
-        # placed ONCE with its logical-axis shardings, and every
-        # jitted step that returns it pins the SAME tree as its
-        # out_shardings — each program's output layout IS the next
-        # program's input layout (the pjit in/out_axis_resources-
-        # matching discipline), so steady state never inserts a
-        # resharding collective between steps. None (meshless) skips
-        # the machinery entirely.
-        self._cache_sh = None
-        if mesh is None:
-            self.cache = create_cache()
-        else:
-            # Each shard is born on its own device, never the whole
-            # cache on the first (see the paged engine's pool).
-            self._cache_sh = mesh_lib.tree_shardings(
-                llama.cache_logical_axes(
-                    quantized=self.kv_cache_dtype != 'bf16'),
-                mesh, shapes=jax.eval_shape(create_cache))
-            self.cache = jax.jit(create_cache,
-                                 out_shardings=self._cache_sh)()
-
-        # slot bookkeeping (host side); device cache.length is
-        # authoritative for attention masking.
-        self._init_slots(max_batch)
-        # Multi-tenant adapter bank (adapter_slots > 0): installs the
-        # stacked multi-LoRA bank into params['layers']['mlora'] BEFORE
-        # the decode programs trace, so every program below carries the
-        # batched gather matmul. adapter_slots=0 leaves the params tree
-        # — and every traced program — byte-identical to before.
-        self.adapters = None
-        if adapter_slots > 0:
-            from skypilot_tpu.inference import adapters as adapters_lib
-            self.adapters = adapters_lib.AdapterRegistry(
-                self, slots=adapter_slots, rank=adapter_rank,
-                adapter_dir=adapter_dir, targets=adapter_targets)
-        self._decode_fn = self._build_decode()
-        self._prefill_fns: Dict[int, Any] = {}
-        # Chunked-prefill scheduler state: slot -> prompt tokens
-        # prefilled so far. A slot in this dict is assigned but not yet
-        # decodable; the scheduling loop interleaves its remaining
-        # chunks with decode horizons.
-        self._prefill_off: Dict[int, int] = {}
-        self._chunk_prefill_fns: Dict[Tuple, Any] = {}
-        # Max mid-prefill slots per chunk batch (padded to a compiled
-        # n bucket); the per-call stacked-rows budget shrinks it
-        # further when the gathered-cache bucket is wide.
-        self._prefill_n_max = self._PREFILL_N_BUCKETS[-1]
-        # Fixed-shape first-token merge (completing chunk rows):
-        # padding entries scatter to the out-of-range sentinel
-        # max_batch and are dropped.
-        self._merge_tokens_drop = jax.jit(
-            lambda tok, slots, vals: tok.at[slots].set(vals,
-                                                       mode='drop'))
-        # KV handoff programs (disaggregated serving): export gathers
-        # keyed by context bucket, ingest scatters keyed by row bucket.
-        self._export_fns: Dict[int, Any] = {}
-        self._ingest_fns: Dict[int, Any] = {}
-        # Speculative decoding (0 = off): n-gram propose + batched
-        # verify instead of the fused decode horizon.
-        self._init_spec(speculate_k)
-
-    @classmethod
-    def from_pretrained(cls, path: str, *, dtype: Any = None,
-                        **kwargs) -> 'InferenceEngine':
-        """Build an engine from an HF checkpoint directory
-        (``config.json`` + safetensors; see ``models/weights.py``).
-        Pass ``quantize='int8'`` for int8 serving (weights AND KV
-        cache)."""
-        import jax.numpy as jnp
-        from skypilot_tpu.models import weights
-        # Quantize host-side during load: only int8 codes + scales ever
-        # reach the device (a 7B bf16 tree would not leave room on a
-        # 16 GB chip for the quantization pass).
-        cfg, params = weights.load_checkpoint(
-            path, dtype=dtype if dtype is not None else jnp.bfloat16,
-            quantize=kwargs.get('quantize'))
-        # The freshly loaded tree has no other owner: let quantization
-        # free bf16 buffers in place if it ever runs on-device.
-        kwargs.setdefault('donate_params', True)
-        return cls(cfg, params, **kwargs)
-
-    def kv_pool_stats(self) -> Dict[str, Any]:
-        """KV capacity/pressure in TOKENS — the schema the telemetry
-        gauges and bench share with the paged engine. The slot cache's
-        capacity is the static ``max_batch x max_seq`` reservation;
-        "used" counts live context rows, and preemptions are always 0
-        (every admitted request owns its full reservation)."""
-        cap = self.max_batch * self.max_seq
-        used = int(self._slot_len.sum())
-        return {
-            'kv_cache_dtype': self.kv_cache_dtype,
-            'pool_token_capacity': cap,
-            'tokens_used': used,
-            'tokens_free': cap - used,
-            'preemptions': int(self.preemptions),
-            'kv_token_bytes': kv_token_bytes(self.cfg,
-                                             self.kv_cache_dtype),
-            # Bytes ONE device stores per token (kv heads shard over
-            # tp) — the per-shard HBM view; token counts above stay
-            # GLOBAL (a token is a token however many chips hold it).
-            'kv_token_bytes_per_shard': kv_token_bytes(
-                self.cfg, self.kv_cache_dtype, mesh=self.mesh),
-            'kv_shards': kv_shard_degree(self.cfg, self.mesh),
-        }
-
-    # -------------------------------------------------- KV handoff
-    def _get_export(self, bucket: int):
-        """Compiled context-row gather for one slot (handoff export):
-        [L, bucket, hkv, d] rows (+ scales) straight off the slot
-        cache, in the STORED dtype — int8 codes and fp32 scales come
-        out exactly as resident, never dequantized."""
-        if bucket in self._export_fns:
-            return self._export_fns[bucket]
-        quantized = self.cache.quantized
-
-        @jax.jit
-        def export(cache, slot):
-            k = cache.k[:, slot, :bucket]
-            v = cache.v[:, slot, :bucket]
-            if quantized:
-                return (k, v, cache.k_scale[:, slot, :bucket],
-                        cache.v_scale[:, slot, :bucket])
-            return k, v
-
-        self._export_fns[bucket] = export
-        return export
-
-    def _gather_kv_rows(self, slot: int, n_rows: int):
-        bucket = min(_bucket_len(max(1, n_rows)), self.max_seq)
-        slot_d = device_upload(np.array(slot, np.int32))
-        out = self._get_export(bucket)(self.cache, slot_d)
-        # Sanctioned d2h: the handoff export IS a host readback by
-        # design (the rows leave this process on the wire).
-        host = host_sync(out)
-        if self.cache.quantized:
-            k, v, ks, vs = host
-            return (k[:, :n_rows], v[:, :n_rows],
-                    ks[:, :n_rows, :, 0], vs[:, :n_rows, :, 0])
-        k, v = host
-        return k[:, :n_rows], v[:, :n_rows], None, None
-
-    def _get_ingest(self, nb: int):
-        """Compiled handoff scatter: land [L, 1, nb, hkv, d] rows (+
-        scales) into one slot's reservation at positions [0, valid),
-        padding rows dropping at the max_seq sentinel."""
-        if nb in self._ingest_fns:
-            return self._ingest_fns[nb]
-        quantized = self.cache.quantized
-        max_seq = self.max_seq
-
-        def _scatter(c, r, slots_arr, pos):
-            return c.at[:, slots_arr[:, None], pos].set(
-                r.astype(c.dtype), mode='drop')
-
-        if quantized:
-            @functools.partial(jax.jit, donate_argnums=(0,),
-                               **self._step_out_shardings(0))
-            def ingest(cache, kq, ks, vq, vs, slots_arr, valid):
-                pos = jnp.arange(nb)[None, :]
-                pos = jnp.where(pos < valid[:, None], pos, max_seq)
-                length = cache.length.at[slots_arr].set(valid,
-                                                        mode='drop')
-                return llama.KVCache(
-                    k=_scatter(cache.k, kq, slots_arr, pos),
-                    v=_scatter(cache.v, vq, slots_arr, pos),
-                    length=length,
-                    k_scale=_scatter(cache.k_scale, ks, slots_arr, pos),
-                    v_scale=_scatter(cache.v_scale, vs, slots_arr, pos))
-        else:
-            @functools.partial(jax.jit, donate_argnums=(0,),
-                               **self._step_out_shardings(0))
-            def ingest(cache, kr, vr, slots_arr, valid):
-                pos = jnp.arange(nb)[None, :]
-                pos = jnp.where(pos < valid[:, None], pos, max_seq)
-                length = cache.length.at[slots_arr].set(valid,
-                                                        mode='drop')
-                return llama.KVCache(
-                    k=_scatter(cache.k, kr, slots_arr, pos),
-                    v=_scatter(cache.v, vr, slots_arr, pos),
-                    length=length)
-
-        self._ingest_fns[nb] = ingest
-        return ingest
-
-    def _land_kv_rows(self, slot: int, req: Request,
-                      snap: Dict[str, Any]) -> None:
-        cfg = self.cfg
-        n_rows = int(snap['n_rows'])
-        nb = min(_bucket_len(max(1, n_rows)), self.max_seq)
-
-        def pad(arr, tail):
-            out = np.zeros((cfg.n_layers, 1, nb, cfg.n_kv_heads)
-                           + tail, dtype=arr.dtype)
-            out[:, 0, :n_rows] = arr.reshape(
-                (cfg.n_layers, n_rows, cfg.n_kv_heads) + tail)
-            return out
-
-        slots_arr = np.array([slot], np.int32)
-        valid = np.array([n_rows], np.int32)
-        ingest = self._get_ingest(nb)
-        code_d = (cfg.head_dim // 2 if self.cache.packed
-                  else cfg.head_dim)
-        if self.cache.quantized:
-            (kq, ks, vq, vs, slots_d, valid_d) = device_upload(
-                (pad(snap['k'], (code_d,)),
-                 pad(snap['k_scale'], (1,)),
-                 pad(snap['v'], (code_d,)),
-                 pad(snap['v_scale'], (1,)), slots_arr, valid))
-            self.cache = ingest(self.cache, kq, ks, vq, vs, slots_d,
-                                valid_d)
-        else:
-            kr, vr, slots_d, valid_d = device_upload(
-                (pad(snap['k'], (cfg.head_dim,)),
-                 pad(snap['v'], (cfg.head_dim,)), slots_arr, valid))
-            self.cache = ingest(self.cache, kr, vr, slots_d, valid_d)
-
-    # ------------------------------------------------------------------
-    # Compiled steps
-    # ------------------------------------------------------------------
-    def _build_decode(self):
-        """Multi-step decode: ``horizon`` steps fused into one program per
-        host sync (llama.decode_horizon's ring-buffer loop). Fusing N
-        steps amortizes the host round trip, the same trick a production
-        engine uses to hide dispatch latency. ``sample`` is STATIC: the all-greedy program
-        skips the top-k/temperature machinery entirely (a full-vocab sort
-        per step otherwise)."""
-        cfg = self.cfg
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           static_argnames=('horizon', 'sample',
-                                            'kv_bucket'),
-                           **self._step_out_shardings(1))
-        def decode_steps(params, cache, tokens, rng, temps, topks, topps,
-                         active, adp, vmask, horizon, sample, kv_bucket):
-            if sample:
-                def sample_fn(logits, step_rng):
-                    return sample_tokens(logits, step_rng, temps, topks,
-                                         topps)
-                rngs = jax.random.split(rng, horizon)
-            else:
-                sample_fn, rngs = None, None
-            toks, cache = llama.decode_horizon(
-                params, cache, tokens, cfg, horizon=horizon,
-                sample_fn=sample_fn, rngs=rngs, kv_bucket=kv_bucket,
-                mlora_idx=adp, vocab_mask=vmask)
-            # inactive slots don't advance their cache length
-            new_len = jnp.where(active, cache.length,
-                                cache.length - horizon)
-            cache = cache._replace(length=new_len)
-            return toks, cache                        # [slots, horizon]
-
-        return decode_steps
-
-    def _get_prefill(self, bucket: int, n: int):
-        """Batched prefill: n prompts (padded to one bucket) in one device
-        call that computes KV, scatters it into the requested slots of the
-        big cache, and returns the first sampled token per prompt. One host
-        round trip per admit cycle instead of three per request.
-
-        Rides ``llama.prefill_rows``: plain causal attention over the
-        bucket (flash kernel on TPU — the old forward-with-scratch-cache
-        path read a bucket of zero cache rows per layer and never hit
-        flash), rows quantized inside the layer scan for int8 caches
-        (halves the stacked-rows transient -> doubles the admission
-        wave), and last-position-only unembed."""
-        key = (bucket, n)
-        if key in self._prefill_fns:
-            return self._prefill_fns[key]
-        cfg, attn_impl = self.cfg, self.attn_impl
-        w8a8 = self.prefill_w8a8
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           **self._step_out_shardings(1))
-        def prefill(params, big_cache, tokens, true_lens, slots,
-                    adp, vmask):
-            """tokens [n, bucket]; true_lens [n]; slots [n] target rows."""
-            last, rows = llama.prefill_rows(
-                params, tokens, true_lens, cfg, attn_impl=attn_impl,
-                quantize_rows=('int4' if big_cache.packed
-                               else big_cache.quantized), w8a8=w8a8,
-                mlora_idx=adp)
-            last = llama.apply_vocab_mask(last, vmask)
-            next_tokens = llama.mask_nonfinite_tokens(
-                last, jnp.argmax(last, -1).astype(jnp.int32))
-            # Scatter KV rows + lengths into the slot cache.
-            length = big_cache.length.at[slots].set(true_lens)
-            if big_cache.quantized:
-                kq, vq, ks, vs = rows
-                return next_tokens, llama.KVCache(
-                    k=big_cache.k.at[:, slots, :bucket].set(kq),
-                    v=big_cache.v.at[:, slots, :bucket].set(vq),
-                    length=length,
-                    k_scale=big_cache.k_scale.at[:, slots, :bucket].set(ks),
-                    v_scale=big_cache.v_scale.at[:, slots, :bucket].set(vs))
-            k_rows, v_rows = rows
-            ck = big_cache.k.at[:, slots, :bucket].set(
-                k_rows.astype(big_cache.k.dtype))
-            cv = big_cache.v.at[:, slots, :bucket].set(
-                v_rows.astype(big_cache.v.dtype))
-            return next_tokens, llama.KVCache(k=ck, v=cv, length=length)
-
-        self._prefill_fns[key] = prefill
-        return prefill
-
-    # ------------------------------------------------------------------
-    _PREFILL_N_BUCKETS = (1, 2, 4, 8, 16, 32)
-
-    # Under saturation, admissions batch into waves of at least this
-    # many slots: a prefill call's cost is dominated by its fixed part
-    # at small n (measured 7B: n=2 ~120 ms vs n=8 ~260 ms — 60 vs 32 ms
-    # per request), so admitting every freed slot immediately spends
-    # ~2x the device time on prefill for the same arrivals.
-    _ADMIT_WAVE_MIN = 4
-
-    def _admit(self) -> List[Tuple[int, int, bool]]:
-        """Admission dispatch. Chunked (default): assign free slots
-        immediately and run at most ONE prefill chunk batch before
-        decode resumes — the scheduling loop (``step``) interleaves the
-        remaining chunks with decode horizons. Monolithic
-        (``prefill_chunk_tokens=0``): the historical whole-prompt
-        admission wave. Both ALWAYS return [] — prefill results ride
-        the async pipeline and their first-token events surface in
-        ``_process_one`` up to ``_PIPELINE_DEPTH`` calls later."""
-        if not self.chunked:
-            return self._admit_monolithic()
-        self._assign_slots()
-        events = self._prefill_chunk_batch()
-        # Burst exception (mirrors the paged engine): while the
-        # DECODING population is under a quarter of the batch (cold
-        # start / arrival burst), the one-chunk-per-step TPOT bound
-        # protects almost nobody — run chunk batches back to back so
-        # the first slots start decoding sooner.
-        while (self._prefill_off
-               and self.num_active - len(self._prefill_off)
-               < self.max_batch // 4):
-            events += self._prefill_chunk_batch()
-        return events
-
-    def _assign_slots(self) -> None:
-        """Reserve free slots for queued requests with a zero prefill
-        cursor; chunks stream in via _prefill_chunk_batch."""
-        for slot in range(self.max_batch):
-            if self._slots[slot] is not None:
-                continue
-            req = self._queue_pop()
-            if req is None:
-                return
-            self._slots[slot] = req
-            self._slot_len[slot] = 0
-            self._prefill_off[slot] = 0
-            self._trace_sched(req)
-
-    def _free_slot(self, slot: int) -> None:
-        self._prefill_off.pop(slot, None)      # cancel mid-prefill
-        super()._free_slot(slot)
-
-    def _slot_remaining_prefill(self, slot: int) -> int:
-        off = self._prefill_off.get(slot)
-        if off is None:
-            return 0
-        return max(0, len(self._slots[slot].prompt) - off)
-
-    def _prefill_chunk_batch(self) -> List[Tuple[int, int, bool]]:
-        """One fixed-size prefill chunk across up to a compiled
-        n-bucket of mid-prefill slots, attending the slots' EXISTING
-        cache rows (nonzero cache offset) and scattering the new rows
-        at each slot's cursor. Completing rows sample their first token
-        ON DEVICE (per-request params) and merge it into the device
-        token vector before this returns, so they decode on the very
-        next horizon; the first-token EVENT surfaces via _process_one.
-        ALWAYS returns []."""
-        pending = sorted(self._prefill_off)
-        if not pending:
-            return []
-        # Per-DEVICE token cost: the stacked chunk transient shards
-        # its kv-head dim over tp, so a tp=2 engine admits twice the
-        # wave within the same per-chip scratch budget.
-        scratch_tok = kv_token_bytes(self.cfg, self.kv_cache_dtype,
-                                     mesh=self.mesh)
-
-        def shapes(batch):
-            # Chunk width: the full chunk, or a smaller bucket when
-            # every pending piece is short (prompt tails) — bounded
-            # compiled-program count, half/quarter the FLOPs.
-            rest_max = max(len(self._slots[s].prompt)
-                           - self._prefill_off[s] for s in batch)
-            chunk_w = min(self.chunk,
-                          _bucket_len(rest_max,
-                                      minimum=min(64, self.chunk)))
-            # Cache-read bucket: covers every batch row's cursor (rows
-            # past each cursor are masked); 0 when no row has context
-            # yet — that variant runs plain causal attention
-            # (flash-eligible), exactly the monolithic first-chunk
-            # math.
-            start_max = int(max(self._slot_len[s] for s in batch))
-            kv_bucket = (0 if start_max == 0
-                         else min(_bucket_len(start_max), self.max_seq))
-            return chunk_w, kv_bucket
-
-        batch = pending[:self._prefill_n_max]
-        chunk_w, kv_bucket = shapes(batch)
-        # The chunk program's transient is the stacked [L, n, chunk_w]
-        # new rows PLUS the gathered [L, n, kv_bucket] cache copy —
-        # cap n to the same scratch budget as the monolithic wave.
-        fit = int(0.75e9) // max(1, (chunk_w + kv_bucket) * scratch_tok)
-        cap = 1
-        for b in self._PREFILL_N_BUCKETS:
-            if b <= fit:
-                cap = b
-        if len(batch) > cap:
-            batch = batch[:cap]
-            chunk_w, kv_bucket = shapes(batch)
-        n = next(b for b in self._PREFILL_N_BUCKETS if b >= len(batch))
-
-        tokens = np.zeros((n, chunk_w), np.int32)
-        starts = np.zeros(n, np.int32)
-        valid = np.zeros(n, np.int32)
-        want = np.full(n, -1, np.int32)
-        # Padding rows carry the out-of-range slot sentinel: their
-        # writes (rows, lengths, token merge) all drop.
-        slots_arr = np.full(n, self.max_batch, np.int32)
-        temps = np.zeros(n, np.float32)
-        topks = np.zeros(n, np.int32)
-        topps = np.ones(n, np.float32)
-        adp_h = (np.full(n, -1, np.int32)
-                 if self.adapters is not None else None)
-        vm_h = (np.ones((n, self.cfg.vocab_size), bool)
-                if self._vmask_any else None)
-        for i, slot in enumerate(batch):
-            req = self._slots[slot]
-            off = self._prefill_off[slot]
-            piece = req.prompt[off:off + chunk_w]
-            tokens[i, :len(piece)] = piece
-            starts[i] = self._slot_len[slot]
-            valid[i] = len(piece)
-            if off + len(piece) == len(req.prompt):
-                want[i] = len(piece) - 1
-            slots_arr[i] = slot
-            temps[i] = req.temperature
-            topks[i] = req.top_k or 0
-            topps[i] = req.top_p
-            if adp_h is not None:
-                adp_h[i] = req._adapter_slot
-            if vm_h is not None and req._vocab_mask is not None:
-                vm_h[i] = req._vocab_mask
-        # Sampling variant only when a COMPLETING row needs it (the
-        # full-vocab sort costs hundreds of ms on TPU; mid-prompt
-        # chunks and greedy completions must not pay it).
-        sample = any(self._slots[s].temperature > 0
-                     for i, s in enumerate(batch) if want[i] >= 0)
-        self._rng, prng = jax.random.split(self._rng)
-        # ONE batched host->device transfer for every host-built
-        # operand (each separate jnp.asarray is its own dispatch round
-        # trip).
-        extras = tuple(x for x in (adp_h, vm_h) if x is not None)
-        uploaded = device_upload(
-            (tokens, starts, valid, want, slots_arr, temps, topks,
-             topps) + extras)
-        (tokens_d, starts_d, valid_d, want_d, slots_d, temps_d,
-         topks_d, topps_d) = uploaded[:8]
-        rest = list(uploaded[8:])
-        adp_d = rest.pop(0) if adp_h is not None else None
-        vm_d = rest.pop(0) if vm_h is not None else None
-        prefill = self._get_chunk_prefill(n, chunk_w, kv_bucket, sample)
-        chunk_t0 = clock.monotonic()
-        with self._prof.phase('prefill_chunk', prompts=n, width=chunk_w,
-                              kv_bucket=kv_bucket), \
-                self._prof.jit_key('chunk_prefill',
-                                   (n, chunk_w, kv_bucket, sample)):
-            first, self.cache = prefill(
-                self.params, self.cache, tokens_d, starts_d, valid_d,
-                want_d, slots_d, adp_d, vm_d, temps_d, topks_d,
-                topps_d, prng)
-        chunk_t1 = clock.monotonic()
-        for i, slot in enumerate(batch):
-            r = self._slots[slot]
-            if r.trace is not None:
-                r.trace.add('prefill_chunk', chunk_t0, chunk_t1,
-                            offset=self._prefill_off[slot],
-                            tokens=int(valid[i]))
-        # Async: host bookkeeping advances NOW (device writes are
-        # program-ordered); completing slots' sampled tokens merge into
-        # the device token vector immediately so they decode on the
-        # next horizon.
-        done_rows: List[Tuple[int, int]] = []    # (row i, slot)
-        for i, slot in enumerate(batch):
-            self._slot_len[slot] += int(valid[i])
-            self._prefill_off[slot] += int(valid[i])
-            if want[i] < 0:
-                continue                         # more chunks to go
-            del self._prefill_off[slot]
-            done_rows.append((i, slot))
-        if done_rows:
-            rows_p = np.zeros(n, np.int32)
-            slots_p = np.full(n, self.max_batch, np.int32)
-            for j, (i, slot) in enumerate(done_rows):
-                rows_p[j], slots_p[j] = i, slot
-            rows_d, sl_d = device_upload((rows_p, slots_p))
-            self._tok_dev = self._merge_tokens_drop(
-                self._tok_dev, sl_d, jnp.take(first, rows_d))
-            self._meta_dirty = True              # slots become decodable
-            self._pending.append({'kind': 'prefill', 'toks': first,
-                                  'batch': [(slot, self._slots[slot], i)
-                                            for i, slot in done_rows]})
-        return []
-
-    def _get_chunk_prefill(self, n: int, chunk_w: int, kv_bucket: int,
-                           sample: bool):
-        """Compiled chunk-prefill program: gather the batch slots' first
-        ``kv_bucket`` cache rows (0 = no cache read — plain causal,
-        flash-eligible), run the chunk through prefill_rows at each
-        row's offset, scatter the new rows back at the cursors
-        (mode='drop': positions past ``valid`` or ``max_seq`` and the
-        padding sentinel slot all discard instead of clamp-corrupting
-        the cache tail), and sample each completing row's next token."""
-        key = (n, chunk_w, kv_bucket, sample)
-        if key in self._chunk_prefill_fns:
-            return self._chunk_prefill_fns[key]
-        cfg, attn_impl = self.cfg, self.attn_impl
-        w8a8 = self.prefill_w8a8
-        max_seq = self.max_seq
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           **self._step_out_shardings(1))
-        def prefill(params, big_cache, tokens, starts, valid, want_idx,
-                    slots, adp, vmask, temps, topks, topps, rng):
-            if kv_bucket:
-                ck = big_cache.k[:, slots, :kv_bucket]
-                cv = big_cache.v[:, slots, :kv_bucket]
-                if big_cache.quantized:
-                    cache_kv = (ck, cv,
-                                big_cache.k_scale[:, slots, :kv_bucket],
-                                big_cache.v_scale[:, slots, :kv_bucket])
-                else:
-                    cache_kv = (ck, cv)
-            else:
-                cache_kv = None
-            last_idx = jnp.clip(want_idx, 0, chunk_w - 1)
-            last, rows = llama.prefill_rows(
-                params, tokens, last_idx + 1, cfg, attn_impl=attn_impl,
-                quantize_rows=('int4' if big_cache.packed
-                               else big_cache.quantized), w8a8=w8a8,
-                cache_kv=cache_kv,
-                cache_len=starts if kv_bucket else None,
-                mlora_idx=adp)
-            # Completing rows' first sampled token honors the grammar.
-            last = llama.apply_vocab_mask(last, vmask)
-            if sample:
-                first = sample_tokens(last, rng, temps, topks, topps)
-            else:
-                first = jnp.argmax(last, -1).astype(jnp.int32)
-            # NaN guard on completing rows (llama.mask_nonfinite_tokens
-            # — the host evicts the poisoned request at readback).
-            first = llama.mask_nonfinite_tokens(last, first)
-            pos = starts[:, None] + jnp.arange(chunk_w)[None, :]
-            pos = jnp.where(jnp.arange(chunk_w)[None, :] < valid[:, None],
-                            pos, max_seq)        # invalid rows drop
-            length = big_cache.length.at[slots].set(starts + valid,
-                                                    mode='drop')
-
-            def scatter(c, r):
-                return c.at[:, slots[:, None], pos].set(
-                    r.astype(c.dtype), mode='drop')
-
-            if big_cache.quantized:
-                kq, vq, ks, vs = rows
-                new_cache = llama.KVCache(
-                    k=scatter(big_cache.k, kq),
-                    v=scatter(big_cache.v, vq), length=length,
-                    k_scale=scatter(big_cache.k_scale, ks),
-                    v_scale=scatter(big_cache.v_scale, vs))
-            else:
-                k_rows, v_rows = rows
-                new_cache = llama.KVCache(k=scatter(big_cache.k, k_rows),
-                                          v=scatter(big_cache.v, v_rows),
-                                          length=length)
-            return first, new_cache
-
-        self._chunk_prefill_fns[key] = prefill
-        return prefill
-
-    # ------------------------------------------------------- speculative
-    def _get_spec_verify(self, sample: bool, kv_bucket: int):
-        """Compiled speculative verify: one forward over the k+1
-        positions [t0, d1..dk] per slot against the slots' existing
-        cache rows (the nonzero-cache-offset prefill path), acceptance
-        on device, and a MASKED scatter of the accepted rows — per-slot
-        variable acceptance never changes a shape, so the jit key is
-        exactly (k, sample, kv_bucket)."""
-        key = (self.speculate_k, sample, kv_bucket)
-        if key in self._spec_verify_fns:
-            return self._spec_verify_fns[key]
-        cfg, attn_impl = self.cfg, self.attn_impl
-        k = self.speculate_k
-        max_seq = self.max_seq
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           **self._step_out_shardings(3))
-        def verify(params, big_cache, tokens, proposals, n_prop, temps,
-                   topks, topps, active, adp, vmask, rng):
-            return _slot_spec_verify(
-                params, big_cache, tokens, proposals, n_prop, temps,
-                topks, topps, active, rng, cfg=cfg,
-                attn_impl=attn_impl, kv_bucket=kv_bucket,
-                max_seq=max_seq, k=k, sample=sample,
-                mlora_idx=adp, vocab_mask=vmask)
-
-        self._spec_verify_fns[key] = verify
-        return verify
-
-    def _get_spec_fused(self, sample: bool, kv_bucket: int,
-                        rounds: int):
-        """Compiled in-scan speculative rounds: ``rounds`` x (device
-        n-gram propose → verify forward → masked commit) fused into ONE
-        program via lax.scan. The verify body is exactly
-        ``_slot_spec_verify`` (greedy byte-identity inherited), the
-        proposer reads a gather-carried right-aligned history window,
-        and the ``rem`` budget carry reproduces the host budget cap so
-        commits never overshoot ``max_new_tokens`` or the sequence
-        capacity. jit key: (k, sample, kv_bucket, rounds)."""
-        key = ('fused', self.speculate_k, sample, kv_bucket, rounds)
-        if key in self._spec_verify_fns:
-            return self._spec_verify_fns[key]
-        from skypilot_tpu.inference import speculative
-        cfg, attn_impl = self.cfg, self.attn_impl
-        k = self.speculate_k
-        max_seq = self.max_seq
-        max_ngram = self.spec_max_ngram
-        H = self.spec_hist_window
-
-        @functools.partial(jax.jit, donate_argnums=(1,),
-                           **self._step_out_shardings(4))
-        def fused(params, big_cache, tokens, hist, rem, temps, topks,
-                  topps, active, adp, vmask, rngs):
-            def round_body(carry, rng):
-                cache, tok, hist, rem = carry
-                prop, n_prop = speculative.ngram_propose_device(
-                    hist, k, max_ngram=max_ngram)
-                # Budget carry: at most ``rem`` tokens may still commit
-                # (n_commit <= n_prop + 1) — _spec_build_proposals's
-                # cap, applied round by round on device.
-                n_prop = jnp.minimum(n_prop, jnp.maximum(rem - 1, 0))
-                act = active & (rem >= 1)
-                commit, n_commit, new_tok, new_cache = \
-                    _slot_spec_verify(
-                        params, cache, tok, prop, n_prop, temps,
-                        topks, topps, act, rng, cfg=cfg,
-                        attn_impl=attn_impl, kv_bucket=kv_bucket,
-                        max_seq=max_seq, k=k, sample=sample,
-                        mlora_idx=adp, vocab_mask=vmask)
-                # History carry: append the commit row and re-right-
-                # align (shift left by n_commit; uncommitted positions
-                # land past the window and are never gathered).
-                combined = jnp.concatenate([hist, commit], axis=1)
-                gidx = (jnp.arange(H, dtype=jnp.int32)[None, :]
-                        + n_commit[:, None])
-                new_hist = jnp.take_along_axis(combined, gidx, axis=1)
-                return ((new_cache, new_tok, new_hist,
-                         rem - n_commit),
-                        (commit, n_commit, n_prop))
-
-            (big_cache, tokens, hist, rem), stacked = jax.lax.scan(
-                round_body, (big_cache, tokens, hist, rem), rngs)
-            commits, n_commits, n_props = stacked
-            return commits, n_commits, n_props, tokens, big_cache
-
-        self._spec_verify_fns[key] = fused
-        return fused
-
-    def _spec_verify_call(self, ready, proposals, n_prop):
-        temps_d, topks_d, topps_d, active_d, sample = \
-            self._slot_meta(ready)
-        k = self.speculate_k
-        max_live = int(max(self._slot_len[s]
-                           for s in range(self.max_batch)
-                           if self._slots[s] is not None))
-        kv_bucket = min(self.max_seq, _bucket_len(max_live + k + 1))
-        if kv_bucket > self.max_seq // 2:
-            kv_bucket = self.max_seq
-        self._rng, rng = jax.random.split(self._rng)
-        prop_d, n_prop_d = device_upload((proposals, n_prop))
-        verify = self._get_spec_verify(sample, kv_bucket)
-        with self._prof.jit_key('spec_verify',
-                                (self.speculate_k, sample, kv_bucket)):
-            commit, n_commit, self._tok_dev, self.cache = verify(
-                self.params, self.cache, self._tok_dev, prop_d, n_prop_d,
-                temps_d, topks_d, topps_d, active_d, self._adp_dev,
-                self._vmask_dev, rng)
-        return commit, n_commit
-
-    def _spec_fused_call(self, ready, rounds):
-        """Dispatch ``rounds`` fused propose→verify→commit rounds in one
-        jitted call (``_spec_step_fused``). The kv bucket covers the
-        worst-case growth ``rounds * (k + 1)`` so every in-scan round
-        reads a long-enough cache slice."""
-        temps_d, topks_d, topps_d, active_d, sample = \
-            self._slot_meta(ready)
-        k = self.speculate_k
-        max_live = int(max(self._slot_len[s]
-                           for s in range(self.max_batch)
-                           if self._slots[s] is not None))
-        kv_bucket = min(self.max_seq,
-                        _bucket_len(max_live + rounds * (k + 1)))
-        if kv_bucket > self.max_seq // 2:
-            kv_bucket = self.max_seq
-        hist, rem = self._spec_hist_state(ready)
-        keys = jax.random.split(self._rng, rounds + 1)
-        self._rng = keys[0]
-        hist_d, rem_d = device_upload((hist, rem))
-        fused = self._get_spec_fused(sample, kv_bucket, rounds)
-        with self._prof.jit_key('spec_fused',
-                                (self.speculate_k, sample, kv_bucket,
-                                 rounds)):
-            commits, n_commits, n_props, self._tok_dev, self.cache = \
-                fused(self.params, self.cache, self._tok_dev, hist_d,
-                      rem_d, temps_d, topks_d, topps_d, active_d,
-                      self._adp_dev, self._vmask_dev, keys[1:])
-        return commits, n_commits, n_props
-
-    def step(self, horizon: int = 1) -> List[Tuple[int, int, bool]]:
-        """Chunked scheduling loop: admit (one chunk batch max), then
-        enqueue decode through the async pipeline. While prompts are
-        mid-prefill the decode horizon is capped by the
-        ``decode_priority_ratio`` token budget so the next chunk runs
-        within a bounded number of decode steps; while the queue is
-        non-empty a medium cap keeps freed slots noticed promptly.
-        Monolithic mode keeps _EngineBase.step semantics unchanged.
-        ``speculate_k > 0`` replaces the fused decode horizon with one
-        synchronous propose→verify→commit round per step (admission —
-        chunked or monolithic — is unchanged); adding
-        ``decode_steps_per_call > 1`` fuses that many rounds into one
-        dispatch instead (in-scan speculative verify)."""
-        if not self.chunked and not self.speculate_k:
-            return super().step(horizon)
-        events: List[Tuple[int, int, bool]] = []
-        with self._prof.phase('readback'):
-            while len(self._pending) >= self._PIPELINE_DEPTH:
-                events.extend(self._process_one())
-        with self._prof.phase('admit'):
-            events.extend(self._admit())
-        if self.speculate_k:
-            if (self.decode_steps_per_call or 0) > 1:
-                events.extend(self._spec_step_fused())
-            else:
-                events.extend(self._spec_step())
-            return events
-        if self.decode_steps_per_call:
-            # Multi-step pin: exactly k fused steps per call — the
-            # dispatch-amortization knob wins over the interleave /
-            # queue-pressure shrinks (capacity caps still apply in
-            # _enqueue_decode).
-            horizon = self.decode_steps_per_call
-        elif self._prefill_off:
-            horizon = min(horizon, self._interleave_horizon())
-        elif self._queue:
-            horizon = min(horizon, 32)
-        with self._prof.phase('decode_enqueue'):
-            enqueued = self._enqueue_decode(horizon)
-        if not enqueued and self._pending:
-            with self._prof.phase('readback'):
-                events.extend(self._process_one())
-        return events
-
-    def _admit_monolithic(self) -> List[Tuple[int, int, bool]]:
-        """Whole-prompt admission waves (``prefill_chunk_tokens=0`` —
-        the pre-chunking baseline, kept for bench comparison)."""
-        free = [s for s in range(self.max_batch) if self._slots[s] is None]
-        wave_min = min(self._ADMIT_WAVE_MIN, self.max_batch)
-        if (0 < len(free) < wave_min and len(free) < self.max_batch
-                and len(self._queue) > len(free) + wave_min):
-            # Saturated (queue outruns capacity) with slots still
-            # decoding: hold admission until a fuller wave accumulates.
-            # Freed slots arrive within ~a call horizon, so the TTFT
-            # cost is bounded; when the queue is short (latency regime)
-            # or every slot is free (nothing to wait for) admission is
-            # immediate.
-            return []
-        batch: List[Tuple[int, Request]] = []
-        for slot in free:
-            req = self._queue_pop()
-            if req is None:
-                break
-            batch.append((slot, req))
-        if not batch:
-            return []
-        # Cap the wave: by the largest compiled bucket, AND by the
-        # prefill stacked-rows transient — the batched prefill stacks
-        # [L, n, bucket] KV rows across the layer scan, and at n=32 x
-        # bucket=256 on a 7B the bf16 stack is 2 GB x2, which pushed the
-        # compile past HBM with the slot cache + weights resident. int8
-        # caches quantize the rows INSIDE the scan (prefill_rows), so
-        # their stack is half the width and the wave twice as deep. The
-        # overflow requeues at the FRONT (keeps FIFO) for the next step.
-        bucket = min(_bucket_len(max(len(r.prompt) for _, r in batch)),
-                     self.max_seq)
-        scratch_tok = kv_token_bytes(self.cfg, self.kv_cache_dtype,
-                                     mesh=self.mesh)
-        fit = int(0.75e9) // max(1, bucket * scratch_tok)
-        cap = 1
-        for b in self._PREFILL_N_BUCKETS:     # largest PADDED n that fits
-            if b <= fit:
-                cap = b
-        if len(batch) > cap:
-            self._requeue_front([req for _, req in batch[cap:]])
-            batch = batch[:cap]
-            bucket = min(_bucket_len(max(len(r.prompt)
-                                         for _, r in batch)),
-                         self.max_seq)
-        # Pad request count to a compiled bucket (extra rows re-prefill the
-        # first request into its own slot — harmless duplicate writes).
-        n = 1
-        for b in self._PREFILL_N_BUCKETS:
-            if b >= len(batch):
-                n = b
-                break
-        else:
-            n = self._PREFILL_N_BUCKETS[-1]
-        prefill = self._get_prefill(bucket, n)
-
-        tokens = np.zeros((n, bucket), np.int32)
-        true_lens = np.zeros(n, np.int32)
-        slots = np.zeros(n, np.int32)
-        adp_h = (np.full(n, -1, np.int32)
-                 if self.adapters is not None else None)
-        vm_h = (np.ones((n, self.cfg.vocab_size), bool)
-                if self._vmask_any else None)
-        for i in range(n):
-            slot, req = batch[min(i, len(batch) - 1)]
-            tokens[i, :len(req.prompt)] = req.prompt
-            true_lens[i] = len(req.prompt)
-            slots[i] = slot
-            if adp_h is not None:
-                adp_h[i] = req._adapter_slot
-            if vm_h is not None and req._vocab_mask is not None:
-                vm_h[i] = req._vocab_mask
-        adp_d = jnp.asarray(adp_h) if adp_h is not None else None
-        vm_d = jnp.asarray(vm_h) if vm_h is not None else None
-        # Queue -> slot happens here, before the dispatch, so that the
-        # whole-prompt prefill is one ``prefill_chunk`` span inside the
-        # ``prefill`` span, as a chunk is in chunked mode.
-        for _, req in batch:
-            self._trace_sched(req)
-        chunk_t0 = clock.monotonic()
-        with self._prof.phase('prefill_chunk', prompts=n, width=bucket), \
-                self._prof.jit_key('prefill', (bucket, n)):
-            next_tokens, self.cache = prefill(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(true_lens), jnp.asarray(slots),
-                adp_d, vm_d)
-        chunk_t1 = clock.monotonic()
-        for _, req in batch:
-            if req.trace is not None:
-                req.trace.add('prefill_chunk', chunk_t0, chunk_t1,
-                              offset=0, tokens=len(req.prompt))
-        # Async: reserve the slots NOW (so the next admission wave and
-        # _enqueue_decode see them taken) but defer the token readback —
-        # the prefill result rides the pipeline and its events surface
-        # in _process_one. The device token vector picks up the prefill
-        # tokens without a host trip.
-        slots_used = np.array([s for s, _ in batch], np.int32)
-        self._tok_dev = self._merge_tokens(
-            self._tok_dev, jnp.asarray(slots_used),
-            next_tokens[:len(batch)])
-        for slot, req in batch:
-            self._slots[slot] = req
-            self._slot_len[slot] = len(req.prompt)
-        self._meta_dirty = True
-        self._pending.append({'kind': 'prefill', 'toks': next_tokens,
-                              'batch': [(slot, req, i) for i, (slot, req)
-                                        in enumerate(batch)]})
-        return []
-
-    _HORIZON_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
-
-    def _enqueue_decode(self, horizon: int = 1) -> bool:
-        """Enqueue one fused-horizon decode call fed entirely by
-        device-resident state (tokens from the previous call's last
-        column, the chained cache). Returns False when nothing could be
-        enqueued. The host reads the result back in _process_one, up to
-        _PIPELINE_DEPTH calls later. Mid-prefill slots (chunked
-        admission cursors still advancing) are masked inactive: their
-        cache lengths are mid-prompt and their token-vector entries
-        stale until the completing chunk merges the first token."""
-        ready = self._decode_ready()
-        active = np.array([r is not None for r in ready])
-        if not active.any():
-            return False
-        # Cap the horizon by remaining KV capacity (+1 for the token
-        # written during the step) — counting the steps already IN
-        # FLIGHT, whose device-side lengths have advanced past the host
-        # view. The max runs over EVERY occupied slot, mid-prefill ones
-        # included: the horizon's ring merge writes (masked-off garbage)
-        # rows at each slot's device length, and dynamic_update_slice
-        # CLAMPS — a merge pushed past max_seq on a nearly-full
-        # mid-prefill slot would slide back over its real prompt rows.
-        max_live = int(max(self._slot_len[s]
-                           for s in range(self.max_batch)
-                           if self._slots[s] is not None))
-        cap = int(self.max_seq - 1 - max_live - self._inflight_steps)
-        if cap < 1:
-            return False
-        horizon = max(1, min(horizon, cap))
-        # Each fused step re-reads the whole [L, b, horizon] ring of rows
-        # produced this horizon; past ~15% of the weight-read traffic the
-        # ring dominates the HBM budget and longer horizons backfire
-        # (measured: 1B model, b=64 — horizon 128 halves throughput vs 64).
-        # The ring rides MODEL dtype (it only quantizes on the merge into
-        # an int8 cache), so its rows are costed at cfg.dtype — costing
-        # them at the pool's int8 width (round-4 bug) both understated
-        # the re-read traffic and allowed rings that blew the HBM budget.
-        ring_cap = _ring_horizon_cap(self.cfg, self.max_batch,
-                                     self._param_bytes, self.mesh)
-        horizon = min(horizon, ring_cap)
-        if self.decode_steps_per_call is None:
-            for b in reversed(self._HORIZON_BUCKETS):
-                if b <= horizon:
-                    horizon = b
-                    break
-        # else: multi-step pin — run EXACTLY k (capacity-clamped above)
-        # so the jit key stays (k, sample, kv_bucket) and the audit's
-        # one-dispatch-per-k-tokens contract holds.
-
-        temps_d, topks_d, topps_d, active_d, sample = \
-            self._slot_meta(ready)
-        # Length-aware KV reads: attention streams only the first
-        # kv_bucket cache rows (decode is HBM-bound on this read). The
-        # bucket must cover every live context through this horizon
-        # (in-flight steps included); power-of-two-ish rounding bounds
-        # compiled-program count.
-        kv_bucket = min(self.max_seq,
-                        _bucket_len(max_live + self._inflight_steps +
-                                    horizon))
-        self._rng, rng = jax.random.split(self._rng)
-        # Per-substep attribution: one dispatch covers ``horizon``
-        # decode substeps (the multi-step amortization the profiler's
-        # per_substep_ms split makes visible).
-        self._prof.note_substeps('decode_enqueue', horizon,
-                                 live_rows=int(active.sum()))
-        self._prof.tag(horizon=horizon, kv_bucket=kv_bucket)
-        with self._prof.jit_key('decode', (horizon, sample, kv_bucket)):
-            toks, self.cache = self._decode_fn(
-                self.params, self.cache, self._tok_dev, rng,
-                temps_d, topks_d, topps_d, active_d, self._adp_dev,
-                self._vmask_dev, horizon, sample, kv_bucket)
-        self._note_decode_step(
-            int(sum(self._slot_len[s] + self._inflight_steps
-                    for s in range(self.max_batch)
-                    if ready[s] is not None)))
-        self._tok_dev = toks[:, -1]
-        self._inflight_steps += horizon
-        self._pending.append({'kind': 'decode', 'toks': toks,
-                              'horizon': horizon,
-                              'snapshot': ready})
-        return True
-
-    def _process_one(self) -> List[Tuple[int, int, bool]]:
-        """Sync the oldest in-flight call and turn it into events. A
-        request that finished (or was cancelled) after the call was
-        enqueued produced garbage rows on the device — skipped here;
-        its cache rows sit past the corrected length and the slot's
-        next prefill overwrites them."""
-        entry = self._pending.popleft()
-        # THE sanctioned device->host readback of the async pipeline:
-        # everything else in the step loop must stay device-side (the
-        # jaxpr audit gates on it).
-        toks = host_sync(entry['toks'])
-        events: List[Tuple[int, int, bool]] = []
-        now = clock.now()
-        if entry['kind'] == 'prefill':
-            for slot, req, row in entry['batch']:
-                if req.finish_time is not None:       # cancelled in flight
-                    continue
-                token = int(toks[row])
-                if token < 0:
-                    # Non-finite sentinel: the prompt blew up in
-                    # prefill — evict just this request.
-                    events.append(self._evict_nonfinite(slot, req))
-                    continue
-                req.first_token_time = now
-                self._trace_first_token(req)
-                req.output.append(token)
-                finished = self._finish_req(slot, req, token)
-                events.append((req.request_id, token, finished))
-            return events
-        self._inflight_steps -= entry['horizon']
-        for slot, req in enumerate(entry['snapshot']):
-            if req is None or req.finish_time is not None:
-                continue
-            if req.first_token_time is None:
-                # Prefill result still queued behind this decode —
-                # cannot happen (FIFO pipeline), but guard anyway.
-                continue
-            for i in range(entry['horizon']):
-                token = int(toks[slot, i])
-                if token < 0:
-                    # Non-finite sentinel: this slot's logits row went
-                    # NaN/Inf mid-horizon. Evict exactly this request
-                    # (its remaining horizon tokens are garbage by
-                    # construction); every other slot's tokens land
-                    # normally.
-                    events.append(self._evict_nonfinite(slot, req))
-                    break
-                req.output.append(token)
-                self._slot_len[slot] += 1
-                finished = self._maybe_finish(slot, token)
-                events.append((req.request_id, token, finished))
-                if finished:
-                    break
-        return events
-
-
 def sample_tokens(logits: jax.Array, step_rng: jax.Array,
                   temps: jax.Array, topks: jax.Array,
                   topps: jax.Array,
                   vocab_mask: Optional[jax.Array] = None) -> jax.Array:
-    """Per-slot next-token sampling, shared by the slot and paged
-    engines' fused decode: optional grammar vocab mask, then
+    """Per-slot next-token sampling of the fused decode: optional
+    grammar vocab mask, then
     temperature scaling, then top-k and nucleus (top-p) filtering
     (``llama.filtered_logits`` — one descending sort of the scaled
     logits, also the distribution speculative verify rejection-samples

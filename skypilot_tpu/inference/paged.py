@@ -1,9 +1,9 @@
 """Paged KV cache with prefix caching and chunked prefill.
 
-The slot cache (``engine.py``) reserves ``max_seq`` rows per slot and
-re-prefills shared prefixes. This module is the vLLM-class capability
-(the reference's serving recipes lean on vLLM's paged attention,
-``llm/vllm/README.md:10``) designed for XLA's static-shape world:
+The one serving cache (a contiguous cache would reserve ``max_seq`` rows
+per slot and re-prefill shared prefixes). This is the vLLM-class
+capability (the reference's serving recipes lean on vLLM's paged
+attention, ``llm/vllm/README.md:10``) for XLA's static-shape world:
 
 - **Page pool**: one ``[L, n_pages, hkv, page, d]`` tensor shared by all
   slots; a slot holds a host-side list of page ids. HBM is proportional
@@ -12,8 +12,8 @@ re-prefills shared prefixes. This module is the vLLM-class capability
   Pallas decode kernel contracts straight off each DMA'd page with no
   in-kernel relayout (see ``ops/paged_attention.py``'s layout note).
 - **Static shapes everywhere**: decode gathers each slot's first ``P``
-  pages where ``P`` is a power-of-two bucket of the live maximum — the
-  same compiled-program-count bound as the slot cache's ``kv_bucket``.
+  pages where ``P`` is a power-of-two bucket of the live maximum,
+  which bounds the count of compiled programs.
   Unused table entries point at page 0, a reserved null/trash page.
 - **Prefix caching**: full pages are content-addressed by the hash of
   the token prefix they complete; a new request reuses the longest
@@ -26,7 +26,7 @@ re-prefills shared prefixes. This module is the vLLM-class capability
 
 int8 (``kv_cache_dtype='int8'``, its own knob — decoupled from the
 weight quantize mode, which it follows only when left on auto): the
-pool quantizes per-row like the slot cache (``k_scale``
+pool quantizes each row by its own absmax (``k_scale``
 [L, n_pages, hkv, page] fp32, head-major like the pool — the kernel
 DMAs scale pages contiguously and the old per-horizon-call relayout
 of the whole scale pool is gone). Every capacity decision — auto pool
@@ -447,13 +447,13 @@ def paged_decode_horizon(
     vocab_mask: Optional[jax.Array] = None,  # [slots, vocab] bool
                                        # constrained-decoding mask
 ):
-    """``horizon`` fused decode steps over the paged pool — the twin of
-    ``llama.decode_horizon`` with the contiguous cache read replaced by
-    either a per-layer page gather or the Pallas paged-attention kernel
-    (``ops/paged_attention.py``: page table as scalar prefetch, pages
-    DMA'd straight from HBM, length-exact per slot — the gather path
-    measured 0.37x the slot cache on a v5e because the gather
-    materializes a full KV copy per layer). table_p must cover
+    """``horizon`` fused decode steps over the paged pool: the cached
+    rows are read by either a per-layer page gather or the Pallas
+    paged-attention kernel (``ops/paged_attention.py``: page table as
+    scalar prefetch, pages DMA'd straight from HBM, length-exact per
+    slot; the gather materializes a full KV copy per layer, which is
+    why a TPU takes the kernel), this horizon's rows from a small ring
+    merged into the pool once after the scan. table_p must cover
     lengths+horizon for active slots.
 
     READ-ONLY on the cache: returns (tokens [slots, horizon],
@@ -533,8 +533,8 @@ def paged_decode_horizon(
                 # here (dynamic_index_in_dim) would force XLA to
                 # materialize a copy of the layer's pool as the
                 # pallas_call operand — one extra read+write of the
-                # whole KV stream per decode step (measured 0.4x the
-                # slot cache on a 7B before this change).
+                # whole KV stream per decode step (a CPU-era reading
+                # had it cost over half the step; not re-measured).
                 from skypilot_tpu.ops.paged_attention import (
                     merge_partial_with_ring_self, paged_decode_attention)
                 interp = jax.default_backend() != 'tpu'
@@ -625,7 +625,7 @@ def merge_ring_into_pool(cache: PagedKVCache, ring_k, ring_v,
     here). Keeping the pool update out of the program whose layer scan
     feeds the pool to pallas_call is what lets XLA alias the donated
     pool buffers in place; fused, the pool double-buffers (+4.4 GB on
-    the 7B bench — an OOM)."""
+    a 7B — an OOM)."""
     horizon = ring_k.shape[2]
     act = (active.astype(jnp.int32) if active is not None
            else jnp.ones_like(lengths))
@@ -946,9 +946,9 @@ from skypilot_tpu.inference.speculative import SpeculativeMixin
 
 
 class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
-    """Continuous-batching engine over the paged pool. Same public API
-    as ``engine.InferenceEngine`` (the serve layer treats them
-    interchangeably — both extend ``_EngineBase``); differs inside:
+    """Continuous-batching engine over the paged pool: the one serving
+    engine. The host-side request lifecycle is ``_EngineBase``'s
+    (``inference/engine.py``); the cache and the programs are here:
 
     - admission matches cached prefix pages, then chunk-prefills only
       the uncached tail (one compiled program per (n, P) bucket pair,
@@ -1003,7 +1003,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         from skypilot_tpu.inference.engine import (prepare_params,
                                                    refuse_unsupported)
         from skypilot_tpu.parallel import mesh as mesh_lib
-        refuse_unsupported(cfg, engine='paged', quantize=quantize,
+        refuse_unsupported(cfg, quantize=quantize,
                            speculate_k=speculate_k,
                            adapter_slots=adapter_slots, mesh=mesh,
                            decode_impl=decode_impl)
@@ -1014,9 +1014,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # quantize mode is known (see below); explicit values are the
         # user's to keep (with the misalignment warning).
         self._page_user = page_size is not None
-        # ``prefill_chunk_tokens`` is the cross-engine spelling of the
-        # chunk knob (the slot engine and serve layer use it); it wins
-        # over ``chunk`` when given.
+        # ``prefill_chunk_tokens`` is the serve layer's spelling of the
+        # chunk knob (``--prefill-chunk-tokens``); it wins over
+        # ``chunk`` when given.
         if prefill_chunk_tokens is not None:
             chunk = prefill_chunk_tokens
         self.chunk = chunk
@@ -1302,12 +1302,12 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
     def _auto_n_pages(self, cfg: ModelConfig, max_batch: int,
                       max_seq: int, page_size: int) -> int:
         """Size the pool from FREE HBM after the weights landed, not
-        from slot-cache parity: the pool is the paged engine's whole
+        from ``max_batch x max_seq``: the pool is the paged engine's whole
         advantage (HBM proportional to live tokens -> more concurrent
         long contexts on the same chip), so idle HBM is wasted
         capacity. A reserve covers decode transients (the horizon ring,
         unembed logits, prefill activations) and XLA workspace. Off the
-        TPU (CPU tests, interpret mode) the pool is slot parity."""
+        TPU (CPU tests, interpret mode) it is ``max_batch x max_seq``."""
         parity = max_batch * -(-max_seq // page_size) + 1
         # Per-page byte cost follows the KV CACHE dtype, not the weight
         # dtype — with the flags decoupled (int8 weights + bf16 KV or
@@ -1316,7 +1316,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         quantized = self.kv_cache_dtype
         if jax.default_backend() != 'tpu':
             # CPU (tests, interpret mode) reports no device memory:
-            # slot parity. Parity reserves NOTHING for the long ring, so
+            # max_batch x max_seq. That reserves NOTHING for the ring, so
             # decode keeps the conservative ring budget.
             self._pool_auto_sized = False
             return parity
@@ -1531,8 +1531,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
 
     def kv_pool_stats(self) -> Dict[str, Any]:
         """KV capacity/pressure in TOKENS (page-granular: a partially
-        filled page counts as used) — the schema shared with the slot
-        engine for the telemetry gauges and bench. Prefix-retained
+        filled page counts as used) — the schema of the telemetry
+        gauges and the JSON ``kv_pool`` block. Prefix-retained
         pages count as FREE: allocation evicts them on demand."""
         from skypilot_tpu.inference.engine import (kv_shard_degree,
                                                    kv_token_bytes)
@@ -2571,9 +2571,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             horizon = self.decode_steps_per_call
         elif self._prefill_off:
             # decode_priority_ratio switches the fixed interleave
-            # horizon to the Sarathi-style token-budget split (shared
-            # with the slot engine); None keeps this engine's
-            # measured-best fixed cap.
+            # horizon to the Sarathi-style token-budget split
+            # (``_EngineBase._interleave_horizon``); None keeps the
+            # fixed cap below.
             horizon = min(horizon,
                           self.interleave_horizon
                           if self.decode_priority_ratio is None

@@ -1,10 +1,12 @@
 """Speculative decoding: prompt-lookup (n-gram) proposer + batched
-on-device verify, shared by the slot and paged engines.
+on-device verify, mixed into the paged engine (which supplies the
+verify programs and the page-room hooks ``_spec_room``,
+``_spec_starved`` and ``_spec_can_fuse``).
 
 Decode is HBM-bound: every generated token pays a full weight-stream
-pass (BENCH_r05: 11.2 of 26.8 ms/step), so emitting ONE token per pass
-caps throughput at the one-token-per-stream wall. Speculative decoding
-breaks it without a draft model:
+pass, so emitting ONE token per pass caps throughput at the
+one-token-per-stream wall. Speculative decoding breaks it without a
+draft model:
 
 - **Propose** (host, numpy): match the last n-gram of each slot's
   prompt+generated history against its own earlier history and propose
@@ -23,7 +25,7 @@ breaks it without a draft model:
   rows past each slot's accepted count scatter to a drop sentinel and
   the cache length advances by ``n_commit`` — per-slot variable
   acceptance never changes a program shape, so the jit key stays
-  ``(k, sample, kv_bucket)`` (the jaxpr audit gates on it).
+  ``(k, sample, P)`` (the jaxpr audit gates on it).
 
 Each verify round emits between 1 (no/failed proposals — a plain
 decode step) and k+1 tokens per slot for one weight-stream pass.
@@ -112,7 +114,7 @@ def ngram_propose_device(hist, k: int, max_ngram: int = 3,
 
 
 # --------------------------------------------------------------------------
-# Device-side acceptance (shared by both engines' verify programs)
+# Device-side acceptance (the single-round and the fused verify programs)
 # --------------------------------------------------------------------------
 def verify_tokens(logits, proposals, n_prop, rng, temps, topks, topps,
                   *, sample: bool):
@@ -190,12 +192,14 @@ def verify_tokens(logits, proposals, n_prop, rng, temps, topks, topps,
 # Engine scaffolding
 # --------------------------------------------------------------------------
 class SpeculativeMixin:
-    """Propose→verify→commit scaffolding shared by the slot and paged
-    engines. Engines call ``_init_spec(speculate_k)`` from __init__,
-    implement ``_spec_verify_call(ready, proposals, n_prop)`` (the
-    jitted verify dispatch; returns (commit, n_commit) device arrays
-    and updates the cache/token vector), and route ``step()`` through
-    ``_spec_step()`` when ``speculate_k > 0``.
+    """Propose→verify→commit scaffolding of the paged engine, which
+    calls ``_init_spec(speculate_k)`` from __init__, implements
+    ``_spec_verify_call(ready, proposals, n_prop)`` (the jitted verify
+    dispatch; returns (commit, n_commit) device arrays and updates the
+    cache/token vector) with the page-room hooks ``_spec_room(slot)``
+    (proposal cap, -1 = cannot take one more token),
+    ``_spec_starved(slots)`` and ``_spec_can_fuse(slot, rounds)``, and
+    routes ``step()`` through ``_spec_step()`` when ``speculate_k > 0``.
 
     The speculative loop is SYNCHRONOUS (one sanctioned host_sync per
     round): the proposer needs the committed tokens on the host before
@@ -204,8 +208,8 @@ class SpeculativeMixin:
     weight stream over up to k+1 tokens per slot.
 
     With ``decode_steps_per_call > 1`` set alongside ``speculate_k``,
-    engines that also implement ``_spec_fused_call(ready, rounds)``
-    route through ``_spec_step_fused()`` instead: the proposer moves ON
+    ``step()`` routes through ``_spec_step_fused()`` and
+    ``_spec_fused_call(ready, rounds)`` instead: the proposer moves ON
     DEVICE (``ngram_propose_device``) and ``rounds`` whole
     propose→verify→commit rounds fuse into one dispatch, so the
     host_sync amortizes ``rounds`` x on top of speculation's k+1 x."""
@@ -276,19 +280,6 @@ class SpeculativeMixin:
                 req.prompt + req.output, self.speculate_k,
                 max_ngram=self.spec_max_ngram)
         self._spec_prepared = prep
-
-    def _spec_room(self, slot: int) -> int:
-        """Extra per-engine cap on proposal count for ``slot`` (e.g.
-        page availability); -1 = the slot cannot even take one more
-        token (engine should preempt). Default: no extra cap."""
-        del slot
-        return self.speculate_k
-
-    def _spec_starved(self, slots: List[int]) -> None:
-        """Hook: slots whose ``_spec_room`` came back negative (cannot
-        commit even one token). Default: nothing (the slot engine's
-        capacity is enforced via the budget cap below)."""
-        del slots
 
     def _spec_build_proposals(self, ready) -> Tuple[np.ndarray,
                                                     np.ndarray, List[int]]:
@@ -385,16 +376,6 @@ class SpeculativeMixin:
         return events
 
     # ------------------------------------------------- fused (in-scan)
-    def _spec_can_fuse(self, slot: int, rounds: int) -> bool:
-        """Hook: can ``slot`` absorb ``rounds`` fused verify rounds of
-        KV growth (up to ``rounds * (k + 1)`` rows) with no host
-        intervention between rounds? Default yes — the slot engine's
-        sentinel-masked scatter plus the in-scan ``rem`` budget carry
-        already bound writes; the paged engine overrides this with an
-        up-front page reservation."""
-        del slot, rounds
-        return True
-
     def _spec_hist_state(self, ready) -> Tuple[np.ndarray, np.ndarray]:
         """Device-proposer inputs for the fused rounds: right-aligned
         history window ``[b, H]`` (left-padded with -1) and per-slot

@@ -176,7 +176,7 @@ LLAMA2_7B = _cfg(name='llama2-7b', vocab_size=32000, dim=4096, n_layers=32,
                  max_seq_len=4096)
 
 # ~1.1B-param config that fits one 16GB v5e chip in bf16 with room for a KV
-# cache — the single-chip flagship for bench.py / __graft_entry__.entry().
+# cache — the single-chip flagship of __graft_entry__.entry().
 LLAMA3_1B = _cfg(name='llama3-1b', vocab_size=128256, dim=2048, n_layers=16,
                  n_heads=32, n_kv_heads=8, ffn_dim=8192)
 

@@ -26,8 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from skypilot_tpu.models.configs import ModelConfig
-from skypilot_tpu.ops.attention import (attention, cached_attention,
-                                        ring_decode_attention)
+from skypilot_tpu.ops.attention import attention, cached_attention
 
 Params = Dict[str, Any]
 
@@ -143,19 +142,15 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
 # KV cache
 # --------------------------------------------------------------------------
 class KVCache(NamedTuple):
-    """Decode cache. k/v: [layers, batch, max_seq, kv_heads, head_dim];
-    length: [batch] valid entries per sequence (supports continuous
-    batching where sequences are at different positions).
+    """The contiguous cache of ``forward(cache=...)``, the plain cached
+    forward pass (no engine holds one: serving decodes through the
+    paged pool, ``inference/paged.py``). k/v: [layers, batch, max_seq,
+    kv_heads, head_dim]; length: [batch] valid entries per sequence.
 
-    int8 mode (``create(..., quantized=True)`` — the engines' own
-    ``kv_cache_dtype`` knob, independent of weight quantization): k/v
-    are int8 with per-(layer, slot, position, head) fp32 absmax/127
-    scales — halves the decode cache read (the second-largest HBM
-    stream after the weights). The dequantizing convert+mul fuses into
-    the attention matmul's operand read, like the weight-only int8
-    path; no materialized bf16 KV copy ever hits HBM. Every write site
-    (prefill scatter, chunked-prefill chunks, decode merges, spec
-    verify commits) quantizes through :func:`quantize_kv_rows`."""
+    int8 mode (``create(..., quantized=True)``): k/v are int8 with
+    per-(layer, row, position, head) fp32 absmax/127 scales, written
+    through :func:`quantize_kv_rows` and contracted in int8
+    (``cached_attention``); int4 packs two codes a byte."""
     k: jax.Array
     v: jax.Array
     length: jax.Array
@@ -242,8 +237,7 @@ def merge_rows_into_cache(cache: KVCache, k_rows: jax.Array,
                           new_length: jax.Array) -> KVCache:
     """Scatter new [L, b, n, hkv, d] KV rows into the cache at each
     batch row's ``starts`` offset, quantizing on the way in when the
-    cache is int8. Shared by the prefill forward and the fused decode
-    horizon."""
+    cache is int8."""
 
     def write(c, n, start):            # c [L,S,h,d] <- n [L,n,h,d] @ start
         return lax.dynamic_update_slice(c, n, (0, start, 0, 0))
@@ -618,8 +612,7 @@ def forward(
 
     Cache-capacity contract: callers must never append past ``max_seq`` —
     ``lax.dynamic_update_slice`` clamps rather than errors inside jit, so an
-    overflow silently corrupts the last cache slot. The inference engine
-    enforces this by construction (it evicts/rejects before overflow).
+    overflow silently corrupts the last cache slot.
 
     Returns (logits [b, s, vocab], new_cache or None), plus the mean MoE
     load-balancing aux loss when ``return_aux`` (0 for dense models).
@@ -805,139 +798,6 @@ def forward(
     return logits, new_cache
 
 
-def prefill_rows(
-    params: Params,
-    tokens: jax.Array,                 # [n, bucket] padded prompts
-    true_lens: jax.Array,              # [n] real prompt lengths
-    cfg: ModelConfig,
-    *,
-    attn_impl: str = 'auto',
-    quantize_rows=False,               # False | True (int8) | 'int4'
-    w8a8: bool = False,
-    cache_kv=None,                     # per-row cache stacks (chunked
-                                       # prefill): ([L, n, S, hkv, d] k,
-                                       # v) bf16 or (kq, vq, ks, vs)
-                                       # int8 codes + scales
-    cache_len: Optional[jax.Array] = None,   # [n] valid cache rows =
-                                       # each row's chunk start offset
-    all_logits: bool = False,          # return [n, bucket, vocab] logits
-                                       # at EVERY position (speculative
-                                       # verify; keep bucket ~k+1 tiny —
-                                       # the full tensor is ~0.5 GB at
-                                       # n=8 x bucket=512)
-    mlora_idx: Optional[jax.Array] = None,  # [n] per-row adapter slot
-                                       # (-1 = none): prefill rows gather
-                                       # bank adapters exactly like
-                                       # decode — chunked included
-):
-    """Prompt/chunk prefill for the slot engine. Without ``cache_kv``:
-    plain causal attention over the padded bucket — flash-eligible on
-    TPU (the forward-with-scratch-cache path it replaces ran
-    ``cached_attention`` against a bucket of zero rows: an extra masked
-    cache read per layer and no flash). With ``cache_kv``/``cache_len``
-    the bucket is a prompt CHUNK attending over a NONZERO cache offset:
-    positions start at ``cache_len`` per row, and each layer attends the
-    gathered cache rows (masked to ``cache_len``) plus the causal chunk
-    (``ops.chunk_attention`` — flash chunk kernel on TPU, two-block XLA
-    softmax elsewhere). Returns only what admission needs:
-
-    - ``last_logits`` [n, vocab] fp32 at each row's position
-      ``true_lens - 1`` (the full [n, bucket, vocab] logits tensor is a
-      ~0.5 GB transient at n=8 x bucket=512 — only one row is ever
-      used; chunked callers pass the completing index + 1);
-    - the per-layer KV rows, quantized INSIDE the layer scan when
-      ``quantize_rows`` (the stacked bf16 [L, n, bucket] rows are the
-      7B prefill's biggest transient — int8 halves it, doubling the
-      admission wave the scratch budget admits):
-      (k_rows, v_rows) bf16, or (kq, vq, ks, vs) int8 + scales.
-
-    ``w8a8`` additionally quantizes activations per token inside the
-    LAYER matmuls (prefill is compute-bound; the MXU's int8 path is 2x
-    bf16 — see ``quantization.w8a8_region``). The unembed stays W8A16:
-    logits feed sampling directly and are not worth the noise.
-    """
-    from skypilot_tpu.models import quantization
-    from skypilot_tpu.ops.attention import chunk_attention
-    x = _embed_tokens(params, tokens, cfg)
-    x = _shard(x, 'batch', 'seq', 'embed')
-    n, s = tokens.shape
-    if cache_len is None:
-        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (n, s))
-    else:
-        positions = cache_len[:, None] + jnp.arange(s)[None, :]
-
-    def emit_rows(k, v):
-        if quantize_rows:
-            quant = (quantize_kv_rows4 if quantize_rows == 'int4'
-                     else quantize_kv_rows)
-            kq, ks = quant(k)
-            vq, vs = quant(v)
-            return (kq, vq, ks, vs)
-        return (k, v)
-
-    if cache_kv is None:
-        def body(carry, layer):
-            def attn_fn(q, k, v):
-                return attention(q, k, v, causal=True, impl=attn_impl)
-
-            xc, (k, v), _ = _layer_core(
-                layer, carry, cfg, positions,
-                None if cfg.latent else attn_fn, mlora_idx=mlora_idx)
-            return xc, emit_rows(k, v)
-
-        xs = None               # every layer stack in turn, below
-    else:
-        if cfg.latent:
-            raise NotImplementedError(
-                'chunked prefill of a latent-attention model runs in '
-                'the paged engine (paged_prefill_chunk)')
-        if len(cache_kv) == 4:
-            ck_all, cv_all, ks_all, vs_all = cache_kv
-        else:
-            (ck_all, cv_all), ks_all, vs_all = cache_kv, None, None
-
-        def body(carry, layer_and_idx):
-            layer, li = layer_and_idx
-            ck = lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-            sk = (lax.dynamic_index_in_dim(ks_all, li, 0, keepdims=False)
-                  if ks_all is not None else None)
-            sv = (lax.dynamic_index_in_dim(vs_all, li, 0, keepdims=False)
-                  if vs_all is not None else None)
-
-            def attn_fn(q, k, v):
-                return chunk_attention(q, k, v, ck, cv, cache_len,
-                                       impl=attn_impl, k_scale=sk,
-                                       v_scale=sv)
-
-            xc, (k, v), _ = _layer_core(layer, carry, cfg, positions,
-                                        attn_fn, mlora_idx=mlora_idx)
-            return xc, emit_rows(k, v)
-
-        xs = (params['layers'], jnp.arange(cfg.n_layers))
-
-    import contextlib
-    ctx = (quantization.w8a8_region() if w8a8
-           else contextlib.nullcontext())
-    with ctx:
-        if xs is None:
-            x, rows = scan_layers(lambda c, layer_idx: body(c, layer_idx[0]),
-                                  x, params, cfg)
-        else:
-            x, rows = lax.scan(body, x, xs)
-    x = rms_norm(x, params['final_norm'], cfg.norm_eps,
-                 cfg.norm_plus_one)
-    if all_logits:
-        # Multi-position logits for speculative verify: every position
-        # of the (tiny) bucket is a next-token distribution the
-        # acceptance test reads.
-        return _unembed_logits(params, x, cfg), rows
-    last_x = jnp.take_along_axis(x, (true_lens - 1)[:, None, None],
-                                 axis=1)
-    last_logits = _unembed_logits(params, last_x, cfg)[:, 0]
-    return last_logits, rows
-
-
 # Sentinel token emitted when a slot's logits row is non-finite
 # (NaN/Inf — numerical blow-up, SDC, poisoned activations). Real token
 # ids are >= 0, so the host readback can evict exactly the poisoned
@@ -957,133 +817,6 @@ def mask_nonfinite_tokens(logits: jax.Array,
     finite = jnp.all(jnp.isfinite(logits), axis=-1)
     return jnp.where(finite, tokens,
                      jnp.asarray(NONFINITE_TOKEN, tokens.dtype))
-
-
-def decode_horizon(
-    params: Params,
-    cache: KVCache,
-    tokens: jax.Array,                 # [b] current token per sequence
-    cfg: ModelConfig,
-    *,
-    horizon: int,
-    sample_fn=None,                    # (logits [b, vocab], rng) -> [b] int32
-    rngs: Optional[jax.Array] = None,  # [horizon] keys when sample_fn set
-    kv_bucket: Optional[int] = None,   # static: attention reads only the
-                                       # first kv_bucket cache rows; caller
-                                       # guarantees max(length)+horizon <=
-                                       # kv_bucket (length-aware decode)
-    mlora_idx: Optional[jax.Array] = None,  # [b] per-slot adapter slot
-                                       # (-1 = none): multi-LoRA bank
-                                       # gather inside the fused scan
-    vocab_mask: Optional[jax.Array] = None,  # [b, vocab] bool, True =
-                                       # allowed (constrained decoding);
-                                       # applied at logits production so
-                                       # greedy AND sampled rows obey it
-):
-    """``horizon`` fused autoregressive decode steps in one program.
-
-    The perf-critical serving loop. The main cache is a loop INVARIANT:
-    its attention mask depends only on the horizon-start lengths, so XLA
-    streams it read-only each step instead of re-materializing it through
-    the scan carry (which costs ~a full cache rewrite per step). Rows
-    produced during the horizon live in a small [layers, b, horizon] ring
-    written at a uniform offset (plain dynamic_update_slice, in-place);
-    one scatter merges the ring into the cache at the end.
-
-    Returns (tokens [b, horizon], new_cache with length = length+horizon);
-    callers with inactive slots correct their lengths afterwards.
-    """
-    b = tokens.shape[0]
-    n_layers, n_kv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-    len0 = cache.length
-    full_k, full_v = cache.k, cache.v
-    ks_full, vs_full = cache.k_scale, cache.v_scale
-    if kv_bucket is not None and kv_bucket <= full_k.shape[2] // 2:
-        # Decode is HBM-bound on the cache read; a static prefix slice
-        # keeps per-step traffic proportional to the LIVE context, not
-        # max_seq. (Rows >= kv_bucket are masked out anyway.) XLA
-        # materializes the sliced prefix as a program temp (the scan
-        # consumes it as a loop invariant), so slicing only pays when it
-        # at least HALVES the read: a 512-of-576 slice allocated 4 GB of
-        # temps to save 11% of traffic and OOM'd a 16 GB chip.
-        cache_k = full_k[:, :, :kv_bucket]
-        cache_v = full_v[:, :, :kv_bucket]
-        k_scale = ks_full[:, :, :kv_bucket] if cache.quantized else None
-        v_scale = vs_full[:, :, :kv_bucket] if cache.quantized else None
-    else:
-        cache_k, cache_v = full_k, full_v
-        k_scale, v_scale = ks_full, vs_full
-    layer_params = params['layers']
-    # The ring (this horizon's rows) stays in model dtype — it is tiny
-    # next to the main cache; only the main cache rides int8.
-    ring_k = jnp.zeros((n_layers, b, horizon, n_kv, hd), cfg.dtype)
-    ring_v = jnp.zeros_like(ring_k)
-    if rngs is None:
-        rngs = jnp.zeros((horizon, 2), jnp.uint32)      # unused filler
-
-    def one_step(carry, step_in):
-        ring_k, ring_v, tok = carry
-        i, rng = step_in
-        x = _embed_tokens(params, tok[:, None], cfg)    # [b, 1, d]
-        positions = (len0 + i)[:, None]
-
-        def layer_body(xc, layer_and_idx):
-            layer, li = layer_and_idx
-            ck = lax.dynamic_index_in_dim(cache_k, li, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cache_v, li, 0, keepdims=False)
-            if cache.quantized:
-                # int8 codes stay int8 across HBM; the per-row scales
-                # fold into logits/probs inside the attention op.
-                sk = lax.dynamic_index_in_dim(k_scale, li, 0,
-                                              keepdims=False)
-                sv = lax.dynamic_index_in_dim(v_scale, li, 0,
-                                              keepdims=False)
-            else:
-                sk = sv = None
-            rk = lax.dynamic_index_in_dim(ring_k, li, 0, keepdims=False)
-            rv = lax.dynamic_index_in_dim(ring_v, li, 0, keepdims=False)
-
-            def attn_fn(q, k, v):
-                return ring_decode_attention(q, k, v, ck, cv, len0,
-                                             rk, rv, i, k_scale=sk,
-                                             v_scale=sv)
-
-            xc, new_kv, _ = _layer_core(layer, xc, cfg, positions,
-                                        attn_fn, mlora_idx=mlora_idx)
-            return xc, new_kv
-
-        x, (k_rows, v_rows) = lax.scan(
-            layer_body, x, (layer_params, jnp.arange(n_layers)))
-        ring_k = lax.dynamic_update_slice(
-            ring_k, k_rows.astype(ring_k.dtype), (0, 0, i, 0, 0))
-        ring_v = lax.dynamic_update_slice(
-            ring_v, v_rows.astype(ring_v.dtype), (0, 0, i, 0, 0))
-
-        x = rms_norm(x, params['final_norm'], cfg.norm_eps,
-                 cfg.norm_plus_one)
-        logits = _unembed_logits(params, x, cfg)[:, 0]
-        # Constrained decoding composes at logits PRODUCTION, not just
-        # inside filtered_logits: the greedy branch takes a raw argmax.
-        logits = apply_vocab_mask(logits, vocab_mask)
-        if sample_fn is None:
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        else:
-            nxt = sample_fn(logits, rng)
-        # NaN blast-radius isolation: a poisoned row emits the
-        # sentinel; the host evicts that request at readback while the
-        # other slots' tokens land normally. The sentinel also carries
-        # into the next step's token (a wrapped embedding lookup —
-        # deterministic garbage on an already-condemned slot).
-        nxt = mask_nonfinite_tokens(logits, nxt)
-        return (ring_k, ring_v, nxt), nxt
-
-    (ring_k, ring_v, _), toks = lax.scan(
-        one_step, (ring_k, ring_v, tokens),
-        (jnp.arange(horizon), rngs))
-
-    new_cache = merge_rows_into_cache(cache, ring_k, ring_v, len0,
-                                      len0 + horizon)
-    return toks.T, new_cache
 
 
 @functools.partial(jax.jit, static_argnames=('cfg',))

@@ -1,8 +1,8 @@
 """Weight-only int8 / int4 quantization for serving.
 
-Decode on TPU is HBM-bound on the weight stream (see bench.py's
-roofline); storing matmul weights as int8 + per-output-channel scales
-halves that traffic, and int4 (two 4-bit codes packed per byte) halves
+Decode on TPU is HBM-bound on the weight stream (``PERF.md``, the
+chat cell's roofline share); storing matmul weights as int8 +
+per-output-channel scales halves that traffic, and int4 (two 4-bit codes packed per byte) halves
 it AGAIN. Dequantization is expressed as convert+multiply immediately
 before each einsum, which XLA fuses into the matmul's operand read —
 the weight crosses HBM as int8 (or packed int4 nibbles). (The same

@@ -127,9 +127,8 @@ def _for_each_tensor(path: str, process) -> None:
     decoded tensor at a time, so peak extra host memory is bounded by
     ``workers x largest tensor`` — not the checkpoint size. safetensors
     reads release the GIL for the file I/O + memcpy, so ``ckpt_load_s``
-    scales with workers until the disk saturates (BENCH_r05 measured
-    10.6 s serial for the 7B). ``process`` must be thread-safe for
-    DISTINCT keys (each key is processed exactly once)."""
+    scales with workers until the disk saturates. ``process`` must be
+    thread-safe for DISTINCT keys (each key is processed exactly once)."""
     from concurrent.futures import ThreadPoolExecutor
 
     from safetensors import safe_open

@@ -162,48 +162,6 @@ def cached_attention(
     return out.reshape(b, s, h, d)
 
 
-def chunk_attention(
-    q: jax.Array,                      # [b, s, h, d] chunk queries
-    k_new: jax.Array,                  # [b, s, hkv, d] chunk keys
-    v_new: jax.Array,                  # [b, s, hkv, d]
-    cache_k: jax.Array,                # [b, S, hkv, d] cache WITHOUT chunk
-    cache_v: jax.Array,
-    cache_len: jax.Array,              # [b] valid cache rows (= chunk
-                                       #     start position per row)
-    *,
-    impl: str = 'auto',
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Chunked-prefill attention: one chunk of new tokens against the
-    rows already in the cache (nonzero cache offset) plus causal
-    self-attention within the chunk.
-
-    Dispatches to the Pallas flash kernel's chunk path on TPU when the
-    shapes fit its tiling (bf16 caches only — int8 codes + scales fold
-    into the XLA two-block softmax instead): the cache prefix and the
-    chunk concatenate into one kv operand and the kernel masks by
-    ``cache_len``. Everywhere else it is ``cached_attention`` — the
-    same two-block stable softmax the decode path uses, so a chunk at
-    offset 0 matches plain causal attention numerically."""
-    s, d = q.shape[1], q.shape[-1]
-    S = cache_k.shape[1]
-    use_flash = (impl in ('auto', 'flash') and k_scale is None
-                 and jax.default_backend() == 'tpu'
-                 and s >= 128 and s % 128 == 0 and d % 128 == 0
-                 and (S + s) % 128 == 0)
-    if use_flash:
-        from skypilot_tpu.ops import flash_attention as fa
-        cat_k = jnp.concatenate([cache_k.astype(k_new.dtype), k_new],
-                                axis=1)
-        cat_v = jnp.concatenate([cache_v.astype(v_new.dtype), v_new],
-                                axis=1)
-        return fa.flash_attention(q, cat_k, cat_v, causal=True,
-                                  cache_len=cache_len, kv_split=S)
-    return cached_attention(q, k_new, v_new, cache_k, cache_v, cache_len,
-                            k_scale=k_scale, v_scale=v_scale)
-
-
 def ring_decode_attention(
     q: jax.Array,                      # [b, 1, h, d] current-token queries
     k_self: jax.Array,                 # [b, 1, hkv, d] current-token keys

@@ -2,8 +2,8 @@
 
 The gather-based paged decode (``inference/paged.py``) materializes a
 contiguous copy of each slot's pages per layer — that copy is a full
-extra read+write of the KV stream, and measured 0.37x the slot cache's
-decode throughput on a v5e. This kernel is the vLLM/JetStream answer
+extra read+write of the KV stream (on an earlier chip it ran at 0.37x a
+contiguous cache's decode). This kernel is the vLLM/JetStream answer
 built the TPU way (SURVEY §7 step 8 "paged KV in Pallas"): the page
 table rides the grid as a SCALAR-PREFETCH operand, each grid step DMAs
 one page of K/V straight from the pool in HBM into VMEM (no
@@ -19,8 +19,8 @@ q AND k, the MXU's native A.B^T form) and the p.v dot contracts page —
 so the kernel performs NO in-kernel relayout. The previous token-major
 ``[page, hkv, d]`` layout needed k.transpose(1, 2, 0) / v.transpose(1,
 0, 2) per page visit: a VPU lane-shuffle of every streamed byte that
-capped the kernel at ~175 GB/s effective vs the slot cache's ~430
-(perf.md "slot vs paged"). Head-major costs the WRITE side a strided
+capped the kernel well below a contiguous read (docs/perf.md, "The
+page pool's layout"). Head-major costs the WRITE side a strided
 row append ([hkv, 1, d] slices, 32 runs x 128 B) — decode writes one
 row per slot per step vs reading hundreds, so the read side wins.
 
@@ -133,7 +133,7 @@ def _kernel(li_ref, table_ref, lens_ref,         # scalar prefetch
     # block specs index straight into it, so the per-layer slice is a
     # DMA address, never a materialized copy (feeding
     # dynamic_index_in_dim output into pallas_call would copy the whole
-    # layer's pool per step — measured 0.4x the slot cache on a 7B).
+    # layer's pool per step: over half a 7B's step, an earlier reading).
     # Quantized pools carry two extra scale operands; the bf16 variant
     # omits them entirely (a dummy scale pool would cost a real HBM DMA
     # per page on the decode hot path).
@@ -339,8 +339,8 @@ def _kernel_manual(li_ref, table_ref, lens_ref,   # scalar prefetch
     the slot's pages itself with double-buffered async copies — block
     j+1 streams from HBM while block j computes. This beats the
     grid-per-page formulation (which pays per-grid-step pipeline
-    overhead on hundreds of tiny steps per layer: measured 0.71x the
-    slot cache's decode on a 7B) and reads length-exact blocks.
+    overhead on hundreds of tiny steps per layer: 0.71x a contiguous
+    cache's decode on a 7B, an earlier reading) and reads length-exact.
 
     ``pages_per_block`` (K) pages are fetched per loop iteration into
     per-page VMEM buffers (async copies issued back-to-back, one wait

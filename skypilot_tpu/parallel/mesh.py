@@ -185,7 +185,7 @@ def initialize_gang_distributed(coordinator_url: str, rank: int,
     unbounded distributed joins — a member that never comes up must
     fail the gang, not hang it). No-op (False) for world <= 1; only
     attempted on multi-host-capable backends — single-process CPU
-    serving (tests, bench) keeps the ``replicated`` data plane, where
+    serving (the tests) keeps the ``replicated`` data plane, where
     each rank holds a full model copy and lockstep is digest-verified
     by the gang bus instead. Idempotent."""
     global _distributed_initialized

@@ -481,8 +481,8 @@ class ServeController:
                     f'{self.port}.')
 
     def crash(self) -> None:
-        """Die like a crashed process (chaos tests / the bench's
-        ``ctrl_recovery`` block): stop the loop and the HTTP API but
+        """Die like a crashed process (the chaos and controller-recovery
+        tests): stop the loop and the HTTP API but
         tear NOTHING down and touch NO rows — replicas keep serving,
         the journal and notes stay exactly as written, and the next
         ``ServeController(..., recover=True)`` must reconcile it all

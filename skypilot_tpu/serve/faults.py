@@ -6,7 +6,7 @@ the serve layer must survive — and none of them used to be exercisable
 in a test. This module turns each one into a *rule* that fires at an
 exact, reproducible point (the Nth invocation of a named injection
 site, or a seeded probability per invocation), so the chaos suite and
-the bench's chaos block can replay the same failure on every run.
+the fleet simulator can replay the same failure on every run.
 
 Configuration: the ``SKYTPU_FAULT_SPEC`` environment variable holds a
 JSON spec (or ``@/path/to/spec.json``), e.g.::
@@ -46,7 +46,7 @@ network or the hardware:
 - ``spot_preemption`` — the probe sweep, once per swept SPOT replica
   only (on-demand replicas never count an invocation, so ``at``/
   ``every`` rules kill the Nth *spot* sweep deterministically — the
-  chaos suite's and the bench's seeded spot-kill schedule). Kind
+  chaos suite's and the simulator's seeded spot-kill schedule). Kind
   ``preempt_signal`` routes through the full spot path: prefix-cache
   checkpoint, graceful drain, teardown, and autoscaler replacement/
   on-demand backfill.

@@ -39,8 +39,8 @@ landing). Followers pull the log through ``/gang/sync`` (their
 heartbeat) and apply it in order to their local engine, so every rank
 executes the same jitted steps in the same order — on a TPU pod these
 are the per-process shards of one ``jax.distributed`` program
-(``parallel/mesh.py::initialize_gang_distributed``); on CPU (tests,
-bench) each rank holds a full replica of the model (the ``replicated``
+(``parallel/mesh.py::initialize_gang_distributed``); on CPU (the
+tests) each rank holds a full replica of the model (the ``replicated``
 data plane) and the lockstep contract is verified *byte-exactly*:
 followers report a digest of every finished request's token stream,
 and any mismatch fails the gang fast (cause ``divergence``).

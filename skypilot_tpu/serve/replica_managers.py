@@ -245,7 +245,7 @@ class ReplicaManager:
         # (preempt_signal — hard kill), 'preempt_warning'
         # (preempt_signal with advance notice — routes through drain),
         # 'spot_preemption' (counted per swept SPOT replica only —
-        # seeded spot-kill schedules for chaos tests and the bench).
+        # seeded spot-kill schedules for the chaos tests).
         self._faults = self._env.fault_injector()
         # Spot resilience: the latest prefix-cache checkpoint exported
         # by a preemption-warned replica (bytes + export wall time;

@@ -1,11 +1,13 @@
 """SLO-aware async serving core: admission, scheduling, shedding,
 streaming.
 
-BENCH_r05 measured the engine sustaining 1218.9 out-tok/s/chip while
-the HTTP path delivered 538 with 14.1 s median TTFT at saturation —
-head-of-line blocking and admission starvation in the serve loop, not
-engine slowness. This module is the piece that closes that gap: it
-sits between the HTTP front end and either inference engine and owns
+An engine that sustains its decode rate can still deliver a fraction
+of it over HTTP at saturation (a CPU-era reading, not measured on the
+chip, had the HTTP path at under half the engine's rate with a
+many-second median TTFT): head-of-line blocking and admission
+starvation in the serve loop, not engine slowness. This module is the
+piece that closes that gap: it sits between the HTTP front end and the
+inference engine and owns
 every decision the old server made implicitly (FIFO into the engine
 queue, unbounded growth, block-until-done handlers):
 

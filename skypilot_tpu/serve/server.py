@@ -1,5 +1,5 @@
 """Replica-side model server: HTTP front end on the in-tree
-InferenceEngine (the piece the reference delegates to vLLM/JetStream
+PagedInferenceEngine (the piece the reference delegates to vLLM/JetStream
 recipes — here it ships in-tree, SURVEY §7 step 8).
 
 Endpoints:
@@ -79,7 +79,6 @@ logger = tpu_logging.init_logger(__name__)
 def build_engine(cfg_name: str, *, max_batch: int, max_seq: int,
                  model_path: Optional[str] = None,
                  quantize: Optional[str] = None,
-                 kv_cache: str = 'paged',
                  kv_cache_dtype: Optional[str] = None,
                  page_size: Optional[int] = None,
                  decode_impl: Optional[str] = None,
@@ -100,13 +99,12 @@ def build_engine(cfg_name: str, *, max_batch: int, max_seq: int,
     programs all align) — which is why this lives outside the
     ModelServer: rank 0's ``_load_engine`` and the rank-N follower
     entry both call exactly this."""
-    from skypilot_tpu.inference.engine import InferenceEngine
     from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
     if gang is not None and gang.is_gang:
         # Multi-host data plane: on a pod-capable backend the gang
         # shares one jax.distributed program (the mesh then spans all
-        # processes); on CPU (tests/bench) each rank keeps a full
+        # processes); on CPU (the tests) each rank keeps a full
         # model replica and lockstep is digest-verified by the gang
         # bus (the 'replicated' plane).
         import jax
@@ -115,15 +113,13 @@ def build_engine(cfg_name: str, *, max_batch: int, max_seq: int,
             mesh_lib.initialize_gang_distributed(
                 gang.coordinator, gang.rank, gang.world,
                 timeout_s=gang.join_timeout_s)
-    engine_cls = (PagedInferenceEngine if kv_cache == 'paged'
-                  else InferenceEngine)
     extra = {}
     if tp * dp > 1:
         from skypilot_tpu.parallel import mesh as mesh_lib
         extra['mesh'] = mesh_lib.serving_mesh(tp, dp)
-    if kv_cache == 'paged' and page_size is not None:
+    if page_size is not None:
         extra['page_size'] = page_size
-    if kv_cache == 'paged' and decode_impl is not None:
+    if decode_impl is not None:
         extra['decode_impl'] = decode_impl
     if prefill_chunk_tokens is not None:
         extra['prefill_chunk_tokens'] = prefill_chunk_tokens
@@ -142,16 +138,17 @@ def build_engine(cfg_name: str, *, max_batch: int, max_seq: int,
         extra['adapter_dir'] = adapter_dir
         extra['adapter_rank'] = adapter_rank
     if model_path:
-        engine = engine_cls.from_pretrained(
+        engine = PagedInferenceEngine.from_pretrained(
             model_path, max_batch=max_batch, max_seq=max_seq,
             quantize=quantize, **extra)
     else:
         cfg = configs.get_config(cfg_name)
-        engine = engine_cls(cfg, max_batch=max_batch, max_seq=max_seq,
-                            quantize=quantize, **extra)
+        engine = PagedInferenceEngine(cfg, max_batch=max_batch,
+                                      max_seq=max_seq, quantize=quantize,
+                                      **extra)
     # Warmup: compile prefill+decode before declaring readiness. Part
     # of the shared recipe — it advances the request-id counter and
-    # (paged) registers prefix pages, so a follower that skipped it
+    # registers prefix pages, so a follower that skipped it
     # would diverge on its very first replayed op.
     engine.add_request([1, 2, 3], max_new_tokens=2)
     engine.run_to_completion(horizon=4)
@@ -203,7 +200,12 @@ class ModelServer:
         self._mesh_spec = mesh_lib.serving_spec_from_env(tp=tp, dp=dp)
         self.tp = self._mesh_spec.tp
         self.dp = self._mesh_spec.dp
-        self.kv_cache = kv_cache      # 'slot' | 'paged' (prefix caching)
+        # perfbench/runners/serve.py (a directory this tree may not
+        # edit) still passes kv_cache='paged': accepted, selects nothing.
+        if kv_cache != 'paged':
+            raise ValueError(
+                f'kv_cache={kv_cache!r}: the paged engine is the one '
+                'engine; the keyword selects nothing')
         # KV storage dtype ('bf16' | 'int8'); None follows --quantize.
         # Decoupled: int8 KV over bf16 weights halves the dominant
         # decode HBM stream (and ~doubles pool capacity) on its own.
@@ -458,7 +460,7 @@ class ModelServer:
         engine = build_engine(
             self.cfg_name, max_batch=self.max_batch,
             max_seq=self.max_seq, model_path=self.model_path,
-            quantize=self.quantize, kv_cache=self.kv_cache,
+            quantize=self.quantize,
             kv_cache_dtype=self.kv_cache_dtype,
             page_size=self.page_size, decode_impl=self.decode_impl,
             prefill_w8a8=self.prefill_w8a8,
@@ -509,8 +511,7 @@ class ModelServer:
                     f'max_batch={self.max_batch} max_seq={self.max_seq} '
                     f'on {device["device_count"]} x '
                     f'{device["device_kind"]} ({device["platform"]}), '
-                    f'decode_impl='
-                    f'{getattr(engine, "decode_impl", None)}')
+                    f'decode_impl={engine.decode_impl}')
 
     def _engine_loop(self) -> None:
         try:
@@ -1213,7 +1214,7 @@ class ModelServer:
         replica and POSTs it to the migration target's ``/kv/warmup``
         instead of letting the target recompute the prefix."""
         eng = self.engine
-        if eng is None or not hasattr(eng, 'export_prefix_entry'):
+        if eng is None:
             return None, 0
         with self._lock:
             if self._gang is not None:
@@ -1270,8 +1271,7 @@ class ModelServer:
         self._m_kv_bytes['ingest'].inc(len(blob))
         return {'entries': len(entries), 'landed': landed,
                 'warmed_rows': warmed_rows,
-                'skipped_capacity': skipped_capacity,
-                'kv_cache': self.kv_cache}
+                'skipped_capacity': skipped_capacity}
 
     def _persist_checkpoint(self) -> None:
         """Write the resilience checkpoint to ``checkpoint_path``
@@ -1449,8 +1449,7 @@ class ModelServer:
         schema is stable from the first scrape (zeros before the
         engine loads or a feature turns on)."""
         eng = self.engine
-        spec = (eng.spec_metrics() if eng is not None
-                and hasattr(eng, 'spec_metrics') else {})
+        spec = eng.spec_metrics() if eng is not None else {}
         g = self._reg.gauge
         g('skytpu_active_slots',
           'Occupied decode slots').set(eng.num_active if eng else 0)
@@ -1466,7 +1465,7 @@ class ModelServer:
               eng.remaining_work_tokens() if eng else 0)
         g('skytpu_prefill_inflight',
           'Slots still streaming prompt chunks in').set(
-              len(getattr(eng, '_prefill_off', ())) if eng else 0)
+              len(eng._prefill_off) if eng else 0)
         g('skytpu_max_batch', 'Configured decode batch').set(
             self.max_batch)
         # Wedge-watchdog age: 0 between steps; sustained growth means
@@ -1527,7 +1526,7 @@ class ModelServer:
         loaded, the configured (tp, dp) spec before — same keys either
         way (every logical axis, 1 when unused)."""
         eng = self.engine
-        if eng is not None and hasattr(eng, 'mesh_axes'):
+        if eng is not None:
             return eng.mesh_axes()
         from skypilot_tpu.parallel import mesh as mesh_lib
         return {a: int(s) for a, s in zip(mesh_lib.MESH_AXES,
@@ -1538,7 +1537,7 @@ class ModelServer:
         the engine loads (the dtype resolves from the configured flags
         so the gauge label never flips once serving starts)."""
         eng = self.engine
-        if eng is not None and hasattr(eng, 'kv_pool_stats'):
+        if eng is not None:
             return eng.kv_pool_stats()
         from skypilot_tpu.inference.engine import resolve_kv_cache_dtype
         return {
@@ -1552,18 +1551,17 @@ class ModelServer:
         """The JSON ``engine`` block: what the engine resolved at
         construction (``decode_impl`` after ``auto``, pool pages, whether
         the pool was sized from live device memory) and where its bytes
-        sit. Same keys before the engine loads and on a slot engine
-        (``decode_impl`` None: it has no paged decode path)."""
+        sit. Same keys before the engine loads (``decode_impl`` None:
+        nothing resolved yet)."""
         eng = self.engine
-        if eng is not None and hasattr(eng, 'resolved_path'):
-            return dict(eng.resolved_path(), kv_cache=self.kv_cache)
+        if eng is not None:
+            return eng.resolved_path()
         return {
             'decode_impl': None, 'decode_interpret': False,
             'prefill_attn': None, 'page_size': 0, 'kv_pool_pages': 0,
             'pool_auto_sized': False,
             'bytes_by_device': {'params': {}, 'kv_pool': {}},
             'jit_first_calls': 0, 'jit_first_call_seconds': 0.0,
-            'kv_cache': self.kv_cache,
         }
 
     def _lora_stats(self) -> Dict[str, Any]:
@@ -1596,8 +1594,7 @@ class ModelServer:
         schema, never a key that appears only once traffic or
         speculation starts)."""
         eng = self.engine
-        spec = (eng.spec_metrics() if eng is not None
-                and hasattr(eng, 'spec_metrics') else {})
+        spec = eng.spec_metrics() if eng is not None else {}
         pool = self._kv_pool_stats()
         sched_stats = self.sched.json_stats()
         return {
@@ -1723,17 +1720,15 @@ class ModelServer:
             # recompiles).
             'lora': self._lora_stats(),
             # Hot-prefix digest (stable schema: page 0 / empty entries
-            # on a slot engine or before the engine loads). Built from
+            # before the engine loads). Built from
             # the engine's HOST-SIDE heat tracker only — shipping it on
             # every probe adds zero d2h and zero recompiles (pinned by
             # the jaxpr-audit serve preset). The prefix-affinity LB
             # policy routes by longest match against these hashes.
             'prefix_digest': {
-                'page': int(getattr(eng, 'page', 0) or 0),
+                'page': int(eng.page) if eng is not None else 0,
                 'entries': (eng.hot_prefix_digest()
-                            if eng is not None
-                            and hasattr(eng, 'hot_prefix_digest')
-                            else []),
+                            if eng is not None else []),
             },
         }
 
@@ -2687,7 +2682,9 @@ class ModelServer:
 
 
 def main() -> None:
-    parser = argparse.ArgumentParser()
+    # No prefix matching: a removed flag (--kv-cache) must be unknown,
+    # not read as the start of another (--kv-cache-dtype).
+    parser = argparse.ArgumentParser(allow_abbrev=False)
     parser.add_argument('--model', default='tiny',
                         help='preset config name (random weights)')
     parser.add_argument('--model-path', default=None,
@@ -2727,18 +2724,12 @@ def main() -> None:
                              'bounded accuracy cost')
     parser.add_argument('--decode-impl', default=None,
                         choices=['gather', 'pallas', 'cross_layer'],
-                        help='paged decode attention path (paged '
-                             'cache only; default = engine auto). '
+                        help='paged decode attention path '
+                             '(default = engine auto). '
                              'cross_layer batches ALL layers\' KV '
                              'page reads per page visit — one kernel '
                              'pass per decode step instead of one '
                              'per layer')
-    parser.add_argument('--kv-cache', default='paged',
-                        choices=['slot', 'paged'],
-                        help='paged (default) = shared page pool with '
-                             'prefix caching, chunked prefill and '
-                             'continuous admission; slot = fixed '
-                             'per-slot reservations')
     parser.add_argument('--page-size', type=int, default=None,
                         help='paged-cache page granularity (tokens); '
                              'default auto-selects a fast-path size '
@@ -2749,8 +2740,7 @@ def main() -> None:
                              'prompts prefill in chunks interleaved '
                              'with decode so running requests keep '
                              'streaming behind long prompts. Engine '
-                             'default 256; 0 = monolithic prefill '
-                             '(slot engine only)')
+                             'default 256')
     parser.add_argument('--decode-priority-ratio', type=float,
                         default=None,
                         help='decode share of the interleaved token '
@@ -2891,10 +2881,6 @@ def main() -> None:
                         default=int(os.environ.get('SKYTPU_REPLICA_PORT',
                                                    '8081')))
     args = parser.parse_args()
-    if args.kv_cache != 'paged' and args.page_size is not None:
-        parser.error('--page-size only applies with --kv-cache paged')
-    if args.kv_cache != 'paged' and args.decode_impl is not None:
-        parser.error('--decode-impl only applies with --kv-cache paged')
     gang_spec = gang_lib.GangSpec.from_env(
         rank=args.gang_rank, world=args.gang_world,
         coordinator=args.gang_coordinator, gang_id=args.gang_id)
@@ -2906,7 +2892,6 @@ def main() -> None:
                          model_path=args.model_path,
                          quantize=args.quantize,
                          tp=args.tp, dp=args.dp,
-                         kv_cache=args.kv_cache,
                          kv_cache_dtype=args.kv_cache_dtype,
                          page_size=args.page_size,
                          decode_impl=args.decode_impl,
@@ -2948,8 +2933,7 @@ def run_follower(spec: 'gang_lib.GangSpec', args) -> None:
     engine = build_engine(
         args.model, max_batch=args.max_batch, max_seq=args.max_seq,
         model_path=args.model_path, quantize=args.quantize,
-        kv_cache=args.kv_cache, kv_cache_dtype=args.kv_cache_dtype,
-        page_size=args.page_size,
+        kv_cache_dtype=args.kv_cache_dtype, page_size=args.page_size,
         decode_impl=getattr(args, 'decode_impl', None),
         prefill_w8a8=args.prefill_w8a8,
         prefill_chunk_tokens=args.prefill_chunk_tokens,

@@ -69,7 +69,7 @@ class SkyServiceSpec:
     # serve/forecaster.py): pre-scale ahead of traffic ramps by the
     # learned provisioning lead time instead of reacting after the ramp
     # lands. The knobs are the forecaster's bucket width, season length
-    # (diurnal period — or minutes for tests/benches), and the default
+    # (diurnal period — or minutes for tests), and the default
     # look-ahead horizon.
     forecast_enabled: bool = False
     forecast_bucket_seconds: float = 10.0

@@ -10,7 +10,7 @@ and millions of requests in seconds of wall time, deterministic to the
 byte for a fixed seed.
 
 Entry points: :func:`skypilot_tpu.serve.sim.scenarios.run_scenario`
-(the ``skytpu sim`` CLI and the bench's ``sim`` block both call it)
+(what the ``skytpu sim`` CLI calls)
 and :class:`skypilot_tpu.serve.sim.fleet.FleetSimulator` for custom
 harnesses. graftcheck GC117 bans every wall-clock read under this
 package — the virtual clock is the only time axis.

@@ -1,7 +1,6 @@
 """Simulated replicas: a fluid queueing model of one model server,
-with service curves calibrated from the repo's BENCH engine numbers,
-speaking exactly the HTTP contract the control plane drives — so the
-REAL replica manager probes, drains, checkpoints and warms them
+with a service curve of serving-path readings, speaking exactly the
+HTTP contract the control plane drives — so the REAL replica manager probes, drains, checkpoints and warms them
 without knowing they are synthetic.
 
 Service model (deliberately fluid, O(1) per event): a replica with
@@ -17,12 +16,14 @@ the sim's recovery-TTFT numbers). Waits beyond ``max_queue_wait_s``
 model the SLO scheduler's token-bounded admission: the request is
 shed with a retryable 429, exactly what the live scheduler does.
 
-Calibration: :meth:`ServiceCurve.from_bench` scans the repo's
-``BENCH_r*.json`` records (newest first) for the serving-path numbers
-— ``tpot_ms_median`` at 0.7 capacity, the prefix-cache hit/miss TTFT
-medians, the paged engine ``batch`` — and falls back to the r05 CPU
-anchors when no record parses. Provision-latency distributions live
-in the scenario (they are a property of the cloud, not the engine).
+Calibration: :meth:`ServiceCurve.from_bench` scans record texts the
+caller hands it (newest first) for the serving-path numbers —
+``tpot_ms_median``, the prefix-cache hit/miss TTFT medians, the engine
+``batch`` — and falls back per field to the default anchors below: a
+CPU-era reading, not measured on the chip (the simulator's scenarios
+are judged on counts and ratios, not on these absolute times).
+Provision-latency distributions live in the scenario (they are a
+property of the cloud, not the engine).
 """
 from __future__ import annotations
 
@@ -38,9 +39,9 @@ from skypilot_tpu.telemetry import fleet as fleet_lib
 from skypilot_tpu.telemetry import registry as registry_lib
 from skypilot_tpu.telemetry import tracing
 
-# r05 fallback anchors (BENCH_r05.json serving_http.at_0p7_capacity and
-# prefix_cache blocks): tpot 23.22 ms, TTFT hit/miss 254.8/350.5 ms,
-# paged batch 48, ~220-token anchor prompts.
+# The simulator's default anchors (round 5's readings, from before the
+# chip was measured by the driver: not a statement about the v5e): tpot
+# 23.22 ms, TTFT hit/miss 254.8/350.5 ms, batch 48, ~220-token prompts.
 _FALLBACK = {'tpot_ms': 23.22, 'ttft_hit_ms': 254.8,
              'ttft_miss_ms': 350.5, 'batch': 48, 'avg_prompt': 220.0}
 
@@ -73,9 +74,9 @@ class ServiceCurve:
     @classmethod
     def from_bench(cls, bench_texts: Optional[List[str]] = None,
                    max_queue_wait_s: float = 8.0) -> 'ServiceCurve':
-        """Calibrate from BENCH record texts (newest first; the caller
-        reads the files — this module does no I/O so it stays pure and
-        GC117-clean). Falls back to the r05 anchors per-field."""
+        """Calibrate from record texts (newest first; the caller reads
+        any files — this module does no I/O so it stays pure and
+        GC117-clean). Falls back to the default anchors per-field."""
         vals = dict(_FALLBACK)
         found: Dict[str, float] = {}
         for text in bench_texts or []:

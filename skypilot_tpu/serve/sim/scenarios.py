@@ -6,7 +6,7 @@ traffic trace, an LB policy, a fault spec (``serve/faults.py`` rules —
 including the sim-targeted sites: correlated spot storms, zone
 outages, flaky probes, stragglers, gang churn) and the simulator
 knobs. ``run_scenario(name, seed=...)`` is the single entry point the
-``skytpu sim`` CLI and the bench's ``sim`` block share.
+``skytpu sim`` CLI and the tests share.
 
 Scenario service curves are calibrated from the repo's BENCH records
 (:func:`calibrated_curve`), scaled to a known per-replica capacity
@@ -24,8 +24,6 @@ autoscaler — the forecast run must shed STRICTLY fewer requests.
 from __future__ import annotations
 
 import dataclasses
-import glob
-import os
 from typing import Any, Callable, Dict, List, Optional
 
 from skypilot_tpu.serve.service_spec import SkyServiceSpec
@@ -36,35 +34,15 @@ from skypilot_tpu.serve.sim import traffic as sim_traffic
 _CURVE_CACHE: Dict[int, sim_replica.ServiceCurve] = {}
 
 
-def _repo_root() -> str:
-    here = os.path.dirname(os.path.abspath(__file__))
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(here))))
-
-
 def calibrated_curve(slots: int = 10) -> sim_replica.ServiceCurve:
-    """A BENCH-calibrated service curve with ``slots`` concurrency
+    """The simulator's default service curve with ``slots`` concurrency
     (slots sized by the scenario so per-replica capacity matches its
-    spec's ``target_qps_per_replica``). Reads the newest
-    ``BENCH_r*.json`` records from the repo root; falls back to the
-    r05 anchors when none parse."""
-    if slots in _CURVE_CACHE:
-        return _CURVE_CACHE[slots]
-    texts: List[str] = []
-    try:
-        paths = sorted(glob.glob(os.path.join(_repo_root(),
-                                              'BENCH_r*.json')),
-                       reverse=True)
-        for p in paths[:4]:
-            with open(p, encoding='utf-8') as f:
-                texts.append(f.read())
-    except OSError:
-        pass
-    base = sim_replica.ServiceCurve.from_bench(texts)
-    curve = dataclasses.replace(base, slots=slots,
-                                kv_pool_tokens=slots * 424)
-    _CURVE_CACHE[slots] = curve
-    return curve
+    spec's ``target_qps_per_replica``)."""
+    if slots not in _CURVE_CACHE:
+        _CURVE_CACHE[slots] = dataclasses.replace(
+            sim_replica.ServiceCurve.from_bench(), slots=slots,
+            kv_pool_tokens=slots * 424)
+    return _CURVE_CACHE[slots]
 
 
 def _spec(**kw: Any) -> SkyServiceSpec:
@@ -515,5 +493,5 @@ def run_scenario(name: str, seed: int = 0,
                  policy: Optional[str] = None,
                  **overrides: Any) -> Dict[str, Any]:
     """Run one named scenario; returns its report dict (the CLI prints
-    it as JSON; the bench embeds it)."""
+    it as JSON)."""
     return get_scenario(name).run(seed=seed, policy=policy, **overrides)

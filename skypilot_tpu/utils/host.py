@@ -92,7 +92,7 @@ def host_scalars(tree: Any) -> Any:
 
 def host_block(tree: Any) -> Any:
     """Block until every array in ``tree`` has been computed, WITHOUT
-    copying it to host (the donation barrier in quantize_params, bench
+    copying it to host (the donation barrier in quantize_params,
     timing fences). Returns ``tree``."""
     with _sanctioned():
         import jax

@@ -17,6 +17,7 @@ import urllib.request
 import jax
 import pytest
 
+import greedy_oracle
 from skypilot_tpu import telemetry
 from skypilot_tpu.serve import faults as faults_lib
 from skypilot_tpu.utils import common_utils
@@ -346,16 +347,11 @@ def test_preemption_warning_routes_through_drain(tmp_path, monkeypatch):
 
 
 # ------------------------------------------------------- engine export
-@pytest.mark.parametrize('kind', ['slot', 'paged'])
-def test_export_inflight_both_engines(kind):
+def test_export_inflight():
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    cfg = configs.get_config('tiny')
-    if kind == 'paged':
-        from skypilot_tpu.inference.paged import PagedInferenceEngine
-        eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64)
-    else:
-        from skypilot_tpu.inference.engine import InferenceEngine
-        eng = InferenceEngine(cfg, max_batch=2, max_seq=64)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64)
     eng.add_request([1, 2, 3], max_new_tokens=8)
     eng.add_request([4, 5], max_new_tokens=4, temperature=0.7,
                     top_k=5, priority=1)
@@ -510,8 +506,10 @@ def test_request_key_idempotent_replay():
 def test_mid_stream_migration_byte_identical(monkeypatch):
     """Deterministic mid-stream break (injected partial_response after
     5 token events): the LB migrates the stream to the other replica
-    with the generated prefix; the client sees one stream whose final
-    tokens are byte-identical to an uninterrupted greedy run."""
+    with the generated prefix; the client sees one whole stream, every
+    token of it the reference's choice (the target recomputes prompt +
+    prefix by prefill where an uninterrupted run decoded: another
+    program, so held to ``greedy_oracle``)."""
     pa = common_utils.find_free_port(19900)
     pb = common_utils.find_free_port(pa + 1)
     sa = _start_server(pa)
@@ -519,9 +517,6 @@ def test_mid_stream_migration_byte_identical(monkeypatch):
     try:
         assert sa._ready.wait(180) and sb._ready.wait(180)
         prompt, gen = [3, 1, 4, 1, 5], 16
-        reference = _generate(f'http://127.0.0.1:{pb}',
-                              {'prompt': prompt,
-                               'max_new_tokens': gen})['tokens']
         ctrl = _FakeController([f'http://127.0.0.1:{pa}',
                                 f'http://127.0.0.1:{pb}'])
         lb, lport = _start_lb(ctrl.url, monkeypatch)
@@ -540,8 +535,9 @@ def test_mid_stream_migration_byte_identical(monkeypatch):
                  'stream': True})
             assert error is None
             assert done is not None
-            assert tokens == reference, (tokens, reference)
-            assert done['tokens'] == reference
+            assert len(tokens) == gen and done['tokens'] == tokens
+            greedy_oracle.assert_server_agrees(prompt, tokens,
+                                               'migrated stream')
             assert reg.get('skytpu_requests_migrated_total',
                            outcome='completed').value == migrated0 + 1
             assert h_rec.count == rec0 + 1
@@ -560,7 +556,7 @@ def test_chaos_kill_replica_mid_stream_zero_lost(monkeypatch):
     """THE chaos contract (deterministic seed): one of two replicas is
     crash-injected mid-stream under concurrent load — zero lost
     requests (every accepted stream completes), and every completed
-    stream's greedy output is byte-identical to an uninterrupted run."""
+    stream's greedy output is whole and the reference's choice."""
     pa = common_utils.find_free_port(19950)
     pb = common_utils.find_free_port(pa + 1)
     # Replica A dies on its 4th engine-loop iteration — mid-stream for
@@ -574,11 +570,6 @@ def test_chaos_kill_replica_mid_stream_zero_lost(monkeypatch):
         assert sa._ready.wait(180) and sb._ready.wait(180)
         prompts = [[11 + i, 3, 5, 7 + i] for i in range(6)]
         gen = 24
-        reference = {
-            tuple(p): _generate(f'http://127.0.0.1:{pb}',
-                                {'prompt': p,
-                                 'max_new_tokens': gen})['tokens']
-            for p in prompts}
         ctrl = _FakeController([f'http://127.0.0.1:{pa}',
                                 f'http://127.0.0.1:{pb}'])
         lb, lport = _start_lb(ctrl.url, monkeypatch, max_attempts=4)
@@ -609,10 +600,11 @@ def test_chaos_kill_replica_mid_stream_zero_lost(monkeypatch):
                 if error is not None or done is None:
                     lost.append((p, error))
                     continue
-                assert tokens == reference[tuple(p)], \
-                    (p, tokens, reference[tuple(p)])
-            # ZERO lost requests: every accepted stream completed with
-            # byte-identical output (a retryable error event would have
+                assert len(tokens) == gen, (p, tokens)
+                greedy_oracle.assert_server_agrees(p, tokens,
+                                                   'stream under chaos')
+            # ZERO lost requests: every accepted stream completed whole
+            # (a retryable error event would have
             # been acceptable per the contract only if no replica
             # survived — here B is alive, so everything completes).
             assert lost == [], lost

@@ -1,19 +1,19 @@
-"""Chunked prefill in the slot engine + prefill/decode interleaving.
+"""Chunked prefill + prefill/decode interleaving.
 
 The fast (not-slow) tests are the tier-1 scheduler smoke: CPU, tiny
-config, one compile apiece — they pin that the chunked path is ON by
-default, that decode makes progress while a long prompt is mid-prefill,
-and the host-side scheduler arithmetic (interleave budget, page-size
-auto-select) with no device work at all. The compile-heavy equivalence
-matrix (chunked == monolithic across slot/paged/int8/prefix-hit) rides
-the slow tier with the other engine suites.
+config, one compile apiece — they pin the default chunk width, that
+decode makes progress while a long prompt is mid-prefill, and the
+host-side scheduler arithmetic (interleave budget, page-size
+auto-select) with no device work at all. The compile-heavy matrix
+(chunked prompts across int8/prefix-hit/sampling/cancel) rides the slow
+tier with the other engine suites.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from skypilot_tpu.inference.engine import InferenceEngine
+import greedy_oracle
 from skypilot_tpu.inference.paged import PagedInferenceEngine
 from skypilot_tpu.models import configs, llama
 
@@ -25,19 +25,6 @@ def setup():
     return cfg, params
 
 
-def _greedy_reference(params, cfg, prompt, n):
-    """Greedy decode via repeated full forwards (no cache)."""
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits, _ = llama.forward(params, jnp.asarray([toks], jnp.int32),
-                                  cfg)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Fast tier: scheduler smoke (tier-1 exercises the chunked path)
 # ---------------------------------------------------------------------------
@@ -45,9 +32,6 @@ class TestSchedulerSmoke:
 
     def test_chunked_on_by_default(self, setup):
         cfg, params = setup
-        eng = InferenceEngine(cfg, params, max_batch=2, max_seq=128,
-                              attn_impl='xla')
-        assert eng.chunked and eng.chunk == 256
         assert PagedInferenceEngine(cfg, params, max_batch=2,
                                     max_seq=128, page_size=8,
                                     attn_impl='xla').chunk == 256
@@ -55,11 +39,12 @@ class TestSchedulerSmoke:
     def test_decode_progresses_while_long_prompt_prefills(self, setup):
         """The scheduler unit contract: with request A decoding, a long
         prompt B prefills in chunks and A gains tokens BETWEEN chunks
-        (bounded TPOT during admission) — plus the chunked output
-        matches the no-cache greedy reference."""
+        (bounded TPOT during admission) — plus the chunked output is
+        the no-cache reference's choice."""
         cfg, params = setup
-        eng = InferenceEngine(cfg, params, max_batch=2, max_seq=256,
-                              attn_impl='xla', prefill_chunk_tokens=16)
+        eng = PagedInferenceEngine(cfg, params, max_batch=2, max_seq=256,
+                                   attn_impl='xla',
+                                   prefill_chunk_tokens=16)
         a = eng.add_request([3, 1, 4, 1, 5], max_new_tokens=64)
         while eng._prefill_off or eng._queue:
             eng.step(horizon=1)
@@ -74,16 +59,17 @@ class TestSchedulerSmoke:
                 saw_interleave = True
         assert saw_interleave
         done = eng.run_to_completion(horizon=4)
-        assert done[b].output == _greedy_reference(params, cfg,
-                                                   prompt_b, 4)
+        assert len(done[b].output) == 4
+        greedy_oracle.assert_agrees(cfg, params, prompt_b, done[b].output,
+                                    what='chunked beside decode')
 
     def test_interleave_horizon_token_budget(self, setup):
         """Host-only arithmetic: the decode_priority_ratio budget
         h = r/(1-r) * chunk * n / active."""
         cfg, params = setup
-        eng = InferenceEngine(cfg, params, max_batch=8, max_seq=128,
-                              prefill_chunk_tokens=64,
-                              decode_priority_ratio=0.5)
+        eng = PagedInferenceEngine(cfg, params, max_batch=8, max_seq=128,
+                                   prefill_chunk_tokens=64,
+                                   decode_priority_ratio=0.5)
         # 2 decodable slots + 1 mid-prefill -> h = 1 * 64 * 1 / 2 = 32
         for s in range(3):
             eng._slots[s] = object()
@@ -153,109 +139,52 @@ class TestSchedulerSmoke:
 
 
 # ---------------------------------------------------------------------------
-# Slow tier: equivalence matrix (chunked == monolithic)
+# Slow tier: chunked prompts through the engine
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
-class TestChunkedEquivalence:
+class TestChunkedEngine:
 
-    def _mono(self, cfg, params, prompts, n_new, **kw):
-        eng = InferenceEngine(cfg, params, max_batch=4, max_seq=256,
-                              attn_impl='xla', prefill_chunk_tokens=0,
-                              **kw)
-        rids = [eng.add_request(p, max_new_tokens=n_new)
-                for p in prompts]
-        done = eng.run_to_completion(horizon=4)
-        return [done[r].output for r in rids]
-
-    def test_slot_chunked_matches_monolithic(self, setup):
+    def test_chunked_agrees_with_oracle(self, setup):
+        """Prompts of under one chunk, of five chunks and of one token
+        side by side: each output is the reference's choice."""
         cfg, params = setup
         prompts = [[3, 1, 4, 1, 5],
                    [(i * 5 + 2) % cfg.vocab_size for i in range(150)],
                    [9],
                    [(i * 11 + 7) % cfg.vocab_size for i in range(40)]]
-        want = self._mono(cfg, params, prompts, 8)
-        eng = InferenceEngine(cfg, params, max_batch=4, max_seq=256,
-                              attn_impl='xla', prefill_chunk_tokens=32)
-        rids = [eng.add_request(p, max_new_tokens=8) for p in prompts]
-        done = eng.run_to_completion(horizon=4)
-        got = [done[r].output for r in rids]
-        assert got == want, (got, want)
+        eng = PagedInferenceEngine(cfg, params, max_batch=4, max_seq=256,
+                                   attn_impl='xla',
+                                   prefill_chunk_tokens=32)
+        got = greedy_oracle.greedy(eng, prompts, 8)
+        greedy_oracle.assert_all_agree(cfg, params, prompts, got,
+                                       'chunk 32', n_new=8)
 
-    def test_slot_chunked_int8_generates(self, setup):
+    def test_chunked_int8_generates(self, setup):
         cfg, params = setup
-        eng = InferenceEngine(cfg, params, max_batch=2, max_seq=256,
-                              quantize='int8', prefill_chunk_tokens=32)
+        eng = PagedInferenceEngine(cfg, params, max_batch=2, max_seq=256,
+                                   quantize='int8',
+                                   prefill_chunk_tokens=32)
         rid = eng.add_request(list(range(1, 100)), max_new_tokens=6)
         done = eng.run_to_completion(horizon=4)
         assert len(done[rid].output) == 6
-
-    def test_paged_chunked_matches_monolithic_slot(self, setup):
-        """Paged chunked prefill — WITHOUT and then WITH a prefix-cache
-        hit (tail-only prefill) — matches monolithic slot outputs."""
-        cfg, params = setup
-        shared = [(i * 5 + 2) % cfg.vocab_size for i in range(64)]
-        p1 = shared + [11, 12]
-        p2 = shared + [13, 14, 15]
-        want = self._mono(cfg, params, [p1, p2], 6)
-        eng = PagedInferenceEngine(cfg, params, max_batch=2,
-                                   max_seq=256, page_size=8, chunk=16,
-                                   attn_impl='xla')
-        r1 = eng.add_request(p1, max_new_tokens=6)
-        done = eng.run_to_completion(horizon=4)
-        assert done[r1].output == want[0]      # cold (no prefix hit)
-        r2 = eng.add_request(p2, max_new_tokens=6)
-        done = eng.run_to_completion(horizon=4)
-        assert eng.alloc.prefix_hits >= 1      # tail-only prefill
-        assert done[r2].output == want[1]
-
-    def test_prefill_rows_chunked_logits_match(self, setup):
-        """Model-layer equivalence: a prompt prefilled as two chunks
-        against gathered cache rows produces the same last logits and
-        KV rows as one monolithic prefill_rows call."""
-        cfg, params = setup
-        n, plen, half = 2, 64, 32
-        toks = np.array([[(i * 7 + r * 13 + 3) % cfg.vocab_size
-                          for i in range(plen)] for r in range(n)],
-                        np.int32)
-        lens = jnp.full((n,), plen, jnp.int32)
-        last_mono, (k_mono, v_mono) = llama.prefill_rows(
-            params, jnp.asarray(toks), lens, cfg, attn_impl='xla')
-        # chunk 1: plain causal (offset 0)
-        _, (k1, v1) = llama.prefill_rows(
-            params, jnp.asarray(toks[:, :half]),
-            jnp.full((n,), half, jnp.int32), cfg, attn_impl='xla')
-        # chunk 2: attends chunk 1's rows at a nonzero cache offset
-        starts = jnp.full((n,), half, jnp.int32)
-        last_chunk, (k2, v2) = llama.prefill_rows(
-            params, jnp.asarray(toks[:, half:]),
-            jnp.full((n,), half, jnp.int32), cfg, attn_impl='xla',
-            cache_kv=(k1, v1), cache_len=starts)
-        np.testing.assert_allclose(np.asarray(last_chunk),
-                                   np.asarray(last_mono),
-                                   rtol=2e-2, atol=2e-2)
-        np.testing.assert_allclose(
-            np.asarray(jnp.concatenate([k1, k2], axis=2)
-                       .astype(jnp.float32)),
-            np.asarray(k_mono.astype(jnp.float32)),
-            rtol=2e-2, atol=2e-2)
 
     def test_sampling_through_chunked_completion(self, setup):
         """A completing chunk samples its first token on device with
         the request's params; hot sampling still yields varied, valid
         tokens, and top_p->0 collapses to the greedy output."""
         cfg, params = setup
-        eng = InferenceEngine(cfg, params, max_batch=2, max_seq=256,
-                              attn_impl='xla', prefill_chunk_tokens=16,
-                              rng_seed=7)
+        eng = PagedInferenceEngine(cfg, params, max_batch=2, max_seq=256,
+                                   attn_impl='xla', prefill_chunk_tokens=16,
+                                   rng_seed=7)
         prompt = [(i * 3 + 1) % cfg.vocab_size for i in range(40)]
         g = eng.add_request(prompt, max_new_tokens=10)
         h = eng.add_request(prompt, max_new_tokens=10,
                             temperature=2.0, top_p=1e-6)
         done = eng.run_to_completion(horizon=4)
         assert done[g].output == done[h].output
-        eng2 = InferenceEngine(cfg, params, max_batch=1, max_seq=256,
-                               attn_impl='xla',
-                               prefill_chunk_tokens=16, rng_seed=7)
+        eng2 = PagedInferenceEngine(cfg, params, max_batch=1, max_seq=256,
+                                    attn_impl='xla',
+                                    prefill_chunk_tokens=16, rng_seed=7)
         rid = eng2.add_request(prompt, max_new_tokens=12,
                                temperature=2.0, top_k=50)
         out = eng2.run_to_completion(horizon=4)[rid].output
@@ -264,8 +193,8 @@ class TestChunkedEquivalence:
 
     def test_cancel_mid_prefill_frees_slot(self, setup):
         cfg, params = setup
-        eng = InferenceEngine(cfg, params, max_batch=1, max_seq=256,
-                              attn_impl='xla', prefill_chunk_tokens=16)
+        eng = PagedInferenceEngine(cfg, params, max_batch=1, max_seq=256,
+                                   attn_impl='xla', prefill_chunk_tokens=16)
         rid = eng.add_request(list(range(1, 150)), max_new_tokens=8)
         eng.step(horizon=1)                    # first chunk in flight
         assert eng._prefill_off

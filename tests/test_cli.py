@@ -52,15 +52,32 @@ class TestBasics:
 
     def test_model_server_help_and_validation(self, runner):
         out = _ok(runner.invoke(cli.cli, ['model-server', '--help']))
-        for opt in ('--speculate-k', '--kv-cache', '--quantize',
+        for opt in ('--speculate-k', '--kv-cache-dtype', '--quantize',
                     '--prefill-chunk-tokens', '--page-size'):
             assert opt in out
-        # --page-size only applies to the paged cache (mirrors the
-        # serve/server.py argparse contract).
-        bad = runner.invoke(cli.cli, ['model-server', '--kv-cache',
-                                      'slot', '--page-size', '128'])
-        assert bad.exit_code != 0
-        assert 'page-size' in bad.output
+
+    def test_model_server_has_no_engine_choice(self, runner):
+        """One engine: ``--kv-cache`` is no option any more, under
+        either spelling of its old values."""
+        for value in ('paged', 'slot'):
+            bad = runner.invoke(cli.cli, ['model-server', '--kv-cache',
+                                          value])
+            assert bad.exit_code != 0
+            assert 'No such option' in bad.output, bad.output
+
+    def test_server_module_has_no_engine_choice(self):
+        """The same for ``python -m skypilot_tpu.serve.server``: the flag
+        is unknown, and is not read as a prefix of ``--kv-cache-dtype``."""
+        import subprocess
+        import sys
+        for value in ('paged', 'int8'):
+            proc = subprocess.run(
+                [sys.executable, '-m', 'skypilot_tpu.serve.server',
+                 '--kv-cache', value], capture_output=True, text=True,
+                timeout=120, check=False)
+            assert proc.returncode == 2, proc.stderr[-2000:]
+            assert 'unrecognized arguments: --kv-cache' in proc.stderr, \
+                proc.stderr[-2000:]
 
     def test_status_empty(self, runner):
         assert 'No existing clusters' in _ok(

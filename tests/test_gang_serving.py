@@ -23,6 +23,7 @@ import urllib.request
 import jax
 import pytest
 
+import greedy_oracle
 from skypilot_tpu import telemetry
 from skypilot_tpu.serve import faults as faults_lib
 from skypilot_tpu.serve import gang as gang_lib
@@ -762,8 +763,9 @@ def test_preempt_gang_checkpoint_recover_byte_identical():
     """Preemption flow across a gang: mid-stream, POST /checkpoint
     exports the gang's state (in-flight KV + hot prefixes; every rank
     acks), a replacement single-process replica warms from the blob,
-    and the resubmitted continuation is byte-identical to an
-    uninterrupted run."""
+    and the resubmitted continuation (a prefill of prompt + prefix over
+    the warmed pages: another program than uninterrupted decode)
+    completes the budget with the reference's choices."""
     from skypilot_tpu.serve.server import ModelServer
     port = common_utils.find_free_port(22800)
     # Deterministic engine stall: the tiny engine otherwise decodes
@@ -782,21 +784,7 @@ def test_preempt_gang_checkpoint_recover_byte_identical():
         assert srv._ready.wait(300)
         follower, _t = _start_thread_follower(base, gang_id='g-ckpt')
         assert _await_barrier(srv, timeout=60), srv._error
-        # gen pinned where the cross-replica recompute is byte-exact
-        # for this prompt (the 100-ish-token near-tie caveat the
-        # robustness docs carry).
         prompt, gen = [9, 2, 6, 4], 48
-        # Uninterrupted reference on a fresh single-process server.
-        port_r = common_utils.find_free_port(22850)
-        ref_srv = ModelServer('tiny', port=port_r, **_FAST)
-        ref_srv.start(block=False)
-        try:
-            assert ref_srv._ready.wait(300)
-            reference = _generate(f'http://127.0.0.1:{port_r}',
-                                  {'prompt': prompt,
-                                   'max_new_tokens': gen})['tokens']
-        finally:
-            ref_srv.stop()
         # Start the stream on the gang; checkpoint mid-flight.
         sr = srv.submit_stream(prompt, max_new_tokens=gen,
                                temperature=0.0, top_k=0, eos_id=None)
@@ -835,7 +823,9 @@ def test_preempt_gang_checkpoint_recover_byte_identical():
                 f'http://127.0.0.1:{port2}',
                 {'prompt': prompt + tokens,
                  'max_new_tokens': gen - len(tokens)})['tokens']
-            assert tokens + cont == reference
+            assert len(tokens + cont) == gen
+            greedy_oracle.assert_server_agrees(prompt, tokens + cont,
+                                               'checkpointed + resumed')
         finally:
             srv2.stop()
     finally:

@@ -182,17 +182,15 @@ def test_mask_nonfinite_tokens_unit():
                             llama.NONFINITE_TOKEN, 0]
 
 
-@pytest.mark.parametrize('kind', ['slot', 'paged'])
-def test_nan_poisoned_params_evict_all(kind):
+def test_nan_poisoned_params_evict_all():
     """Poisoned weights (every logits row NaN): every live request is
     evicted with ``nan_evicted`` — never streamed as argmax-of-NaN
     (which is token 0, silently plausible)."""
     import jax.numpy as jnp
-    from skypilot_tpu.inference.engine import InferenceEngine
     from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    cls = InferenceEngine if kind == 'slot' else PagedInferenceEngine
-    eng = cls(configs.get_config('tiny'), max_batch=2, max_seq=64)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64)
     rid0 = eng.add_request([1, 2, 3, 4], max_new_tokens=4)
     fin = eng.run_to_completion()
     assert len(fin[rid0].output) == 4            # healthy baseline
@@ -216,10 +214,13 @@ def test_nan_blast_radius_is_one_request():
     """Co-batched isolation: when ONE slot's readback carries the
     sentinel, exactly that request is evicted; its neighbor's tokens
     land and the neighbor runs to completion untouched."""
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                          max_seq=64)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64)
+    # On the CPU every result is ready at once: keep the pipeline's lag,
+    # which this test reaches into.
+    eng._eager_drain = False
     ra = eng.add_request([1, 2, 3, 4], max_new_tokens=6)
     rb = eng.add_request([9, 8, 7, 6], max_new_tokens=6)
     # Drive until both are decoding with a pending decode call.

@@ -1,9 +1,9 @@
 """graftcheck part B: the runtime jaxpr-audit regression gate.
 
 Asserts the invariants the serving tier's performance rests on: the
-slot/paged engines' steady-state decode + chunked-prefill loops perform
+paged engine's steady-state decode + chunked-prefill loops perform
 ZERO device->host transfers outside the sanctioned host_sync readback,
-and compile exactly once per (horizon, sample, kv_bucket) key —
+and compile exactly once per (horizon, sample) key and page bucket —
 repeated same-shaped calls never grow the jit caches. A regression here
 is a silent per-step tax in production, which is why it hard-fails in
 CI instead of waiting for a bench round to notice."""
@@ -80,57 +80,34 @@ def _assert_hot_loop_clean(report):
     assert not report.f64_promotions, '\n' + report.format()
 
 
-def test_slot_engine_decode_and_chunked_prefill_audit():
+def test_paged_engine_audit():
     """The decode step and the chunked-prefill step: zero d2h
     transfers outside host_sync, and exactly one compile per static
     key — the caches do not grow across repeated same-shaped calls."""
-    report = jaxpr_audit.audit_engine('slot', chunked=True)
+    report = jaxpr_audit.audit_engine()
     _assert_hot_loop_clean(report)
     # The sanctioned lagged readback itself must still be present
     # (the engine DOES read tokens back — through host_sync).
     assert report.transfers, 'expected sanctioned pipeline readbacks'
     # The audit exercised the chunked-prefill path and the recompile
     # key was observed.
-    assert 'chunk_prefill' in report.compile_counts
-    assert any('kv_bucket' in k for k in report.static_keys)
+    before, after = report.compile_counts['prefill']
+    assert before >= 1 and after == before
+    assert any('horizon' in k for k in report.static_keys)
 
 
-@pytest.mark.slow
-def test_slot_engine_monolithic_audit():
-    _assert_hot_loop_clean(
-        jaxpr_audit.audit_engine('slot', chunked=False))
-
-
-def test_paged_engine_audit():
-    report = jaxpr_audit.audit_engine('paged', chunked=True)
-    _assert_hot_loop_clean(report)
-    assert report.transfers, 'expected sanctioned pipeline readbacks'
-
-
-def test_slot_engine_speculative_audit():
+def test_paged_engine_speculative_audit():
     """The speculative propose→verify→commit steady state: zero d2h
     transfers outside the sanctioned per-round commit sync, and the
-    verify jit cache bounded by the (k, sample, kv_bucket) key set —
-    per-slot variable acceptance rides masked commits, never fresh
-    shapes."""
-    report = jaxpr_audit.audit_engine('slot', chunked=True,
-                                      speculate_k=4)
+    verify jit cache bounded by the (k, sample, P) key set — per-slot
+    variable acceptance rides masked commits, never fresh shapes."""
+    report = jaxpr_audit.audit_engine(speculate_k=4)
     _assert_hot_loop_clean(report)
     assert report.transfers, 'expected sanctioned commit readbacks'
     assert 'spec_verify' in report.compile_counts
     before, after = report.compile_counts['spec_verify']
     assert before >= 1 and after == before
-    assert any('kv_bucket' in k and k.get('k') == 4
-               for k in report.static_keys)
-
-
-def test_paged_engine_speculative_audit():
-    report = jaxpr_audit.audit_engine('paged', chunked=True,
-                                      speculate_k=4)
-    _assert_hot_loop_clean(report)
-    assert 'spec_verify' in report.compile_counts
-    before, after = report.compile_counts['spec_verify']
-    assert before >= 1 and after == before
+    assert any('P' in k and k.get('k') == 4 for k in report.static_keys)
 
 
 def test_llama_forward_jaxpr_audit():
@@ -145,7 +122,7 @@ def test_telemetry_parity_audit():
     transfers, zero steady-state recompiles, and its jit cache is
     byte-for-byte the same SIZE as a telemetry-off run's (profiling is
     host-side around dispatches, never inside programs)."""
-    report = jaxpr_audit.audit_telemetry_parity('slot')
+    report = jaxpr_audit.audit_telemetry_parity()
     assert report.ok(), report.format()
     off, on = report.compile_counts['jit cache size (off vs on)']
     assert off == on and on > 0
@@ -154,36 +131,23 @@ def test_telemetry_parity_audit():
     assert not report.unsanctioned_transfers
 
 
-@pytest.mark.slow
-def test_telemetry_parity_audit_paged():
-    report = jaxpr_audit.audit_telemetry_parity('paged')
-    assert report.ok(), report.format()
-
-
 def test_kv_int8_paged_audit():
     """int8 KV over bf16 weights (the decoupled kv_cache_dtype path):
     quantize-on-write in the chunked-prefill and decode scans plus the
     fused-dequant reads add zero unsanctioned d2h transfers and zero
     steady-state recompiles — the jit key set stays what the bf16
     engine observes."""
-    report = jaxpr_audit.audit_engine('paged', chunked=True,
-                                      kv_cache_dtype='int8')
+    report = jaxpr_audit.audit_engine(kv_cache_dtype='int8')
     _assert_hot_loop_clean(report)
     assert report.transfers, 'expected sanctioned pipeline readbacks'
 
 
-@pytest.mark.slow
-def test_kv_int8_slot_audit():
-    report = jaxpr_audit.audit_engine('slot', chunked=True,
-                                      kv_cache_dtype='int8')
-    _assert_hot_loop_clean(report)
-    assert any('kv_bucket' in k for k in report.static_keys)
-
-
 def test_kv_int8_presets_registered():
-    """The kv-int8 presets gate CI through the default preset list."""
+    """The kv-int8 preset gates CI through the default preset list,
+    which holds no preset of the deleted slot engine."""
     assert 'kv-int8' in jaxpr_audit.PRESETS
-    assert 'kv-int8-slot' in jaxpr_audit.PRESETS
+    assert 'kv-int8' in jaxpr_audit.DEFAULT_PRESETS
+    assert not [n for n in jaxpr_audit.PRESETS if 'slot' in n]
 
 
 # ----------------------------------------------------- prefix digest
@@ -225,7 +189,7 @@ def test_paged_tp_audit():
     ring-shaped gather appearing here means an output sharding stopped
     matching the next step's input sharding."""
     _need_devices(2)
-    report = jaxpr_audit.audit_engine('paged', chunked=True, mesh_tp=2)
+    report = jaxpr_audit.audit_engine(mesh_tp=2)
     _assert_hot_loop_clean(report)
     assert report.collectives, 'tp preset must census collectives'
     assert report.collective_violations() == [], report.format()
@@ -237,7 +201,7 @@ def test_paged_tp_audit():
 @pytest.mark.slow
 def test_paged_tp_int8_audit():
     _need_devices(2)
-    report = jaxpr_audit.audit_engine('paged', chunked=True, mesh_tp=2,
+    report = jaxpr_audit.audit_engine(mesh_tp=2,
                                       kv_cache_dtype='int8')
     _assert_hot_loop_clean(report)
     assert report.collective_violations() == [], report.format()
@@ -289,18 +253,9 @@ def test_int4_paged_audit():
     """int4 fused-dequant weights: the packed-nibble unpack inside
     qeinsum adds zero unsanctioned d2h and zero steady-state jit-cache
     growth on the paged hot loop (the `int4` default preset)."""
-    report = jaxpr_audit.audit_engine('paged', chunked=True,
-                                      quantize='int4')
+    report = jaxpr_audit.audit_engine(quantize='int4')
     _assert_hot_loop_clean(report)
     assert report.transfers, 'expected sanctioned pipeline readbacks'
-
-
-@pytest.mark.slow
-def test_int4_slot_audit():
-    report = jaxpr_audit.audit_engine('slot', chunked=True,
-                                      quantize='int4')
-    _assert_hot_loop_clean(report)
-    assert any('kv_bucket' in k for k in report.static_keys)
 
 
 def test_multistep_audit():
@@ -329,7 +284,7 @@ def test_int4_multistep_presets_registered():
     for name in ('int4', 'multistep', 'int4-multistep'):
         assert name in jaxpr_audit.PRESETS, name
         assert name in jaxpr_audit.DEFAULT_PRESETS, name
-    assert 'int4-slot' in jaxpr_audit.PRESETS
+
 
 # ------------------------------------------------------ KV round two
 def test_kv_int4_paged_audit():
@@ -337,18 +292,9 @@ def test_kv_int4_paged_audit():
     quantize-on-write plus the in-kernel fused-dequant reads add zero
     unsanctioned d2h and zero steady-state jit-cache growth — halving
     KV bytes must not buy a single host round-trip."""
-    report = jaxpr_audit.audit_engine('paged', chunked=True,
-                                      kv_cache_dtype='int4')
+    report = jaxpr_audit.audit_engine(kv_cache_dtype='int4')
     _assert_hot_loop_clean(report)
     assert report.transfers, 'expected sanctioned pipeline readbacks'
-
-
-@pytest.mark.slow
-def test_kv_int4_slot_audit():
-    report = jaxpr_audit.audit_engine('slot', chunked=True,
-                                      kv_cache_dtype='int4')
-    _assert_hot_loop_clean(report)
-    assert any('kv_bucket' in k for k in report.static_keys)
 
 
 def test_fused_attn_audit():
@@ -356,8 +302,7 @@ def test_fused_attn_audit():
     folding the ring+current-token merge into the kernel's final grid
     step must be free at the dispatch boundary — same transfer and
     recompile gates as the stock paged preset."""
-    report = jaxpr_audit.audit_engine('paged', chunked=True,
-                                      decode_impl='cross_layer')
+    report = jaxpr_audit.audit_engine(decode_impl='cross_layer')
     _assert_hot_loop_clean(report)
     assert report.transfers, 'expected sanctioned pipeline readbacks'
 
@@ -381,7 +326,6 @@ def test_spec_multistep_audit():
 
 
 def test_kv_round2_presets_registered():
-    for name in ('kv-int4', 'kv-int4-slot', 'fused-attn',
-                 'spec-multistep'):
+    for name in ('kv-int4', 'fused-attn', 'spec-multistep'):
         assert name in jaxpr_audit.PRESETS, name
         assert name in jaxpr_audit.DEFAULT_PRESETS, name

@@ -11,27 +11,28 @@ Contracts pinned here:
 - **Fused merge (op level).** ``paged_decode_attention_fused`` (cache
   pages + ring + current token in ONE kernel) matches the per-layer
   partial + ``merge_partial_with_ring_self`` XLA merge to float ulps.
-- **``decode_impl='cross_layer'`` (engine level).** Greedy decode is
-  byte-identical to ``gather`` and ``pallas`` across every KV dtype.
+- **``decode_impl`` (engine level).** ``gather``, ``pallas`` and
+  ``cross_layer`` greedy decode each emit the plain forward's choices
+  (``greedy_oracle``) across every KV dtype.
 - **int4 KV.** Packed uint8 nibble pools (head_dim/2 minor) with
-  absmax/7 scales serve byte-identically to bf16 KV on the tiny model,
-  and the full divergence matrix (chunked prefill, prefix-cache reuse,
-  speculative commits) holds in the slow tier.
+  absmax/7 scales serve the reference's choices to int4 rounding, and
+  the matrix (chunked prefill, prefix-cache reuse, speculative
+  commits) holds in the slow tier.
 - **In-scan speculative verify.** ``speculate_k`` composed with
   ``decode_steps_per_call > 1`` fuses that many propose→verify→commit
-  rounds into ONE dispatch; greedy output stays byte-identical to
-  vanilla decode AND to single-round speculation on both engines; the
-  device n-gram proposer matches the host proposer on the windowed
-  history; paged pool pressure falls back to single-round verify with
-  no output change.
+  rounds into ONE dispatch; vanilla, single-round and fused decode are
+  three programs, each held to the oracle; the device n-gram proposer
+  matches the host proposer on the windowed history; pool pressure
+  falls back to single-round verify and requests still complete with
+  the reference's tokens.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from skypilot_tpu.inference.engine import (InferenceEngine,
-                                           kv_token_bytes,
+import greedy_oracle
+from skypilot_tpu.inference.engine import (kv_token_bytes,
                                            resolve_kv_cache_dtype)
 from skypilot_tpu.inference.paged import PagedInferenceEngine, PagedKVCache
 from skypilot_tpu.models import configs, llama
@@ -53,13 +54,18 @@ def setup():
     return cfg, params
 
 
-def _greedy(engcls, cfg, params, prompts, n_new, **kw):
-    eng = engcls(cfg, params, max_batch=len(prompts), max_seq=64,
-                 attn_impl='xla', **kw)
-    rids = [eng.add_request(list(p), max_new_tokens=n_new)
-            for p in prompts]
-    done = eng.run_to_completion(horizon=2)
-    return [done[r].output for r in rids], eng
+def _greedy(cfg, params, prompts, n_new, max_batch=None, max_seq=64,
+            horizon=2, **kw):
+    eng = PagedInferenceEngine(cfg, params,
+                               max_batch=max_batch or len(prompts),
+                               max_seq=max_seq, attn_impl='xla', **kw)
+    return greedy_oracle.greedy(eng, prompts, n_new, horizon=horizon), eng
+
+
+def _assert_agree(cfg, params, prompts, outs, n_new, kv_dtype='bf16',
+                  what=''):
+    greedy_oracle.assert_all_agree(cfg, params, prompts, outs, what,
+                                   greedy_oracle.KV_KIND[kv_dtype], n_new)
 
 
 # ---------------------------------------------------------------------------
@@ -221,31 +227,27 @@ def test_fused_kernel_matches_xla_merge(mode):
 
 @pytest.mark.parametrize('dtype', ['bf16', 'int8', 'int4'])
 def test_cross_layer_engine_identity(setup, dtype):
-    """``decode_impl='cross_layer'`` greedy decode is byte-identical to
-    ``gather`` and ``pallas`` for every KV dtype (the fused kernel is
-    the same math, one dispatch fewer per layer)."""
+    """``decode_impl`` 'gather', 'pallas' and 'cross_layer' (the same
+    math in three programs) each decode the reference's choices, for
+    every KV dtype."""
     cfg, params = setup
-    outs = {}
     for impl in ('gather', 'pallas', 'cross_layer'):
-        outs[impl], _ = _greedy(
-            PagedInferenceEngine, cfg, params, PROMPTS, 5,
-            page_size=8, kv_cache_dtype=dtype, decode_impl=impl)
-    assert outs['cross_layer'] == outs['gather'], dtype
-    assert outs['pallas'] == outs['gather'], dtype
+        outs, _ = _greedy(cfg, params, PROMPTS, 5, page_size=8,
+                          kv_cache_dtype=dtype, decode_impl=impl)
+        _assert_agree(cfg, params, PROMPTS, outs, 5, dtype,
+                      f'{impl}/{dtype}')
 
 
 def test_int4_greedy_smoke(setup):
-    """Tier-1 smoke: int4 KV greedy decode matches bf16 KV on both
-    engines (tiny model; the divergence matrix rides the slow tier)."""
+    """Tier-1 smoke: greedy decode over int4 KV emits the reference's
+    choices to int4 rounding, as bf16 KV does to bf16's (tiny model;
+    the matrix rides the slow tier)."""
     cfg, params = setup
-    for engcls, kw in ((InferenceEngine, {}),
-                       (PagedInferenceEngine, {'page_size': 8})):
-        bf, _ = _greedy(engcls, cfg, params, PROMPTS, 8,
-                        kv_cache_dtype='bf16', **kw)
-        i4, eng = _greedy(engcls, cfg, params, PROMPTS, 8,
-                          kv_cache_dtype='int4', **kw)
-        assert i4 == bf, engcls.__name__
-        assert eng.cache.packed and eng.kv_cache_dtype == 'int4'
+    for dtype in ('bf16', 'int4'):
+        outs, eng = _greedy(cfg, params, PROMPTS, 8, page_size=8,
+                            kv_cache_dtype=dtype)
+        _assert_agree(cfg, params, PROMPTS, outs, 8, dtype, dtype)
+    assert eng.cache.packed and eng.kv_cache_dtype == 'int4'
 
 
 # ---------------------------------------------------------------------------
@@ -275,26 +277,26 @@ def test_ngram_propose_device_matches_host():
         assert (np.asarray(prop)[0, m:] == 0).all()
 
 
-@pytest.mark.parametrize('engcls,kw', [
-    (InferenceEngine, {}),
-    (PagedInferenceEngine, {'page_size': 8, 'decode_impl': 'gather'}),
-])
-def test_spec_fused_byte_identity(setup, engcls, kw):
-    """THE composition contract: speculate_k x decode_steps_per_call
-    fused rounds commit byte-identically to vanilla greedy decode AND
-    to single-round speculation — the in-scan device proposer and
-    budget carry change dispatch count only, never tokens."""
+SPEC_PROMPTS = [REPETITIVE[:16], [2, 7, 2, 7, 2, 7, 2, 7]]
+SPEC_KW = {'page_size': 8, 'decode_impl': 'gather'}
+
+
+def test_spec_fused_agrees_with_oracle(setup):
+    """THE composition contract: vanilla greedy decode, single-round
+    speculation and speculate_k x decode_steps_per_call fused rounds all
+    commit the reference's choices — the in-scan device proposer and
+    budget carry change the dispatch count, never what is accepted."""
     cfg, params = setup
-    prompts = [REPETITIVE[:16], [2, 7, 2, 7, 2, 7, 2, 7]]
-    base, _ = _greedy(engcls, cfg, params, prompts, 12, **kw)
-    single, e1 = _greedy(engcls, cfg, params, prompts, 12,
-                         speculate_k=3, **kw)
-    fused, e2 = _greedy(engcls, cfg, params, prompts, 12,
-                        speculate_k=3, decode_steps_per_call=3, **kw)
-    assert single == base
-    assert fused == base
+    engines = {}
+    for what, kw in (('vanilla', {}), ('single', {'speculate_k': 3}),
+                     ('fused', {'speculate_k': 3,
+                                'decode_steps_per_call': 3})):
+        outs, engines[what] = _greedy(cfg, params, SPEC_PROMPTS, 12,
+                                      **SPEC_KW, **kw)
+        _assert_agree(cfg, params, SPEC_PROMPTS, outs, 12, what=what)
     # Both paths accept drafts on the repetitive prompts, and the
     # stable metrics schema keeps reporting.
+    e1, e2 = engines['single'], engines['fused']
     assert e1.spec_metrics()['spec_accepted'] > 0
     assert e2.spec_metrics()['spec_accepted'] > 0
     assert e2.spec_metrics()['spec_rounds'] >= e2.spec_metrics()[
@@ -303,46 +305,35 @@ def test_spec_fused_byte_identity(setup, engcls, kw):
 
 def test_spec_fused_int4_composes(setup):
     """All three fronts at once: int4 KV + fused spec rounds still
-    match the bf16 vanilla output on the tiny model."""
+    commit the reference's choices, to int4 rounding."""
     cfg, params = setup
-    prompts = [REPETITIVE[:16], [2, 7, 2, 7, 2, 7, 2, 7]]
-    want, _ = _greedy(PagedInferenceEngine, cfg, params, prompts, 10,
-                      page_size=8, decode_impl='gather')
-    got, eng = _greedy(PagedInferenceEngine, cfg, params, prompts, 10,
-                       page_size=8, decode_impl='gather',
+    got, eng = _greedy(cfg, params, SPEC_PROMPTS, 10,
                        kv_cache_dtype='int4', speculate_k=3,
-                       decode_steps_per_call=3)
-    assert got == want
+                       decode_steps_per_call=3, **SPEC_KW)
+    _assert_agree(cfg, params, SPEC_PROMPTS, got, 10, 'int4',
+                  'int4 KV + fused spec')
     assert eng.cache.packed
 
 
 def test_spec_fused_pool_pressure_fallback(setup):
     """When the pool cannot reserve rounds x (k+1) rows up front, the
-    fused step falls back to single-round verify — output unchanged,
-    requests complete."""
+    fused step falls back to single-round verify — requests complete,
+    with the reference's tokens."""
     cfg, params = setup
-    prompts = [REPETITIVE[:16], [2, 7, 2, 7, 2, 7, 2, 7]]
-    want, _ = _greedy(PagedInferenceEngine, cfg, params, prompts, 10,
-                      page_size=8, decode_impl='gather')
-    eng = PagedInferenceEngine(cfg, params, max_batch=2, max_seq=64,
-                               page_size=8, n_pages=10,
-                               attn_impl='xla', decode_impl='gather',
-                               speculate_k=3, decode_steps_per_call=4)
-    rids = [eng.add_request(list(p), max_new_tokens=10)
-            for p in prompts]
-    done = eng.run_to_completion(horizon=2)
-    assert [done[r].output for r in rids] == want
+    got, _ = _greedy(cfg, params, SPEC_PROMPTS, 10, n_pages=10,
+                     speculate_k=3, decode_steps_per_call=4, **SPEC_KW)
+    _assert_agree(cfg, params, SPEC_PROMPTS, got, 10,
+                  what='fused spec under pool pressure')
 
 
 def test_spec_fused_budget_respected(setup):
     """The in-scan ``rem`` carry never overshoots ``max_new_tokens``
     even when rounds x (k+1) far exceeds the remaining budget."""
     cfg, params = setup
-    got, _ = _greedy(InferenceEngine, cfg, params, [REPETITIVE[:16]],
-                     3, speculate_k=4, decode_steps_per_call=4)
-    want, _ = _greedy(InferenceEngine, cfg, params, [REPETITIVE[:16]],
-                      3)
-    assert got == want and len(got[0]) == 3
+    got, _ = _greedy(cfg, params, [REPETITIVE[:16]], 3, speculate_k=4,
+                     decode_steps_per_call=4)
+    _assert_agree(cfg, params, [REPETITIVE[:16]], got, 3,
+                  what='fused spec at the budget')
 
 
 # ---------------------------------------------------------------------------
@@ -355,62 +346,24 @@ MATRIX_PROMPTS = [[3, 1, 4, 1, 5], [2, 7, 1, 8, 2, 8, 1, 8],
 @pytest.mark.slow
 class TestKVInt4Equivalence:
 
-    def _greedy4(self, engcls, cfg, params, prompts, n_new, **kw):
-        eng = engcls(cfg, params, max_batch=4, max_seq=256,
-                     attn_impl='xla', **kw)
-        rids = [eng.add_request(list(p), max_new_tokens=n_new)
-                for p in prompts]
-        done = eng.run_to_completion(horizon=4)
-        return [done[r].output for r in rids], eng
-
-    def test_slot_chunked_prefill(self, setup):
-        """Chunking contract under int4: prompts that fit in ONE chunk
-        are byte-identical chunked vs monolithic (chunking is a no-op);
-        for longer prompts later chunks attend over already-quantized
-        rows where monolithic prefill rides full precision in-window —
-        a REAL int4 perturbation, so the pin is first-token agreement
-        and completion, not byte identity. (int8's finer grid kept the
-        tiny model's argmax stable; int4's 15-level grid does not —
-        divergence on random-init weights is the quantization error
-        itself, same philosophy as test_int4.)"""
-        cfg, params = setup
-        i4, _ = self._greedy4(InferenceEngine, cfg, params,
-                              MATRIX_PROMPTS, 12,
-                              kv_cache_dtype='int4',
-                              prefill_chunk_tokens=16)
-        mono, _ = self._greedy4(InferenceEngine, cfg, params,
-                                MATRIX_PROMPTS, 12,
-                                kv_cache_dtype='int4',
-                                prefill_chunk_tokens=0)
-        assert i4[0] == mono[0] and i4[1] == mono[1]   # <= one chunk
-        assert i4[2][0] == mono[2][0]                  # 60-token prompt
-        assert all(len(o) == 12 for o in i4)
-        # Against bf16 KV the short prompts keep a long exact prefix.
-        bf, _ = self._greedy4(InferenceEngine, cfg, params,
-                              MATRIX_PROMPTS, 12,
-                              kv_cache_dtype='bf16',
-                              prefill_chunk_tokens=16)
-        for a, b in zip(i4[:2], bf[:2]):
-            agree = sum(x == y for x, y in zip(a, b))
-            assert agree >= 8, (a, b)
+    def _greedy4(self, cfg, params, prompts, n_new, **kw):
+        return _greedy(cfg, params, prompts, n_new, max_batch=4,
+                       max_seq=256, horizon=4, **kw)
 
     def test_paged_chunked_prefill(self, setup):
-        """Same contract on the paged pool: chunk-size invariance for
-        sub-chunk prompts, first-token agreement beyond, and the chunk
-        counter proves the 60-token prompt actually chunked."""
+        """Chunking under int4: later chunks attend over already
+        quantized rows, so chunk widths 16 and 8 are different programs
+        with a REAL int4 perturbation between them; each is held to the
+        oracle at int4 rounding, and the chunk counter proves the
+        60-token prompt actually chunked."""
         cfg, params = setup
-        c16, eng = self._greedy4(PagedInferenceEngine, cfg, params,
-                                 MATRIX_PROMPTS, 12,
-                                 kv_cache_dtype='int4', page_size=8,
-                                 chunk=16)
-        c8, _ = self._greedy4(PagedInferenceEngine, cfg, params,
-                              MATRIX_PROMPTS, 12,
-                              kv_cache_dtype='int4', page_size=8,
-                              chunk=8)
-        assert c16[0] == c8[0]                 # 5 tokens: <= any chunk
-        assert c16[2][0] == c8[2][0]
-        assert all(len(o) == 12 for o in c16)
-        assert eng.chunks_prefilled >= 4       # 60-token prompt, chunk 16
+        for chunk, n_chunks in ((16, 4), (8, 8)):
+            outs, eng = self._greedy4(cfg, params, MATRIX_PROMPTS, 12,
+                                      kv_cache_dtype='int4', page_size=8,
+                                      chunk=chunk)
+            _assert_agree(cfg, params, MATRIX_PROMPTS, outs, 12, 'int4',
+                          f'chunk {chunk}')
+            assert eng.chunks_prefilled >= n_chunks
 
     def test_prefix_cache_reuse(self, setup):
         """THE reuse contract: a prefix HIT serving from already-packed
@@ -420,9 +373,9 @@ class TestKVInt4Equivalence:
         cfg, params = setup
         shared = [(i * 5 + 2) % 256 for i in range(64)]
         p1, p2 = shared + [11, 12], shared + [13, 14, 15]
-        cold, _ = self._greedy4(PagedInferenceEngine, cfg, params,
-                                [p2], 8, kv_cache_dtype='int4',
-                                page_size=8, chunk=16)
+        cold, _ = self._greedy4(cfg, params, [p2], 8,
+                                kv_cache_dtype='int4', page_size=8,
+                                chunk=16)
         eng = PagedInferenceEngine(cfg, params, max_batch=1,
                                    max_seq=256, page_size=8, chunk=16,
                                    attn_impl='xla',
@@ -436,22 +389,14 @@ class TestKVInt4Equivalence:
         assert done[r2].output == cold[0]
 
     def test_speculative_commits(self, setup):
-        """Spec verify with int4 KV: bounded divergence (in-window
-        verify rows ride full precision vs requantized vanilla rows —
-        same contract as int8 KV), nonzero acceptance."""
+        """Spec verify with int4 KV (in-window verify rows ride full
+        precision where vanilla rows are requantized): the reference's
+        choices to int4 rounding, nonzero acceptance."""
         cfg, params = setup
-        for engcls, kw in ((InferenceEngine, {}),
-                           (PagedInferenceEngine, {'page_size': 8})):
-            want, _ = self._greedy4(engcls, cfg, params,
-                                    [REPETITIVE, MATRIX_PROMPTS[2]],
-                                    16, kv_cache_dtype='int4', **kw)
-            got, eng = self._greedy4(engcls, cfg, params,
-                                     [REPETITIVE, MATRIX_PROMPTS[2]],
-                                     16, kv_cache_dtype='int4',
-                                     speculate_k=4, **kw)
-            for a, b in zip(want, got):
-                assert a[:10] == b[:10], engcls.__name__
-                agree = sum(x == y for x, y in zip(a, b))
-                assert agree >= int(0.85 * len(a)), (engcls.__name__,
-                                                     a, b)
-            assert eng.spec_metrics()['spec_accepted'] > 0
+        prompts = [REPETITIVE, MATRIX_PROMPTS[2]]
+        got, eng = self._greedy4(cfg, params, prompts, 16,
+                                 kv_cache_dtype='int4', page_size=8,
+                                 speculate_k=4)
+        _assert_agree(cfg, params, prompts, got, 16, 'int4',
+                      'spec over int4 KV')
+        assert eng.spec_metrics()['spec_accepted'] > 0

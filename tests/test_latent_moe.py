@@ -391,14 +391,12 @@ def test_wrong_mathematics_fails_the_tolerance(variant, dtype):
     ({'adapter_slots': 2}, 'adapter_slots'),
     ({'mesh': 'tp2'}, 'mesh'),
     ({'decode_impl': 'cross_layer'}, 'decode_impl'),
-    ({'engine': 'slot'}, 'engine'),
     ({'call': 'export'}, 'KV export/ingest'),
     ({'call': 'ingest'}, 'KV export/ingest'),
 ])
 def test_refused_with_its_reason(kwargs, reason):
     """What the model cannot yet be combined with raises where it is
     asked for, naming what and why; nothing fails silently."""
-    from skypilot_tpu.inference.engine import InferenceEngine
     from skypilot_tpu.inference.paged import PagedInferenceEngine
     cfg, params = make('float32')
     kwargs = dict(kwargs)
@@ -406,10 +404,6 @@ def test_refused_with_its_reason(kwargs, reason):
         from skypilot_tpu.parallel import mesh as mesh_lib
         kwargs['mesh'] = mesh_lib.serving_mesh(2, 1)
     base = dict(params=params, max_batch=2, max_seq=32)
-    if kwargs.pop('engine', None) == 'slot':
-        with pytest.raises(ValueError, match=reason):
-            InferenceEngine(cfg, **base)
-        return
     call = kwargs.pop('call', None)
     if call is None:
         with pytest.raises(ValueError, match=reason):

@@ -110,10 +110,10 @@ def test_engine_queue_pop_orders_by_priority_fifo_within():
     urgent (lowest priority) first, FIFO within a class — a paged
     preemption requeue cannot park a latency request behind newly
     queued throughput work."""
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                          max_seq=64)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64)
     ids = [eng.add_request([1, 2, 3], max_new_tokens=2, priority=p)
            for p in (1, 0, 1, 0)]
     popped = [eng._queue_pop().request_id for _ in range(4)]
@@ -380,13 +380,12 @@ def _post(port, payload, timeout=60, headers=None):
     return urllib.request.urlopen(req, timeout=timeout)
 
 
-@pytest.fixture(params=['slot', 'paged'])
-def tiny_server(request):
+@pytest.fixture()
+def tiny_server():
     from skypilot_tpu.serve.server import ModelServer
     from skypilot_tpu.utils import common_utils
     port = common_utils.find_free_port(19300)
-    server = ModelServer('tiny', max_batch=2, max_seq=64, port=port,
-                         kv_cache=request.param)
+    server = ModelServer('tiny', max_batch=2, max_seq=64, port=port)
     server.start(block=False)
     assert server._ready.wait(180)
     yield server
@@ -482,7 +481,7 @@ def test_latency_tier_ttft_bounded_under_overload():
     from skypilot_tpu.utils import common_utils
     port = common_utils.find_free_port(19400)
     server = ModelServer('tiny', max_batch=2, max_seq=128, port=port,
-                         kv_cache='paged', max_queue_tokens=100_000)
+                         max_queue_tokens=100_000)
     server.start(block=False)
     try:
         assert server._ready.wait(180)

@@ -454,30 +454,24 @@ class TestCheckpointCodec:
 
 
 # ------------------------------------------- engine checkpoint/recovery
-def _make_engine(kind, **kw):
+def _make_engine(**kw):
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    cfg = configs.get_config('tiny')
-    if kind == 'paged':
-        from skypilot_tpu.inference.paged import PagedInferenceEngine
-        return PagedInferenceEngine(cfg, max_batch=2, max_seq=256,
-                                    telemetry=False, **kw)
-    from skypilot_tpu.inference.engine import InferenceEngine
-    return InferenceEngine(cfg, max_batch=2, max_seq=256,
-                           telemetry=False, **kw)
+    return PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                                max_seq=256, telemetry=False, **kw)
 
 
 SHARED_PREFIX = [7 + (j % 50) for j in range(40)]
 
 
-@pytest.mark.parametrize('kind', ['slot', 'paged'])
-def test_preempt_checkpoint_recover_byte_identical(kind):
-    """The full preemption->checkpoint->recovery loop at engine level,
-    both engines: a request mid-decode checkpoints (SKKV) and resumes
-    BYTE-IDENTICALLY on a fresh engine; on the paged engine the hot
-    prefix chains additionally checkpoint (SKPF) and a warmed fresh
-    engine serves a shared-prefix prompt with a prefix HIT and the
-    identical continuation."""
-    eng = _make_engine(kind)
+def test_preempt_checkpoint_recover_byte_identical():
+    """The full preemption->checkpoint->recovery loop at engine level:
+    a request mid-decode checkpoints (SKKV) and resumes
+    BYTE-IDENTICALLY on a fresh engine; the hot prefix chains
+    additionally checkpoint (SKPF) and a warmed fresh engine serves a
+    shared-prefix prompt with a prefix HIT and the identical
+    continuation."""
+    eng = _make_engine()
     prompt = SHARED_PREFIX + [3, 4, 5]
     rid = eng.add_request(list(prompt), max_new_tokens=12)
     while True:
@@ -488,11 +482,9 @@ def test_preempt_checkpoint_recover_byte_identical(kind):
             break
     snap, _ = eng.export_kv_snapshot(rid)
     assert snap is not None
-    entries = [snap]
-    if kind == 'paged':
-        pentries, _ = eng.export_prefix_snapshots()
-        assert pentries, 'hot prefix chains must export'
-        entries += pentries
+    pentries, _ = eng.export_prefix_snapshots()
+    assert pentries, 'hot prefix chains must export'
+    entries = [snap] + pentries
     blob = kv_transfer.encode_checkpoint(entries)
     # Reference: the uninterrupted run.
     eng.run_to_completion(horizon=8)
@@ -500,22 +492,18 @@ def test_preempt_checkpoint_recover_byte_identical(kind):
 
     decoded = kv_transfer.decode_checkpoint(blob)
     # (a) In-flight resume: byte-identical continuation on a FRESH
-    # engine (both engines).
-    eng2 = _make_engine(kind)
+    # engine.
+    eng2 = _make_engine()
     req_entry = next(e for e in decoded
                      if e['entry_kind'] == 'request')
     rid2 = eng2.ingest_kv_snapshot(req_entry)
     eng2.run_to_completion(horizon=8)
     assert list(eng2.pop_finished(rid2).output) == ref
 
-    # (b) Prefix warmup: a warmed fresh paged engine prefix-HITS the
-    # shared prefix and continues byte-identically; the slot engine
-    # honestly lands nothing (no prefix cache).
-    eng3 = _make_engine(kind)
+    # (b) Prefix warmup: a warmed fresh engine prefix-HITS the shared
+    # prefix and continues byte-identically.
+    eng3 = _make_engine()
     rows = sum(eng3.warm_prefix(e) for e in decoded)
-    if kind == 'slot':
-        assert rows == 0
-        return
     assert rows > 0
     hits0 = eng3.alloc.prefix_hits
     rid3 = eng3.add_request(list(prompt), max_new_tokens=12)
@@ -530,14 +518,14 @@ def test_preempt_checkpoint_recover_byte_identical(kind):
 
 
 def test_warm_prefix_idempotent_and_validated():
-    eng = _make_engine('paged')
+    eng = _make_engine()
     prompt = SHARED_PREFIX + [9, 9]
     rid = eng.add_request(list(prompt), max_new_tokens=4)
     eng.run_to_completion(horizon=8)
     eng.pop_finished(rid)
     entries, _ = eng.export_prefix_snapshots()
     assert entries
-    eng2 = _make_engine('paged')
+    eng2 = _make_engine()
     assert sum(eng2.warm_prefix(e) for e in entries) > 0
     # Idempotent: a second warmup of the same chains lands nothing.
     assert sum(eng2.warm_prefix(e) for e in entries) == 0
@@ -550,7 +538,7 @@ def test_warm_prefix_idempotent_and_validated():
 
 def test_warm_prefix_capacity_refusal_is_retryable():
     from skypilot_tpu.inference.kv_transfer import HandoffCapacityError
-    eng = _make_engine('paged')
+    eng = _make_engine()
     long_prompt = [3 + (j % 90) for j in range(150)]
     rid = eng.add_request(list(long_prompt), max_new_tokens=4)
     eng.run_to_completion(horizon=8)
@@ -558,7 +546,7 @@ def test_warm_prefix_capacity_refusal_is_retryable():
     entries, _ = eng.export_prefix_snapshots()
     assert entries
     # A pool too small for the chain refuses retryably.
-    tiny = _make_engine('paged', n_pages=3)
+    tiny = _make_engine(n_pages=3)
     with pytest.raises(HandoffCapacityError):
         for e in entries:
             tiny.warm_prefix(e)

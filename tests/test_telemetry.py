@@ -259,21 +259,15 @@ def _span_names(trace):
     return [s['name'] for s in trace.to_dict()['spans']]
 
 
-@pytest.mark.parametrize('kind', ['slot', 'paged'])
-def test_request_trace_span_order_e2e(kind):
+def test_request_trace_span_order_e2e():
     """A finished request's trace holds queue → prefill (with per-chunk
     spans) → decode in order, all durations non-negative, published
     exactly once to the ring buffer."""
     from skypilot_tpu.models import configs
     cfg = configs.get_config('tiny')
-    if kind == 'paged':
-        from skypilot_tpu.inference.paged import PagedInferenceEngine
-        eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64,
-                                   prefill_chunk_tokens=8)
-    else:
-        from skypilot_tpu.inference.engine import InferenceEngine
-        eng = InferenceEngine(cfg, max_batch=2, max_seq=64,
-                              prefill_chunk_tokens=8)
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
+    eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64,
+                               prefill_chunk_tokens=8)
     rid = eng.add_request([1, 2, 3] * 7, max_new_tokens=5)
     done = eng.run_to_completion(horizon=8)
     assert rid in done
@@ -296,10 +290,10 @@ def test_request_trace_span_order_e2e(kind):
 
 
 def test_trace_cancel_publishes_trace():
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                          max_seq=64)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64)
     rid = eng.add_request([1, 2, 3, 4], max_new_tokens=30)
     eng.step(horizon=1)
     assert eng.cancel(rid)
@@ -309,11 +303,11 @@ def test_trace_cancel_publishes_trace():
 
 
 def test_telemetry_off_no_traces_no_phases():
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
     before = len(tracing.get_trace_buffer())
-    eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                          max_seq=64, telemetry=False)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64, telemetry=False)
     rid = eng.add_request([1, 2, 3], max_new_tokens=3)
     done = eng.run_to_completion(horizon=4)
     assert rid in done and done[rid].trace is None
@@ -324,10 +318,10 @@ def test_telemetry_off_no_traces_no_phases():
 def test_chrome_trace_export(tmp_path):
     """Completed traces export as a chrome://tracing file via the
     utils/timeline.py writer."""
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                          max_seq=64, prefill_chunk_tokens=8)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64, prefill_chunk_tokens=8)
     rid = eng.add_request([5, 6, 7] * 5, max_new_tokens=4)
     eng.run_to_completion(horizon=8)
     out = tmp_path / 'req_trace.json'
@@ -345,10 +339,10 @@ def test_chrome_trace_export(tmp_path):
 def test_step_phase_profiler_and_compile_events():
     """The engine records per-phase wall time and one first-call event
     per distinct jit key (steady state adds none)."""
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
-    eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                          max_seq=64, prefill_chunk_tokens=8)
+    eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                               max_seq=64, prefill_chunk_tokens=8)
     for _ in range(2):
         eng.add_request([1, 2, 3] * 7, max_new_tokens=4)
         eng.run_to_completion(horizon=8)
@@ -370,12 +364,12 @@ def test_kv_round2_series_registered_at_construction():
     the KV read-traffic gauge in the registry — zero from the first
     scrape, before any decode dispatch."""
     from skypilot_tpu import telemetry
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
     registry_lib.reset_registry()
     try:
-        InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                        max_seq=64)
+        PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                             max_seq=64)
         prom = telemetry.get_registry().render_prometheus()
     finally:
         registry_lib.reset_registry()
@@ -387,8 +381,7 @@ def test_kv_round2_series_registered_at_construction():
     assert 'skytpu_engine_decode_substeps_total 0' in prom
 
 
-@pytest.mark.parametrize('kind', ['slot', 'paged'])
-def test_kv_round2_series_updated_by_decode(kind):
+def test_kv_round2_series_updated_by_decode():
     """After decode traffic the KV read gauge carries live-context x
     per-token bytes, and live rows / substeps is the mean live batch
     of a step (one request in two slots: exactly 1)."""
@@ -398,13 +391,9 @@ def test_kv_round2_series_updated_by_decode(kind):
     registry_lib.reset_registry()
     try:
         cfg = configs.get_config('tiny')
-        if kind == 'paged':
-            from skypilot_tpu.inference.paged import PagedInferenceEngine
-            eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64,
-                                       decode_impl='gather')
-        else:
-            from skypilot_tpu.inference.engine import InferenceEngine
-            eng = InferenceEngine(cfg, max_batch=2, max_seq=64)
+        from skypilot_tpu.inference.paged import PagedInferenceEngine
+        eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64,
+                                   decode_impl='gather')
         eng.add_request([1, 2, 3, 4, 5], max_new_tokens=4)
         eng.run_to_completion(horizon=4)
         reg = telemetry.get_registry()
@@ -427,12 +416,12 @@ def test_adapter_series_registered_at_construction():
     CONSTRUCTION — zeros (and full free slots) from the first scrape,
     before any adapter ever loads."""
     from skypilot_tpu import telemetry
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
     registry_lib.reset_registry()
     try:
-        InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                        max_seq=64, adapter_slots=3, adapter_rank=4)
+        PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                             max_seq=64, adapter_slots=3, adapter_rank=4)
         prom = telemetry.get_registry().render_prometheus()
     finally:
         registry_lib.reset_registry()
@@ -454,13 +443,13 @@ def test_adapter_series_updated_by_traffic():
     seen."""
     import numpy as np
     from skypilot_tpu import telemetry
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs, multilora
     registry_lib.reset_registry()
     try:
         cfg = configs.get_config('tiny')
-        eng = InferenceEngine(cfg, max_batch=2, max_seq=64,
-                              adapter_slots=2, adapter_rank=4)
+        eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64,
+                                   adapter_slots=2, adapter_rank=4)
         reg = eng.adapters
         rng = np.random.default_rng(0)
         for i in range(3):
@@ -502,13 +491,13 @@ def test_adapter_request_labels_bounded():
     distinct names, new ones collapse into adapter="other" — a tenant
     flood cannot grow the metric cardinality without bound."""
     from skypilot_tpu import telemetry
-    from skypilot_tpu.inference.engine import InferenceEngine
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
     from skypilot_tpu.models import configs
     registry_lib.reset_registry()
     try:
-        eng = InferenceEngine(configs.get_config('tiny'), max_batch=2,
-                              max_seq=64, adapter_slots=1,
-                              adapter_rank=4)
+        eng = PagedInferenceEngine(configs.get_config('tiny'), max_batch=2,
+                                   max_seq=64, adapter_slots=1,
+                                   adapter_rank=4)
         reg = eng.adapters
         for i in range(12):
             reg.note_request(f'tenant{i}')
