@@ -207,25 +207,48 @@ def _scatter_rows_lane_packed(pool: jax.Array, rows: jax.Array,
     ``128 / w`` tokens to a lane row (``PagedKVCache.create``): pool [L,
     n_pages, 1, page * w / 128, 128], rows [L, slots, n, 1, w]. Token
     ``t`` of a page is lanes ``(t % per) * w ...`` of lane row ``t //
-    per``; the scatter's window is [w] at (row, lane). Flat and in place
-    like its twin."""
+    per``. Flat and in place like its twin, and like it one scatter of
+    WHOLE lane rows: each token writes its full 128-lane row, whose
+    other places hold what this call writes there (a slot's tokens are
+    consecutive, so a lane row's tokens are neighbours along ``n``) or
+    else what the pool holds (one gather of the touched lane rows).
+    Tokens of one lane row so write the same bytes, and duplicate
+    indices are harmless; a lane row never straddles a page, and a page
+    is written by one slot (the trash page excepted: it may hold
+    anything).
+
+    Not a [w] window at (row, lane): XLA lowers that 2-D scatter to a
+    serial loop of one trip a row, 2,048 trips of 3.9 us in every ring
+    merge and every prefill chunk of GLM-4.7-Flash (``PERF.md``, PR
+    33)."""
     L, n_pages, hkv, lane_rows, lanes = pool.shape
     w = rows.shape[-1]
     per = lanes // w
     assert hkv == 1, 'lane-packed rows have no head axis'
+    slots, n = flat_idx.shape
     flat_pool = pool.reshape(L * n_pages * lane_rows, lanes)
-    f = flat_idx.reshape(-1)                            # [slots*n] tokens
-    row = (jnp.arange(L)[:, None] * (n_pages * lane_rows)
-           + (f // per)[None, :]).reshape(-1)
-    lane = jnp.broadcast_to(((f % per) * w)[None, :],
-                            (L, f.size)).reshape(-1)
-    flat_pool = lax.scatter(
-        flat_pool, jnp.stack([row, lane], axis=-1),
-        rows.reshape(-1, w).astype(flat_pool.dtype),
-        lax.ScatterDimensionNumbers(
-            update_window_dims=(1,), inserted_window_dims=(0,),
-            scatter_dims_to_operand_dims=(0, 1)),
-        mode='drop')
+    place = flat_idx % per                              # [slots, n]
+    idx = (jnp.arange(L)[:, None] * (n_pages * lane_rows)
+           + (flat_idx // per).reshape(-1)[None, :]).reshape(-1)
+    # Place k of token j's lane row is token j + k - place_j of the same
+    # slot, if this call writes it there (the redirect of rows past
+    # ``valid_len`` to the trash page breaks the run of indices). Every
+    # index below is in bounds; ``mode='clip'`` spares the programs the
+    # code of the default's fill (a quarter of this function's, in every
+    # prefill and merge program).
+    src = jnp.arange(n)[None, :, None] + jnp.arange(per) - place[..., None]
+    want = (flat_idx - place)[..., None] + jnp.arange(per)
+    near = jnp.clip(src, 0, n - 1)                      # [slots, n, per]
+    written = (src == near) & (jnp.take_along_axis(
+        flat_idx, near.reshape(slots, n * per), axis=1, mode='clip'
+    ).reshape(slots, n, per) == want)
+    new = jnp.take_along_axis(
+        rows.reshape(L, slots, n, w).astype(flat_pool.dtype),
+        near.reshape(1, slots, n * per, 1), axis=2, mode='clip')
+    old = flat_pool.at[idx].get(mode='clip').reshape(L, slots, n, per, w)
+    full = jnp.where(written[None, ..., None],
+                     new.reshape(L, slots, n, per, w), old)
+    flat_pool = flat_pool.at[idx].set(full.reshape(-1, lanes), mode='drop')
     return flat_pool.reshape(pool.shape)
 
 

@@ -393,3 +393,68 @@ def test_latent_decode_program_reads_no_page_it_does_not_need(
         assert temp < 50e6
     else:
         assert 'gather' in made and temp > 250e6
+
+
+# ----------------------------------- the latent cache's lane-packed row write
+@pytest.mark.parametrize('form', ['whole_lane_rows', 'windowed'])
+@pytest.mark.parametrize('program', ['merge_ring_into_pool',
+                                     'prefill_chunk_row_write'])
+def test_latent_row_writes_hold_no_loop_and_no_pool_copy(
+        one_chip, monkeypatch, program, form):
+    """The two programs that write rows into the ``longctx`` cell's pools
+    (8 layers, 2818 pages of 128, latent 512 + rope 64 two tokens to a
+    lane row): the ring merge of 32 slots x 8 steps, and the row write
+    that ends a 1 x 256 prefill chunk. Each is 2,048 rope rows. Written
+    as whole lane rows they compile to one ``scatter`` a pool: no
+    ``while``, nothing made that is as large as the rope pool (369 MB),
+    next to no temp, both pools aliased in place. The 2-D windowed
+    scatter they replace compiled to a serial ``while`` of 2,048 trips
+    (``s32[2048,2]`` indices), 6-7.6 ms of an 8.1 ms merge and of a 23.4
+    ms chunk on the chip (``PERF.md``, PR 33)."""
+    cfg = _glm_cfg()
+    cache = _glm_cache(one_chip, cfg)
+    if form == 'windowed':
+        # the 2-D windowed scatter PR 33 replaced: the witness that this
+        # test can see a loop
+        from test_lane_packed_rows import windowed_scatter
+        monkeypatch.setattr(paged, '_scatter_rows_lane_packed',
+                            windowed_scatter)
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def rows(slots, n):
+        return (s((cfg.n_layers, slots, n, 1, cfg.kv_lora_rank),
+                  jnp.bfloat16),
+                s((cfg.n_layers, slots, n, 1, cfg.qk_rope_head_dim),
+                  jnp.bfloat16))
+
+    if program == 'merge_ring_into_pool':
+        target = paged.merge_ring_into_pool
+        operands = (*rows(GLM_SLOTS, 8), s((GLM_SLOTS, 64)),
+                    s((GLM_SLOTS,)), s((GLM_SLOTS,), jnp.bool_))
+    else:
+        target = paged.merge_rows_into_pool
+        operands = (*rows(1, 256), s((1, 64)), s((1,)), s((1,)))
+    # A function of its own for each case: ``jit`` caches the trace by
+    # function and shapes, and the two forms differ in neither.
+    compiled = jax.jit(lambda *args: target(*args), donate_argnums=(0,)
+                       ).lower(cache, *operands).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    pools = (cache.pool_k.size + cache.pool_v.size) * 2          # bytes
+    assert mem.alias_size_in_bytes >= pools
+    if form == 'windowed':
+        assert ' while(' in text and 's32[2048,2]' in text
+        return
+    assert ' while(' not in text
+    assert text.count(' scatter(') == 2
+    # Nothing as large as the rope pool is made but by the two scatters
+    # (each in a fusion of its own), and those in place: no temp to relay
+    # a pool through.
+    in_place = {'parameter', 'get-tuple-element', 'tuple', 'bitcast',
+                'scatter', 'fusion'}
+    made = {op: n for op, n in _result_sizes(text).items()
+            if op not in in_place and n >= cache.pool_v.size}
+    assert not made, made
+    assert mem.temp_size_in_bytes < 4e6
