@@ -844,7 +844,7 @@ def engine_dispatch_costs(engine, fns: Sequence[Callable],
 
         def ring(width):
             return jax.ShapeDtypeStruct(
-                (cfg.n_layers, engine.max_batch, horizon, spec.heads,
+                (cfg.n_cache_layers, engine.max_batch, horizon, spec.heads,
                  width), cfg.dtype,
                 sharding=getattr(engine, '_ring_sh', None))
         margs = (cache, ring(spec.k_dim), ring(spec.v_dim), table, lengths,
@@ -1215,7 +1215,7 @@ def abstract_paged_cache(cfg, n_pages: int, page_size: int,
     import jax.numpy as jnp
 
     from skypilot_tpu.inference.paged import PagedKVCache
-    L, hkv, d = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    L, hkv, d = cfg.n_cache_layers, cfg.n_kv_heads, cfg.head_dim
     if kv_cache_dtype == 'int4':
         pool = jax.ShapeDtypeStruct((L, n_pages, hkv, page_size, d // 2),
                                     jnp.uint8)

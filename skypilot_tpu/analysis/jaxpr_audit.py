@@ -567,7 +567,7 @@ def _decode_chain_collectives(engine, inner, captured
         horizon = args[12]
         cfg = engine.cfg
         ring = jax.ShapeDtypeStruct(
-            (cfg.n_layers, engine.max_batch, horizon, cfg.n_kv_heads,
+            (cfg.n_cache_layers, engine.max_batch, horizon, cfg.n_kv_heads,
              cfg.head_dim), cfg.dtype,
             sharding=getattr(engine, '_ring_sh', None))
         txt = fn.lower(cache, ring, ring, table, lengths,
@@ -1240,6 +1240,12 @@ PRESETS: Dict[str, Callable[[], AuditReport]] = {
     # dispatch gathers no page of the pool.
     'paged-latent-moe': lambda: audit_engine(model='tiny-glm',
                                              decode_impl='pallas'),
+    # A looped decoder (tiny-ouro: 3 passes over 2 layers, 6 cache
+    # layers) through the paged engine as the chip runs it: the pass
+    # scan around the layer scan adds no transfer and no recompile, and
+    # the kernel reads the pool at pass * n_layers + layer.
+    'paged-looped': lambda: audit_engine(model='tiny-ouro',
+                                         decode_impl='pallas'),
 }
 
 # Presets that need a multi-device backend: preset -> device count.

@@ -123,7 +123,7 @@ def _ring_row_bytes(cfg, batch: int, mesh=None) -> int:
     tp like the cache it merges into (batch sharding is NOT credited —
     the paged ring rides a replicated batch, so dividing by dp would
     under-reserve)."""
-    return (cfg.n_layers * batch * cfg.kv_spec.row_values *
+    return (cfg.n_cache_layers * batch * cfg.kv_spec.row_values *
             jnp.dtype(cfg.dtype).itemsize
             ) // kv_shard_degree(cfg, mesh)
 
@@ -194,7 +194,7 @@ def kv_token_bytes(cfg, quantized: bool, mesh=None) -> int:
         row_w = spec.k_dim + spec.v_dim + 8
     else:
         row_w = (spec.k_dim + spec.v_dim) * jnp.dtype(cfg.dtype).itemsize
-    return (cfg.n_layers * spec.heads * row_w
+    return (cfg.n_cache_layers * spec.heads * row_w
             ) // kv_shard_degree(cfg, mesh)
 
 
@@ -204,6 +204,8 @@ def refuse_unsupported(cfg, **asked) -> None:
     silently. ``asked`` holds what the caller was asked for:
     ``quantize``, ``kv_cache_dtype`` (resolved), ``speculate_k``,
     ``adapter_slots``, ``mesh``, ``decode_impl``."""
+    if cfg.n_loops > 1:
+        _refuse_looped(cfg, asked)
     if not cfg.latent:
         return
     reasons = {
@@ -230,12 +232,44 @@ def refuse_unsupported(cfg, **asked) -> None:
                         'is the latent paged kernel, with the ring '
                         'merged in XLA'),
     }
+    _refuse_first(cfg, f'attn_kind={cfg.attn_kind!r}, '
+                  f'ffn_kind={cfg.ffn_kind!r}', reasons, asked)
+
+
+def _refuse_first(cfg, what: str, reasons, asked) -> None:
     for name, (hit, why) in reasons.items():
         if hit:
             raise ValueError(
-                f'{cfg.name} (attn_kind={cfg.attn_kind!r}, '
-                f'ffn_kind={cfg.ffn_kind!r}) cannot be combined with '
+                f'{cfg.name} ({what}) cannot be combined with '
                 f'{name}={asked[name]!r}: {why}')
+
+
+def _refuse_looped(cfg, asked) -> None:
+    """``refuse_unsupported`` for a model whose layers run ``n_loops``
+    times a token, over ``n_cache_layers`` cache rows."""
+    what = f'n_loops={cfg.n_loops}'
+    if cfg.early_exit_threshold != 1:
+        raise ValueError(
+            f'{cfg.name} ({what}) cannot be served with '
+            f'early_exit_threshold={cfg.early_exit_threshold!r}: every '
+            'row of a step takes every pass; rows that leave the loop '
+            'at different passes need a scheduler and a decode program '
+            'that fill the cache layers they skip (ROADMAP.md)')
+    _refuse_first(cfg, what, {
+        'speculate_k': (bool(asked.get('speculate_k')),
+                        'paged_spec_verify scans the layers once, over '
+                        'n_layers cache rows'),
+        'adapter_slots': (bool(asked.get('adapter_slots')),
+                          'the bank is gathered once a layer; no test '
+                          'holds it to a looped pass'),
+        'mesh': (asked.get('mesh') is not None,
+                 'no looped program has been compiled or held to its '
+                 'reference over a mesh'),
+        'decode_impl': (asked.get('decode_impl') == 'cross_layer',
+                        'the fused-merge kernel has not been held to a '
+                        "reference over pass * n_layers + layer; "
+                        "'pallas' takes that row as its layer"),
+    }, asked)
 
 
 # Telemetry series every engine registers at construction (zeros from
@@ -340,14 +374,29 @@ class _EngineBase:
         # contract: dashboards never join against a series that
         # appears only after the first decode.
         self._kv_read_gauge = None
+        self._kv_layout_gauges = None
         if self.telemetry_enabled:
             from skypilot_tpu.telemetry import registry as registry_lib
             reg = registry_lib.get_registry()
+            self._kv_layout_gauges = (
+                reg.gauge(profiler_lib.KV_CACHE_LAYERS_METRIC,
+                          'Cache layers a token has: layers x passes'),
+                reg.gauge(profiler_lib.KV_TOKEN_BYTES_METRIC,
+                          'Stored bytes of one cached token over all '
+                          'its cache layers'))
             self._kv_read_gauge = reg.gauge(
                 KV_READ_METRIC,
                 'KV-cache bytes one decode substep streams from HBM '
                 '(live context rows x per-token stored cost, per '
                 'shard) — the bandwidth-wall numerator')
+
+    def _note_kv_layout(self) -> None:
+        """Set the two layout gauges, once ``cfg`` and the cache dtype
+        are resolved."""
+        if self._kv_layout_gauges is not None:
+            layers, token_bytes = self._kv_layout_gauges
+            layers.set(self.cfg.n_cache_layers)
+            token_bytes.set(kv_token_bytes(self.cfg, self.kv_cache_dtype))
 
     def _note_decode_step(self, live_tokens: int) -> None:
         """Per-dispatch attribution behind the KV-round-two gauge: the

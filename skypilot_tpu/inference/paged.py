@@ -63,7 +63,8 @@ class PagedKVCache(NamedTuple):
     writes); the allocator never hands it out. Per-slot lengths are
     HOST state (the engine controls every admit/advance), passed as a
     small per-call argument — no device length bookkeeping."""
-    pool_k: jax.Array                      # [L, n_pages, hkv, page, d]
+    pool_k: jax.Array                      # [L, n_pages, hkv, page, d],
+                                           # L = cfg.n_cache_layers
     pool_v: jax.Array
     k_scale: Optional[jax.Array] = None    # [L, n_pages, hkv, page]
     v_scale: Optional[jax.Array] = None
@@ -101,7 +102,8 @@ class PagedKVCache(NamedTuple):
         if kv_dtype is None:
             kv_dtype = 'int8' if quantized else 'bf16'
         spec = cfg.kv_spec
-        shape = (cfg.n_layers, n_pages, spec.heads, page_size, spec.k_dim)
+        shape = (cfg.n_cache_layers, n_pages, spec.heads, page_size,
+                 spec.k_dim)
         if spec.v_dim != spec.k_dim:
             # A latent cache: the normed latent rows in the K pool, the
             # roped key part in the V pool; bf16 only (refuse_unsupported).
@@ -484,7 +486,7 @@ def paged_decode_horizon(
     the ring into the pool via ``merge_ring_into_pool`` in a separate
     donated program (see its docstring for why)."""
     b = tokens.shape[0]
-    n_layers, spec = cfg.n_layers, cfg.kv_spec
+    n_layers, spec = cfg.n_cache_layers, cfg.kv_spec
     len0 = lengths
     pool_k, pool_v = cache.pool_k, cache.pool_v
     ks_pool, vs_pool = cache.k_scale, cache.v_scale
@@ -606,14 +608,12 @@ def paged_decode_horizon(
                 live=live)
             return xc, (new_kv, aux)
 
-        x, ((k_rows, v_rows), aux) = llama.scan_layers(layer_body, x,
-                                                       params, cfg)
+        x, ((k_rows, v_rows), aux), _ = llama.run_loops(layer_body, x,
+                                                        params, cfg)
         ring_k = lax.dynamic_update_slice(
             ring_k, k_rows.astype(ring_k.dtype), (0, 0, i, 0, 0))
         ring_v = lax.dynamic_update_slice(
             ring_v, v_rows.astype(ring_v.dtype), (0, 0, i, 0, 0))
-        x = llama.rms_norm(x, params['final_norm'], cfg.norm_eps,
-                           cfg.norm_plus_one)
         logits = llama._unembed_logits(params, x, cfg)[:, 0]
         # Constrained decoding at logits production (covers the raw
         # greedy argmax branch too).
@@ -734,10 +734,8 @@ def paged_prefill_chunk(
 
     from skypilot_tpu.models.quantization import w8a8_region
     with (w8a8_region() if w8a8 else contextlib.nullcontext()):
-        x, (k_rows, v_rows) = llama.scan_layers(layer_body, x, params,
-                                                cfg)
-    x = llama.rms_norm(x, params['final_norm'], cfg.norm_eps,
-                       cfg.norm_plus_one)
+        x, (k_rows, v_rows), _ = llama.run_loops(layer_body, x, params,
+                                                 cfg)
     idx = jnp.clip(want_idx, 0, chunk - 1)
     last_x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
     logits = llama._unembed_logits(params, last_x, cfg)[:, 0]
@@ -1081,6 +1079,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                                                      quantize)
         refuse_unsupported(cfg, kv_cache_dtype=self.kv_cache_dtype,
                            quantize=quantize)
+        self._note_kv_layout()
         kv_int8 = self.kv_cache_dtype == 'int8'
         if page_size is None:
             page_size = self._auto_page_size(cfg, max_seq, kv_int8,
@@ -1155,7 +1154,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             from jax.sharding import NamedSharding
             self._ring_sh = NamedSharding(mesh, mesh_lib.spec_for(
                 ('layers', 'batch', None, 'kv_heads', 'head_dim'),
-                shape=(cfg.n_layers, max_batch, 1, cfg.kv_spec.heads,
+                shape=(cfg.n_cache_layers, max_batch, 1,
+                       cfg.kv_spec.heads,
                        cfg.kv_spec.k_dim),
                 mesh=mesh))
 
@@ -1585,6 +1585,12 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 f'{self.cfg.name}: KV export/ingest (handoff, prefix '
                 'snapshots) moves [pages, kv_heads, head_dim] rows; the '
                 "wire format has no latent row yet (kv_transfer.py)")
+        if self.cfg.n_loops > 1:
+            raise NotImplementedError(
+                f'{self.cfg.name}: KV export/ingest (handoff, prefix '
+                'snapshots) checks [n_layers, rows, kv_heads] blocks; a '
+                f'looped model caches {self.cfg.n_cache_layers} layers a '
+                'token (kv_transfer.py)')
 
     # What one chunk program's transients may take beside the weights and
     # the pool, and what a score element of its attention costs at the
@@ -1949,12 +1955,14 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         extras = tuple(x for x in (adp_h, vm_h) if x is not None)
         # Query-key pairs under the causal mask that the chunk needs, a
         # layer: each valid row against its context and the piece up to
-        # itself. On the upload's annotation too, so that a trace holds
-        # the pairs of exactly the chunks it holds.
+        # itself; and its valid tokens. On the upload's annotation too,
+        # so that a trace holds the counts of exactly the chunks it holds.
         pairs = sum(int(v) * int(c) + int(v) * (int(v) + 1) // 2
                     for v, c in zip(valid[:len(batch)],
                                     lengths[:len(batch)]))
-        with self._prof.phase('admit_upload', pairs=pairs):
+        chunk_tokens = int(sum(valid[:len(batch)]))
+        with self._prof.phase('admit_upload', pairs=pairs,
+                              tokens=chunk_tokens):
             uploaded = device_upload(
                 (table_p, tokens, lengths, valid, want, temps, topks,
                  topps) + extras)
@@ -1980,7 +1988,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 topps_d, prng)
         chunk_t1 = clock.monotonic()
         self.chunks_prefilled += 1
-        self._prof.note_prefill_pairs(pairs)
+        self._prof.note_prefill_pairs(pairs, tokens=chunk_tokens)
         for i, slot in enumerate(batch):
             r = self._slots[slot]
             if r.trace is not None:

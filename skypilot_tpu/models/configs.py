@@ -98,6 +98,24 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_ffn_dim: int = 0
     routed_scaling_factor: float = 1.0
+    # A looped decoder: the SAME ``n_layers`` layers run ``n_loops``
+    # times a token, the final norm after every pass and its output
+    # entering the next. Each (pass, layer) keeps a cache of its own:
+    # ``n_cache_layers`` rows a token. ``post_norms`` puts a second
+    # RMSNorm on each branch's OUTPUT, before the residual add (sandwich
+    # norms). ``exit_gate`` holds a Linear(dim -> 1) read after every
+    # pass; its sigmoids give the distribution over passes to leave at
+    # (``llama.forward(return_exit=True)``). ``early_exit_threshold`` 1
+    # means every token takes every pass: the one value served.
+    n_loops: int = 1
+    post_norms: bool = False
+    exit_gate: bool = False
+    early_exit_threshold: float = 1.0
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Cache rows a token has: one per (pass, layer)."""
+        return self.n_layers * self.n_loops
 
     @property
     def lora_enabled(self) -> bool:
@@ -128,12 +146,9 @@ class ModelConfig:
             return KVSpec(1, self.kv_lora_rank, self.qk_rope_head_dim)
         return KVSpec(self.n_kv_heads, self.head_dim, self.head_dim)
 
-    @property
-    def num_params(self) -> int:
-        """Approximate parameter count (embeddings + blocks + head)."""
-        if self.latent:
-            from skypilot_tpu.models import latent_moe
-            return latent_moe.num_params(self)
+    def _dense_param_split(self):
+        """(parameters of the layer stack, held once; all the others:
+        embeddings, final norm, exit gate)."""
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
         q_dim = self.n_heads * self.head_dim
         kv_dim = self.n_kv_heads * self.head_dim
@@ -142,16 +157,29 @@ class ModelConfig:
         if self.is_moe:
             ffn *= self.n_experts
             ffn += d * self.n_experts           # router
-        per_layer = attn + ffn + 2 * d          # + 2 norms
+        norms = 4 if self.post_norms else 2
         embeds = v * d if self.tie_embeddings else v * d * 2
-        return embeds + self.n_layers * per_layer + d
+        gate = d + 1 if self.exit_gate else 0
+        return self.n_layers * (attn + ffn + norms * d), embeds + d + gate
+
+    @property
+    def num_params(self) -> int:
+        """Approximate parameter count (embeddings + blocks + head)."""
+        if self.latent:
+            from skypilot_tpu.models import latent_moe
+            return latent_moe.num_params(self)
+        return sum(self._dense_param_split())
 
     def flops_per_token(self, training: bool = False) -> float:
-        """~2*N matmul FLOPs per token fwd (6*N with backward)."""
+        """~2*N matmul FLOPs per token fwd (6*N with backward); a
+        looped model's layers are held once and worked ``n_loops``
+        times."""
         n = self.num_params
         if self.latent:
             from skypilot_tpu.models import latent_moe
             n = latent_moe.num_params(self, active_only=True)
+        elif self.n_loops > 1:
+            n += (self.n_loops - 1) * self._dense_param_split()[0]
         if self.is_moe:
             # only active experts count
             d, f = self.dim, self.ffn_dim
@@ -238,10 +266,24 @@ TINY_GLM = _cfg(
     n_routed_experts=8, n_experts_per_token=2, n_shared_experts=1,
     moe_ffn_dim=96, routed_scaling_factor=1.8)
 
+# ByteDance/Ouro-2.6B (``ouro``) as published: plain multi-head
+# attention, 48 layers run 4 times a token.
+OURO_2_6B = _cfg(
+    name='ouro-2.6b', vocab_size=49152, dim=2048, n_layers=48, n_heads=16,
+    n_kv_heads=16, ffn_dim=5632, max_seq_len=65536, rope_theta=1000000.0,
+    norm_eps=1e-6, n_loops=4, post_norms=True, exit_gate=True)
+
+# Sizes that differ where a mix-up would hide: loops != layers, heads !=
+# kv heads, dim != heads x head_dim.
+TINY_OURO = _cfg(
+    name='tiny-ouro', vocab_size=256, dim=64, n_layers=2, n_heads=4,
+    n_kv_heads=2, ffn_dim=160, max_seq_len=128, remat='none',
+    head_dim_override=24, n_loops=3, post_norms=True, exit_gate=True)
+
 PRESETS = {c.name: c for c in [
     LLAMA3_8B, LLAMA3_70B, LLAMA2_7B, LLAMA3_1B, MIXTRAL_8X7B,
     GEMMA_2B, GEMMA_7B, QWEN2_7B, TINY, TINY_MOE, TINY_GEMMA,
-    TINY_QWEN, GLM_4_7_FLASH, TINY_GLM]}
+    TINY_QWEN, GLM_4_7_FLASH, TINY_GLM, OURO_2_6B, TINY_OURO]}
 
 
 def get_config(name: str) -> ModelConfig:

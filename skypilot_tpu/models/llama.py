@@ -71,6 +71,18 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             'wo': stack_init(keys[5], (n_h, hd, d), n_h * hd),
         },
     }
+    if cfg.post_norms:                  # sandwich: a norm on each branch's output
+        params['layers'].update({
+            name: jnp.zeros_like(params['layers']['attn_norm'])
+            if cfg.norm_plus_one
+            else jnp.ones_like(params['layers']['attn_norm'])
+            for name in ('attn_post_norm', 'ffn_post_norm')})
+    if cfg.exit_gate:                   # Linear(dim -> 1) after every pass
+        params['exit_gate'] = {
+            'w': _dense_init(jax.random.fold_in(keys[7], 1), (d,),
+                             cfg.dtype, d),
+            'b': jnp.zeros((1,), jnp.float32),
+        }
     if cfg.qkv_bias:                    # Qwen2-family attention biases
         params['layers'].update({
             'bq': jnp.zeros((L, n_h, hd), jnp.float32),
@@ -115,6 +127,13 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             'wo': ('layers', 'heads', 'head_dim', 'embed'),
         },
     }
+    if cfg.post_norms:
+        axes['layers'].update({
+            'attn_post_norm': ('layers', 'norm'),
+            'ffn_post_norm': ('layers', 'norm'),
+        })
+    if cfg.exit_gate:
+        axes['exit_gate'] = {'w': ('norm',), 'b': (None,)}
     if cfg.qkv_bias:
         axes['layers'].update({
             'bq': ('layers', 'heads', 'head_dim'),
@@ -492,6 +511,57 @@ def scan_layers(body, x: jax.Array, params: Params, cfg: ModelConfig):
     return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
 
 
+def run_loops(body, x: jax.Array, params: Params, cfg: ModelConfig,
+              with_exit: bool = False):
+    """Every pass of the model over its layers, and the final norm:
+    ``scan_layers`` once and the norm after it, or for a looped model
+    (``cfg.n_loops`` > 1) a ``lax.scan`` over the passes around the two,
+    the SAME weights in every pass and each pass's normed output the
+    next one's input. ``body`` gets ``li`` = pass * n_layers + layer,
+    the row of a cache stacked over ``cfg.n_cache_layers``; the
+    per-layer outputs come back stacked the same way.
+
+    Returns (normed x, per-cache-layer outputs, exit gate), the gate
+    None unless ``with_exit``: each pass's sigmoid(x . w + b), [passes,
+    batch, seq] float32, from the state the pass left."""
+
+    def final_norm(x):
+        return rms_norm(x, params['final_norm'], cfg.norm_eps,
+                        cfg.norm_plus_one)
+
+    def gate(x):
+        g = params['exit_gate']
+        return jax.nn.sigmoid(
+            jnp.einsum('bsd,d->bs', x.astype(jnp.float32),
+                       g['w'].astype(jnp.float32)) + g['b'][0])
+
+    def one_pass(x, t):
+        def at_pass(carry, layer_idx):
+            layer, li = layer_idx
+            return body(carry, (layer, t * cfg.n_layers + li))
+
+        x, ys = scan_layers(body if t is None else at_pass, x, params, cfg)
+        x = final_norm(x)
+        return x, (ys, gate(x) if with_exit else None)
+
+    if cfg.n_loops == 1:        # no scan over one pass: the program it was
+        x, (ys, lam) = one_pass(x, None)
+        return x, ys, (lam[None] if with_exit else None)
+    x, (ys, lam) = lax.scan(one_pass, x, jnp.arange(cfg.n_loops))
+    ys = jax.tree.map(
+        lambda a: a.reshape((cfg.n_cache_layers,) + a.shape[2:]), ys)
+    return x, ys, lam
+
+
+def exit_pdf(lam: jax.Array) -> jax.Array:
+    """[passes, ...] exit-gate sigmoids -> the distribution over the pass
+    a token leaves at: lam[t] times the share still running, the last
+    pass taking what is left."""
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate([lam[:-1] * before[:-1], before[-1:]], axis=0)
+
+
 def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
                 positions: jax.Array, attn_fn,
                 mlora_idx: Optional[jax.Array] = None,
@@ -520,38 +590,44 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
     ml = layer.get('mlora') if isinstance(layer, dict) else None
     if mlora_idx is None:
         ml = None
-    q = qeinsum('bsd,dhk->bshk', h, layer['wq'])
-    k = qeinsum('bsd,dhk->bshk', h, layer['wk'])
-    v = qeinsum('bsd,dhk->bshk', h, layer['wv'])
-    if lo is not None:
-        from skypilot_tpu.models import lora as lora_lib
-        q = q + lora_lib.apply(lo, 'wq', h, cfg)
-        k = k + lora_lib.apply(lo, 'wk', h, cfg)
-        v = v + lora_lib.apply(lo, 'wv', h, cfg)
-    if ml is not None:
-        from skypilot_tpu.models import multilora
-        q = multilora.adjusted(ml, 'wq', h, q, mlora_idx)
-        k = multilora.adjusted(ml, 'wk', h, k, mlora_idx)
-        v = multilora.adjusted(ml, 'wv', h, v, mlora_idx)
-    if cfg.qkv_bias:
-        q = q + layer['bq'].astype(q.dtype)
-        k = k + layer['bk'].astype(k.dtype)
-        v = v + layer['bv'].astype(v.dtype)
-    q = _shard(q, 'batch', 'seq', 'heads', 'head_dim')
-    q = checkpoint_name(rope(q, positions, cfg.rope_theta), 'q_rope')
-    k = checkpoint_name(rope(k, positions, cfg.rope_theta), 'k_rope')
-    v = checkpoint_name(v, 'v_proj')
-    out = attn_fn(q, k, v)
-    # Named for selective remat (cfg.remat='attn'): saving the attention
-    # output keeps the backward pass from re-running the whole attention
-    # forward, at [b,s,h,d] bytes per layer.
-    out = checkpoint_name(out, 'attn_out')
-    out = _shard(out, 'batch', 'seq', 'heads', 'head_dim')
-    proj = qeinsum('bshk,hkd->bsd', out, layer['wo'])
-    if lo is not None:
-        proj = proj + lora_lib.apply(lo, 'wo', out, cfg)
-    if ml is not None:
-        proj = multilora.adjusted(ml, 'wo', out, proj, mlora_idx)
+    # The scopes name device time for the trace (perfbench/scopes.py);
+    # they change no operation.
+    with jax.named_scope('gqa_attn'):
+        q = qeinsum('bsd,dhk->bshk', h, layer['wq'])
+        k = qeinsum('bsd,dhk->bshk', h, layer['wk'])
+        v = qeinsum('bsd,dhk->bshk', h, layer['wv'])
+        if lo is not None:
+            from skypilot_tpu.models import lora as lora_lib
+            q = q + lora_lib.apply(lo, 'wq', h, cfg)
+            k = k + lora_lib.apply(lo, 'wk', h, cfg)
+            v = v + lora_lib.apply(lo, 'wv', h, cfg)
+        if ml is not None:
+            from skypilot_tpu.models import multilora
+            q = multilora.adjusted(ml, 'wq', h, q, mlora_idx)
+            k = multilora.adjusted(ml, 'wk', h, k, mlora_idx)
+            v = multilora.adjusted(ml, 'wv', h, v, mlora_idx)
+        if cfg.qkv_bias:
+            q = q + layer['bq'].astype(q.dtype)
+            k = k + layer['bk'].astype(k.dtype)
+            v = v + layer['bv'].astype(v.dtype)
+        q = _shard(q, 'batch', 'seq', 'heads', 'head_dim')
+        q = checkpoint_name(rope(q, positions, cfg.rope_theta), 'q_rope')
+        k = checkpoint_name(rope(k, positions, cfg.rope_theta), 'k_rope')
+        v = checkpoint_name(v, 'v_proj')
+        out = attn_fn(q, k, v)
+        # Named for selective remat (cfg.remat='attn'): saving the
+        # attention output keeps the backward pass from re-running the
+        # whole attention forward, at [b,s,h,d] bytes per layer.
+        out = checkpoint_name(out, 'attn_out')
+        out = _shard(out, 'batch', 'seq', 'heads', 'head_dim')
+        proj = qeinsum('bshk,hkd->bsd', out, layer['wo'])
+        if lo is not None:
+            proj = proj + lora_lib.apply(lo, 'wo', out, cfg)
+        if ml is not None:
+            proj = multilora.adjusted(ml, 'wo', out, proj, mlora_idx)
+        if cfg.post_norms:
+            proj = rms_norm(proj, layer['attn_post_norm'], cfg.norm_eps,
+                            cfg.norm_plus_one)
     x = x + proj
     h = rms_norm(x, layer['ffn_norm'], cfg.norm_eps,
                  cfg.norm_plus_one)
@@ -559,8 +635,12 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
         from skypilot_tpu.models import moe
         ffn_out, aux = moe.moe_ffn(layer, h, cfg)
     else:
-        ffn_out = _ffn(layer, h, cfg, mlora_idx=mlora_idx)
+        with jax.named_scope('dense_ffn'):
+            ffn_out = _ffn(layer, h, cfg, mlora_idx=mlora_idx)
         aux = jnp.zeros((), jnp.float32)
+    if cfg.post_norms:
+        ffn_out = rms_norm(ffn_out, layer['ffn_post_norm'], cfg.norm_eps,
+                           cfg.norm_plus_one)
     x = x + ffn_out
     x = _shard(x, 'batch', 'seq', 'embed')
     return x, (k, v), aux
@@ -605,6 +685,7 @@ def forward(
     cache: Optional[KVCache] = None,
     attn_impl: str = 'auto',
     return_aux: bool = False,
+    return_exit: bool = False,
 ):
     """Run the model. Without a cache: training/eval full-sequence causal
     attention; positions are [0..s). With a cache: prefill/decode — tokens
@@ -615,12 +696,22 @@ def forward(
     overflow silently corrupts the last cache slot.
 
     Returns (logits [b, s, vocab], new_cache or None), plus the mean MoE
-    load-balancing aux loss when ``return_aux`` (0 for dense models).
+    load-balancing aux loss when ``return_aux`` (0 for dense models),
+    plus the exit distribution [passes, b, s] when ``return_exit`` (a
+    model with ``exit_gate``; the logits are the last pass's either way).
     """
     if cfg.latent and cache is not None:
         raise NotImplementedError(
             'a latent-attention model has no contiguous KVCache: it '
             'decodes through the paged pool (inference/paged.py)')
+    if cfg.n_loops > 1 and (cache is not None or _pp_mesh() is not None):
+        raise NotImplementedError(
+            f'{cfg.name} runs its layers {cfg.n_loops} times a token: the '
+            'contiguous KVCache and the pipeline schedule hold one row '
+            'and one stage a layer; it decodes through the paged pool '
+            '(inference/paged.py)')
+    if return_exit and not cfg.exit_gate:
+        raise ValueError(f'{cfg.name} has no exit gate')
     x = _embed_tokens(params, tokens, cfg)
     x = _shard(x, 'batch', 'seq', 'embed')
     b, s = tokens.shape
@@ -671,6 +762,7 @@ def forward(
         return body
 
     body = make_body(positions, cache_len)
+    normed = False
 
     if cache is None:
         pp_mesh = _pp_mesh()
@@ -710,7 +802,9 @@ def forward(
                 out, _, aux = body(carry, (layer_idx[0], None))
                 return out, aux
 
-            x, aux_layers = scan_layers(scan_body, x, params, cfg)
+            x, aux_layers, lam = run_loops(scan_body, x, params, cfg,
+                                           with_exit=return_exit)
+            normed = True       # every pass ends in the final norm
         new_cache = None
     else:
         # The cache is a loop INVARIANT (closed over, indexed per layer),
@@ -789,13 +883,17 @@ def forward(
         new_cache = merge_rows_into_cache(cache, k_rows, v_rows,
                                           cache.length, cache.length + s)
 
-    x = rms_norm(x, params['final_norm'], cfg.norm_eps,
-                 cfg.norm_plus_one)
+    if not normed:
+        x = rms_norm(x, params['final_norm'], cfg.norm_eps,
+                     cfg.norm_plus_one)
     logits = _unembed_logits(params, x, cfg)
     logits = _shard(logits, 'batch', 'seq', 'vocab')
+    out = (logits, new_cache)
     if return_aux:
-        return logits, new_cache, jnp.mean(aux_layers)
-    return logits, new_cache
+        out += (jnp.mean(aux_layers),)
+    if return_exit:
+        out += (exit_pdf(lam),)
+    return out
 
 
 # Sentinel token emitted when a slot's logits row is non-finite

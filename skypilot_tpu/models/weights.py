@@ -37,6 +37,7 @@ _ARCH_FAMILY = {
     'GemmaForCausalLM': 'gemma',
     'MixtralForCausalLM': 'mixtral',
     'Qwen2ForCausalLM': 'qwen2',
+    'OuroForCausalLM': 'ouro',
 }
 
 
@@ -78,7 +79,17 @@ def config_from_hf(hf: Dict[str, Any],
     if family == 'mixtral':
         kw.update(n_experts=hf['num_local_experts'],
                   n_experts_per_token=hf.get('num_experts_per_tok', 2))
+    if family == 'ouro':
+        # A looped decoder: sandwich norms and the exit gate come with
+        # the family, the passes with ``total_ut_steps``.
+        kw.update(n_loops=hf['total_ut_steps'], post_norms=True,
+                  exit_gate=True, early_exit_threshold=float(
+                      hf.get('early_exit_threshold', 1.0)))
     return ModelConfig(**kw)
+
+
+_EXIT_GATE_KEYS = ('model.early_exit_gate.weight',
+                   'model.early_exit_gate.bias')
 
 
 def _read_hf_config(path: str) -> Dict[str, Any]:
@@ -173,6 +184,12 @@ def _hf_key_map(cfg: ModelConfig) -> Dict[str, Tuple[str, ...]]:
         'self_attn.v_proj.weight': ('layers', 'wv'),
         'self_attn.o_proj.weight': ('layers', 'wo'),
     }
+    if cfg.post_norms:
+        m.update({
+            'input_layernorm_2.weight': ('layers', 'attn_post_norm'),
+            'post_attention_layernorm_2.weight': ('layers',
+                                                  'ffn_post_norm'),
+        })
     if cfg.qkv_bias:
         m.update({
             'self_attn.q_proj.bias': ('layers', 'bq'),
@@ -200,7 +217,7 @@ def _transform(leaf: Tuple[str, ...], w: np.ndarray,
     """HF [out, in] Linear -> our input-major layout (+ head reshapes)."""
     name = leaf[1]
     hd = cfg.head_dim
-    if name in ('attn_norm', 'ffn_norm'):
+    if name.endswith('norm'):
         return w.astype(np.float32)
     if name == 'bq':
         return w.reshape(cfg.n_heads, hd).astype(np.float32)
@@ -267,6 +284,12 @@ def load_hf_params(path: str, cfg: ModelConfig,
                     top['unembed'] = w.T
                     seen.add(key)
             return
+        if key in _EXIT_GATE_KEYS:
+            if cfg.exit_gate:
+                with alloc_lock:
+                    top[key] = w
+                    seen.add(key)
+            return
         if not key.startswith('model.layers.'):
             return
         rest = key[len('model.layers.'):]
@@ -299,6 +322,8 @@ def load_hf_params(path: str, cfg: ModelConfig,
     expected = {'model.embed_tokens.weight', 'model.norm.weight'}
     if not cfg.tie_embeddings:
         expected.add('lm_head.weight')
+    if cfg.exit_gate:
+        expected.update(_EXIT_GATE_KEYS)
     for i in range(L):
         for suffix in key_map:
             expected.add(f'model.layers.{i}.{suffix}')
@@ -311,8 +336,7 @@ def load_hf_params(path: str, cfg: ModelConfig,
     from skypilot_tpu.models import quantization
 
     def cast(name: str, a: np.ndarray) -> Any:
-        if name in ('attn_norm', 'ffn_norm', 'final_norm',
-                    'bq', 'bk', 'bv'):
+        if name.endswith('norm') or name in ('bq', 'bk', 'bv'):
             return jnp.asarray(a, jnp.float32)
         if quantize is not None and name in quantization.REDUCE_AXES:
             # int4 packs the dense leaves; MoE expert leaves stay int8
@@ -337,6 +361,11 @@ def load_hf_params(path: str, cfg: ModelConfig,
             params['layers'][k] = cast(k, bufs.pop(k))
     if not cfg.tie_embeddings:
         params['unembed'] = cast('unembed', top['unembed'])
+    if cfg.exit_gate:                   # Linear(dim -> 1): [1, d] and [1]
+        w, b = (top[k] for k in _EXIT_GATE_KEYS)
+        params['exit_gate'] = {
+            'w': jnp.asarray(np.asarray(w.reshape(-1), cfg.dtype)),
+            'b': jnp.asarray(b.reshape(1), jnp.float32)}
     return params
 
 
@@ -599,8 +628,9 @@ def hf_config_dict(cfg: ModelConfig,
     the exact inverse of ``config_from_hf``)."""
     arch = {'llama': 'LlamaForCausalLM', 'gemma': 'GemmaForCausalLM',
             'mixtral': 'MixtralForCausalLM',
-            'qwen2': 'Qwen2ForCausalLM'}
-    family = ('mixtral' if cfg.is_moe else
+            'qwen2': 'Qwen2ForCausalLM', 'ouro': 'OuroForCausalLM'}
+    family = ('ouro' if cfg.n_loops > 1 else
+              'mixtral' if cfg.is_moe else
               'gemma' if cfg.norm_plus_one else
               'qwen2' if cfg.qkv_bias else 'llama')
     hf_cfg: Dict[str, Any] = {
@@ -624,6 +654,9 @@ def hf_config_dict(cfg: ModelConfig,
                       num_experts_per_tok=cfg.n_experts_per_token)
     if family == 'gemma':
         hf_cfg['hidden_act'] = 'gelu_pytorch_tanh'
+    if family == 'ouro':
+        hf_cfg.update(total_ut_steps=cfg.n_loops,
+                      early_exit_threshold=cfg.early_exit_threshold)
     return hf_cfg
 
 
@@ -648,11 +681,19 @@ def save_hf_checkpoint(path: str, cfg: ModelConfig, params: Params) -> None:
     out['model.norm.weight'] = np_(params['final_norm'])
     if not cfg.tie_embeddings:
         out['lm_head.weight'] = np_(params['unembed']).T
+    if cfg.exit_gate:
+        out[_EXIT_GATE_KEYS[0]] = np_(params['exit_gate']['w'])[None]
+        out[_EXIT_GATE_KEYS[1]] = np_(params['exit_gate']['b'])
     lp = params['layers']
     for i in range(cfg.n_layers):
         p = f'model.layers.{i}.'
         out[p + 'input_layernorm.weight'] = np_(lp['attn_norm'][i])
         out[p + 'post_attention_layernorm.weight'] = np_(lp['ffn_norm'][i])
+        if cfg.post_norms:
+            out[p + 'input_layernorm_2.weight'] = np_(
+                lp['attn_post_norm'][i])
+            out[p + 'post_attention_layernorm_2.weight'] = np_(
+                lp['ffn_post_norm'][i])
         out[p + 'self_attn.q_proj.weight'] = (
             np_(lp['wq'][i]).reshape(cfg.dim, cfg.n_heads * hd).T)
         out[p + 'self_attn.k_proj.weight'] = (

@@ -1655,6 +1655,12 @@ class ModelServer:
                     profiler_lib.ATTN_PAGES_LIVE_METRIC),
                 'decode_attn_pages_table_total': self._counter_value(
                     profiler_lib.ATTN_PAGES_TABLE_METRIC),
+                'prefill_tokens_total': self._counter_value(
+                    profiler_lib.PREFILL_TOKENS_METRIC),
+                'kv_cache_layers': self._counter_value(
+                    profiler_lib.KV_CACHE_LAYERS_METRIC),
+                'kv_token_bytes': self._counter_value(
+                    profiler_lib.KV_TOKEN_BYTES_METRIC),
             },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),

@@ -52,6 +52,12 @@ MOE_LAYER_STEPS_METRIC = 'skytpu_moe_layer_steps_total'
 MOE_DISTINCT_METRIC = 'skytpu_moe_distinct_experts_total'
 MOE_ASSIGNMENTS_METRIC = 'skytpu_moe_assignments_total'
 PREFILL_PAIRS_METRIC = 'skytpu_prefill_attn_pairs_total'
+PREFILL_TOKENS_METRIC = 'skytpu_prefill_tokens_total'
+# What one cached token is (gauges the engine sets once it is built): its
+# cache layers (layers x passes of a looped model) and its stored bytes
+# over all of them.
+KV_CACHE_LAYERS_METRIC = 'skytpu_kv_cache_layers'
+KV_TOKEN_BYTES_METRIC = 'skytpu_kv_token_bytes'
 # The latent paged decode kernel (ops/latent_paged_attention.py): pages
 # it reads against pages the padded table holds.
 ATTN_PAGES_LIVE_METRIC = 'skytpu_decode_attn_pages_live_total'
@@ -84,8 +90,8 @@ class NullProfiler:
     def note_distinct_experts(self, n: int, layer_steps: int) -> None:
         del n, layer_steps
 
-    def note_prefill_pairs(self, n: int) -> None:
-        del n
+    def note_prefill_pairs(self, n: int, tokens: int = 0) -> None:
+        del n, tokens
 
     def note_decode_attn_pages(self, live: int, table: int) -> None:
         del live, table
@@ -141,6 +147,9 @@ class StepProfiler:
             PREFILL_PAIRS_METRIC,
             'Query-key pairs under the causal mask that enqueued '
             'prefill chunks needed, per layer')
+        self._prefill_tokens = self._reg.counter(
+            PREFILL_TOKENS_METRIC,
+            'Prompt tokens of enqueued prefill chunks (padding left out)')
         self._attn_pages_live = self._reg.counter(
             ATTN_PAGES_LIVE_METRIC,
             "Pages of live rows' own contexts (ceil(length / page) a "
@@ -263,8 +272,11 @@ class StepProfiler:
                                            'layer_steps': layer_steps}):
             self._open.pop().__exit__(None, None, None)
 
-    def note_prefill_pairs(self, n: int) -> None:
+    def note_prefill_pairs(self, n: int, tokens: int = 0) -> None:
+        """A prefill chunk's dispatch: its query-key pairs a layer and
+        its valid tokens. Host arithmetic."""
         self._prefill_pairs.inc(n)
+        self._prefill_tokens.inc(tokens)
 
     def note_decode_attn_pages(self, live: int, table: int) -> None:
         """Pages a decode dispatch's attention reads a layer (``live``:

@@ -161,7 +161,9 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, PAGE=PAGE,
                           jnp.asarray(lengths), active)
             steps = tap.take()
             toks = np.asarray(toks)
-            assert toks.shape == (n + 1, horizon)     # + the experts row
+            # + the experts row of a model that routes
+            assert toks.shape == (
+                n + (cfg.ffn_kind == 'routed_shared'), horizon)
             for i in range(n):
                 for h in range(horizon):
                     got[i][int(lengths[i]) + h] = steps[h][i]

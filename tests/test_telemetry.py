@@ -994,9 +994,16 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          # the latent paged decode kernel (PR 31): zeros
                          # for a GQA cache
                          'decode_attn_pages_live_total',
-                         'decode_attn_pages_table_total'}
+                         'decode_attn_pages_table_total',
+                         # what a cached token is, and the prompt tokens
+                         # prefilled (PR 35)
+                         'prefill_tokens_total', 'kv_cache_layers',
+                         'kv_token_bytes'}
     assert all(isinstance(v, (int, float)) for v in loop.values())
     assert loop['moe_layer_steps_total'] == 0
+    assert loop['prefill_tokens_total'] > 0
+    assert loop['kv_cache_layers'] == 2             # tiny: 2 layers, 1 pass
+    assert loop['kv_token_bytes'] == 2 * 2 * 2 * 16 * 2
     assert loop['prefill_attn_pairs_total'] > 0     # the warm-up's prompt
     # The boot's warm-up request ran on the engine directly: substeps
     # are counted, but no engine-loop turn has taken the lock yet.

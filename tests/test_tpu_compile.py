@@ -458,3 +458,47 @@ def test_latent_row_writes_hold_no_loop_and_no_pool_copy(
             if op not in in_place and n >= cache.pool_v.size}
     assert not made, made
     assert mem.temp_size_in_bytes < 4e6
+
+
+# ------------------------------------------------ a looped decoder's decode
+def test_looped_decode_program_keeps_the_pass_loop_and_one_kernel(
+        one_chip, monkeypatch):
+    """The whole ``paged_decode_horizon`` of the ``ouro-2.6b.reason``
+    cell (48 layers x 4 passes, 192 cache layers, 16 slots, a 41-page
+    pool of 201 MB pages): three nested loops (horizon, passes, layers:
+    the pass loop is not unrolled into 192 layers), ONE paged kernel
+    whose ``layer`` is pass * 48 + layer, and nothing as large as a
+    cache layer of the pool made beside the ring (0.40 GB) and the
+    step's transients (1.21 GB of temp; compiler, PR 35)."""
+    cfg = configs.OURO_2_6B
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    slots, n_pages, table_p, horizon = 16, 41, 4, 16
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda key: llama.init_params(key, cfg), jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(lambda: paged.PagedKVCache.create(
+        cfg, n_pages=n_pages, page_size=PAGE)))
+    assert cache.pool_k.shape == (192, n_pages, 16, PAGE, 128)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, cache, table, tokens, lengths, active):
+        return paged.paged_decode_horizon(
+            params, cache, table, tokens, lengths, cfg, horizon=horizon,
+            active=active, decode_impl='pallas')
+
+    compiled = jax.jit(decode).lower(
+        params, cache, vec((slots, table_p)), vec((slots,)),
+        vec((slots,)), vec((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 1
+    assert text.count(' while(') == 3
+    mem = compiled.memory_analysis()
+    ring = 2 * 192 * slots * horizon * 16 * 128 * 2
+    assert mem.output_size_in_bytes == pytest.approx(ring, rel=0.01)
+    assert mem.temp_size_in_bytes < 1.5e9

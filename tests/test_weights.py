@@ -6,6 +6,7 @@ The reference serves *real* HF checkpoints through external engines
 (``llm/llama-3/llama3.yaml:109``); this proves our in-tree engine computes
 the same function as the HF reference implementation for those layouts.
 """
+import dataclasses
 import json
 import os
 
@@ -284,6 +285,38 @@ def test_qwen2_save_load_roundtrip(tmp_path):
     l2, _ = llama.forward(params2, jnp.asarray(tok), cfg2)
     np.testing.assert_allclose(np.asarray(l1, np.float32),
                                np.asarray(l2, np.float32), atol=2e-2)
+
+
+def test_ouro_save_load_roundtrip(tmp_path):
+    """``model_type: ouro`` (``total_ut_steps``, ``input_layernorm_2``,
+    ``post_attention_layernorm_2``, ``model.early_exit_gate``) written
+    and read back: the same configuration, tree and logits."""
+    cfg = configs.TINY_OURO
+    params = llama.init_params(jax.random.PRNGKey(3), cfg)
+    # norms off one and a gate bias off zero, so the round trip tests them
+    for name in ('attn_post_norm', 'ffn_post_norm'):
+        params['layers'][name] = params['layers'][name] * jnp.linspace(
+            0.5, 1.5, cfg.dim)
+    params['exit_gate']['b'] = params['exit_gate']['b'] + 0.25
+    path = str(tmp_path / 'rto')
+    weights.save_hf_checkpoint(path, cfg, params)
+    with open(os.path.join(path, 'config.json'), encoding='utf-8') as f:
+        hf = json.load(f)
+    assert hf['model_type'] == 'ouro' and hf['total_ut_steps'] == 3
+    cfg2, params2 = weights.load_checkpoint(path, dtype=cfg.dtype)
+    assert cfg2 == dataclasses.replace(cfg, name='ouro', remat='block')
+    assert jax.tree.structure(params2) == jax.tree.structure(params)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(params2)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    tok = np.arange(24).reshape(1, 24) % cfg.vocab_size
+    l1, _, e1 = llama.forward(params, jnp.asarray(tok), cfg,
+                              return_exit=True)
+    l2, _, e2 = llama.forward(params2, jnp.asarray(tok), cfg2,
+                              return_exit=True)
+    np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
+    np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
 
 
 @pytest.mark.slow
