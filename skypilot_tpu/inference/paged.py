@@ -183,8 +183,10 @@ def _scatter_rows(pool: jax.Array, rows: jax.Array,
     because the scatter windows [L, ..] span the operand's major dim.
     Fully flat windows are [page-row] = the operand's own minor layout,
     the scatter runs IN PLACE (0-byte temps, donation holds), at the
-    price of a slower per-row scatter (~3-4x the token-major merge,
-    bounded at ~3% of a decode-horizon program)."""
+    price of a slower per-row scatter: ~70 ns a row on a v5e, which was
+    15 % of ``ouro-2.6b.reason``'s device time while every slot's rows
+    were written (``PERF.md``, PR 35). ``_write_live_rows`` calls this
+    for live rows only."""
     if pool.ndim == 5 and rows.shape[-1] != pool.shape[-1]:
         return _scatter_rows_lane_packed(pool, rows, flat_idx)
     L, n_pages, hkv, page = pool.shape[:4]
@@ -254,15 +256,93 @@ def _scatter_rows_lane_packed(pool: jax.Array, rows: jax.Array,
     return flat_pool.reshape(pool.shape)
 
 
+# Rows a trip of ``_write_live_rows`` scatters into a pool at the least.
+# Alone on a v5e (``PERF.md``, PR 36): into bf16 pools a row costs its
+# 70 ns at 24,576 rows a trip as in one scatter of 786,432, and a trip's
+# own cost is ~17 us; into int8 pools with their f32 scale pools a row
+# costs twice as much in trips of 7,168 rows and below as in trips of
+# 14,336 and above (the compiler stages a scale pool through fast memory
+# only for the larger scatter).
+_UNIT_ROWS_MIN = 16384
+
+
+def _write_unit(n_layers: int, hkv: int, n: int) -> int:
+    """Tokens of one slot that a trip of ``_write_live_rows`` writes: the
+    smallest power of two >= 8 whose rows (``n_layers * hkv`` a token)
+    reach ``_UNIT_ROWS_MIN``, if it divides the slot's run of ``n``
+    tokens into more than one unit; else the whole run. From static
+    shapes only: small enough that a chunk's padding is skipped where a
+    token is many rows (8 tokens on ``ouro-2.6b``: 3,072 rows a token),
+    the whole run where it is few (``qwen2-7b``: 112; a latent cache: a
+    row a layer), so that a live slot costs what it cost."""
+    unit = 8
+    while n_layers * hkv * unit < _UNIT_ROWS_MIN:
+        unit *= 2
+    return unit if unit < n and n % unit == 0 else n
+
+
+def _write_live_rows(pools, rows, table: jax.Array, starts: jax.Array,
+                     valid_len: jax.Array, page: int):
+    """Scatter the rows that something will read: ``pools`` and ``rows``
+    are matching tuples (each pool as ``_scatter_rows`` takes it, each
+    row array [L, slots, n, hkv] + tail), ``table`` [slots, P], ``starts``
+    and ``valid_len`` [slots]. Returns the pools.
+
+    A slot's ``n`` new tokens are ``n / unit`` units of consecutive
+    tokens, and a unit is live iff its first token lies before the
+    slot's ``valid_len``. ONE loop runs over the live units, live-first,
+    and a trip scatters one unit's rows into every pool, in place: its
+    trip count is the live work. A dead slot of a ring merge and the
+    padding of a prefill chunk cost nothing, where every row used to be
+    scattered at the full price, the dead ones to the trash page. Rows
+    of a live unit past ``valid_len`` (at most ``unit - 1``) still go
+    there. Trips run one after another, so a lane row that two units
+    share is read back by the second as the first left it
+    (``_scatter_rows_lane_packed``)."""
+    n_layers, slots, n, hkv = rows[0].shape[:4]
+    unit = _write_unit(n_layers, hkv, n)
+    per_slot = n // unit
+    # Every unit's first token, tokens due and write indices, [slots *
+    # per_slot] of each: a few hundred at the most. (``repeat`` and a
+    # stable ``argsort`` of the flags, not gathers and ``nonzero``: these
+    # trace and lower in a third of the time, in each of a server's ~100
+    # programs that write rows.)
+    def per_unit(x):                       # [slots, ...] -> [units, ...]
+        return jnp.repeat(x, per_slot, axis=0)
+    off = jnp.tile(jnp.arange(per_slot, dtype=starts.dtype) * unit, slots)
+    due = per_unit(valid_len) - off
+    live = due > 0
+    flat_idx = _flat_write_indices(per_unit(table), per_unit(starts) + off,
+                                   unit, due, page)
+    order = jnp.argsort(~live, stable=True)             # live units first
+    # [L, slots, n] -> [L, units, unit]: a slot's tokens are consecutive
+    units = [r.reshape((n_layers, slots * per_slot, unit) + r.shape[3:])
+             for r in rows]
+
+    def write_unit(i, pools):
+        u = order[i]
+        idx = lax.dynamic_slice_in_dim(flat_idx, u, 1)
+        return tuple(
+            _scatter_rows(pool, lax.dynamic_slice_in_dim(r, u, 1, axis=1),
+                          idx)
+            for pool, r in zip(pools, units))
+
+    return lax.fori_loop(0, jnp.sum(live, dtype=jnp.int32), write_unit,
+                         tuple(pools))
+
+
 def merge_rows_into_pool(cache: PagedKVCache, k_rows, v_rows,
                          table: jax.Array, starts: jax.Array,
                          valid_len: jax.Array,
                          mesh=None) -> PagedKVCache:
     """Scatter [L, slots, n, hkv, d] new rows into the pool through the
-    page table. For int8 pools the rows arrive PRE-quantized as
-    ``(codes, scales)`` tuples — quantizing per layer inside the caller's
-    scan keeps the stacked transient int8 (a 7B prefill chunk's bf16
-    [L, n, chunk] rows alone are ~4 GB; int8 is ~1 GB).
+    page table: token j of slot b, if j < ``valid_len_b``, lands at
+    position ``starts_b + j`` of the slot's pages; rows past
+    ``valid_len`` are written nowhere a request reads
+    (``_write_live_rows``). For int8 pools the rows arrive PRE-quantized
+    as ``(codes, scales)`` tuples — quantizing per layer inside the
+    caller's scan keeps the stacked transient int8 (a 7B prefill chunk's
+    bf16 [L, n, chunk] rows alone are ~4 GB; int8 is ~1 GB).
 
     ``mesh``: REQUIRED whenever the pool is tp-sharded. The fully-flat
     scatter below folds the head dim into its indices, which GSPMD
@@ -280,23 +360,18 @@ def merge_rows_into_pool(cache: PagedKVCache, k_rows, v_rows,
     if axes is not None:
         return _merge_rows_sharded(cache, k_rows, v_rows, table, starts,
                                    valid_len, mesh, *axes)
+    pools, rows = _pools_and_rows(cache, k_rows, v_rows)
+    return PagedKVCache(*_write_live_rows(pools, rows, table, starts,
+                                          valid_len, cache.page_size))
+
+
+def _pools_and_rows(cache: PagedKVCache, k_rows, v_rows):
+    """The cache's pools and the row array bound for each, in
+    ``PagedKVCache``'s field order."""
     if cache.quantized:
-        kq, ks = k_rows
-        vq, vs = v_rows
-        n = kq.shape[2]
-        flat_idx = _flat_write_indices(table, starts, n, valid_len,
-                                       cache.page_size)
-        return cache._replace(
-            pool_k=_scatter_rows(cache.pool_k, kq, flat_idx),
-            pool_v=_scatter_rows(cache.pool_v, vq, flat_idx),
-            k_scale=_scatter_rows(cache.k_scale, ks, flat_idx),
-            v_scale=_scatter_rows(cache.v_scale, vs, flat_idx))
-    n = k_rows.shape[2]
-    flat_idx = _flat_write_indices(table, starts, n, valid_len,
-                                   cache.page_size)
-    return cache._replace(
-        pool_k=_scatter_rows(cache.pool_k, k_rows, flat_idx),
-        pool_v=_scatter_rows(cache.pool_v, v_rows, flat_idx))
+        (kq, ks), (vq, vs) = k_rows, v_rows
+        return tuple(cache), (kq, vq, ks, vs)
+    return (cache.pool_k, cache.pool_v), (k_rows, v_rows)
 
 
 def _pool_shard_axes(cache: PagedKVCache, table: jax.Array, mesh):
@@ -329,33 +404,14 @@ def _merge_rows_sharded(cache: PagedKVCache, k_rows, v_rows,
     dp-sharded. See the caller's docstring for why GSPMD alone cannot
     do this without all-gathering the pool."""
     from jax.sharding import PartitionSpec as P
-    quantized = cache.quantized
-    pool_s = P(None, None, tp, None, None)
-    spool_s = P(None, None, tp, None)
+    pools, rows = _pools_and_rows(cache, k_rows, v_rows)
+    pool_specs = tuple(P(None, None, tp, *(None,) * (pool.ndim - 3))
+                       for pool in pools)      # scale pools are rank 4
     rows_s = P(None, dp, None, tp, None)      # codes AND rank-5 scales
-    args: List[Any] = [cache.pool_k, cache.pool_v]
-    specs: List[Any] = [pool_s, pool_s]
-    if quantized:
-        kq, ks = k_rows
-        vq, vs = v_rows
-        args += [cache.k_scale, cache.v_scale, kq, ks, vq, vs]
-        specs += [spool_s, spool_s, rows_s, rows_s, rows_s, rows_s]
-    else:
-        args += [k_rows, v_rows]
-        specs += [rows_s, rows_s]
-    args += [table, starts, valid_len]
-    specs += [P(dp, None), P(dp), P(dp)]
-    out_s = ((pool_s, pool_s, spool_s, spool_s) if quantized
-             else (pool_s, pool_s))
 
     def body(*flat):
-        if quantized:
-            pk, pv, ksc, vsc, akq, aks, avq, avs, tbl, st, vl = flat
-            rows = [akq, aks, avq, avs]
-        else:
-            pk, pv, akr, avr, tbl, st, vl = flat
-            ksc = vsc = None
-            rows = [akr, avr]
+        pools, rows = flat[:len(pool_specs)], flat[len(pool_specs):-3]
+        tbl, st, vl = flat[-3:]
         if dp is not None:
             # Regroup the dp-sharded row batch so EVERY dp shard
             # applies every slot's updates — the pool replicates over
@@ -366,30 +422,20 @@ def _merge_rows_sharded(cache: PagedKVCache, k_rows, v_rows,
             tbl = lax.all_gather(tbl, dp, axis=0, tiled=True)
             st = lax.all_gather(st, dp, axis=0, tiled=True)
             vl = lax.all_gather(vl, dp, axis=0, tiled=True)
-        local = PagedKVCache(pool_k=pk, pool_v=pv, k_scale=ksc,
-                             v_scale=vsc)
-        n = rows[0].shape[2]
-        flat_idx = _flat_write_indices(tbl, st, n, vl, local.page_size)
-        if quantized:
-            akq, aks, avq, avs = rows
-            return (_scatter_rows(pk, akq, flat_idx),
-                    _scatter_rows(pv, avq, flat_idx),
-                    _scatter_rows(ksc, aks, flat_idx),
-                    _scatter_rows(vsc, avs, flat_idx))
-        akr, avr = rows
-        return (_scatter_rows(pk, akr, flat_idx),
-                _scatter_rows(pv, avr, flat_idx))
+        return _write_live_rows(pools, rows, tbl, st, vl,
+                                cache.page_size)
 
     # Replication checking is off: with a dp-sharded row batch the pool
     # outputs ARE replicated over dp — every shard gathers the full row
     # set before scattering — but the checker cannot see through the
     # explicit all_gather.
-    out = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                        out_specs=out_s, check_vma=False)(*args)
-    if quantized:
-        return cache._replace(pool_k=out[0], pool_v=out[1],
-                              k_scale=out[2], v_scale=out[3])
-    return cache._replace(pool_k=out[0], pool_v=out[1])
+    out = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=pool_specs + (rows_s,) * len(rows)
+        + (P(dp, None), P(dp), P(dp)),
+        out_specs=pool_specs, check_vma=False)(
+            *pools, *rows, table, starts, valid_len)
+    return PagedKVCache(*out)
 
 
 def _maybe_quantize_rows(new_kv, quantized):
@@ -786,8 +832,8 @@ def paged_spec_verify(
     written so far (``paged_prefill_chunk``'s attention math with
     every position's logits kept), device-side acceptance
     (``speculative.verify_tokens``), and a MASKED merge of the accepted
-    rows — ``merge_rows_into_pool``'s ``valid_len`` mask redirects rows
-    past each slot's commit count to the trash page, so per-slot
+    rows — ``merge_rows_into_pool``'s ``valid_len`` keeps rows past each
+    slot's commit count out of every page a request reads, so per-slot
     variable acceptance never changes a program shape.
 
     Returns ``(commit [n, k+1], n_commit [n], new_tok [n], new_cache)``
@@ -1922,7 +1968,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             P_needed = max(P_needed, self._pages_needed(
                 int(lengths[i]) + int(valid[i])))
         for i in range(len(batch), n):           # padding rows: valid=0
-            lengths[i] = self._slot_len[batch[0]]   # rows write to trash
+            lengths[i] = self._slot_len[batch[0]]   # no row is written
         from skypilot_tpu.inference.engine import _bucket_len
         P = _bucket_len(P_needed, minimum=1)
         table_p = np.zeros((n, P), np.int32)
@@ -1989,6 +2035,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         chunk_t1 = clock.monotonic()
         self.chunks_prefilled += 1
         self._prof.note_prefill_pairs(pairs, tokens=chunk_tokens)
+        self._prof.note_pool_write(chunk_tokens, n * chunk_w)
         for i, slot in enumerate(batch):
             r = self._slots[slot]
             if r.trace is not None:
@@ -2291,7 +2338,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
     def _get_ingest(self, nb: int, P: int):
         """Compiled handoff merge: land a [L, 1, nb, hkv(, d)] row
         batch into the pool through a [1, P] page table (padding rows
-        past ``valid`` redirect to the trash page). Donates the pool —
+        past ``valid`` reach no page a request reads). Donates the pool —
         the scatter runs in place like every other merge."""
         key = (nb, P)
         if key in self._ingest_fns:
@@ -2328,8 +2375,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         P = _bucket_len(len(pages), minimum=1)
         # Row bucket: bounded compiled-program count. nb may exceed
         # P*page for non-power-of-two page sizes; padding rows past
-        # ``valid`` mask to the trash page (their clamped table
-        # lookups are discarded), so the overshoot is harmless.
+        # ``valid`` are skipped or masked to the trash page (their
+        # clamped table lookups are discarded), so the overshoot is
+        # harmless.
         nb = _bucket_len(n_rows, minimum=8)
         table = np.zeros((1, P), np.int32)
         table[0, :len(pages)] = pages
@@ -2756,6 +2804,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 horizon * sum(self._pages_needed(int(lengths[s]))
                               for s in active_slots),
                 horizon * self.max_batch * P)
+        self._prof.note_pool_write(horizon * len(active_slots),
+                                   horizon * self.max_batch)
         self._prof.tag(horizon=horizon, pages=P)
         with self._prof.jit_key('decode', (horizon, sample, P)):
             toks, self.cache = self._decode_fn(

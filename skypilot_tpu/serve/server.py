@@ -1661,6 +1661,10 @@ class ModelServer:
                     profiler_lib.KV_CACHE_LAYERS_METRIC),
                 'kv_token_bytes': self._counter_value(
                     profiler_lib.KV_TOKEN_BYTES_METRIC),
+                'pool_write_rows_live_total': self._counter_value(
+                    profiler_lib.POOL_ROWS_LIVE_METRIC),
+                'pool_write_rows_offered_total': self._counter_value(
+                    profiler_lib.POOL_ROWS_OFFERED_METRIC),
             },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),

@@ -62,6 +62,11 @@ KV_TOKEN_BYTES_METRIC = 'skytpu_kv_token_bytes'
 # it reads against pages the padded table holds.
 ATTN_PAGES_LIVE_METRIC = 'skytpu_decode_attn_pages_live_total'
 ATTN_PAGES_TABLE_METRIC = 'skytpu_decode_attn_pages_table_total'
+# Row writes into the paged pools (inference/paged.py
+# ``_write_live_rows``): token rows due against token rows the programs'
+# shapes carried; the difference is what the write loop skips.
+POOL_ROWS_LIVE_METRIC = 'skytpu_pool_write_rows_live_total'
+POOL_ROWS_OFFERED_METRIC = 'skytpu_pool_write_rows_offered_total'
 ANNOTATION_PREFIX = 'skytpu:'
 
 
@@ -95,6 +100,9 @@ class NullProfiler:
 
     def note_decode_attn_pages(self, live: int, table: int) -> None:
         del live, table
+
+    def note_pool_write(self, live: int, offered: int) -> None:
+        del live, offered
 
     def phase_stats(self) -> Dict[str, Any]:
         return {}
@@ -160,6 +168,15 @@ class StepProfiler:
             'Pages the padded page table of those dispatches held (slots '
             'x page bucket), summed over decode substeps (under the live '
             'counter: the share of the table that is live)')
+        self._pool_rows_live = self._reg.counter(
+            POOL_ROWS_LIVE_METRIC,
+            'Token rows due for the paged pools in enqueued ring merges '
+            'and prefill chunks (the sum of their slots\' valid_len)')
+        self._pool_rows_offered = self._reg.counter(
+            POOL_ROWS_OFFERED_METRIC,
+            'Token rows those programs\' shapes carried (slots x tokens a '
+            'slot; over the live counter: the share of a row write that '
+            'the loop over live units skips)')
         self._hists: Dict[str, registry_lib.Histogram] = {}
         self._seen_keys: Dict[str, set] = {}
         self.compile_events: List[Dict[str, Any]] = []
@@ -284,6 +301,14 @@ class StepProfiler:
         times the dispatch's substeps. Host arithmetic at the enqueue."""
         self._attn_pages_live.inc(live)
         self._attn_pages_table.inc(table)
+
+    def note_pool_write(self, live: int, offered: int) -> None:
+        """A ring merge's or a prefill chunk's dispatch: the token rows
+        due for the pools (the sum of ``valid_len``) and the rows the
+        program's shape carries (slots x tokens a slot). Host
+        arithmetic."""
+        self._pool_rows_live.inc(live)
+        self._pool_rows_offered.inc(offered)
 
     def phase_stats(self) -> Dict[str, Any]:
         """Per-phase summary for THIS engine (bench's latency

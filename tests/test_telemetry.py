@@ -998,10 +998,16 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          # what a cached token is, and the prompt tokens
                          # prefilled (PR 35)
                          'prefill_tokens_total', 'kv_cache_layers',
-                         'kv_token_bytes'}
+                         'kv_token_bytes',
+                         # token rows due for the pools against rows the
+                         # row-write programs' shapes carried (PR 36)
+                         'pool_write_rows_live_total',
+                         'pool_write_rows_offered_total'}
     assert all(isinstance(v, (int, float)) for v in loop.values())
     assert loop['moe_layer_steps_total'] == 0
     assert loop['prefill_tokens_total'] > 0
+    assert 0 < loop['pool_write_rows_live_total'] \
+        < loop['pool_write_rows_offered_total']     # 1 live slot of 2
     assert loop['kv_cache_layers'] == 2             # tiny: 2 layers, 1 pass
     assert loop['kv_token_bytes'] == 2 * 2 * 2 * 16 * 2
     assert loop['prefill_attn_pairs_total'] > 0     # the warm-up's prompt

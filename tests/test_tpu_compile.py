@@ -395,69 +395,186 @@ def test_latent_decode_program_reads_no_page_it_does_not_need(
         assert 'gather' in made and temp > 250e6
 
 
-# ----------------------------------- the latent cache's lane-packed row write
-@pytest.mark.parametrize('form', ['whole_lane_rows', 'windowed'])
-@pytest.mark.parametrize('program', ['merge_ring_into_pool',
-                                     'prefill_chunk_row_write'])
-def test_latent_row_writes_hold_no_loop_and_no_pool_copy(
-        one_chip, monkeypatch, program, form):
-    """The two programs that write rows into the ``longctx`` cell's pools
-    (8 layers, 2818 pages of 128, latent 512 + rope 64 two tokens to a
-    lane row): the ring merge of 32 slots x 8 steps, and the row write
-    that ends a 1 x 256 prefill chunk. Each is 2,048 rope rows. Written
-    as whole lane rows they compile to one ``scatter`` a pool: no
-    ``while``, nothing made that is as large as the rope pool (369 MB),
-    next to no temp, both pools aliased in place. The 2-D windowed
-    scatter they replace compiled to a serial ``while`` of 2,048 trips
-    (``s32[2048,2]`` indices), 6-7.6 ms of an 8.1 ms merge and of a 23.4
-    ms chunk on the chip (``PERF.md``, PR 33)."""
-    cfg = _glm_cfg()
-    cache = _glm_cache(one_chip, cfg)
-    if form == 'windowed':
-        # the 2-D windowed scatter PR 33 replaced: the witness that this
-        # test can see a loop
-        from test_lane_packed_rows import windowed_scatter
-        monkeypatch.setattr(paged, '_scatter_rows_lane_packed',
-                            windowed_scatter)
+# ------------------------------------------------ row writes into the pools
+def _compile_row_write(one_chip, cfg, cache, program, slots, table_p,
+                       kv_dtype='bf16'):
+    """One of the two programs that write rows into ``cache``'s pools,
+    compiled with the cache donated: the ring merge of ``slots`` x 8
+    steps, or the row write that ends a 1 x 256 prefill chunk."""
+    spec, layers = cfg.kv_spec, cfg.n_cache_layers
 
     def s(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def rows(slots, n):
-        return (s((cfg.n_layers, slots, n, 1, cfg.kv_lora_rank),
-                  jnp.bfloat16),
-                s((cfg.n_layers, slots, n, 1, cfg.qk_rope_head_dim),
-                  jnp.bfloat16))
+    def rows(n_slots, n, quantized):
+        shape = (layers, n_slots, n, spec.heads)
+        if quantized:
+            pair = (s(shape + (spec.k_dim,), jnp.int8),
+                    s(shape + (1,), jnp.float32))
+            return pair, pair
+        return (s(shape + (spec.k_dim,), jnp.bfloat16),
+                s(shape + (spec.v_dim,), jnp.bfloat16))
 
-    if program == 'merge_ring_into_pool':
+    if program == 'merge_ring_into_pool':    # quantizes the ring itself
         target = paged.merge_ring_into_pool
-        operands = (*rows(GLM_SLOTS, 8), s((GLM_SLOTS, 64)),
-                    s((GLM_SLOTS,)), s((GLM_SLOTS,), jnp.bool_))
+        operands = (*rows(slots, 8, False), s((slots, table_p)),
+                    s((slots,)), s((slots,), jnp.bool_))
     else:
         target = paged.merge_rows_into_pool
-        operands = (*rows(1, 256), s((1, 64)), s((1,)), s((1,)))
+        operands = (*rows(1, 256, kv_dtype == 'int8'), s((1, table_p)),
+                    s((1,)), s((1,)))
     # A function of its own for each case: ``jit`` caches the trace by
-    # function and shapes, and the two forms differ in neither.
-    compiled = jax.jit(lambda *args: target(*args), donate_argnums=(0,)
-                       ).lower(cache, *operands).compile()
+    # function and shapes, and a patched form differs in neither.
+    return jax.jit(lambda *args: target(*args), donate_argnums=(0,)
+                   ).lower(cache, *operands).compile()
+
+
+def _assert_live_unit_loop_in_place(compiled, cache, moved=(),
+                                    temp_limit=4e6):
+    """One ``while`` (the loop over live units) whose scatters are the
+    pools', every pool aliased in place, under 4 MB of temp, and nothing
+    made as large as the smallest pool but by those scatters (and by
+    the opcodes ``moved``, which the caller accounts for)."""
+    import math
+    import re
     text = compiled.as_text()
     mem = compiled.memory_analysis()
-    pools = (cache.pool_k.size + cache.pool_v.size) * 2          # bytes
-    assert mem.alias_size_in_bytes >= pools
-    if form == 'windowed':
-        assert ' while(' in text and 's32[2048,2]' in text
-        return
-    assert ' while(' not in text
-    assert text.count(' scatter(') == 2
-    # Nothing as large as the rope pool is made but by the two scatters
-    # (each in a fusion of its own), and those in place: no temp to relay
-    # a pool through.
+    pools = jax.tree.leaves(cache)
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    assert mem.temp_size_in_bytes < temp_limit
+    assert text.count(' while(') == 1
+    smallest = min(p.size for p in pools)
+    scattered = []
+    for line in text.splitlines():
+        m = re.match(r'\s*(?:ROOT )?%?[\w.\-]+ = [a-z0-9]+\[([0-9,]+)\]'
+                     r'\S* scatter\(', line)
+        if m:
+            scattered.append(math.prod(map(int, m.group(1).split(','))))
+    big = [n for n in scattered if n >= smallest]
+    assert len(big) == len(pools), scattered
+    # the others order the live units: a few hundred flags at the most
+    assert all(n < 1024 for n in scattered if n < smallest), scattered
     in_place = {'parameter', 'get-tuple-element', 'tuple', 'bitcast',
-                'scatter', 'fusion'}
+                'scatter', 'fusion', 'while', *moved}
     made = {op: n for op, n in _result_sizes(text).items()
-            if op not in in_place and n >= cache.pool_v.size}
+            if op not in in_place and n >= smallest}
     assert not made, made
-    assert mem.temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize('form', ['whole_lane_rows', 'windowed'])
+@pytest.mark.parametrize('program', ['merge_ring_into_pool',
+                                     'prefill_chunk_row_write'])
+def test_latent_row_writes_hold_no_serial_row_loop_and_no_pool_copy(
+        one_chip, monkeypatch, program, form):
+    """The two programs that write rows into the ``longctx`` cell's pools
+    (8 layers, 2818 pages of 128, latent 512 + rope 64 two tokens to a
+    lane row): the ring merge of 32 slots x 8 steps, and the row write
+    that ends a 1 x 256 prefill chunk. Written as whole lane rows, a
+    unit's rope rows are one ``scatter``: the one ``while`` is the loop
+    over live units (PR 36; its unit here is the slot's whole run), its
+    scatters are the two pools', nothing is made that is as large as the
+    rope pool (369 MB), next to no temp, both pools aliased in place.
+    The 2-D windowed scatter that PR 33 replaced compiled to a serial
+    ``while`` of one trip a rope row (``s32[rows,2]`` indices; 2,048 rows
+    a call then), 6-7.6 ms of an 8.1 ms merge and of a 23.4 ms chunk on
+    the chip (``PERF.md``, PR 33): it is the witness that this test can
+    see such a loop, now nested in the unit loop."""
+    cfg = _glm_cfg()
+    cache = _glm_cache(one_chip, cfg)
+    if form == 'windowed':
+        from test_lane_packed_rows import windowed_scatter
+        monkeypatch.setattr(paged, '_scatter_rows_lane_packed',
+                            windowed_scatter)
+    compiled = _compile_row_write(one_chip, cfg, cache, program,
+                                  GLM_SLOTS, 64)
+    text = compiled.as_text()
+    serial_rows = 's32[%d,2]' % (cfg.n_layers * (
+        8 if program == 'merge_ring_into_pool' else 256))
+    if form == 'windowed':
+        assert text.count(' while(') == 2 and serial_rows in text
+        pools = (cache.pool_k.size + cache.pool_v.size) * 2      # bytes
+        assert compiled.memory_analysis().alias_size_in_bytes >= pools
+        return
+    assert serial_rows not in text
+    _assert_live_unit_loop_in_place(compiled, cache)
+
+
+def _cell_cache(one_chip, cell):
+    """(cfg, cache shapes, slots, page-table bucket, KV dtype) of a GQA
+    serving cell's engine, as ``PERF.md`` §4 has them."""
+    cfg, n_pages, kv_dtype, slots, table_p = {
+        'ouro-2.6b.reason': (configs.OURO_2_6B, 41, 'bf16', 16, 4),
+        'qwen2-7b.chat': (configs.QWEN2_7B, 1451, 'int8', 64, 16),
+    }[cell]
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: paged.PagedKVCache.create(
+            cfg, n_pages=n_pages, page_size=PAGE, kv_dtype=kv_dtype)))
+    return cfg, cache, slots, table_p, kv_dtype
+
+
+@pytest.mark.parametrize('program', ['merge_ring_into_pool',
+                                     'prefill_chunk_row_write'])
+@pytest.mark.parametrize('cell', ['ouro-2.6b.reason', 'qwen2-7b.chat'])
+def test_live_row_writes_alias_every_pool_at_the_cells_shapes(
+        one_chip, cell, program):
+    """The loop over live units at ``reason``'s shapes (two bf16 pools
+    of 192 cache layers x 41 pages = 8.25 GB; a ring of 16 slots x 8
+    steps, a 1 x 256 chunk) and at chat's (int8 codes + two f32 scale
+    pools of 1451 pages = 5.49 GB; a ring of 64 slots x 8): every pool
+    aliased in place, under 4 MB of temp, nothing as large as a pool
+    made (compiler, PR 36)."""
+    cfg, cache, slots, table_p, kv_dtype = _cell_cache(one_chip, cell)
+    if cell == 'ouro-2.6b.reason':
+        assert cache.pool_k.shape == (192, 41, 16, PAGE, 128)
+    compiled = _compile_row_write(one_chip, cfg, cache, program, slots,
+                                  table_p, kv_dtype)
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        8.25e9 if cell == 'ouro-2.6b.reason' else 5.49e9)
+    # Chat's chunk, whose unit is the slot's whole run: the compiler
+    # holds one f32 scale pool (83 MB, 1.5 % of the pools' bytes) in the
+    # fast memory across its scatter, as the parent's one-scatter
+    # program did: a move between memory spaces, in place in the
+    # program's own memory; no code pool moves. And the trip's slice of
+    # the rows is a copy of them: 7.6 MB of int8 codes and scales.
+    moved, temp_limit = (), 4e6
+    if (cell, program) == ('qwen2-7b.chat', 'prefill_chunk_row_write'):
+        moved = ('copy-start', 'copy-done', 'slice-start', 'slice-done',
+                 'custom-call')       # ConcatBitcast of the slices
+        temp_limit = 4e6 + 7.6e6
+    _assert_live_unit_loop_in_place(compiled, cache, moved, temp_limit)
+    made = _result_sizes(compiled.as_text())
+    assert all(made.get(op, 0) < cache.pool_k.size for op in moved)
+
+
+def test_a_token_window_written_by_update_slice_relays_a_whole_pool(
+        one_chip, monkeypatch):
+    """The witness that the check above can see a copy: a unit written as
+    ``dynamic_update_slice`` windows of one token, [L, 1, hkv, 1, d],
+    looks cheaper than the flat scatter and makes layout assignment
+    relay a pool through 4.13 GB of temp at ``reason``'s shapes
+    (compiler, PR 36; ``_scatter_rows``' docstring)."""
+    from jax import lax
+
+    def update_slices(pool, rows, flat_idx):
+        page = pool.shape[3]
+        for j in range(rows.shape[2]):
+            tok = flat_idx[0, j]
+            pool = lax.dynamic_update_slice(
+                pool, rows[:, 0, j][:, None, :, None].astype(pool.dtype),
+                (0, tok // page, 0, tok % page, 0))
+        return pool
+
+    monkeypatch.setattr(paged, '_scatter_rows', update_slices)
+    cfg, cache, slots, table_p, _ = _cell_cache(one_chip,
+                                                'ouro-2.6b.reason')
+    compiled = _compile_row_write(one_chip, cfg, cache,
+                                  'merge_ring_into_pool', slots, table_p)
+    one_pool = cache.pool_k.size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes >= one_pool
+    with pytest.raises(AssertionError):
+        _assert_live_unit_loop_in_place(compiled, cache)
 
 
 # ------------------------------------------------ a looped decoder's decode
