@@ -206,6 +206,8 @@ def refuse_unsupported(cfg, **asked) -> None:
     ``adapter_slots``, ``mesh``, ``decode_impl``."""
     if cfg.n_loops > 1:
         _refuse_looped(cfg, asked)
+    if cfg.recurrent:
+        _refuse_recurrent(cfg, asked)
     if not cfg.latent:
         return
     reasons = {
@@ -269,6 +271,45 @@ def _refuse_looped(cfg, asked) -> None:
                         'the fused-merge kernel has not been held to a '
                         "reference over pass * n_layers + layer; "
                         "'pallas' takes that row as its layer"),
+    }, asked)
+
+
+def _refuse_recurrent(cfg, asked) -> None:
+    """``refuse_unsupported`` for a model some of whose layers keep a
+    per-slot state in place of cache rows (``cfg.mixer_pattern``). (KV
+    export / ingest and prefix snapshots are refused where they are
+    asked for, ``PagedInferenceEngine._refuse_kv_transfer``; a prefix
+    is neither matched nor registered: no state is kept at a page
+    boundary.)"""
+    if cfg.n_loops > 1:
+        raise ValueError(
+            f'{cfg.name} (mixer_pattern={cfg.mixer_pattern}) cannot be '
+            f'combined with n_loops={cfg.n_loops}: a state a slot is '
+            'kept per layer, not per (pass, layer)')
+    _refuse_first(cfg, f'mixer_pattern={cfg.mixer_pattern}', {
+        'quantize': (asked.get('quantize') is not None,
+                     'quantize_params knows the dense GQA leaves only: '
+                     'not the recurrent mixer\'s projections nor the '
+                     'expert stacks'),
+        'kv_cache_dtype': (asked.get('kv_cache_dtype', 'bf16') != 'bf16',
+                           'the recurrent state is float32 and is not '
+                           'quantized, and no test holds quantized rows '
+                           'beside it to the reference'),
+        'speculate_k': (bool(asked.get('speculate_k')),
+                        'a rejected draft would have to roll the '
+                        'recurrent state back, and no state before the '
+                        'drafts is kept'),
+        'adapter_slots': (bool(asked.get('adapter_slots')),
+                          'the LoRA bank targets wq/wk/wv/wo and the '
+                          'dense FFN; the recurrent mixer and the routed '
+                          'FFN have neither'),
+        'mesh': (asked.get('mesh') is not None,
+                 'the per-slot state and the held experts are not yet '
+                 'placed over a mesh (no all-to-all)'),
+        'decode_impl': (asked.get('decode_impl') == 'cross_layer',
+                        'the fused-merge kernel has not been held to a '
+                        'reference on a model whose cache layers are '
+                        'not its layers'),
     }, asked)
 
 
@@ -383,7 +424,16 @@ class _EngineBase:
                           'Cache layers a token has: layers x passes'),
                 reg.gauge(profiler_lib.KV_TOKEN_BYTES_METRIC,
                           'Stored bytes of one cached token over all '
-                          'its cache layers'))
+                          'its cache layers'),
+                reg.gauge(profiler_lib.RECURRENT_LAYERS_METRIC,
+                          'Layers that keep a per-slot state in place of '
+                          'cache rows'),
+                reg.gauge(profiler_lib.RECURRENT_STATE_BYTES_METRIC,
+                          "Bytes of one slot's recurrent state over all "
+                          'those layers'),
+                reg.gauge(profiler_lib.MOE_HELD_EXPERTS_METRIC,
+                          "Routed experts of a layer this program holds "
+                          '(all of them unless told a range)'))
             self._kv_read_gauge = reg.gauge(
                 KV_READ_METRIC,
                 'KV-cache bytes one decode substep streams from HBM '
@@ -394,9 +444,18 @@ class _EngineBase:
         """Set the two layout gauges, once ``cfg`` and the cache dtype
         are resolved."""
         if self._kv_layout_gauges is not None:
-            layers, token_bytes = self._kv_layout_gauges
+            layers, token_bytes = self._kv_layout_gauges[:2]
             layers.set(self.cfg.n_cache_layers)
             token_bytes.set(kv_token_bytes(self.cfg, self.kv_cache_dtype))
+
+    def _note_state_layout(self) -> None:
+        """Set the gauges of what the model keeps beside cache rows and
+        of the experts it holds, once the state is sized."""
+        if self._kv_layout_gauges is not None:
+            layers, state_bytes, held = self._kv_layout_gauges[2:]
+            layers.set(self.cfg.n_recurrent_layers)
+            state_bytes.set(self._state_slot_bytes)
+            held.set(self.cfg.held_experts)
 
     def _note_decode_step(self, live_tokens: int) -> None:
         """Per-dispatch attribution behind the KV-round-two gauge: the

@@ -138,6 +138,104 @@ class PagedKVCache(NamedTuple):
                    pool_v=jnp.zeros(shape, cfg.dtype))
 
 
+class RecurrentState(NamedTuple):
+    """What the layers that cache no rows keep instead, a slot: the
+    sequence's whole past in a fixed size (``cfg.state_spec``), beside
+    the pool in the one engine. It has no pages, no ring and no length:
+    the programs that advance a slot's tokens advance its state in place
+    (donated), a slot's first chunk starts it from zeros, and nothing
+    can rebuild it from the pool's rows."""
+    state: jax.Array       # [recurrent layers, slots, heads, dk, dv] f32
+    conv: jax.Array        # [recurrent layers, slots, taps - 1, channels]
+
+    @classmethod
+    def create(cls, cfg: ModelConfig, slots: int) -> 'RecurrentState':
+        spec, L = cfg.state_spec, cfg.n_recurrent_layers
+        return cls(
+            state=jnp.zeros((L, slots, spec.heads, spec.k_dim, spec.v_dim),
+                            jnp.float32),
+            conv=jnp.zeros((L, slots, spec.conv_rows, spec.conv_dim),
+                           cfg.dtype))
+
+    def rows(self, slot_ids: jax.Array, fresh: jax.Array
+             ) -> 'RecurrentState':
+        """The state of ``slot_ids`` [n] (an id past the slots: any
+        row), zeros where ``fresh``: a sequence's start."""
+        def take(a):
+            a = jnp.take(a, slot_ids, axis=1, mode='clip')
+            keep = ~fresh.reshape((1, -1) + (1,) * (a.ndim - 2))
+            return jnp.where(keep, a, jnp.zeros((), a.dtype))
+        return RecurrentState(take(self.state), take(self.conv))
+
+    def with_rows(self, slot_ids: jax.Array, rows: 'RecurrentState'
+                  ) -> 'RecurrentState':
+        """``rows`` written back at ``slot_ids``; an id past the slots
+        writes nothing."""
+        return RecurrentState(
+            self.state.at[:, slot_ids].set(rows.state, mode='drop'),
+            self.conv.at[:, slot_ids].set(rows.conv, mode='drop'))
+
+
+def _recurrent_layer(layer: Params, li, x: jax.Array, rec: RecurrentState,
+                     cfg: ModelConfig, positions: jax.Array, live,
+                     kernel: bool = False):
+    """One layer whose mixer keeps a state and no rows: row ``li`` of
+    ``rec`` in, the state after these tokens written back in its place
+    (``rec`` is a loop carry: in place). ``kernel`` (one token a slot):
+    the state is advanced where it lies by ``ops.kda``'s kernel, the
+    live slots' alone; else by XLA on every row it is given. Returns
+    ((x, rec), the layer's expert counters)."""
+    tail = lax.dynamic_index_in_dim(rec.conv, li, 0, keepdims=False)
+    if kernel:
+        from skypilot_tpu.ops.kda import recurrent_step_in_place
+        slots, advanced = x.shape[0], {}
+        active = (jnp.ones((slots,), bool) if live is None
+                  else jnp.broadcast_to(live, (slots, 1))[:, 0])
+
+        def step_fn(state, *qkvgb):
+            del state                   # it stays in the stack
+            advanced['state'], o = recurrent_step_in_place(
+                rec.state, li, *qkvgb, active,
+                interpret=jax.default_backend() != 'tpu')
+            return o, None
+
+        x, (_, tail), aux = llama._layer_core(
+            layer, x, cfg, positions, step_fn, live=live, rec=(None, tail))
+        state = advanced['state']
+    else:
+        before = lax.dynamic_index_in_dim(rec.state, li, 0, keepdims=False)
+        x, (after, tail), aux = llama._layer_core(
+            layer, x, cfg, positions, None, live=live, rec=(before, tail))
+        state = lax.dynamic_update_index_in_dim(rec.state, after, li, 0)
+    conv = lax.dynamic_update_index_in_dim(
+        rec.conv, tail.astype(rec.conv.dtype), li, 0)
+    return (x, RecurrentState(state, conv)), aux
+
+
+def _carrying_state(kv_layer_body, cfg: ModelConfig, positions: jax.Array,
+                    live, kernel: bool = False):
+    """The layer body of a model with recurrent layers, from the body of
+    a layer that caches rows: the carry is (x, every recurrent layer's
+    state); a recurrent layer advances its row of the state, any other
+    runs ``kv_layer_body`` on x and hands the state through."""
+    def body(carry, layer_and_idx):
+        x, rec = carry
+        layer, li = layer_and_idx
+        if 'kda' in layer:
+            return _recurrent_layer(layer, li, x, rec, cfg, positions, live,
+                                    kernel=kernel)
+        x, ys = kv_layer_body(x, layer_and_idx)
+        return (x, rec), ys
+    return body
+
+
+def _rows_need_live(cfg: ModelConfig) -> bool:
+    """Whether a layer reads which rows carry a token: routed experts
+    (a dead row routes nowhere) and recurrent mixers (it moves no
+    state)."""
+    return cfg.latent or cfg.ffn_kind == 'routed_shared' or cfg.recurrent
+
+
 def paged_cache_logical_axes(quantized: bool = False) -> PagedKVCache:
     pool = ('layers', None, 'kv_heads', None, 'head_dim')
     if quantized:
@@ -517,6 +615,8 @@ def paged_decode_horizon(
                                        # bank gather inside the scan
     vocab_mask: Optional[jax.Array] = None,  # [slots, vocab] bool
                                        # constrained-decoding mask
+    rec: Optional[RecurrentState] = None,    # the recurrent layers'
+                                       # state, every slot's
 ):
     """``horizon`` fused decode steps over the paged pool: the cached
     rows are read by either a per-layer page gather or the Pallas
@@ -530,7 +630,10 @@ def paged_decode_horizon(
     READ-ONLY on the cache: returns (tokens [slots, horizon],
     ring_k, ring_v [L, slots, horizon, hkv, d]); the caller scatters
     the ring into the pool via ``merge_ring_into_pool`` in a separate
-    donated program (see its docstring for why)."""
+    donated program (see its docstring for why). A recurrent model's
+    ``rec`` has no ring: it is carried through the steps, each active
+    slot's state advanced a token a step (the others' left as they
+    were), and returned as a fourth output for the caller to donate."""
     b = tokens.shape[0]
     n_layers, spec = cfg.n_cache_layers, cfg.kv_spec
     len0 = lengths
@@ -544,13 +647,13 @@ def paged_decode_horizon(
                        cfg.dtype)
     ring_v = jnp.zeros((n_layers, b, horizon, spec.heads, spec.v_dim),
                        cfg.dtype)
-    live = (None if not cfg.latent or active is None
+    live = (None if not _rows_need_live(cfg) or active is None
             else active[:, None])
     if rngs is None:
         rngs = jnp.zeros((horizon, 2), jnp.uint32)
 
     def one_step(carry, step_in):
-        ring_k, ring_v, tok = carry
+        ring_k, ring_v, tok, rec = carry
         i, rng = step_in
         x = llama._embed_tokens(params, tok[:, None], cfg)
         positions = (len0 + i)[:, None]
@@ -654,8 +757,21 @@ def paged_decode_horizon(
                 live=live)
             return xc, (new_kv, aux)
 
-        x, ((k_rows, v_rows), aux), _ = llama.run_loops(layer_body, x,
-                                                        params, cfg)
+        if rec is None:
+            x, ((k_rows, v_rows), aux), _ = llama.run_loops(
+                layer_body, x, params, cfg)
+        else:
+            # 'pallas': the state kernel, where its blocks tile
+            # (ops/kda.py); else XLA.
+            state = cfg.state_spec
+            (x, rec), ys, _ = llama.run_loops(_carrying_state(
+                layer_body, cfg, positions, live,
+                kernel=(decode_impl == 'pallas'
+                        and state.k_dim % _LANES == 0
+                        and state.v_dim % _LANES == 0)),
+                (x, rec), params, cfg)
+            (k_rows, v_rows), aux = ys['gqa']
+            aux = jnp.concatenate([aux, ys['kda']])
         ring_k = lax.dynamic_update_slice(
             ring_k, k_rows.astype(ring_k.dtype), (0, 0, i, 0, 0))
         ring_v = lax.dynamic_update_slice(
@@ -672,16 +788,23 @@ def paged_decode_horizon(
         # (host evicts exactly that request at readback; co-batched
         # slots continue) — see llama.mask_nonfinite_tokens.
         nxt = llama.mask_nonfinite_tokens(logits, nxt)
-        return (ring_k, ring_v, nxt), (nxt, jnp.sum(aux))
+        return (ring_k, ring_v, nxt, rec), (nxt, jnp.sum(aux, axis=0))
 
-    (ring_k, ring_v, _), (toks, step_aux) = lax.scan(
-        one_step, (ring_k, ring_v, tokens), (jnp.arange(horizon), rngs))
+    (ring_k, ring_v, _, rec), (toks, step_aux) = lax.scan(
+        one_step, (ring_k, ring_v, tokens, rec),
+        (jnp.arange(horizon), rngs))
     toks = toks.T
     if cfg.ffn_kind == 'routed_shared':
         # One more row under the slots' tokens: the distinct experts each
-        # step read, summed over its expert layers. It rides the one
-        # readback the call has (PagedInferenceEngine._process_one).
-        toks = jnp.concatenate([toks, step_aux.astype(toks.dtype)[None]])
+        # step read, summed over its expert layers (and, where the model
+        # holds a share of its experts, a second: the assignments that
+        # were held). They ride the one readback the call has
+        # (PagedInferenceEngine._process_one).
+        counted = step_aux.astype(toks.dtype)
+        toks = jnp.concatenate(
+            [toks, counted[None] if counted.ndim == 1 else counted.T])
+    if rec is not None:
+        return toks, ring_k, ring_v, rec
     return toks, ring_k, ring_v
 
 
@@ -723,6 +846,10 @@ def paged_prefill_chunk(
     mlora_idx: Optional[jax.Array] = None,   # [n] adapter slot per row
     vocab_mask: Optional[jax.Array] = None,  # [n, vocab] bool mask for
                                        # the completing rows' first token
+    rec: Optional[RecurrentState] = None,    # the recurrent layers'
+                                       # state, every slot's
+    slot_ids: Optional[jax.Array] = None,    # [n] each row's slot (past
+                                       # the slots: a padding row)
 ):
     """One fixed-size prefill chunk for ``n`` slots: attends against the
     pages written so far (each slot's ``lengths``) plus causal
@@ -739,7 +866,13 @@ def paged_prefill_chunk(
     idle was a double-digit share of sustained-serving slot time once
     decode itself got fast). ``w8a8`` quantizes the layer-matmul
     activations per token (prefill is compute-bound; see
-    ``quantization.w8a8_region``) — the unembed stays W8A16."""
+    ``quantization.w8a8_region``) — the unembed stays W8A16.
+
+    A recurrent model's ``rec`` carries each slot's state from chunk to
+    chunk: the rows' states are taken out (zeros for a row with no
+    context yet: a new request's first chunk), advanced over the row's
+    ``valid`` tokens (padding moves nothing) and written back; the
+    return is then (first_tokens, new cache, new rec)."""
     n, chunk = tokens.shape
     len0 = lengths
     pool_k, pool_v = cache.pool_k, cache.pool_v
@@ -747,8 +880,9 @@ def paged_prefill_chunk(
     x = llama._embed_tokens(params, tokens, cfg)
     positions = len0[:, None] + jnp.arange(chunk)[None, :]
     # Padding rows of a piece route nowhere (a latent model's experts).
-    live = (jnp.arange(chunk)[None, :] < valid[:, None] if cfg.latent
-            else None)
+    live = (jnp.arange(chunk)[None, :] < valid[:, None]
+            if _rows_need_live(cfg) else None)
+    rec_rows = None if rec is None else rec.rows(slot_ids, len0 == 0)
 
     def layer_body(xc, layer_and_idx):
         layer, li = layer_and_idx
@@ -780,8 +914,14 @@ def paged_prefill_chunk(
 
     from skypilot_tpu.models.quantization import w8a8_region
     with (w8a8_region() if w8a8 else contextlib.nullcontext()):
-        x, (k_rows, v_rows), _ = llama.run_loops(layer_body, x, params,
-                                                 cfg)
+        if rec is None:
+            x, (k_rows, v_rows), _ = llama.run_loops(layer_body, x, params,
+                                                     cfg)
+        else:
+            (x, rec_rows), ys, _ = llama.run_loops(
+                _carrying_state(layer_body, cfg, positions, live),
+                (x, rec_rows), params, cfg)
+            k_rows, v_rows = ys['gqa']
     idx = jnp.clip(want_idx, 0, chunk - 1)
     last_x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
     logits = llama._unembed_logits(params, last_x, cfg)[:, 0]
@@ -801,6 +941,8 @@ def paged_prefill_chunk(
 
     new_cache = merge_rows_into_pool(cache, k_rows, v_rows, table_p,
                                      len0, valid_len=valid, mesh=mesh)
+    if rec is not None:
+        return first, new_cache, rec.with_rows(slot_ids, rec_rows)
     return first, new_cache
 
 
@@ -1165,6 +1307,33 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # its ring budget stays at the conservative cap — a user pool
         # sized to fill HBM under the old 512 MB assumption must not
         # suddenly meet a 3x ring at runtime.
+        # The recurrent layers' per-slot state (None: every layer caches
+        # rows). Its shape depends on ``max_batch`` alone, so it adds no
+        # program and no key; it is made before the pool is sized, which
+        # takes what is left.
+        self.rec = (RecurrentState.create(cfg, max_batch)
+                    if cfg.recurrent else None)
+        self._state_slot_bytes = (
+            cfg.n_recurrent_layers * cfg.state_spec.slot_bytes(
+                jnp.dtype(cfg.dtype).itemsize) if cfg.recurrent else 0)
+        # A state cannot be rebuilt from pages: a recurrent model's
+        # requests match and register no prefix (no snapshot is kept at
+        # page boundaries yet), and a preempted one prefills again from
+        # its first token.
+        self._prefix_reuse = not cfg.recurrent
+        # The half-width chunk program (every pending piece <= 128
+        # tokens) halves a chunk's FLOPs. A recurrent model's chunk
+        # mostly reads weights (its mixers' matrices and every held
+        # expert): a 100-token piece alone takes 15.7-16.0 ms through
+        # the 128-wide program and 17.9 through the 256-wide one (my
+        # chip run, PR 37: 2 ms of 18 to win, for a request whose last
+        # piece is prefilled alone). Each of its programs is 6.3-7.3 MB
+        # in the compile cache and the second width doubles their count
+        # (18 more at max_batch 32 and 2k prompts: 6 minutes of
+        # compiles, 115 MB): it keeps the one width until its programs
+        # are smaller (ROADMAP B6 e).
+        self._narrow_chunk = not cfg.recurrent
+        self._note_state_layout()
         self._pool_auto_sized = n_pages is None
         if n_pages is None:
             n_pages = self._auto_n_pages(cfg, max_batch, max_seq,
@@ -1324,6 +1493,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         self._bytes_by_device = {
             'params': device_lib.bytes_by_device(self.params),
             'kv_pool': device_lib.bytes_by_device(self.cache)}
+        if self.rec is not None:
+            self._bytes_by_device['recurrent_state'] = \
+                device_lib.bytes_by_device(self.rec)
 
     @staticmethod
     def _int8_fast_path_reachable(cfg: ModelConfig, mesh) -> bool:
@@ -1407,7 +1579,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # (sharded leaves count their local shard, dp-replicated leaves
         # in full — dividing by mesh.size here was the dp>1 oversizing
         # bug).
-        used = max(used, self._param_bytes + int(0.15e9))
+        used = max(used, self._param_bytes + int(0.15e9)
+                   + self._state_slot_bytes * max_batch)
         # The reserve must cover the decode transients at the LONGEST
         # horizon the ring budget allows — sizing the pool without
         # them compiled programs past HBM at batch=48 on a 7B. The
@@ -1468,12 +1641,17 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         merge_kwargs = ({'out_shardings': self._cache_sh}
                         if self._cache_sh is not None else {})
 
+        # A recurrent model's state is advanced by this program (it has
+        # no ring to merge later): donated, updated in place.
+        rec_kwargs = ({'donate_argnames': ('rec',)}
+                      if self.rec is not None else {})
+
         @functools.partial(jax.jit,
                            static_argnames=('horizon', 'sample'),
-                           **ring_kwargs)
+                           **ring_kwargs, **rec_kwargs)
         def decode_steps(params, cache, table_p, tokens, lengths, rng,
                          temps, topks, topps, active, adp, vmask,
-                         horizon, sample):
+                         horizon, sample, rec=None):
             if sample:
                 def sample_fn(logits, step_rng):
                     from skypilot_tpu.inference.engine import sample_tokens
@@ -1487,7 +1665,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 horizon=horizon, sample_fn=sample_fn, rngs=rngs,
                 active=active, decode_impl=decode_impl,
                 pages_per_block=self.pages_per_block,
-                mlora_idx=adp, vocab_mask=vmask)
+                mlora_idx=adp, vocab_mask=vmask, rec=rec)
 
         # A named function, not a jitted ``functools.partial`` (which
         # has no name: the program was ``jit__unknown`` in every device
@@ -1503,9 +1681,12 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         def decode_and_merge(params, cache, table_p, tokens, lengths,
                              rng, temps, topks, topps, active, adp,
                              vmask, horizon, sample):
-            toks, ring_k, ring_v = decode_steps(
+            toks, ring_k, ring_v, *rec = decode_steps(
                 params, cache, table_p, tokens, lengths, rng, temps,
-                topks, topps, active, adp, vmask, horizon, sample)
+                topks, topps, active, adp, vmask, horizon, sample,
+                rec=self.rec)
+            if rec:
+                self.rec, = rec
             new_cache = merge(cache, ring_k, ring_v, table_p, lengths,
                               active)
             return toks, new_cache
@@ -1522,14 +1703,17 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             mesh = self.mesh
 
             @functools.partial(jax.jit, donate_argnums=(1,),
+                               donate_argnames=('rec',),
                                **self._step_out_shardings(1))
             def prefill(params, cache, table_p, tokens, lengths, valid,
-                        want_idx, adp, vmask, temps, topks, topps, rng):
+                        want_idx, adp, vmask, temps, topks, topps, rng,
+                        rec=None, slot_ids=None):
                 return paged_prefill_chunk(
                     params, cache, table_p, tokens, lengths, valid,
                     want_idx, cfg, temps=temps if sample else None,
                     topks=topks, topps=topps, rng=rng, w8a8=w8a8,
-                    mesh=mesh, mlora_idx=adp, vocab_mask=vmask)
+                    mesh=mesh, mlora_idx=adp, vocab_mask=vmask, rec=rec,
+                    slot_ids=slot_ids)
 
             self._prefill_fns[key] = prefill
         return self._prefill_fns[key]
@@ -1561,9 +1745,29 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             'kv_cache_dtype': self.kv_cache_dtype,
             # Allocatable tokens (page 0 is the reserved trash page).
             'pool_token_capacity': (self.alloc.n_pages - 1) * self.page,
+            # What the layers that cache no rows keep, beside the pool.
+            'recurrent_layers': self.cfg.n_recurrent_layers,
+            'recurrent_state_slot_bytes': self._state_slot_bytes,
+            'recurrent_state_bytes': (self._state_slot_bytes
+                                      * self.max_batch),
             'prefix_hits': self.alloc.prefix_hits,
             'prefix_misses': self.alloc.prefix_misses,
         }
+
+    def recurrent_state_of(self, req) -> Optional[np.ndarray]:
+        """The recurrent state the slot of ``req`` (a request this
+        engine ran) holds now, on the host: float32 [recurrent layers,
+        heads, k_dim, v_dim]; None for a model with no recurrent layer.
+        A finished request's state lies where it was left until another
+        request's first chunk starts the slot from zeros, so a caller
+        that ran ``req`` alone reads the state after every token of its
+        context but the last one produced (a budget-bound request takes
+        no step past its budget: ``_maybe_early_free``). It is a
+        reading, for a comparison with a reference; no snapshot is kept
+        for reuse (ROADMAP B6)."""
+        if self.rec is None:
+            return None
+        return np.asarray(self.rec.state[:, req._slot], np.float32)
 
     def resolved_path(self) -> Dict[str, Any]:
         """What construction resolved (kernel path, pool) and where the
@@ -1626,6 +1830,13 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         }
 
     def _refuse_kv_transfer(self) -> None:
+        if self.cfg.recurrent:
+            raise NotImplementedError(
+                f'{self.cfg.name}: KV export/ingest (handoff, prefix '
+                'snapshots) moves cache rows; '
+                f'{self.cfg.n_recurrent_layers} of its layers keep a '
+                'per-slot state that no row holds, and the wire format '
+                'has no state record yet (kv_transfer.py)')
         if self.cfg.latent:
             raise NotImplementedError(
                 f'{self.cfg.name}: KV export/ingest (handoff, prefix '
@@ -1648,26 +1859,41 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
     # budget and the 32 x 32 that does not fit (compiler, PR 28) outside.
     _ATTN_SCORE_BYTES = 4.5
     _CHUNK_TRANSIENT_BUDGET = int(2.2e9)
+    # float32 arrays of [heads, k_dim] a token that a recurrent mixer's
+    # chunked form holds at once (its q, k, v, log decays, their
+    # decayed forms and the WY terms): the compile for a described v5e
+    # has 131 MB a 256-token piece of 64 heads x 128 (compiler, PR 37).
+    _RECURRENT_TOKEN_ARRAYS = 16
+
+    def _chunk_piece_bytes(self, pages: int) -> int:
+        """What one prompt's piece adds to a chunk program's transients
+        at the page bucket ``pages``: its attention scores [heads,
+        chunk, pages x page]; routed experts' sorted rows (top-k copies
+        of a token through gate, up and down); a recurrent mixer's
+        float32 terms a token."""
+        cfg = self.cfg
+        piece = pages * (cfg.n_heads * self.chunk * self.page
+                         * self._ATTN_SCORE_BYTES)
+        if cfg.ffn_kind == 'routed_shared':
+            piece += self.chunk * cfg.n_experts_per_token * (
+                6 * cfg.dim + 8 * cfg.moe_ffn_dim)
+        if cfg.recurrent:
+            spec = cfg.state_spec
+            piece += (self.chunk * spec.heads * spec.k_dim * 4
+                      * self._RECURRENT_TOKEN_ARRAYS)
+        return int(piece)
 
     def _chunk_batch_cap(self, batch: List[int]) -> int:
         """How many of ``batch``'s pieces one chunk program may hold. Its
-        attention scores are [prompts, heads, chunk, pages x page] at the
-        page bucket of the longest context in it and the prompt bucket
-        above the count, and nothing else capped them: 8k-token prompts
-        batched 32 wide ended the server with RESOURCE_EXHAUSTED. Routed
-        experts add their sorted rows (top-k copies of a token through
-        gate, up and down). Slot-parity pools (the CPU) were sized
-        against nothing and are not capped."""
+        transients (``_chunk_piece_bytes``) are counted at the page
+        bucket of the longest context in it and the prompt bucket above
+        the count, and nothing else capped them: 8k-token prompts
+        batched 32 wide ended the server with RESOURCE_EXHAUSTED.
+        Slot-parity pools (the CPU) were sized against nothing and are
+        not capped."""
         if not self._pool_auto_sized:
             return len(batch)
         from skypilot_tpu.inference.engine import _bucket_len
-        cfg = self.cfg
-        page_bytes = (cfg.n_heads * self.chunk * self.page
-                      * self._ATTN_SCORE_BYTES)
-        row_bytes = 0
-        if cfg.ffn_kind == 'routed_shared':
-            row_bytes = self.chunk * cfg.n_experts_per_token * (
-                6 * cfg.dim + 8 * cfg.moe_ffn_dim)
         pages, fit = 1, 1
         for count, slot in enumerate(batch, 1):
             req = self._slots[slot]
@@ -1677,7 +1903,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 int(self._slot_len[slot]) + min(self.chunk, rest)),
                 minimum=1))
             n = next(b for b in self._PREFILL_N_BUCKETS if b >= count)
-            if n * (pages * page_bytes + row_bytes) \
+            if n * self._chunk_piece_bytes(pages) \
                     > self._CHUNK_TRANSIENT_BUDGET:
                 break
             fit = count
@@ -1818,7 +2044,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # decode call), so registration is capped there — a mid-prefill
         # victim must not register pages it never filled.
         written = (req.prompt + req.output)[:int(self._slot_len[slot]) + 1]
-        if self._pages[slot]:
+        if self._pages[slot] and self._prefix_reuse:
             self.alloc.register_prefix(written, self._pages[slot],
                                        getattr(req, '_n_matched', 0))
         if req.trace is not None:
@@ -1870,7 +2096,14 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # prompt+output resumes generation exactly where it stopped,
             # and the completed-prefill logits ARE its next token.
             ctx = req.prompt + req.output
-            matched = self.alloc.match_prefix(ctx)
+            matched = (self.alloc.match_prefix(ctx) if self._prefix_reuse
+                       else [])
+            if self.rec is not None:
+                # The slot's state starts from zeros at the first chunk
+                # (``lengths == 0``); a resumed request's whole context
+                # is prefilled again, because no state was kept.
+                self._prof.note_state_reset(
+                    recompute_tokens=len(ctx) if req.output else 0)
             # Quantize the resume point to the canonical chunk grid.
             # A cold prefill chunks from offset 0, so its boundaries are
             # exact multiples of ``self.chunk``; resuming a prefix hit at
@@ -1910,6 +2143,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             self._slot_len[slot] = len(matched) * self.page
             req._n_matched = len(matched)        # host-only annotations
             req._ctx = ctx
+            req._slot = slot
             if matched:
                 # A prefix HIT is the strongest heat signal — shared
                 # prefixes are exactly what the preemption checkpoint
@@ -1947,7 +2181,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             - self._prefill_off[s]
             for s in batch)
         chunk_w = (128 if self.chunk > 128 and rest_max <= 128
-                   else self.chunk)
+                   and self._narrow_chunk else self.chunk)
         tokens = np.zeros((n, chunk_w), np.int32)
         lengths = np.zeros(n, np.int32)
         valid = np.zeros(n, np.int32)
@@ -1998,7 +2232,12 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # ONE batched host->device transfer for every host-built
         # operand: each separate jnp.asarray is its own dispatch round
         # trip, nine of them per admission otherwise.
-        extras = tuple(x for x in (adp_h, vm_h) if x is not None)
+        # A recurrent model's rows name their slots (padding: past them).
+        slots_h = None
+        if self.rec is not None:
+            slots_h = np.full(n, self.max_batch, np.int32)
+            slots_h[:len(batch)] = batch
+        extras = tuple(x for x in (adp_h, vm_h, slots_h) if x is not None)
         # Query-key pairs under the causal mask that the chunk needs, a
         # layer: each valid row against its context and the piece up to
         # itself; and its valid tokens. On the upload's annotation too,
@@ -2017,6 +2256,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         rest = list(uploaded[8:])
         adp_d = rest.pop(0) if adp_h is not None else None
         vm_d = rest.pop(0) if vm_h is not None else None
+        state_kwargs = ({} if slots_h is None
+                        else {'rec': self.rec, 'slot_ids': rest.pop(0)})
         # Sampling variant only when a row COMPLETING this chunk needs
         # it: sample_tokens sorts the [n, vocab] logits (hundreds of ms
         # on TPU at vocab 32k) — mid-prompt chunks and greedy
@@ -2028,10 +2269,12 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         with self._prof.phase('prefill_chunk', prompts=n, pages=P,
                               width=chunk_w, sample=sample), \
                 self._prof.jit_key('prefill', (n, P, sample, chunk_w)):
-            first, self.cache = prefill(
+            first, self.cache, *rec = prefill(
                 self.params, self.cache, table_d, tokens_d, lengths_d,
                 valid_d, want_d, adp_d, vm_d, temps_d, topks_d,
-                topps_d, prng)
+                topps_d, prng, **state_kwargs)
+            if rec:
+                self.rec, = rec
         chunk_t1 = clock.monotonic()
         self.chunks_prefilled += 1
         self._prof.note_prefill_pairs(pairs, tokens=chunk_tokens)
@@ -2057,9 +2300,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 continue                         # more chunks to go
             del self._prefill_off[slot]
             self._await_first.add(slot)
-            self.alloc.register_prefix(req._ctx, self._pages[slot],
-                                       req._n_matched)
-            self._note_hot_prefix(req._ctx)
+            if self._prefix_reuse:
+                self.alloc.register_prefix(req._ctx, self._pages[slot],
+                                           req._n_matched)
+                self._note_hot_prefix(req._ctx)
             done_rows.append((i, slot))
         if done_rows:
             # FIXED [n] shapes for the token gather + merge: a
@@ -2876,7 +3120,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # The row under the slots' tokens (paged_decode_horizon).
             self._prof.note_distinct_experts(
                 int(vals[self.max_batch].sum()),
-                entry['horizon'] * self._moe_layers)
+                entry['horizon'] * self._moe_layers,
+                held_assignments=(
+                    None if self.cfg.holds_every_expert
+                    else int(vals[self.max_batch + 1].sum())))
         for slot, req in enumerate(entry['snapshot']):
             if req is None:
                 continue

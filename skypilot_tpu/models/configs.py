@@ -27,6 +27,22 @@ class KVSpec(NamedTuple):
         return self.heads * (self.k_dim + self.v_dim)
 
 
+class StateSpec(NamedTuple):
+    """The state one sequence keeps in one recurrent layer, whatever its
+    length: ``heads`` matrices of ``k_dim`` x ``v_dim`` float32, and the
+    last ``conv_rows`` inputs of the short convolution, ``conv_dim``
+    channels each, in the model's dtype."""
+    heads: int
+    k_dim: int
+    v_dim: int
+    conv_rows: int
+    conv_dim: int
+
+    def slot_bytes(self, dtype_bytes: int) -> int:
+        return (self.heads * self.k_dim * self.v_dim * 4
+                + self.conv_rows * self.conv_dim * dtype_bytes)
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """A decoder-only transformer configuration. ``attn_kind`` and
@@ -111,11 +127,87 @@ class ModelConfig:
     post_norms: bool = False
     exit_gate: bool = False
     early_exit_threshold: float = 1.0
+    # A period pattern of mixer kinds, repeated over the layers: () =
+    # every layer is ``attn_kind``. 'gqa' caches rows a token
+    # (``kv_spec``); 'kda' (Kimi Delta Attention, ``models/kda.py``)
+    # keeps a fixed state a sequence (``state_spec``) and no rows:
+    # ``kda_heads`` x ``kda_head_dim`` keys and values behind a causal
+    # depthwise conv of ``kda_conv`` taps, low-rank (``kda_gate_rank``)
+    # decay and output gates. ``use_rope`` False leaves the GQA layers
+    # without rotary; ``attn_gate`` multiplies their attention output by
+    # sigmoid(W_gate h) elementwise.
+    mixer_pattern: tuple = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_rank: int = 0
+    use_rope: bool = True
+    attn_gate: bool = False
+    # The routed experts this program holds of each layer's
+    # ``n_routed_experts``: ``n_held_experts`` (None = all) from
+    # ``first_held_expert`` on. The router keeps its width; assignments
+    # to absent experts add nothing (one chip's share of an
+    # expert-parallel layer, without its exchange).
+    n_held_experts: Optional[int] = None
+    first_held_expert: int = 0
+
+    def __post_init__(self):
+        # a list out of a JSON file: hashable, as a jit static must be
+        object.__setattr__(self, 'mixer_pattern', tuple(self.mixer_pattern))
+        if self.mixer_pattern:
+            bad = set(self.mixer_pattern) - {'gqa', 'kda'}
+            if bad or self.n_layers % len(self.mixer_pattern):
+                raise ValueError(
+                    f'{self.name}: mixer_pattern {self.mixer_pattern} must '
+                    "hold 'gqa' / 'kda' and divide n_layers "
+                    f'{self.n_layers}')
+        held, first = self.held_experts, self.first_held_expert
+        if not 0 <= first <= first + held <= max(self.n_routed_experts,
+                                                 held):
+            raise ValueError(
+                f'{self.name}: held experts [{first}, {first + held}) lie '
+                f'outside the {self.n_routed_experts} routed over')
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """The mixer kind of every layer, in layer order."""
+        if not self.mixer_pattern:
+            return (self.attn_kind,) * self.n_layers
+        return self.mixer_pattern * (self.n_layers
+                                     // len(self.mixer_pattern))
+
+    @property
+    def n_recurrent_layers(self) -> int:
+        """Layers that keep a fixed state a sequence and no cache rows."""
+        return self.layer_kinds.count('kda')
+
+    @property
+    def recurrent(self) -> bool:
+        return self.n_recurrent_layers > 0
 
     @property
     def n_cache_layers(self) -> int:
-        """Cache rows a token has: one per (pass, layer)."""
-        return self.n_layers * self.n_loops
+        """Cache rows a token has: one per (pass, layer that caches
+        rows)."""
+        return (self.n_layers - self.n_recurrent_layers) * self.n_loops
+
+    @property
+    def state_spec(self) -> Optional[StateSpec]:
+        """What one sequence's state in one recurrent layer is made of."""
+        if not self.recurrent:
+            return None
+        return StateSpec(self.kda_heads, self.kda_head_dim,
+                         self.kda_head_dim, self.kda_conv - 1,
+                         3 * self.kda_heads * self.kda_head_dim)
+
+    @property
+    def held_experts(self) -> int:
+        return (self.n_routed_experts if self.n_held_experts is None
+                else self.n_held_experts)
+
+    @property
+    def holds_every_expert(self) -> bool:
+        return self.held_experts == self.n_routed_experts
 
     @property
     def lora_enabled(self) -> bool:
@@ -168,6 +260,9 @@ class ModelConfig:
         if self.latent:
             from skypilot_tpu.models import latent_moe
             return latent_moe.num_params(self)
+        if self.mixer_pattern:
+            from skypilot_tpu.models import kda
+            return kda.num_params(self)
         return sum(self._dense_param_split())
 
     def flops_per_token(self, training: bool = False) -> float:
@@ -178,6 +273,9 @@ class ModelConfig:
         if self.latent:
             from skypilot_tpu.models import latent_moe
             n = latent_moe.num_params(self, active_only=True)
+        elif self.mixer_pattern:
+            from skypilot_tpu.models import kda
+            n = kda.num_params(self, active_only=True)
         elif self.n_loops > 1:
             n += (self.n_loops - 1) * self._dense_param_split()[0]
         if self.is_moe:
@@ -280,10 +378,38 @@ TINY_OURO = _cfg(
     n_kv_heads=2, ffn_dim=160, max_seq_len=128, remat='none',
     head_dim_override=24, n_loops=3, post_norms=True, exit_gate=True)
 
+# upstage/Solar-Open2-250B (``solar_open2``) as ONE CHIP'S SHARE of an
+# 8-way expert-parallel stage: one whole period [GQA, KDA, KDA, KDA] of
+# the published 12, experts 0-39 of each layer's 320 (routed over all
+# 320, 8 a token), 1/8 of the 196,608-row vocabulary; every width as
+# published (perfbench/configs/solar-open2-250b.json has the arithmetic).
+SOLAR_OPEN2_250B = _cfg(
+    name='solar-open2-250b', vocab_size=24576, dim=4096, n_layers=4,
+    n_heads=64, n_kv_heads=8, ffn_dim=10240, max_seq_len=1048576,
+    rope_theta=10000.0, norm_eps=1e-5, head_dim_override=128,
+    mixer_pattern=('gqa', 'kda', 'kda', 'kda'), kda_heads=64,
+    kda_head_dim=128, kda_conv=4, kda_gate_rank=128, use_rope=False,
+    attn_gate=True, ffn_kind='routed_shared', n_routed_experts=320,
+    n_experts_per_token=8, n_shared_experts=1, moe_ffn_dim=1280,
+    routed_scaling_factor=1.0, n_held_experts=40, first_held_expert=0)
+
+# Two periods; heads != KV heads, KDA heads != heads, its head width !=
+# the GQA one, the gate rank != either, 4 of 16 experts held from 4 on.
+TINY_SOLAR = _cfg(
+    name='tiny-solar', vocab_size=256, dim=64, n_layers=8, n_heads=4,
+    n_kv_heads=2, ffn_dim=160, max_seq_len=256, remat='none',
+    head_dim_override=24, mixer_pattern=('gqa', 'kda', 'kda', 'kda'),
+    kda_heads=3, kda_head_dim=16, kda_conv=4, kda_gate_rank=8,
+    use_rope=False, attn_gate=True, ffn_kind='routed_shared',
+    n_routed_experts=16, n_experts_per_token=2, n_shared_experts=1,
+    moe_ffn_dim=48, routed_scaling_factor=1.0, n_held_experts=4,
+    first_held_expert=4)
+
 PRESETS = {c.name: c for c in [
     LLAMA3_8B, LLAMA3_70B, LLAMA2_7B, LLAMA3_1B, MIXTRAL_8X7B,
     GEMMA_2B, GEMMA_7B, QWEN2_7B, TINY, TINY_MOE, TINY_GEMMA,
-    TINY_QWEN, GLM_4_7_FLASH, TINY_GLM, OURO_2_6B, TINY_OURO]}
+    TINY_QWEN, GLM_4_7_FLASH, TINY_GLM, OURO_2_6B, TINY_OURO,
+    SOLAR_OPEN2_250B, TINY_SOLAR]}
 
 
 def get_config(name: str) -> ModelConfig:

@@ -245,13 +245,24 @@ def routed_experts(experts: Params, expert_layer, x: jax.Array,
     read (int32 scalar). ``experts`` is the whole stack [layers, E, ...]
     and ``expert_layer`` this layer's row of it: the kernel takes the
     stack as it lies, the layer's groups offset into it, and no layer's
-    experts are ever sliced out as a copy (``llama.scan_layers``)."""
+    experts are ever sliced out as a copy (``llama.scan_layers``).
+
+    Told which experts it holds (``cfg.held_experts`` of the
+    ``n_routed_experts`` routed over, from ``first_held_expert``), the
+    layer computes the chosen experts that lie in its range: an
+    assignment to an absent expert owns no group and adds nothing, as a
+    dead row's does. The count is then [2]: distinct experts read, and
+    the live assignments that were held."""
     T, d = x.shape
-    k, E = cfg.n_experts_per_token, cfg.n_routed_experts
+    k, E = cfg.n_experts_per_token, cfg.held_experts
     n_stacked = experts['w_gate'].shape[0]
     experts = {name: leaf.reshape((n_stacked * E,) + leaf.shape[2:])
                for name, leaf in experts.items()}
     flat = chosen.reshape(T * k)
+    partial = not cfg.holds_every_expert
+    if partial:                         # into the held range, or E
+        flat = flat - cfg.first_held_expert
+        flat = jnp.where((flat >= 0) & (flat < E), flat, E)
     if live is not None:                # E: owns no group, sorts last
         flat = jnp.where(jnp.repeat(live, k), flat, E)
     # A counting sort by expert (a TPU sort of 32k keys takes 20 s to
@@ -284,7 +295,10 @@ def routed_experts(experts: Params, expert_layer, x: jax.Array,
     keep = (flat < E)[:, None]
     down = jnp.where(keep, down[dest] * w.reshape(T * k, 1), 0.0)
     y = down.reshape(T, k, d).sum(1).astype(x.dtype)
-    return y, jnp.sum(layer_sizes > 0).astype(jnp.int32)
+    distinct = jnp.sum(layer_sizes > 0).astype(jnp.int32)
+    if partial:
+        return y, jnp.stack([distinct, jnp.sum(layer_sizes)])
+    return y, distinct
 
 
 def _moe_ffn(layer: Params, h: jax.Array, cfg: ModelConfig,

@@ -45,6 +45,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     if cfg.latent:
         from skypilot_tpu.models import latent_moe
         return latent_moe.init_params(rng, cfg)
+    if cfg.mixer_pattern:
+        from skypilot_tpu.models import kda
+        return kda.init_params(rng, cfg)
     d, hd = cfg.dim, cfg.head_dim
     n_h, n_kv, f, L = cfg.n_heads, cfg.n_kv_heads, cfg.ffn_dim, cfg.n_layers
     keys = jax.random.split(rng, 8)
@@ -115,6 +118,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if cfg.latent:
         from skypilot_tpu.models import latent_moe
         return latent_moe.param_logical_axes(cfg)
+    if cfg.mixer_pattern:
+        from skypilot_tpu.models import kda
+        return kda.param_logical_axes(cfg)
     axes: Params = {
         'embed': ('vocab_in', 'embed'),
         'final_norm': ('norm',),
@@ -490,7 +496,14 @@ def scan_layers(body, x: jax.Array, params: Params, cfg: ModelConfig):
     (604 MB of expert weights a layer step on GLM-4.7-Flash: every expert
     read, whatever was routed; compiler, PR 30). The layer gets the whole
     stack with ``expert_layer``, its row in it, and the grouped matmul
-    takes the row as a group offset."""
+    takes the row as a group offset.
+
+    A model of several mixer kinds (``cfg.mixer_pattern``) is scanned
+    period by period (``kda.scan_periods``): ``li`` is then the layer's
+    index among its kind and the outputs come back as {kind: stacked}."""
+    if cfg.mixer_pattern:
+        from skypilot_tpu.models import kda
+        return kda.scan_periods(body, x, params, cfg)
     outs = []
     for stack, first in layer_stacks(params, cfg):
         held = {k: stack[k] for k in ('experts',) if k in stack}
@@ -521,11 +534,16 @@ def run_loops(body, x: jax.Array, params: Params, cfg: ModelConfig,
     the row of a cache stacked over ``cfg.n_cache_layers``; the
     per-layer outputs come back stacked the same way.
 
+    ``x`` may be a tuple (x, what the body carries beside it: a
+    recurrent model's state); the norm is x's.
+
     Returns (normed x, per-cache-layer outputs, exit gate), the gate
     None unless ``with_exit``: each pass's sigmoid(x . w + b), [passes,
     batch, seq] float32, from the state the pass left."""
 
     def final_norm(x):
+        if isinstance(x, tuple):
+            return (final_norm(x[0]),) + x[1:]
         return rms_norm(x, params['final_norm'], cfg.norm_eps,
                         cfg.norm_plus_one)
 
@@ -565,13 +583,18 @@ def exit_pdf(lam: jax.Array) -> jax.Array:
 def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
                 positions: jax.Array, attn_fn,
                 mlora_idx: Optional[jax.Array] = None,
-                live: Optional[jax.Array] = None):
+                live: Optional[jax.Array] = None, rec=None):
     """One transformer layer, parameterized by the attention op so every
     path (training full-sequence, prefill/decode against a cache, the
     fused serving loop) shares ONE copy of the layer math. ``attn_fn``
     maps roped (q, k, v) to the attention output (a latent model's maps
     its absorbed queries and new cache rows: ``latent_moe.layer_core``,
-    which alone reads ``live``, the rows that carry a token).
+    which reads ``live``, the rows that carry a token, as a routed FFN
+    and a recurrent mixer do). A layer whose mixer keeps a state and no
+    rows (``models/kda.py``) takes ``rec``, the state before these
+    tokens (None: a sequence's start), and returns the state after them
+    in the place of the new kv rows; its ``attn_fn`` (None: the XLA
+    form) is the one-token recurrence, ``kda.mixer``'s ``step_fn``.
 
     ``mlora_idx`` ([b] int32, -1 = none) gathers per-row adapters from
     the ``layer['mlora']`` bank slice (multi-tenant serving); None (the
@@ -585,6 +608,12 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
     from jax.ad_checkpoint import checkpoint_name
     h = rms_norm(x, layer['attn_norm'], cfg.norm_eps,
                   cfg.norm_plus_one)
+    if 'kda' in layer:
+        from skypilot_tpu.models import kda
+        with jax.named_scope('kda_mix'):
+            proj, new_rec = kda.mixer(layer['kda'], h, cfg, rec=rec,
+                                      live=live, step_fn=attn_fn)
+        return _routed_ffn_branch(layer, x + proj, cfg, live, new_rec)
     from skypilot_tpu.models.quantization import qeinsum
     lo = layer.get('lora') if isinstance(layer, dict) else None
     ml = layer.get('mlora') if isinstance(layer, dict) else None
@@ -611,10 +640,16 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
             k = k + layer['bk'].astype(k.dtype)
             v = v + layer['bv'].astype(v.dtype)
         q = _shard(q, 'batch', 'seq', 'heads', 'head_dim')
-        q = checkpoint_name(rope(q, positions, cfg.rope_theta), 'q_rope')
-        k = checkpoint_name(rope(k, positions, cfg.rope_theta), 'k_rope')
+        rot = ((lambda a: rope(a, positions, cfg.rope_theta))
+               if cfg.use_rope else (lambda a: a))
+        q = checkpoint_name(rot(q), 'q_rope')
+        k = checkpoint_name(rot(k), 'k_rope')
         v = checkpoint_name(v, 'v_proj')
         out = attn_fn(q, k, v)
+        if cfg.attn_gate:       # o * sigmoid(W_gate h), a channel
+            out = (out * jax.nn.sigmoid(qeinsum(
+                'bsd,dhk->bshk', h, layer['w_attn_gate']).astype(
+                    jnp.float32))).astype(out.dtype)
         # Named for selective remat (cfg.remat='attn'): saving the
         # attention output keeps the backward pass from re-running the
         # whole attention forward, at [b,s,h,d] bytes per layer.
@@ -629,6 +664,8 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
             proj = rms_norm(proj, layer['attn_post_norm'], cfg.norm_eps,
                             cfg.norm_plus_one)
     x = x + proj
+    if cfg.ffn_kind == 'routed_shared':
+        return _routed_ffn_branch(layer, x, cfg, live, (k, v))
     h = rms_norm(x, layer['ffn_norm'], cfg.norm_eps,
                  cfg.norm_plus_one)
     if cfg.is_moe:
@@ -644,6 +681,17 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
     x = x + ffn_out
     x = _shard(x, 'batch', 'seq', 'embed')
     return x, (k, v), aux
+
+
+def _routed_ffn_branch(layer: Params, x: jax.Array, cfg: ModelConfig,
+                       live: Optional[jax.Array], mixer_out):
+    """The second half of a layer whose FFN is routed + shared experts
+    (``latent_moe``'s): (x + FFN(norm(x)), ``mixer_out`` handed through,
+    the layer's expert counters in the place of an auxiliary loss)."""
+    from skypilot_tpu.models import latent_moe
+    h = rms_norm(x, layer['ffn_norm'], cfg.norm_eps)
+    ffn_out, counted = latent_moe._moe_ffn(layer, h, cfg, live)
+    return x + ffn_out, mixer_out, counted
 
 
 def _layer_fn(layer: Params, x: jax.Array, cfg: ModelConfig,
@@ -712,6 +760,12 @@ def forward(
             '(inference/paged.py)')
     if return_exit and not cfg.exit_gate:
         raise ValueError(f'{cfg.name} has no exit gate')
+    if cfg.mixer_pattern and (cache is not None or _pp_mesh() is not None):
+        raise NotImplementedError(
+            f'{cfg.name} holds layers of kinds {cfg.mixer_pattern}: the '
+            'contiguous KVCache and the pipeline schedule hold one kind '
+            'of layer; it decodes through the paged pool and its '
+            'per-slot state (inference/paged.py)')
     x = _embed_tokens(params, tokens, cfg)
     x = _shard(x, 'batch', 'seq', 'embed')
     b, s = tokens.shape
@@ -804,6 +858,10 @@ def forward(
 
             x, aux_layers, lam = run_loops(scan_body, x, params, cfg,
                                            with_exit=return_exit)
+            if cfg.mixer_pattern:       # {kind: per-layer} -> per-layer
+                aux_layers = jnp.concatenate(
+                    [a.reshape(a.shape[0], -1)[:, 0]
+                     for a in jax.tree.leaves(aux_layers)])
             normed = True       # every pass ends in the final norm
         new_cache = None
     else:

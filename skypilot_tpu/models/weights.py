@@ -38,6 +38,7 @@ _ARCH_FAMILY = {
     'MixtralForCausalLM': 'mixtral',
     'Qwen2ForCausalLM': 'qwen2',
     'OuroForCausalLM': 'ouro',
+    'SolarOpen2ForCausalLM': 'solar_open2',
 }
 
 
@@ -85,7 +86,53 @@ def config_from_hf(hf: Dict[str, Any],
         kw.update(n_loops=hf['total_ut_steps'], post_norms=True,
                   exit_gate=True, early_exit_threshold=float(
                       hf.get('early_exit_threshold', 1.0)))
+    if family == 'solar_open2':
+        kw.update(_solar_open2_fields(hf))
     return ModelConfig(**kw)
+
+
+def _solar_open2_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """``model_type: solar_open2``: gated NoPE GQA layers at
+    ``gqa_layers``, Kimi Delta Attention (``linear_attn_config``) at the
+    others, every FFN routed + shared experts. Not in ``config.json``
+    and set by the family's convention: the low-rank gates' rank (the
+    linear head width). ``n_held_experts`` / ``first_held_expert`` are
+    this repo's keys for one chip's share of an expert-parallel layer."""
+    n, gqa = hf['num_hidden_layers'], set(hf['gqa_layers'])
+    period = hf['gqa_interval'] + 1
+    pattern = tuple('gqa' if i in gqa else 'kda' for i in range(period))
+    if n % period or [i for i in range(n)
+                      if (pattern[i % period] == 'gqa') != (i in gqa)]:
+        raise ValueError(f'gqa_layers {sorted(gqa)} do not repeat with '
+                         f'period {period} over {n} layers')
+    if hf.get('first_k_dense_replace', 0) or hf.get('kda_use_full_proj'):
+        raise ValueError('solar_open2 with leading dense layers or '
+                         'full-rank KDA gate projections is not built')
+    lin = hf['linear_attn_config']
+    return dict(
+        mixer_pattern=pattern, kda_heads=lin['num_heads'],
+        kda_head_dim=lin['head_dim'],
+        kda_conv=lin['short_conv_kernel_size'],
+        kda_gate_rank=lin['head_dim'],
+        use_rope=bool(hf.get('use_rope', True)),
+        attn_gate=bool(hf.get('use_gqa_gate', False)),
+        ffn_kind='routed_shared', n_routed_experts=hf['n_routed_experts'],
+        n_experts_per_token=hf['num_experts_per_tok'],
+        n_shared_experts=hf['n_shared_experts'],
+        moe_ffn_dim=hf['moe_intermediate_size'],
+        routed_scaling_factor=float(hf.get('routed_scaling_factor', 1.0)),
+        n_held_experts=hf.get('n_held_experts'),
+        first_held_expert=hf.get('first_held_expert', 0))
+
+
+def _refuse_tensors(cfg: ModelConfig) -> None:
+    if cfg.mixer_pattern:
+        raise NotImplementedError(
+            f'{cfg.name}: the checkpoint tensor names of a model with '
+            f'layers of kinds {cfg.mixer_pattern} are not mapped (no '
+            'published checkpoint is at hand to hold a mapping to); its '
+            'config.json is read and written, its weights are made from '
+            'a seed (llama.init_params)')
 
 
 _EXIT_GATE_KEYS = ('model.early_exit_gate.weight',
@@ -253,6 +300,7 @@ def load_hf_params(path: str, cfg: ModelConfig,
     if quantize is not None and quantize not in ('int8', 'int4'):
         # Validate BEFORE streaming gigabytes of tensors.
         raise ValueError(f'unknown quantize mode {quantize!r}')
+    _refuse_tensors(cfg)
     key_map = _hf_key_map(cfg)
     L = cfg.n_layers
     stacked: Dict[str, np.ndarray] = {}     # our layer-leaf name -> buffer
@@ -628,8 +676,10 @@ def hf_config_dict(cfg: ModelConfig,
     the exact inverse of ``config_from_hf``)."""
     arch = {'llama': 'LlamaForCausalLM', 'gemma': 'GemmaForCausalLM',
             'mixtral': 'MixtralForCausalLM',
-            'qwen2': 'Qwen2ForCausalLM', 'ouro': 'OuroForCausalLM'}
-    family = ('ouro' if cfg.n_loops > 1 else
+            'qwen2': 'Qwen2ForCausalLM', 'ouro': 'OuroForCausalLM',
+            'solar_open2': 'SolarOpen2ForCausalLM'}
+    family = ('solar_open2' if cfg.mixer_pattern else
+              'ouro' if cfg.n_loops > 1 else
               'mixtral' if cfg.is_moe else
               'gemma' if cfg.norm_plus_one else
               'qwen2' if cfg.qkv_bias else 'llama')
@@ -657,6 +707,26 @@ def hf_config_dict(cfg: ModelConfig,
     if family == 'ouro':
         hf_cfg.update(total_ut_steps=cfg.n_loops,
                       early_exit_threshold=cfg.early_exit_threshold)
+    if family == 'solar_open2':
+        period = len(cfg.mixer_pattern)
+        hf_cfg.update(
+            gqa_interval=period - 1,
+            gqa_layers=[i for i, k in enumerate(cfg.layer_kinds)
+                        if k == 'gqa'],
+            linear_attn_config={
+                'short_conv_kernel_size': cfg.kda_conv,
+                'head_dim': cfg.kda_head_dim, 'num_heads': cfg.kda_heads,
+                'num_kv_heads': None},
+            use_rope=cfg.use_rope, use_gqa_gate=cfg.attn_gate,
+            kda_use_full_proj=False, kda_allow_neg_eigval=True,
+            first_k_dense_replace=0,
+            n_routed_experts=cfg.n_routed_experts,
+            num_experts_per_tok=cfg.n_experts_per_token,
+            n_shared_experts=cfg.n_shared_experts,
+            moe_intermediate_size=cfg.moe_ffn_dim, norm_topk_prob=True,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            n_held_experts=cfg.n_held_experts,
+            first_held_expert=cfg.first_held_expert)
     return hf_cfg
 
 
@@ -665,6 +735,7 @@ def save_hf_checkpoint(path: str, cfg: ModelConfig, params: Params) -> None:
     ``model.safetensors`` in HF layout (used by tests and for handing
     trained weights back to HF-ecosystem tools)."""
     from safetensors.numpy import save_file
+    _refuse_tensors(cfg)
     os.makedirs(path, exist_ok=True)
     hd = cfg.head_dim
     out: Dict[str, np.ndarray] = {}

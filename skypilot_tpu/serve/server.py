@@ -1665,6 +1665,18 @@ class ModelServer:
                     profiler_lib.POOL_ROWS_LIVE_METRIC),
                 'pool_write_rows_offered_total': self._counter_value(
                     profiler_lib.POOL_ROWS_OFFERED_METRIC),
+                'moe_held_experts': self._counter_value(
+                    profiler_lib.MOE_HELD_EXPERTS_METRIC),
+                'moe_assignments_held_total': self._counter_value(
+                    profiler_lib.MOE_ASSIGNMENTS_HELD_METRIC),
+                'recurrent_layers': self._counter_value(
+                    profiler_lib.RECURRENT_LAYERS_METRIC),
+                'recurrent_state_bytes': self._counter_value(
+                    profiler_lib.RECURRENT_STATE_BYTES_METRIC),
+                'state_resets_total': self._counter_value(
+                    profiler_lib.STATE_RESETS_METRIC),
+                'state_recompute_tokens_total': self._counter_value(
+                    profiler_lib.STATE_RECOMPUTE_METRIC),
             },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),

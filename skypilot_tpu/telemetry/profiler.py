@@ -51,6 +51,19 @@ LIVE_ROWS_METRIC = 'skytpu_engine_decode_live_rows_total'
 MOE_LAYER_STEPS_METRIC = 'skytpu_moe_layer_steps_total'
 MOE_DISTINCT_METRIC = 'skytpu_moe_distinct_experts_total'
 MOE_ASSIGNMENTS_METRIC = 'skytpu_moe_assignments_total'
+# A model that holds a share of its routed experts (``n_held_experts``):
+# the held count (a gauge) and the live assignments that fell to held
+# experts, counted on the device beside the distinct experts.
+MOE_HELD_EXPERTS_METRIC = 'skytpu_moe_held_experts'
+MOE_ASSIGNMENTS_HELD_METRIC = 'skytpu_moe_assignments_held_total'
+# Layers that keep a per-slot state in place of cache rows
+# (``inference/paged.py`` ``RecurrentState``): how many, a slot's bytes
+# over all of them (gauges), slots whose state was started from zeros,
+# and tokens prefilled AGAIN after a preemption because no state was kept.
+RECURRENT_LAYERS_METRIC = 'skytpu_recurrent_layers'
+RECURRENT_STATE_BYTES_METRIC = 'skytpu_recurrent_state_bytes'
+STATE_RESETS_METRIC = 'skytpu_state_resets_total'
+STATE_RECOMPUTE_METRIC = 'skytpu_state_recompute_tokens_total'
 PREFILL_PAIRS_METRIC = 'skytpu_prefill_attn_pairs_total'
 PREFILL_TOKENS_METRIC = 'skytpu_prefill_tokens_total'
 # What one cached token is (gauges the engine sets once it is built): its
@@ -92,8 +105,13 @@ class NullProfiler:
                       moe_layers: int = 0, top_k: int = 0) -> None:
         del name, n, live_rows, moe_layers, top_k
 
-    def note_distinct_experts(self, n: int, layer_steps: int) -> None:
-        del n, layer_steps
+    def note_distinct_experts(self, n: int, layer_steps: int,
+                              held_assignments: Optional[int] = None
+                              ) -> None:
+        del n, layer_steps, held_assignments
+
+    def note_state_reset(self, recompute_tokens: int = 0) -> None:
+        del recompute_tokens
 
     def note_prefill_pairs(self, n: int, tokens: int = 0) -> None:
         del n, tokens
@@ -151,6 +169,19 @@ class StepProfiler:
             MOE_ASSIGNMENTS_METRIC,
             'Token-to-expert assignments of live rows, summed over '
             'expert layers and decode substeps')
+        self._moe_assignments_held = self._reg.counter(
+            MOE_ASSIGNMENTS_HELD_METRIC,
+            'Of those assignments, the ones to experts this program '
+            'holds (counted on the device; all of them where every '
+            'expert is held)')
+        self._state_resets = self._reg.counter(
+            STATE_RESETS_METRIC,
+            "Slots whose recurrent state a request's first chunk "
+            'started from zeros')
+        self._state_recompute = self._reg.counter(
+            STATE_RECOMPUTE_METRIC,
+            'Tokens prefilled again after a preemption because a '
+            'recurrent state cannot be rebuilt from pages')
         self._prefill_pairs = self._reg.counter(
             PREFILL_PAIRS_METRIC,
             'Query-key pairs under the causal mask that enqueued '
@@ -279,15 +310,28 @@ class StepProfiler:
         with self._lock:
             self._substeps[name] = self._substeps.get(name, 0) + n
 
-    def note_distinct_experts(self, n: int, layer_steps: int) -> None:
+    def note_distinct_experts(self, n: int, layer_steps: int,
+                              held_assignments: Optional[int] = None
+                              ) -> None:
         """Distinct experts a decode dispatch read over its
         ``layer_steps`` (substeps x expert layers), as read back with its
         tokens; traced, an instant ``skytpu:moe_readback`` carries both,
-        so that a trace holds the counts of the calls it holds."""
+        so that a trace holds the counts of the calls it holds.
+        ``held_assignments``: a model holding a share of its experts
+        reads back how many live assignments fell to them."""
         self._moe_distinct.inc(n)
+        if held_assignments is not None:
+            self._moe_assignments_held.inc(held_assignments)
         if self._annotate('moe_readback', {'distinct': n,
                                            'layer_steps': layer_steps}):
             self._open.pop().__exit__(None, None, None)
+
+    def note_state_reset(self, recompute_tokens: int = 0) -> None:
+        """A slot of a recurrent model handed to a request: its state
+        starts from zeros; ``recompute_tokens`` of a resumed request's
+        context are prefilled a second time."""
+        self._state_resets.inc(1)
+        self._state_recompute.inc(recompute_tokens)
 
     def note_prefill_pairs(self, n: int, tokens: int = 0) -> None:
         """A prefill chunk's dispatch: its query-key pairs a layer and

@@ -296,6 +296,11 @@ def test_one_pass_presets_pool_shapes_and_program_keys_unchanged(preset):
                                chunk=16, kv_cache_dtype='int8')
     assert eng.cache.pool_k.shape == (L, 4 * 8 + 1, hkv, 8, hd)
     assert eng.cache.k_scale.shape == (L, 4 * 8 + 1, hkv, 8)
+    # no period pattern, no recurrent layer: no state beside the pool,
+    # prefixes matched and registered as before
+    assert (cfg.mixer_pattern, cfg.n_recurrent_layers) == ((), 0)
+    assert eng.rec is None and eng._prefix_reuse
+    assert eng.memory_stats()['recurrent_state_bytes'] == 0
     rng = np.random.default_rng(7)
     for n in (21, 5):
         eng.add_request(rng.integers(0, 256, n).tolist(), max_new_tokens=6)

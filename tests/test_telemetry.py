@@ -1002,7 +1002,14 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          # token rows due for the pools against rows the
                          # row-write programs' shapes carried (PR 36)
                          'pool_write_rows_live_total',
-                         'pool_write_rows_offered_total'}
+                         'pool_write_rows_offered_total',
+                         # held experts of a model told its range, and
+                         # the per-slot state of recurrent layers (PR
+                         # 37): zeros for a model with neither
+                         'moe_held_experts', 'moe_assignments_held_total',
+                         'recurrent_layers', 'recurrent_state_bytes',
+                         'state_resets_total',
+                         'state_recompute_tokens_total'}
     assert all(isinstance(v, (int, float)) for v in loop.values())
     assert loop['moe_layer_steps_total'] == 0
     assert loop['prefill_tokens_total'] > 0

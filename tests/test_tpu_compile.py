@@ -619,3 +619,144 @@ def test_looped_decode_program_keeps_the_pass_loop_and_one_kernel(
     ring = 2 * 192 * slots * horizon * 16 * 128 * 2
     assert mem.output_size_in_bytes == pytest.approx(ring, rel=0.01)
     assert mem.temp_size_in_bytes < 1.5e9
+
+
+# --------------------------- a recurrent state beside the pool (PR 37)
+def _solar_operands(one_chip, slots, n_pages=2000):
+    cfg = configs.SOLAR_OPEN2_250B
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda key: llama.init_params(key, cfg), jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(lambda: paged.PagedKVCache.create(
+        cfg, n_pages=n_pages, page_size=PAGE)))
+    rec = shapes(jax.eval_shape(
+        lambda: paged.RecurrentState.create(cfg, slots)))
+    assert cache.pool_k.shape == (1, n_pages, 8, PAGE, 128)
+    assert rec.state.shape == (3, slots, 64, 128, 128)
+    assert rec.conv.shape == (3, slots, 3, 3 * 8192)
+    return cfg, params, cache, rec
+
+
+@pytest.mark.parametrize('slots', [32, 64])
+def test_hybrid_decode_program_advances_the_state_in_place(
+        one_chip, monkeypatch, slots):
+    """The whole ``paged_decode_horizon`` of the
+    ``solar-open2-250b.longgen`` cell (one period [GQA, KDA, KDA, KDA],
+    40 of 320 experts held, 32 slots; and 64): the donated recurrent
+    state (13,025,280 B a slot: 417 MB at 32 slots, 834 MB at 64) is
+    aliased to its output, so no second copy of it exists; the loops are
+    the horizon, the period and the run of 3 KDA layers (ONE traced KDA
+    body); one paged kernel (the GQA layer), 2 x 3 grouped expert
+    matmuls and the KDA state kernel. 0.19 GB of temp at 32 slots
+    (compiler, PR 37)."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    cfg, params, cache, rec = _solar_operands(one_chip, slots)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, cache, table, tokens, lengths, active, rec):
+        return paged.paged_decode_horizon(
+            params, cache, table, tokens, lengths, cfg, horizon=8,
+            active=active, decode_impl='pallas', rec=rec)
+
+    compiled = jax.jit(decode, donate_argnames=('rec',)).lower(
+        params, cache, vec((slots, 8)), vec((slots,)), vec((slots,)),
+        vec((slots,), jnp.bool_), rec=rec).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    state_bytes = slots * 13_025_280
+    assert mem.alias_size_in_bytes == state_bytes
+    ring = 2 * slots * 8 * 8 * 128 * 2
+    assert mem.output_size_in_bytes == pytest.approx(
+        state_bytes + ring, rel=0.01)
+    assert mem.temp_size_in_bytes < 0.45e9 * slots / 32
+    assert text.count('tpu_custom_call') == 8
+    assert text.count(' while(') <= 5
+
+
+def test_hybrid_prefill_chunk_carries_the_state_in_place(one_chip,
+                                                         monkeypatch):
+    """``paged_prefill_chunk`` of the same cell, 4 prompts x 256 tokens
+    over an 8-page bucket: pools and recurrent state are all aliased to
+    their outputs (1.05 GB + 0.42 GB; the 4 rows' states are taken out,
+    advanced through the chunked delta rule and scattered back), and
+    the transients stay under 0.7 GB (0.49 GB; compiler, PR 37)."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    slots, n, table_p, chunk = 32, 4, 8, 256
+    cfg, params, cache, rec = _solar_operands(one_chip, slots)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def prefill(params, cache, table, tokens, lengths, valid, want,
+                rec=None, slot_ids=None):
+        return paged.paged_prefill_chunk(
+            params, cache, table, tokens, lengths, valid, want, cfg,
+            rec=rec, slot_ids=slot_ids)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,),
+                       donate_argnames=('rec',)).lower(
+        params, cache, vec((n, table_p)), vec((n, chunk)), vec((n,)),
+        vec((n,)), vec((n,)), rec=rec, slot_ids=vec((n,))).compile()
+    mem = compiled.memory_analysis()
+    held = (2 * cache.pool_k.size * 2) + slots * 13_025_280
+    assert mem.alias_size_in_bytes == held
+    assert mem.output_size_in_bytes == pytest.approx(held, rel=0.001)
+    assert mem.temp_size_in_bytes < 0.7e9
+    assert compiled.as_text().count('tpu_custom_call') == 6
+
+
+def test_hybrid_chunk_batches_count_the_recurrent_mixers_terms(
+        one_chip, monkeypatch):
+    """``_chunk_batch_cap`` at the cell's sizes. A piece's attention
+    scores and expert rows alone would admit 16 prompts a chunk at up to
+    4 pages (1.75 GB counted), but that program holds 2.22 GB, over the
+    2.2 GB the cap exists to keep: its peak is the KDA mixers' float32
+    terms, 131 MB a 256-token piece (compiler, PR 37). With them counted
+    the cell forms 8 prompts at up to 4 pages and 4 beyond, 18 prefill
+    programs in all, and the largest of them stays inside the budget
+    (1.1 GB)."""
+    import types
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    engine_cls = paged.PagedInferenceEngine
+    slots, chunk = 32, 256
+    cfg, params, cache, rec = _solar_operands(one_chip, slots)
+    eng = types.SimpleNamespace(
+        cfg=cfg, chunk=chunk, page=PAGE,
+        _ATTN_SCORE_BYTES=engine_cls._ATTN_SCORE_BYTES,
+        _RECURRENT_TOKEN_ARRAYS=engine_cls._RECURRENT_TOKEN_ARRAYS)
+    budget = engine_cls._CHUNK_TRANSIENT_BUDGET
+
+    def prompts_max(pages):
+        piece = engine_cls._chunk_piece_bytes(eng, pages)
+        return max(n for n in engine_cls._PREFILL_N_BUCKETS
+                   if n * piece <= budget)
+
+    assert {p: prompts_max(p) for p in (1, 2, 4, 8, 16)} == {
+        1: 8, 2: 8, 4: 8, 8: 4, 16: 4}
+    dense = types.SimpleNamespace(**{**vars(eng), 'cfg': configs.QWEN2_7B})
+    assert engine_cls._chunk_piece_bytes(dense, 4) == int(
+        4 * 28 * chunk * PAGE * 4.5)         # a dense model's: as it was
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def prefill(params, cache, table, tokens, lengths, valid, want,
+                rec=None, slot_ids=None):
+        return paged.paged_prefill_chunk(
+            params, cache, table, tokens, lengths, valid, want, cfg,
+            rec=rec, slot_ids=slot_ids)
+
+    def temp_bytes(n, table_p):
+        return jax.jit(prefill, donate_argnums=(1,),
+                       donate_argnames=('rec',)).lower(
+            params, cache, vec((n, table_p)), vec((n, chunk)), vec((n,)),
+            vec((n,)), vec((n,)), rec=rec, slot_ids=vec((n,))
+        ).compile().memory_analysis().temp_size_in_bytes
+
+    assert temp_bytes(8, 4) < 1.3e9
+    assert temp_bytes(16, 4) > budget
