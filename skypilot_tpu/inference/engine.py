@@ -336,7 +336,8 @@ def _ring_horizon_cap(cfg, batch: int, param_bytes: int,
 def prepare_params(cfg: ModelConfig, params, *, quantize=None, mesh=None,
                    donate_params: bool = False):
     """Param preparation for an engine: LoRA merge, init-if-absent,
-    optional int8 / int4 quantization, mesh sharding. Returns (cfg,
+    a recurrent mixer's serving layout, optional int8 / int4
+    quantization, mesh sharding. Returns (cfg,
     params, effective_quantize) — cfg changes when a LoRA checkpoint is
     folded (lora_rank drops to 0).
 
@@ -345,6 +346,7 @@ def prepare_params(cfg: ModelConfig, params, *, quantize=None, mesh=None,
     is sharded FIRST so a 7B-class checkpoint never has to fit
     (bf16 + int8) on one chip; single-device quantization frees each
     bf16 leaf as its int8 replacement lands when ``donate_params``."""
+    from skypilot_tpu.models import kda
     from skypilot_tpu.models import lora as lora_lib
     from skypilot_tpu.models import quantization
     # A LoRA checkpoint serves as its merged model: fold the adapters
@@ -355,6 +357,11 @@ def prepare_params(cfg: ModelConfig, params, *, quantize=None, mesh=None,
                                        donate=donate_params)
     if params is None:
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    # A recurrent mixer's q/k/v as the one matrix its dot reads where it
+    # lies (``kda.serving_layout``; a tree without that stack passes).
+    # Undonated, the caller's leaves stay whole: the benchmark reads the
+    # same tree into its reference after the engine is built.
+    params = kda.serving_layout(params, donate=donate_params)
     if quantize is not None and quantize not in ('int8', 'int4'):
         raise ValueError(f'unknown quantize mode {quantize!r}; '
                          "supported: 'int8', 'int4'")

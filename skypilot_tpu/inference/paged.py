@@ -1788,6 +1788,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             'kv_pool_pages': self.alloc.n_pages,
             'pool_auto_sized': bool(self._pool_auto_sized),
             'bytes_by_device': self._bytes_by_device,
+            # The recurrent mixers' q/k/v leaf as the programs read it
+            # (``kda.serving_layout``); None without such a stack.
+            'kda_qkv': (list(self.params['kda_layers']['kda']['wqkv'].shape)
+                        if 'kda_layers' in self.params else None),
             # First dispatch per jit static key (the profiler's compile
             # events): steady state over repeated shapes adds none.
             'jit_first_calls': len(compiles),

@@ -25,7 +25,8 @@ norm and gate are this file's ``mixer`` and shared by both.
 
 The parameter tree keeps ``llama``'s top level and holds a stack a kind:
 ``layers`` (the 'gqa' layers, layer-major) and ``kda_layers``, each with
-its own router, held experts and shared expert.
+its own router, held experts and shared expert. The programs run on
+``serving_layout``'s tree: a KDA layer's q/k/v as one 2-D matrix.
 """
 from __future__ import annotations
 
@@ -38,6 +39,7 @@ from jax import lax
 
 from skypilot_tpu.models.configs import ModelConfig
 from skypilot_tpu.ops import kda as kda_ops
+from skypilot_tpu.utils.host import host_block
 
 Params = Dict[str, Any]
 
@@ -90,6 +92,35 @@ def leaf_plan(cfg: ModelConfig) -> Dict[str, Any]:
     }
     return {'layers': dict(gqa, **ffn),
             'kda_layers': dict({'attn_norm': d, 'kda': kda}, **ffn)}
+
+
+@jax.jit
+def _fuse_qkv(wq, wk, wv):
+    return jnp.concatenate([w.reshape(w.shape[:2] + (-1,))
+                            for w in (wq, wk, wv)], axis=-1)
+
+
+def serving_layout(params: Params, donate: bool = False) -> Params:
+    """The tree the programs run on: the KDA stack's ``wq``, ``wk``,
+    ``wv`` [L, d, H, dk] (``leaf_plan``'s, which the loader and the
+    reference share) as ONE ``wqkv`` [L, d, 3*H*dk], channels in the
+    conv weight's order (q, k, v, each head-major). A 2-D weight is
+    read by its dot where it lies in the scanned stack; a 3-D one, its
+    contraction axis major, is copied into fast memory a layer a step
+    first (``PERF.md``, PR 38). Every other leaf is the caller's own;
+    a tree with no such stack, or already in this layout, comes back
+    as it is. ``donate`` frees the three leaves once ``wqkv`` exists."""
+    stack = params.get('kda_layers')
+    if stack is None or 'wq' not in stack['kda']:
+        return params
+    mix = dict(stack['kda'])
+    parts = [mix.pop(w) for w in ('wq', 'wk', 'wv')]
+    mix['wqkv'] = _fuse_qkv(*parts)
+    if donate:
+        host_block(mix['wqkv'])
+        for w in parts:
+            w.delete()
+    return dict(params, kda_layers=dict(stack, kda=mix))
 
 
 def vector_shape(kind: str, cfg: ModelConfig) -> Tuple[int, ...]:
@@ -178,8 +209,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def param_logical_axes(cfg: ModelConfig) -> Params:
-    """``init_params``' structure with logical-axis tuples as leaves
-    (no mesh is served yet: ``engine.refuse_unsupported``)."""
+    """The structure of ``serving_layout(init_params(...))`` with
+    logical-axis tuples as leaves (no mesh is served yet:
+    ``engine.refuse_unsupported``)."""
     ffn = {'w_gate': ('layers', 'embed', 'mlp'),
            'w_up': ('layers', 'embed', 'mlp'),
            'w_down': ('layers', 'mlp', 'embed')}
@@ -197,7 +229,8 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if cfg.attn_gate:
         gqa['w_attn_gate'] = proj
     low = ('layers', None, 'heads', 'head_dim')
-    kda = {'wq': proj, 'wk': proj, 'wv': proj, 'conv': ('layers', None, None),
+    kda = {'wqkv': ('layers', 'embed', None),
+           'conv': ('layers', None, None),
            'a_down': ('layers', 'embed', None), 'a_up': low,
            'A_log': ('layers', 'heads'),
            'dt_bias': ('layers', 'heads', 'head_dim'),
@@ -317,7 +350,8 @@ def output(p: Params, o: jax.Array, gate: jax.Array, cfg: ModelConfig,
 
 def mixer(p: Params, h: jax.Array, cfg: ModelConfig, rec=None,
           live: Optional[jax.Array] = None, step_fn=None):
-    """h [b,s,d] normed -> (y [b,s,d], the state after the run). ``rec``
+    """h [b,s,d] normed -> (y [b,s,d], the state after the run); ``p``
+    a layer of ``serving_layout``'s tree. ``rec``
     = (S [b,H,dk,dv] float32, conv tail [b, taps-1, 3*H*dk]) the state
     before it, None = a sequence's start (zeros). ``live`` [b,s] or
     [b,1] bool (None = all) marks the rows that carry a token, a prefix
@@ -333,9 +367,7 @@ def mixer(p: Params, h: jax.Array, cfg: ModelConfig, rec=None,
                jnp.zeros((b, cfg.kda_conv - 1, 3 * H * dk), h.dtype))
     S, tail = rec
     step_fn = step_fn or kda_ops.recurrent_step
-    x = jnp.concatenate(
-        [jnp.einsum('bsd,dhk->bshk', h, p[w]).reshape(b, s, H * dk)
-         for w in ('wq', 'wk', 'wv')], axis=-1)
+    x = jnp.einsum('bsd,dn->bsn', h, p['wqkv'])
     qkv, seen = short_conv(p['conv'], tail, x)
     q, k, v = (a.reshape(b, s, H, dk) for a in jnp.split(qkv, 3, axis=-1))
     q, k = l2_normalise(q) * dk ** -0.5, l2_normalise(k)

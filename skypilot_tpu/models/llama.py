@@ -766,6 +766,9 @@ def forward(
             'contiguous KVCache and the pipeline schedule hold one kind '
             'of layer; it decodes through the paged pool and its '
             'per-slot state (inference/paged.py)')
+    if cfg.mixer_pattern:
+        from skypilot_tpu.models import kda
+        params = kda.serving_layout(params)
     x = _embed_tokens(params, tokens, cfg)
     x = _shard(x, 'batch', 'seq', 'embed')
     b, s = tokens.shape

@@ -1561,6 +1561,7 @@ class ModelServer:
             'prefill_attn': None, 'page_size': 0, 'kv_pool_pages': 0,
             'pool_auto_sized': False,
             'bytes_by_device': {'params': {}, 'kv_pool': {}},
+            'kda_qkv': None,
             'jit_first_calls': 0, 'jit_first_call_seconds': 0.0,
         }
 

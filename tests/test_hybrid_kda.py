@@ -167,7 +167,8 @@ def test_padding_moves_neither_state_nor_conv_tail():
     """A run whose last rows carry no token leaves the state and the
     tail where the run without them does."""
     cfg, params = make('float32')
-    p = jax.tree.map(lambda a: a[0], params['kda_layers']['kda'])
+    p = jax.tree.map(lambda a: a[0],
+                     kda.serving_layout(params)['kda_layers']['kda'])
     rng = np.random.default_rng(0)
     h = jnp.asarray(rng.normal(size=(2, 16, cfg.dim)), jnp.float32)
     spec = cfg.state_spec
@@ -186,6 +187,77 @@ def test_padding_moves_neither_state_nor_conv_tail():
     assert np.array_equal(tail[0], tail0[0])
     assert np.array_equal(S[1], rec[0][1])
     assert np.array_equal(tail[1], rec[1][1])
+
+
+@pytest.mark.parametrize('s,live', [(1, None), (1, [[True], [False]]),
+                                    (16, None), (37, [[True] * 37,
+                                                      [True] * 9 + [False] * 28])])
+def test_serving_layout_is_the_three_projections(s, live):
+    """``mixer`` over ``serving_layout``'s one ``wqkv`` gives the output,
+    state and conv tail that the three 3-D projections of ``leaf_plan``'s
+    tree give (the parent's formula, below), one token a row (decode)
+    and chunked, from a state mid-sequence."""
+    cfg, params = make('float32')
+    three = jax.tree.map(lambda a: a[1], params['kda_layers']['kda'])
+    one = jax.tree.map(lambda a: a[1],
+                       kda.serving_layout(params)['kda_layers']['kda'])
+    assert set(one) == set(three) - {'wq', 'wk', 'wv'} | {'wqkv'}
+    H, dk = cfg.kda_heads, cfg.kda_head_dim
+    assert one['wqkv'].shape == (cfg.dim, 3 * H * dk)
+    rng = np.random.default_rng(7)
+    h = jnp.asarray(rng.normal(size=(2, s, cfg.dim)), jnp.float32)
+    spec = cfg.state_spec
+    rec = (jnp.asarray(rng.normal(size=(2, spec.heads, spec.k_dim,
+                                        spec.v_dim)), jnp.float32),
+           jnp.asarray(rng.normal(size=(2, spec.conv_rows, spec.conv_dim)),
+                       jnp.float32))
+    live = None if live is None else jnp.asarray(live)
+    einsum, replaced = jnp.einsum, []
+
+    def three_projections(subscripts, *operands, **kw):
+        if subscripts != 'bsd,dn->bsn':
+            return einsum(subscripts, *operands, **kw)
+        x = operands[0]
+        replaced.append(x.shape)
+        return jnp.concatenate(
+            [einsum('bsd,dhk->bshk', x, three[w]).reshape(2, s, H * dk)
+             for w in ('wq', 'wk', 'wv')], axis=-1)
+
+    with jax.default_matmul_precision('highest'):
+        got = kda.mixer(one, h, cfg, rec=rec, live=live)
+        with mock.patch.object(jnp, 'einsum', three_projections):
+            want = kda.mixer(one, h, cfg, rec=rec, live=live)
+    assert replaced == [h.shape]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-5
+
+
+def test_prepare_params_leaves_the_callers_tree_whole():
+    """The engine serves ``serving_layout``'s tree and the caller keeps
+    its own: ``wq``/``wk``/``wv`` alive and bitwise what they were (the
+    benchmark reads them into its reference after the engine is built),
+    every other leaf shared, not copied. Donated, the three are freed."""
+    from skypilot_tpu.inference.engine import prepare_params
+    cfg, params = make('bfloat16')
+    mix = params['kda_layers']['kda']
+    before = {w: np.asarray(mix[w]) for w in ('wq', 'wk', 'wv')}
+    _, served, _ = prepare_params(cfg, params)
+    for w, was in before.items():
+        assert not mix[w].is_deleted()
+        assert np.array_equal(np.asarray(mix[w]), was)
+    got = served['kda_layers']['kda']
+    assert 'wq' not in got and got['wqkv'].shape == (
+        6, cfg.dim, 3 * cfg.kda_heads * cfg.kda_head_dim)
+    assert got['conv'] is mix['conv']
+    assert served['layers'] is params['layers']
+    assert served['kda_layers']['experts'] is params['kda_layers']['experts']
+    assert prepare_params(cfg, served)[1]['kda_layers']['kda'] is got
+    _, served, _ = prepare_params(cfg, params, donate_params=True)
+    assert all(mix[w].is_deleted() for w in before)
+    assert np.array_equal(
+        np.asarray(served['kda_layers']['kda']['wqkv'][..., :cfg.kda_heads
+                                                       * cfg.kda_head_dim]),
+        before['wq'].reshape(6, cfg.dim, -1))
 
 
 # ------------------------------------------------------ (b) paged programs
@@ -215,6 +287,7 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, slots,
     decode steps in fused horizons over all 4 slots (the others dead),
     the ring merged and the state carried in between. Returns per
     prompt (tokens generated, {position: logits})."""
+    params = kda.serving_layout(params)
     n, n_rows, B = len(prompts), len(prompts) + 1, 4
     per_row = -(-(max(map(len, prompts)) + n_new) // PAGE)
     P = 1

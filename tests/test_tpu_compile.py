@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from skypilot_tpu.inference import paged
-from skypilot_tpu.models import configs, llama, quantization
+from skypilot_tpu.models import configs, kda, llama, quantization
 from skypilot_tpu.ops import flash_attention as fa
 from skypilot_tpu.ops import paged_attention as pa
 
@@ -630,7 +630,8 @@ def _solar_operands(one_chip, slots, n_pages=2000):
             x.shape, x.dtype, sharding=one_chip), tree)
 
     params = shapes(jax.eval_shape(
-        lambda key: llama.init_params(key, cfg), jax.random.PRNGKey(0)))
+        lambda key: kda.serving_layout(llama.init_params(key, cfg)),
+        jax.random.PRNGKey(0)))
     cache = shapes(jax.eval_shape(lambda: paged.PagedKVCache.create(
         cfg, n_pages=n_pages, page_size=PAGE)))
     rec = shapes(jax.eval_shape(
@@ -639,6 +640,26 @@ def _solar_operands(one_chip, slots, n_pages=2000):
     assert rec.state.shape == (3, slots, 64, 128, 128)
     assert rec.conv.shape == (3, slots, 3, 3 * 8192)
     return cfg, params, cache, rec
+
+
+def _assert_projections_read_in_place(text, cfg):
+    """A KDA layer's q/k/v weight reaches its dot where it lies: no
+    dynamic-slice (fused or not) makes a copy as large as one
+    projection matrix, and the traced KDA body holds ONE projection dot
+    (``serving_layout``'s 2-D ``wqkv``). The 3-D leaves were sliced out
+    of the scanned stack into fast memory first, three copies of 67 MB
+    a layer a step (``constant_dynamic-slice_fusion``; compiler, PR 38)."""
+    import math
+    import re
+    matrix = cfg.dim * cfg.kda_heads * cfg.kda_head_dim
+    copies = [m.group(1) for m in re.finditer(
+        r'%(\S*dynamic-slice\S*) = \(?\w+\[([\d,]*)\]', text)
+        if math.prod(int(n) for n in m.group(2).split(',') if n) >= matrix]
+    assert copies == []
+    dots = [re.search(r'kda_mix/(bsd,dn->bsn|bsd,dhk->bshk)/', line)
+            for line in text.splitlines()
+            if re.search(r' (convolution|dot)\(', line)]
+    assert [d.group(1) for d in dots if d] == ['bsd,dn->bsn']
 
 
 @pytest.mark.parametrize('slots', [32, 64])
@@ -651,8 +672,8 @@ def test_hybrid_decode_program_advances_the_state_in_place(
     aliased to its output, so no second copy of it exists; the loops are
     the horizon, the period and the run of 3 KDA layers (ONE traced KDA
     body); one paged kernel (the GQA layer), 2 x 3 grouped expert
-    matmuls and the KDA state kernel. 0.19 GB of temp at 32 slots
-    (compiler, PR 37)."""
+    matmuls and the KDA state kernel; each KDA layer's q/k/v read in
+    place by one dot. 0.19 GB of temp at 32 slots (compiler, PR 37)."""
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     cfg, params, cache, rec = _solar_operands(one_chip, slots)
 
@@ -676,6 +697,7 @@ def test_hybrid_decode_program_advances_the_state_in_place(
     assert mem.temp_size_in_bytes < 0.45e9 * slots / 32
     assert text.count('tpu_custom_call') == 8
     assert text.count(' while(') <= 5
+    _assert_projections_read_in_place(text, cfg)
 
 
 def test_hybrid_prefill_chunk_carries_the_state_in_place(one_chip,
@@ -684,7 +706,8 @@ def test_hybrid_prefill_chunk_carries_the_state_in_place(one_chip,
     over an 8-page bucket: pools and recurrent state are all aliased to
     their outputs (1.05 GB + 0.42 GB; the 4 rows' states are taken out,
     advanced through the chunked delta rule and scattered back), and
-    the transients stay under 0.7 GB (0.49 GB; compiler, PR 37)."""
+    the transients stay under 0.7 GB (0.49 GB; compiler, PR 37); the
+    q/k/v weights are read in place, as in decode."""
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     slots, n, table_p, chunk = 32, 4, 8, 256
     cfg, params, cache, rec = _solar_operands(one_chip, slots)
@@ -707,7 +730,9 @@ def test_hybrid_prefill_chunk_carries_the_state_in_place(one_chip,
     assert mem.alias_size_in_bytes == held
     assert mem.output_size_in_bytes == pytest.approx(held, rel=0.001)
     assert mem.temp_size_in_bytes < 0.7e9
-    assert compiled.as_text().count('tpu_custom_call') == 6
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 6
+    _assert_projections_read_in_place(text, cfg)
 
 
 def test_hybrid_chunk_batches_count_the_recurrent_mixers_terms(
