@@ -588,24 +588,28 @@ class _EngineBase:
         if self._meta_dirty or self._meta_dev is None:
             temps = np.array([r.temperature if r else 0.0
                               for r in ready], np.float32)
-            self._meta_dev = (
-                jnp.asarray(temps),
-                jnp.asarray([r.top_k if r else 0 for r in ready],
-                            np.int32),
-                jnp.asarray([r.top_p if r else 1.0 for r in ready],
-                            np.float32),
-                jnp.asarray(np.array([r is not None for r in ready])),
-                bool((temps > 0).any()))
+            # ``jnp.asarray`` of a list converts on the device: eager ops.
+            with self._prof.phase('eager', site='slot_meta'):
+                self._meta_dev = (
+                    jnp.asarray(temps),
+                    jnp.asarray([r.top_k if r else 0 for r in ready],
+                                np.int32),
+                    jnp.asarray([r.top_p if r else 1.0 for r in ready],
+                                np.float32),
+                    jnp.asarray(np.array([r is not None for r in ready])),
+                    bool((temps > 0).any()))
             if getattr(self, 'adapters', None) is not None:
-                self._adp_dev = device_upload(np.array(
-                    [r._adapter_slot if r is not None else -1
-                     for r in ready], np.int32))
+                with self._prof.phase('upload'):
+                    self._adp_dev = device_upload(np.array(
+                        [r._adapter_slot if r is not None else -1
+                         for r in ready], np.int32))
             if self._vmask_any:
                 vm = np.ones((len(ready), self.cfg.vocab_size), bool)
                 for i, r in enumerate(ready):
                     if r is not None and r._vocab_mask is not None:
                         vm[i] = r._vocab_mask
-                self._vmask_dev = device_upload(vm)
+                with self._prof.phase('upload'):
+                    self._vmask_dev = device_upload(vm)
             self._meta_dirty = False
         return self._meta_dev
 
@@ -1153,11 +1157,13 @@ class _EngineBase:
         self._slot_len[slot] = int(snap['n_rows'])
         # Current token = the last generated one; decode resumes on
         # the very next horizon without a host round trip.
-        slot_d, tok_d = device_upload(
-            (np.array([slot], np.int32),
-             np.array([ctx[-1]], np.int32)))
-        self._tok_dev = self._merge_tokens_drop(self._tok_dev, slot_d,
-                                                tok_d)
+        with self._prof.phase('upload'):
+            slot_d, tok_d = device_upload(
+                (np.array([slot], np.int32),
+                 np.array([ctx[-1]], np.int32)))
+        with self._prof.phase('dispatch'):
+            self._tok_dev = self._merge_tokens_drop(self._tok_dev, slot_d,
+                                                    tok_d)
         self._meta_dirty = True
         return req.request_id
 

@@ -2232,7 +2232,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 adp_h[i] = req._adapter_slot
             if vm_h is not None and req._vocab_mask is not None:
                 vm_h[i] = req._vocab_mask
-        self._rng, prng = jax.random.split(self._rng)   # device op
+        with self._prof.phase('eager', site='rng_split'):
+            self._rng, prng = jax.random.split(self._rng)
         # ONE batched host->device transfer for every host-built
         # operand: each separate jnp.asarray is its own dispatch round
         # trip, nine of them per admission otherwise.
@@ -2251,7 +2252,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                                     lengths[:len(batch)]))
         chunk_tokens = int(sum(valid[:len(batch)]))
         with self._prof.phase('admit_upload', pairs=pairs,
-                              tokens=chunk_tokens):
+                              tokens=chunk_tokens), \
+                self._prof.phase('upload'):
             uploaded = device_upload(
                 (table_p, tokens, lengths, valid, want, temps, topks,
                  topps) + extras)
@@ -2272,6 +2274,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         chunk_t0 = clock.monotonic()
         with self._prof.phase('prefill_chunk', prompts=n, pages=P,
                               width=chunk_w, sample=sample), \
+                self._prof.phase('dispatch'), \
                 self._prof.jit_key('prefill', (n, P, sample, chunk_w)):
             first, self.cache, *rec = prefill(
                 self.params, self.cache, table_d, tokens_d, lengths_d,
@@ -2322,9 +2325,13 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             for j, (i, slot) in enumerate(done_rows):
                 rows_p[j], slots_p[j] = i, slot
             with self._prof.phase('admit_token_merge'):
-                rows_d, slots_d = device_upload((rows_p, slots_p))
-                self._tok_dev = self._merge_tokens_drop(
-                    self._tok_dev, slots_d, jnp.take(first, rows_d))
+                with self._prof.phase('upload'):
+                    rows_d, slots_d = device_upload((rows_p, slots_p))
+                with self._prof.phase('eager', site='first_token_take'):
+                    picked = jnp.take(first, rows_d)
+                with self._prof.phase('dispatch'):
+                    self._tok_dev = self._merge_tokens_drop(
+                        self._tok_dev, slots_d, picked)
             self._meta_dirty = True          # slots become decodable
             self._pending.append({
                 'kind': 'prefill', 'toks': first,
@@ -2432,8 +2439,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             P = _bucket_len(len(pages), minimum=1)
             table = np.zeros((P,), np.int32)
             table[:len(pages)] = pages
-            out = self._get_export(P)(self.cache,
-                                      device_upload(table))
+            with self._prof.phase('upload'):
+                table_d = device_upload(table)
+            out = self._get_export(P)(self.cache, table_d)
             # Sanctioned d2h: the checkpoint export IS a host
             # readback by design (the rows leave on the wire or
             # land in a checkpoint file).
@@ -2571,7 +2579,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         table = np.zeros((P,), np.int32)
         ps = self._pages[slot][:P]
         table[:len(ps)] = ps
-        table_d = device_upload(table)
+        with self._prof.phase('upload'):
+            table_d = device_upload(table)
         out = self._get_export(P)(self.cache, table_d)
         # Sanctioned d2h: the handoff export IS a host readback by
         # design (the rows leave this process on the wire).
@@ -2645,19 +2654,21 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         # scatter is tail-shape-generic, only the pad buffer cares.
         code_d = cfg.head_dim // 2 if self.cache.packed else cfg.head_dim
         if self.cache.quantized:
-            (kq, ks, vq, vs, table_d, starts_d,
-             valid_d) = device_upload(
-                (pad(snap['k'], (code_d,)),
-                 pad(snap['k_scale'], (1,)),
-                 pad(snap['v'], (code_d,)),
-                 pad(snap['v_scale'], (1,)), table, starts, valid))
+            with self._prof.phase('upload'):
+                (kq, ks, vq, vs, table_d, starts_d,
+                 valid_d) = device_upload(
+                    (pad(snap['k'], (code_d,)),
+                     pad(snap['k_scale'], (1,)),
+                     pad(snap['v'], (code_d,)),
+                     pad(snap['v_scale'], (1,)), table, starts, valid))
             self.cache = ingest(self.cache, kq, ks, vq, vs,
                                 table_d, starts_d, valid_d)
         else:
-            kr, vr, table_d, starts_d, valid_d = device_upload(
-                (pad(snap['k'], (cfg.head_dim,)),
-                 pad(snap['v'], (cfg.head_dim,)), table, starts,
-                 valid))
+            with self._prof.phase('upload'):
+                kr, vr, table_d, starts_d, valid_d = device_upload(
+                    (pad(snap['k'], (cfg.head_dim,)),
+                     pad(snap['v'], (cfg.head_dim,)), table, starts,
+                     valid))
             self.cache = ingest(self.cache, kr, vr, table_d,
                                 starts_d, valid_d)
 
@@ -2710,7 +2721,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         the pool alone once the others release."""
         for slot in slots:
             if self._slots[slot] is not None:
-                self._preempt_slot(slot)
+                with self._prof.phase('preempt'):
+                    self._preempt_slot(slot)
 
     def _get_spec_verify(self, n: int, P: int, sample: bool):
         key = (self.speculate_k, sample, P)
@@ -2748,12 +2760,15 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             ps = self._pages[s][:P]
             table_p[s, :len(ps)] = ps
         lengths = self._slot_len.astype(np.int32)
-        self._rng, rng = jax.random.split(self._rng)
-        table_d, prop_d, n_prop_d, lengths_d = device_upload(
-            (table_p, proposals, n_prop, lengths))
+        with self._prof.phase('eager', site='rng_split'):
+            self._rng, rng = jax.random.split(self._rng)
+        with self._prof.phase('upload'):
+            table_d, prop_d, n_prop_d, lengths_d = device_upload(
+                (table_p, proposals, n_prop, lengths))
         verify = self._get_spec_verify(self.max_batch, P, sample)
-        with self._prof.jit_key('spec_verify',
-                                (self.speculate_k, sample, P)):
+        with self._prof.phase('dispatch'), \
+                self._prof.jit_key('spec_verify',
+                                   (self.speculate_k, sample, P)):
             commit, n_commit, self._tok_dev, self.cache = verify(
                 self.params, self.cache, table_d, self._tok_dev, prop_d,
                 n_prop_d, lengths_d, active_d, self._adp_dev,
@@ -2849,18 +2864,21 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             table_p[s, :len(ps)] = ps
         lengths = self._slot_len.astype(np.int32)
         hist, rem = self._spec_hist_state(ready)
-        keys = jax.random.split(self._rng, rounds + 1)
-        self._rng = keys[0]
-        table_d, hist_d, rem_d, lengths_d = device_upload(
-            (table_p, hist, rem, lengths))
+        with self._prof.phase('eager', site='rng_split'):
+            keys = jax.random.split(self._rng, rounds + 1)
+            self._rng, round_keys = keys[0], keys[1:]
+        with self._prof.phase('upload'):
+            table_d, hist_d, rem_d, lengths_d = device_upload(
+                (table_p, hist, rem, lengths))
         fused = self._get_spec_fused(self.max_batch, P, sample, rounds)
-        with self._prof.jit_key('spec_fused',
-                                (self.speculate_k, sample, P, rounds)):
+        with self._prof.phase('dispatch'), \
+                self._prof.jit_key('spec_fused',
+                                   (self.speculate_k, sample, P, rounds)):
             commits, n_commits, n_props, self._tok_dev, self.cache = \
                 fused(self.params, self.cache, table_d, self._tok_dev,
                       hist_d, rem_d, lengths_d, active_d, self._adp_dev,
                       self._vmask_dev, temps_d, topks_d, topps_d,
-                      keys[1:])
+                      round_keys)
         return commits, n_commits, n_props
 
     def step(self, horizon: int = 1) -> List[Tuple[int, int, bool]]:
@@ -3001,8 +3019,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 # its returned events) and retry next step.
                 drained = list(self._deferred_events)
                 self._deferred_events = []
-                while self._pending:
-                    drained.extend(self._process_one())
+                with self._prof.phase('pressure_drain'):
+                    while self._pending:
+                        drained.extend(self._process_one())
                 self._deferred_events = drained
                 return False
             # Victim pool: every occupied slot (mid-prefill ones hold
@@ -3018,7 +3037,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                     'KV page pool exhausted even at horizon=1 with one '
                     'active request; raise n_pages')
             victim = max(cands, key=lambda s: self._slots[s].request_id)
-            self._preempt_slot(victim)
+            with self._prof.phase('preempt'):
+                self._preempt_slot(victim)
             if victim in active_slots:
                 active_slots.remove(victim)
 
@@ -3037,8 +3057,10 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             table_p[s, :len(ps)] = ps
         # Device-truth lengths at this call = processed + in-flight.
         lengths = (self._slot_len + self._slot_inflight).astype(np.int32)
-        self._rng, rng = jax.random.split(self._rng)
-        table_dd, lengths_dd = device_upload((table_p, lengths))
+        with self._prof.phase('eager', site='rng_split'):
+            self._rng, rng = jax.random.split(self._rng)
+        with self._prof.phase('upload'):
+            table_dd, lengths_dd = device_upload((table_p, lengths))
         # Per-substep attribution: one dispatch covers ``horizon``
         # decode substeps (multi-step amortization; the profiler's
         # per_substep_ms split makes it visible).
@@ -3055,7 +3077,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         self._prof.note_pool_write(horizon * len(active_slots),
                                    horizon * self.max_batch)
         self._prof.tag(horizon=horizon, pages=P)
-        with self._prof.jit_key('decode', (horizon, sample, P)):
+        with self._prof.phase('dispatch'), \
+                self._prof.jit_key('decode', (horizon, sample, P)):
             toks, self.cache = self._decode_fn(
                 self.params, self.cache, table_dd,
                 self._tok_dev, lengths_dd, rng,
@@ -3063,7 +3086,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 self._vmask_dev, horizon, sample)
         self._note_decode_step(
             int(sum(int(lengths[s]) for s in active_slots)))
-        self._tok_dev = toks[:self.max_batch, -1]
+        with self._prof.phase('eager', site='token_slice'):
+            self._tok_dev = toks[:self.max_batch, -1]
         # Snapshot the epochs BEFORE any early free below bumps them:
         # the entry must record the epochs its tokens were produced
         # under, or a recycled slot's stale entry would pass the epoch

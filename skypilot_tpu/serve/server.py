@@ -528,7 +528,11 @@ class ModelServer:
 
         while not self._stopping:
             try:
-                self._work.wait()
+                # Parked for lack of work: the loop account's one cause
+                # that says the chip idles because nothing arrived.
+                prof = self.engine.profiler
+                with prof.phase('wait_work'):
+                    self._work.wait()
                 if self._stopping:
                     break
                 if (self._gang is not None
@@ -596,7 +600,6 @@ class ModelServer:
                     # Stale results (a slot turned over meanwhile) are
                     # revalidated and recomputed inside step().
                     self.engine.prepare_proposals()
-                prof = self.engine.profiler
                 with self._engine_lock_timed(prof):
                     # Top the engine up from the scheduler's tier
                     # queues (priority + SRW order, tier budget
@@ -611,6 +614,7 @@ class ModelServer:
                     # parks here instead of spinning — release_hold /
                     # submit / drain all set the wake event.
                     if self.engine.has_runnable_work():
+                        prof.note_turn()
                         # Adaptive fused horizon: long fused calls
                         # maximize throughput at saturation (dispatch
                         # is pipelined away, but per-call host work
@@ -1597,6 +1601,8 @@ class ModelServer:
         eng = self.engine
         spec = eng.spec_metrics() if eng is not None else {}
         pool = self._kv_pool_stats()
+        loop_s = (eng.profiler if eng is not None
+                  else profiler_lib.NullProfiler()).loop_seconds()
         sched_stats = self.sched.json_stats()
         return {
             'requests_served': int(self._m_served.value),
@@ -1678,6 +1684,13 @@ class ModelServer:
                     profiler_lib.STATE_RESETS_METRIC),
                 'state_recompute_tokens_total': self._counter_value(
                     profiler_lib.STATE_RECOMPUTE_METRIC),
+                # The loop account: the engine thread's seconds by cause
+                # (the open span counted to now, so they add up to the
+                # thread's clock) and the turns that had work.
+                **{f'loop_{cause}_seconds_total': loop_s[cause]
+                   for cause in profiler_lib.LOOP_CAUSES},
+                'loop_turns_total': self._counter_value(
+                    profiler_lib.LOOP_TURNS_METRIC),
             },
             # Speculative decoding gauges (zeros when off).
             'speculate_k': spec.get('speculate_k', 0),

@@ -25,6 +25,18 @@ per-engine latency decomposition) and into the process registry
 ``/metrics`` surface). :class:`NullProfiler` is the telemetry-off
 no-op twin with the same API.
 
+The loop account: a phase's inclusive time counts its nested phases
+again, so the histograms cannot be added up. Beside them the profiler
+charges every instant of ONE thread (the one that built it: the engine
+loop's) to exactly one cause — the innermost open phase, or ``loop``
+for the thread's own code between phases — from the same clock pair
+each phase already takes. ``loop_seconds()`` therefore adds up to that
+thread's clock since construction; the cumulative counters are
+``skytpu_engine_loop_seconds_total{cause=...}``, every cause of
+:data:`LOOP_CAUSES` registered up front. Phases entered on other
+threads (a handler exporting KV rows) keep their histogram and
+annotation and charge nothing.
+
 One timeline with the device: while a ``jax.profiler`` trace of the
 process runs, every phase (and every first call per jit key) is also a
 ``jax.profiler.TraceAnnotation`` named ``skytpu:<phase>``, so it lands
@@ -80,6 +92,21 @@ ATTN_PAGES_TABLE_METRIC = 'skytpu_decode_attn_pages_table_total'
 # shapes carried; the difference is what the write loop skips.
 POOL_ROWS_LIVE_METRIC = 'skytpu_pool_write_rows_live_total'
 POOL_ROWS_OFFERED_METRIC = 'skytpu_pool_write_rows_offered_total'
+# The loop account: seconds of the engine thread by cause, and the turns
+# of the serving loop that had work.
+LOOP_SECONDS_METRIC = 'skytpu_engine_loop_seconds_total'
+LOOP_TURNS_METRIC = 'skytpu_engine_loop_turns_total'
+# Every cause the account charges (a phase name, or ``loop``):
+# ``wait_work`` the serving loop parked for lack of work; ``dispatch`` a
+# jitted program handed to the runtime; ``upload`` a host->device copy;
+# ``eager`` a device op outside the jitted programs (its annotation names
+# the ``site``); ``first_call`` a call that compiles; ``pressure_drain``
+# and ``preempt`` the pool-pressure paths of the decode enqueue.
+LOOP_CAUSES = ('loop', 'wait_work', 'lock_wait', 'fill_engine',
+               'route_events', 'readback', 'admit', 'admit_upload',
+               'prefill_chunk', 'admit_token_merge', 'decode_enqueue',
+               'pressure_drain', 'preempt', 'spec_verify', 'dispatch',
+               'upload', 'eager', 'first_call')
 ANNOTATION_PREFIX = 'skytpu:'
 
 
@@ -121,6 +148,12 @@ class NullProfiler:
 
     def note_pool_write(self, live: int, offered: int) -> None:
         del live, offered
+
+    def note_turn(self) -> None:
+        pass
+
+    def loop_seconds(self) -> Dict[str, float]:
+        return dict.fromkeys(LOOP_CAUSES, 0.0)
 
     def phase_stats(self) -> Dict[str, Any]:
         return {}
@@ -208,6 +241,21 @@ class StepProfiler:
             'Token rows those programs\' shapes carried (slots x tokens a '
             'slot; over the live counter: the share of a row write that '
             'the loop over live units skips)')
+        self._turns = self._reg.counter(
+            LOOP_TURNS_METRIC,
+            'Turns of the serving loop that had runnable work')
+        self._loop_counters = {cause: self._reg.counter(
+            LOOP_SECONDS_METRIC,
+            "Seconds of the engine thread charged to each cause: the "
+            "innermost open phase, or 'loop' between phases (a span "
+            'counts when it is left)', cause=cause) for cause in LOOP_CAUSES}
+        # The account of the building thread: its seconds by cause, the
+        # phases it has open (innermost last), and the cause charged now
+        # with when that began.
+        self._owner = threading.get_ident()
+        self._self_s = dict.fromkeys(LOOP_CAUSES, 0.0)
+        self._causes: List[str] = []
+        self._cursor: Tuple[str, float] = ('loop', clock.monotonic())
         self._hists: Dict[str, registry_lib.Histogram] = {}
         self._seen_keys: Dict[str, set] = {}
         self.compile_events: List[Dict[str, Any]] = []
@@ -218,6 +266,29 @@ class StepProfiler:
         # The annotations now open, innermost last (engine thread only;
         # empty while no trace runs).
         self._open: List[Any] = []
+
+    def _charge(self, now: float, cause: str) -> None:
+        """Charge the owner thread's time since the last transition to
+        the cause charged until now, and charge ``cause`` from here on.
+        Called with ``self._lock`` held."""
+        was, since = self._cursor
+        self._cursor = (cause, now)
+        self._self_s[was] += now - since
+        self._loop_counters[was].inc(now - since)
+
+    def _enter(self, name: str, t0: float) -> bool:
+        """Open ``name`` in the account if the caller is its thread."""
+        if threading.get_ident() != self._owner:
+            return False
+        with self._lock:
+            self._charge(t0, name)
+            self._causes.append(name)
+        return True
+
+    def _leave(self, t1: float) -> None:
+        """Close the innermost phase; with ``self._lock`` held."""
+        self._causes.pop()
+        self._charge(t1, self._causes[-1] if self._causes else 'loop')
 
     def _phase_hist(self, name: str) -> registry_lib.Histogram:
         hist = self._hists.get(name)
@@ -251,14 +322,18 @@ class StepProfiler:
     def phase(self, name: str, **key: Any):
         annotated = self._annotate(name, key)
         t0 = clock.monotonic()
+        counted = self._enter(name, t0)
         try:
             yield
         finally:
-            dt = clock.monotonic() - t0
+            t1 = clock.monotonic()
+            dt = t1 - t0
             if annotated:
                 self._open.pop().__exit__(None, None, None)
             self._phase_hist(name).observe(dt)
             with self._lock:
+                if counted:
+                    self._leave(t1)
                 acc = self._acc.setdefault(name, [0, 0.0, 0.0])
                 acc[0] += 1
                 acc[1] += dt
@@ -267,7 +342,8 @@ class StepProfiler:
     @contextlib.contextmanager
     def jit_key(self, fn: str, key: Tuple):
         """Time the FIRST dispatch of each (fn, static key) — the call
-        that pays compilation. Subsequent calls: one set lookup."""
+        that pays compilation, the account's ``first_call``. Subsequent
+        calls: one set lookup."""
         seen = self._seen_keys.setdefault(fn, set())
         if key in seen:
             yield
@@ -275,12 +351,17 @@ class StepProfiler:
         annotated = self._annotate('first_call',
                                    {'fn': fn, 'key': repr(key)})
         t0 = clock.monotonic()
+        counted = self._enter('first_call', t0)
         try:
             yield
         finally:
-            dt = clock.monotonic() - t0
+            t1 = clock.monotonic()
+            dt = t1 - t0
             if annotated:
                 self._open.pop().__exit__(None, None, None)
+            if counted:
+                with self._lock:
+                    self._leave(t1)
             seen.add(key)
             self._reg.histogram(
                 COMPILE_METRIC,
@@ -353,6 +434,21 @@ class StepProfiler:
         arithmetic."""
         self._pool_rows_live.inc(live)
         self._pool_rows_offered.inc(offered)
+
+    def note_turn(self) -> None:
+        """A turn of the serving loop that found runnable work."""
+        self._turns.inc(1)
+
+    def loop_seconds(self) -> Dict[str, float]:
+        """The owner thread's seconds by cause, the cause charged now
+        counted up to this call: they add up to that thread's clock
+        since this profiler was built (the registry's counters lag by
+        the open span)."""
+        with self._lock:
+            out = dict(self._self_s)
+            cause, since = self._cursor
+            out[cause] += clock.monotonic() - since
+        return out
 
     def phase_stats(self) -> Dict[str, Any]:
         """Per-phase summary for THIS engine (bench's latency

@@ -799,7 +799,8 @@ def test_server_prometheus_metrics_and_debug_requests():
 def _profile(trace_dir, body):
     """Run ``body`` under a jax.profiler trace (host and device events,
     no Python tracer: what the benchmark's ``--trace 1`` records);
-    returns (plane name, line name, event name, stats) of every event."""
+    returns (plane name, line name, event name, stats, start ns, end ns)
+    of every event."""
     import glob
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -812,7 +813,8 @@ def _profile(trace_dir, body):
     found = sorted(glob.glob(str(
         trace_dir / 'plugins' / 'profile' / '*' / '*.xplane.pb')))
     data = jax.profiler.ProfileData.from_file(found[-1])
-    return [(p.name, ln.name, e.name, dict(e.stats))
+    return [(p.name, ln.name, e.name, dict(e.stats), e.start_ns,
+             e.start_ns + e.duration_ns)
             for p in data.planes for ln in p.lines for e in ln.events]
 
 
@@ -837,7 +839,7 @@ def test_profiled_engine_phases_on_the_trace_clock(tmp_path):
         eng.run_to_completion(horizon=4)
 
     events = _profile(tmp_path, body)
-    host = [(name, stats) for plane, _, name, stats in events
+    host = [(name, stats) for plane, _, name, stats, _, _ in events
             if plane.startswith('/host:')]
     names = {name for name, _ in host}
     for phase in ('admit', 'admit_upload', 'admit_token_merge',
@@ -852,7 +854,7 @@ def test_profiled_engine_phases_on_the_trace_clock(tmp_path):
                   if n == 'skytpu:decode_enqueue' and st)
     assert decode['horizon'] == 4 and decode['pages'] >= 1
     # The programs the device ran, by the name their operations carry.
-    modules = {st['hlo_module'] for _, _, _, st in events
+    modules = {st['hlo_module'] for _, _, _, st, _, _ in events
                if 'hlo_module' in st}
     assert 'jit_merge_ring_into_pool' in modules, modules
     assert 'jit_decode_steps' in modules and 'jit_prefill' in modules
@@ -886,6 +888,148 @@ def test_annotation_costs_nothing_without_a_trace(monkeypatch):
         pass
     assert Boom.built == 0 and not prof._open
     assert set(prof.phase_stats()['phases']) == {'admit', 'prefill_chunk'}
+
+
+# ---------------------------------------------------------------------------
+# The loop account: every instant of the engine thread charged to one cause
+# ---------------------------------------------------------------------------
+def test_nested_phases_charge_the_innermost_cause(monkeypatch):
+    """Self time: an outer phase is charged only while nothing is open
+    inside it, a first dispatch is ``first_call``, the thread's time
+    between phases is ``loop``, and the account adds up to the clock;
+    the registry's counters lag by the span still open. A phase entered
+    on another thread keeps its histogram and charges nothing."""
+    from skypilot_tpu.telemetry import clock
+    from skypilot_tpu.telemetry import profiler as profiler_lib
+    now = [100.0]
+    monkeypatch.setattr(clock, 'monotonic', lambda: now[0])
+    reg = registry_lib.MetricsRegistry()
+    prof = profiler_lib.StepProfiler(engine='t', registry=reg)
+    # Every cause is on the registry before any phase: zeros.
+    for cause in profiler_lib.LOOP_CAUSES:
+        assert reg.get(profiler_lib.LOOP_SECONDS_METRIC,
+                       cause=cause).value == 0.0
+    assert reg.get(profiler_lib.LOOP_TURNS_METRIC).value == 0.0
+
+    def at(t):
+        now[0] = 100.0 + t
+
+    at(1)
+    with prof.phase('admit'):
+        at(3)
+        with prof.phase('upload'):
+            at(4)
+        at(6)
+        with prof.jit_key('decode', (8, False, 1)):
+            at(10)
+        with prof.jit_key('decode', (8, False, 1)):   # seen: no cause
+            at(10.5)
+        at(11)
+    at(15)
+    seconds = prof.loop_seconds()
+    assert seconds['loop'] == 1 + 4                   # 0-1, 11-15 (open)
+    assert seconds['admit'] == 2 + 2 + 1              # 1-3, 4-6, 10-11
+    assert seconds['upload'] == 1 and seconds['first_call'] == 4
+    assert sum(seconds.values()) == 15
+    assert set(seconds) == set(profiler_lib.LOOP_CAUSES)
+    counted = {c: reg.get(profiler_lib.LOOP_SECONDS_METRIC, cause=c).value
+               for c in ('loop', 'admit', 'upload', 'first_call')}
+    assert counted == {'loop': 1, 'admit': 5, 'upload': 1,
+                       'first_call': 4}
+    # The inclusive histograms are as they were.
+    phases = prof.phase_stats()['phases']
+    assert phases['admit']['total_s'] == 10 and phases['upload']['total_s'] == 1
+
+    def elsewhere():
+        with prof.phase('upload'):
+            at(20)
+    worker = threading.Thread(target=elsewhere)
+    worker.start()
+    worker.join()
+    assert prof.phase_stats()['phases']['upload']['count'] == 2
+    seconds = prof.loop_seconds()
+    assert seconds['upload'] == 1 and seconds['loop'] == 1 + 9
+    prof.note_turn()
+    assert reg.get(profiler_lib.LOOP_TURNS_METRIC).value == 1.0
+    null = profiler_lib.NullProfiler()
+    null.note_turn()
+    assert null.loop_seconds() == dict.fromkeys(profiler_lib.LOOP_CAUSES,
+                                                0.0)
+
+
+def test_every_phase_of_the_package_is_a_cause():
+    """The JSON block lists ``LOOP_CAUSES``: a phase named anywhere else
+    would charge time that no key reports."""
+    import pathlib
+    import re
+    from skypilot_tpu.telemetry import profiler as profiler_lib
+    root = pathlib.Path(profiler_lib.__file__).parents[1]
+    named = set()
+    for path in root.rglob('*.py'):
+        named.update(re.findall(r"\.phase\(\s*'(\w+)'", path.read_text()))
+    assert {'wait_work', 'dispatch', 'upload', 'eager', 'preempt',
+            'pressure_drain'} <= named
+    assert named <= set(profiler_lib.LOOP_CAUSES), (
+        named - set(profiler_lib.LOOP_CAUSES))
+
+
+def _engine_line(events):
+    """The host line of the thread that entered the ``skytpu:`` events,
+    as (start, end, name, stats) sorted outer first."""
+    lines = {}
+    for plane, line, name, stats, s, e in events:
+        if plane.startswith('/host:'):
+            lines.setdefault(line, []).append((s, e, name, stats))
+    best = max(lines.values(), key=lambda evs: sum(
+        n.startswith('skytpu:') for _, _, n, _ in evs))
+    return sorted(best, key=lambda ev: (ev[0], -ev[1]))
+
+
+def _inside(ev, outer):
+    return outer[0] <= ev[0] and ev[1] <= outer[1]
+
+
+def test_profiled_engine_holds_the_loop_account_spans(tmp_path):
+    """Traced, the decode enqueue holds ``skytpu:dispatch``,
+    ``skytpu:upload`` and ``skytpu:eager`` with its ``site``, a new
+    program key a ``skytpu:first_call`` inside its dispatch, and every
+    program the thread hands the runtime outside a compile lies in a
+    dispatch or an eager span."""
+    eng = _tiny_paged()
+    eng.add_request([1, 2, 3] * 7, max_new_tokens=5)
+    eng.run_to_completion(horizon=4)            # compile outside
+
+    def body():
+        eng.add_request([4, 5, 6] * 7, max_new_tokens=5)
+        eng.run_to_completion(horizon=4)
+        eng.add_request([7, 8] * 5, max_new_tokens=3)
+        eng.run_to_completion(horizon=2)        # a new decode key
+
+    events = _profile(tmp_path, body)
+    line = _engine_line(events)
+    spans = [ev for ev in line if ev[2].startswith('skytpu:')]
+    enqueues = [ev for ev in spans if ev[2] == 'skytpu:decode_enqueue']
+    for cause in ('dispatch', 'upload', 'eager'):
+        assert any(ev[2] == f'skytpu:{cause}' and _inside(ev, enq)
+                   for ev in spans for enq in enqueues), cause
+    sites = {ev[3].get('site') for ev in spans if ev[2] == 'skytpu:eager'}
+    assert {'rng_split', 'token_slice', 'slot_meta',
+            'first_token_take'} <= sites, sites
+    assert all(ev[3].get('site') for ev in spans
+               if ev[2] == 'skytpu:eager')
+    first = [ev for ev in spans if ev[2] == 'skytpu:first_call']
+    assert any(ev[3]['fn'] == 'decode'
+               and ev[3]['key'].startswith('(2, False,')
+               for ev in first), [ev[3] for ev in first]
+    assert all(any(_inside(f, d) for d in spans
+                   if d[2] == 'skytpu:dispatch') for f in first)
+    holders = [ev for ev in spans if ev[2] in (
+        'skytpu:dispatch', 'skytpu:eager', 'skytpu:first_call')]
+    programs = [ev for ev in line if ev[2].startswith('PjitFunction(')]
+    assert programs
+    loose = [ev[2] for ev in programs
+             if not any(_inside(ev, h) for h in holders)]
+    assert not loose, loose
 
 
 def test_request_trace_stage_arithmetic():
@@ -976,6 +1120,7 @@ def stage_server():
 
 
 def test_ttft_stages_present_and_zero_before_traffic(stage_server):
+    from skypilot_tpu.telemetry import profiler as profiler_lib
     _, _, before, _ = stage_server
     assert list(before['ttft_stages']) == list(tracing.TTFT_STAGES)
     for stage, block in before['ttft_stages'].items():
@@ -1009,8 +1154,15 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          'moe_held_experts', 'moe_assignments_held_total',
                          'recurrent_layers', 'recurrent_state_bytes',
                          'state_resets_total',
-                         'state_recompute_tokens_total'}
+                         'state_recompute_tokens_total',
+                         # the loop account: the engine thread's seconds
+                         # by cause, and the turns that had work
+                         'loop_turns_total'} | {
+        f'loop_{cause}_seconds_total'
+        for cause in profiler_lib.LOOP_CAUSES}
     assert all(isinstance(v, (int, float)) for v in loop.values())
+    assert loop['loop_turns_total'] == 0.0
+    assert loop['loop_wait_work_seconds_total'] > 0.0    # parked
     assert loop['moe_layer_steps_total'] == 0
     assert loop['prefill_tokens_total'] > 0
     assert 0 < loop['pool_write_rows_live_total'] \
@@ -1099,6 +1251,77 @@ def test_stage_and_loop_series_in_prometheus_text(stage_server):
     assert 'skytpu_attn_kernel_ms' not in prom
 
 
+def _idle_loop(port):
+    """``engine_loop`` once the loop has parked: two scrapes 50 ms
+    apart that count the same turns."""
+    import time
+    last = _get_json(port, '/metrics?format=json')['engine_loop']
+    for _ in range(100):
+        time.sleep(0.05)
+        now = _get_json(port, '/metrics?format=json')['engine_loop']
+        if now['loop_turns_total'] == last['loop_turns_total']:
+            return now
+        last = now
+    raise AssertionError('the engine loop never parked')
+
+
+def test_loop_account_adds_up_over_a_served_burst(stage_server):
+    """Over a burst of streamed requests the engine thread's causes add
+    up to its clock, each phase the loop enters at top level is its
+    causes' self times, and the new spans took their share."""
+    from skypilot_tpu.telemetry import profiler as profiler_lib
+    server, port, _, _ = stage_server
+    prof = server.engine.profiler
+    top = ('lock_wait', 'fill_engine', 'readback', 'admit',
+           'decode_enqueue', 'route_events')
+
+    def inclusive():
+        phases = prof.phase_stats()['phases']
+        return sum(phases.get(p, {}).get('total_s', 0.0) for p in top)
+
+    a = _idle_loop(port)
+    inc_a = inclusive()
+    workers = [threading.Thread(target=_stream_generate,
+                                args=(port, [5, 3, 9, i] * 4, 6))
+               for i in range(3)]
+    for w in workers:
+        w.start()
+    for w in workers:
+        w.join()
+    b = _idle_loop(port)
+    inc_b = inclusive()
+    d = {k: b[k] - a[k] for k in a}
+    turns = d['loop_turns_total']
+    assert turns > 0
+    causes = {c: d[f'loop_{c}_seconds_total']
+              for c in profiler_lib.LOOP_CAUSES}
+    assert abs(sum(causes.values()) - d['clock_s']) <= 1e-3 * turns
+    # Self times under the loop's top-level phases are those phases,
+    # inclusive (the scrapes both fell in a wait, which neither counts).
+    under_top = sum(v for c, v in causes.items()
+                    if c not in ('loop', 'wait_work'))
+    assert abs(under_top - (inc_b - inc_a)) <= 1e-3 * turns, (
+        under_top, inc_b - inc_a)
+    for cause in ('wait_work', 'lock_wait', 'fill_engine', 'decode_enqueue',
+                  'route_events', 'dispatch', 'upload', 'eager'):
+        assert causes[cause] > 0, (cause, causes)
+
+
+def test_parked_server_waits_for_work(stage_server):
+    """With nothing to do the engine thread is in ``wait_work`` (the
+    open span counted to the scrape) and counts no turn."""
+    import time
+    _, port, _, _ = stage_server
+    a = _idle_loop(port)
+    time.sleep(0.3)
+    b = _get_json(port, '/metrics?format=json')['engine_loop']
+    clock_s = b['clock_s'] - a['clock_s']
+    waited = (b['loop_wait_work_seconds_total']
+              - a['loop_wait_work_seconds_total'])
+    assert b['loop_turns_total'] == a['loop_turns_total']
+    assert 0.9 * clock_s <= waited <= clock_s + 1e-3, (waited, clock_s)
+
+
 def test_telemetry_off_no_annotation_no_stage(monkeypatch, tmp_path):
     """``SKYTPU_TELEMETRY=0``: a profiled request through the server
     enters no annotation, mints no trace and stamps no stage."""
@@ -1123,13 +1346,15 @@ def test_telemetry_off_no_annotation_no_stage(monkeypatch, tmp_path):
 
         events = _profile(tmp_path, body)
         assert out['events'][-1]['done']
-        assert not [name for _, _, name, _ in events
+        assert not [name for _, _, name, _, _, _ in events
                     if name.startswith('skytpu:')]
         assert len(tracing.get_trace_buffer()) == traces_before
         m = _get_json(port, '/metrics?format=json')
         assert all(block['n'] == 0
                    for block in m['ttft_stages'].values())
         assert m['requests_served'] == 1
+        assert all(m['engine_loop'][f'loop_{cause}_seconds_total'] == 0.0
+                   for cause in profiler_lib.LOOP_CAUSES)
     finally:
         server.stop()
         registry_lib.reset_registry()
