@@ -66,6 +66,26 @@ _GEN_ALIASES = {
 _TPU_RE = re.compile(r'^tpu[-_]?(v[0-9]+[a-z]*?(?:litepod|lite|p|e)?)[-_]([0-9]+)$',
                      re.IGNORECASE)
 
+# What a JAX device reports as its ``device_kind``, by generation.
+_DEVICE_KINDS = {
+    'tpu v2': 'v2',
+    'tpu v3': 'v3',
+    'tpu v4': 'v4',
+    'tpu v5 lite': 'v5e',
+    'tpu v5e': 'v5e',
+    'tpu v5': 'v5p',
+    'tpu v5p': 'v5p',
+    'tpu v6 lite': 'v6e',
+    'tpu v6e': 'v6e',
+}
+
+
+def generation_of_device(device_kind: str) -> Optional[TpuGeneration]:
+    """The generation of a JAX device's ``device_kind`` ('TPU v5 lite'),
+    or None for a device that is no TPU this module knows (the CPU)."""
+    name = _DEVICE_KINDS.get(device_kind.strip().lower())
+    return TPU_GENERATIONS[name] if name else None
+
 
 @dataclasses.dataclass(frozen=True)
 class TpuTopology:

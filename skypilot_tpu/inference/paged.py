@@ -1026,6 +1026,48 @@ def paged_spec_verify(
     return commit, n_commit, new_tok, new_cache
 
 
+def _chip_generation():
+    """The TPU generation of the first device (its peaks), or None off a
+    TPU: the CPU has no peaks here, and its chunk keeps its width."""
+    from skypilot_tpu import accelerators
+    return accelerators.generation_of_device(jax.devices()[0].device_kind)
+
+
+def wide_piece_width(params: Params, cfg: ModelConfig, chunk: int,
+                     gen) -> int:
+    """The width of a piece that a prompt prefilled alone takes: ``chunk``
+    times the largest power of two up to 4 that the piece's balance width
+    holds. A chunk program reads every stored weight but the embedding
+    table whatever its width (the head too, for the completing row), at
+    ``gen``'s HBM rate; each token's matmuls touch each weight but the
+    embedding and the head once, at ``gen``'s bf16 MXU rate, and of a
+    routed layer's held experts the share a token reaches (top-k / the
+    experts routed over). The balance width is where the two take
+    equally long: 120-240 tokens for a dense model (a weight is 1-2
+    bytes and 2 FLOPs a token), thousands where most stored bytes are
+    experts a token does not reach. A looped model reads and computes
+    every pass alike, so its passes cancel. ``gen`` None keeps
+    ``chunk``."""
+    if gen is None:
+        return chunk
+    from skypilot_tpu.models.quantization import per_device_bytes
+    read = per_device_bytes(params) - per_device_bytes(params['embed'])
+    touched = 0.0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        keys = {getattr(k, 'key', None) for k in path}
+        if keys & {'embed', 'unembed'}:
+            continue
+        share = (cfg.n_experts_per_token / cfg.n_routed_experts
+                 if 'experts' in keys else 1.0)
+        touched += per_device_bytes(leaf) / leaf.dtype.itemsize * share
+    flop_per_byte = gen.peak_bf16_tflops * 1e12 / (gen.hbm_bw_gbps * 1e9)
+    balance = flop_per_byte * read / (2 * touched)
+    width = chunk
+    while width < 4 * chunk and 2 * width <= balance:
+        width *= 2
+    return width
+
+
 # ---------------------------------------------------------------------------
 # Host-side allocator + prefix index
 # ---------------------------------------------------------------------------
@@ -1466,6 +1508,25 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         for b in self._PREFILL_N_BUCKETS:
             if b <= n_fit:
                 self._prefill_n_max = b
+        # A prompt prefilled alone takes pieces this wide while its tail
+        # lasts (``_prefill_chunk_batch``): where a chunk's time is the
+        # weights it reads, as a routed model's is (GLM-4.7-Flash re-read
+        # 8.5 GB of experts for each 256 tokens), a wider piece reads them
+        # once for more tokens. A dense model's balance width lies below
+        # its chunk, so its width is ``chunk`` and its programs are as
+        # they were. The piece is held to the transients a batch of
+        # width / chunk prompts at the longest context's page bucket may
+        # take, and halved until it fits.
+        from skypilot_tpu.inference.engine import _bucket_len
+        wide = wide_piece_width(self.params, cfg, chunk, _chip_generation())
+        pages_max = _bucket_len(self._pages_needed(max_seq), minimum=1)
+        while wide > chunk and (
+                wide // chunk > self._prefill_n_max
+                or self._chunk_piece_bytes(pages_max, wide)
+                > self._CHUNK_TRANSIENT_BUDGET):
+            wide //= 2
+        self._wide_chunk = wide
+        self._prof.note_wide_width(wide)
         # Expert layers a decode step runs (0: the model routes nothing),
         # for the expert counters of the profiler.
         self._moe_layers = (cfg.n_layers - cfg.n_dense_layers
@@ -1869,21 +1930,23 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
     # has 131 MB a 256-token piece of 64 heads x 128 (compiler, PR 37).
     _RECURRENT_TOKEN_ARRAYS = 16
 
-    def _chunk_piece_bytes(self, pages: int) -> int:
-        """What one prompt's piece adds to a chunk program's transients
-        at the page bucket ``pages``: its attention scores [heads,
-        chunk, pages x page]; routed experts' sorted rows (top-k copies
-        of a token through gate, up and down); a recurrent mixer's
-        float32 terms a token."""
+    def _chunk_piece_bytes(self, pages: int,
+                           width: Optional[int] = None) -> int:
+        """What one prompt's piece of ``width`` tokens (``chunk``) adds
+        to a chunk program's transients at the page bucket ``pages``: its
+        attention scores [heads, width, pages x page]; routed experts'
+        sorted rows (top-k copies of a token through gate, up and down);
+        a recurrent mixer's float32 terms a token."""
         cfg = self.cfg
-        piece = pages * (cfg.n_heads * self.chunk * self.page
+        width = width or self.chunk
+        piece = pages * (cfg.n_heads * width * self.page
                          * self._ATTN_SCORE_BYTES)
         if cfg.ffn_kind == 'routed_shared':
-            piece += self.chunk * cfg.n_experts_per_token * (
+            piece += width * cfg.n_experts_per_token * (
                 6 * cfg.dim + 8 * cfg.moe_ffn_dim)
         if cfg.recurrent:
             spec = cfg.state_spec
-            piece += (self.chunk * spec.heads * spec.k_dim * 4
+            piece += (width * spec.heads * spec.k_dim * 4
                       * self._RECURRENT_TOKEN_ARRAYS)
         return int(piece)
 
@@ -2110,7 +2173,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                     recompute_tokens=len(ctx) if req.output else 0)
             # Quantize the resume point to the canonical chunk grid.
             # A cold prefill chunks from offset 0, so its boundaries are
-            # exact multiples of ``self.chunk``; resuming a prefix hit at
+            # exact multiples of the wide width while its pieces are wide
+            # and of ``self.chunk`` after (``_on_cold_grid``); resuming a
+            # prefix hit at
             # an arbitrary page boundary regroups the same attention
             # terms across cached_attention's two softmax blocks
             # (cache-sum + in-chunk-sum), and the few-ULP denominator
@@ -2128,7 +2193,8 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # uninterrupted-run contract needs the mid-grid resume.
             if not req.output:
                 keep = len(matched)
-                while keep and (keep * self.page) % self.chunk:
+                while keep and not self._on_cold_grid(keep * self.page,
+                                                      len(ctx)):
                     keep -= 1
                 for p in matched[keep:]:
                     self.alloc.release(p)
@@ -2159,9 +2225,21 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 req.trace.instant('prefix_cache_hit',
                                   pages=len(matched))
 
+    def _on_cold_grid(self, at: int, length: int) -> bool:
+        """Whether a cold prefill of ``length`` tokens, alone from offset
+        0, starts a piece at ``at``: it takes wide pieces while its tail
+        holds one, so its boundaries are the multiples of the wide width
+        up to the last of them within ``length`` and the multiples of
+        ``chunk`` from there (every multiple of ``chunk`` where the two
+        widths are one)."""
+        wide = self._wide_chunk
+        return at % self.chunk == 0 and (
+            at % wide == 0 or at >= length // wide * wide)
+
     def _prefill_chunk_batch(self) -> List[Tuple[int, int, bool]]:
         """One fixed-size chunk across up to a compiled n-bucket of
-        mid-prefill slots. ALWAYS returns [] — completing slots'
+        mid-prefill slots (the wide width for one slot alone whose tail
+        holds it, ``wide_piece_width``). ALWAYS returns [] — completing slots'
         first tokens are sampled ON DEVICE (per-request params) and
         merged into the device token vector before this returns, so
         they decode next horizon; the first-token EVENT surfaces via
@@ -2184,8 +2262,16 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             - self._slots[s]._n_matched * self.page
             - self._prefill_off[s]
             for s in batch)
-        chunk_w = (128 if self.chunk > 128 and rest_max <= 128
-                   and self._narrow_chunk else self.chunk)
+        # A prompt alone whose tail holds a whole wide piece takes one:
+        # the piece reads the weights once for that many tokens, and
+        # several prompts of one batch already share that read.
+        wide = (self._wide_chunk > self.chunk and len(batch) == 1
+                and rest_max >= self._wide_chunk)
+        if wide:
+            chunk_w = self._wide_chunk
+        else:
+            chunk_w = (128 if self.chunk > 128 and rest_max <= 128
+                       and self._narrow_chunk else self.chunk)
         tokens = np.zeros((n, chunk_w), np.int32)
         lengths = np.zeros(n, np.int32)
         valid = np.zeros(n, np.int32)
@@ -2273,7 +2359,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         prefill = self._get_prefill(n, P, sample, chunk_w)
         chunk_t0 = clock.monotonic()
         with self._prof.phase('prefill_chunk', prompts=n, pages=P,
-                              width=chunk_w, sample=sample), \
+                              width=chunk_w, sample=sample, wide=wide), \
                 self._prof.phase('dispatch'), \
                 self._prof.jit_key('prefill', (n, P, sample, chunk_w)):
             first, self.cache, *rec = prefill(
@@ -2284,7 +2370,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 self.rec, = rec
         chunk_t1 = clock.monotonic()
         self.chunks_prefilled += 1
-        self._prof.note_prefill_pairs(pairs, tokens=chunk_tokens)
+        self._prof.note_prefill_pairs(pairs, tokens=chunk_tokens, wide=wide)
         self._prof.note_pool_write(chunk_tokens, n * chunk_w)
         for i, slot in enumerate(batch):
             r = self._slots[slot]

@@ -1664,6 +1664,12 @@ class ModelServer:
                     profiler_lib.ATTN_PAGES_TABLE_METRIC),
                 'prefill_tokens_total': self._counter_value(
                     profiler_lib.PREFILL_TOKENS_METRIC),
+                'prefill_wide_pieces_total': self._counter_value(
+                    profiler_lib.PREFILL_WIDE_PIECES_METRIC),
+                'prefill_wide_tokens_total': self._counter_value(
+                    profiler_lib.PREFILL_WIDE_TOKENS_METRIC),
+                'prefill_wide_width': self._counter_value(
+                    profiler_lib.PREFILL_WIDE_WIDTH_METRIC),
                 'kv_cache_layers': self._counter_value(
                     profiler_lib.KV_CACHE_LAYERS_METRIC),
                 'kv_token_bytes': self._counter_value(

@@ -78,6 +78,13 @@ STATE_RESETS_METRIC = 'skytpu_state_resets_total'
 STATE_RECOMPUTE_METRIC = 'skytpu_state_recompute_tokens_total'
 PREFILL_PAIRS_METRIC = 'skytpu_prefill_attn_pairs_total'
 PREFILL_TOKENS_METRIC = 'skytpu_prefill_tokens_total'
+# Pieces wider than the chunk, taken by a prompt prefilled alone
+# (``paged.wide_piece_width``): how many, their tokens (over the prefill
+# tokens: how often the wide width engages), and the width (a gauge; the
+# chunk itself where the model's chunk is not bound by weight reads).
+PREFILL_WIDE_PIECES_METRIC = 'skytpu_prefill_wide_pieces_total'
+PREFILL_WIDE_TOKENS_METRIC = 'skytpu_prefill_wide_tokens_total'
+PREFILL_WIDE_WIDTH_METRIC = 'skytpu_prefill_wide_width'
 # What one cached token is (gauges the engine sets once it is built): its
 # cache layers (layers x passes of a looped model) and its stored bytes
 # over all of them.
@@ -140,8 +147,12 @@ class NullProfiler:
     def note_state_reset(self, recompute_tokens: int = 0) -> None:
         del recompute_tokens
 
-    def note_prefill_pairs(self, n: int, tokens: int = 0) -> None:
-        del n, tokens
+    def note_prefill_pairs(self, n: int, tokens: int = 0,
+                           wide: bool = False) -> None:
+        del n, tokens, wide
+
+    def note_wide_width(self, width: int) -> None:
+        del width
 
     def note_decode_attn_pages(self, live: int, table: int) -> None:
         del live, table
@@ -222,6 +233,18 @@ class StepProfiler:
         self._prefill_tokens = self._reg.counter(
             PREFILL_TOKENS_METRIC,
             'Prompt tokens of enqueued prefill chunks (padding left out)')
+        self._prefill_wide_pieces = self._reg.counter(
+            PREFILL_WIDE_PIECES_METRIC,
+            'Enqueued prefill chunks that held one prompt in a piece wider '
+            'than the chunk')
+        self._prefill_wide_tokens = self._reg.counter(
+            PREFILL_WIDE_TOKENS_METRIC,
+            'Prompt tokens of those wide pieces (over the prefill tokens: '
+            'the share prefilled wide)')
+        self._prefill_wide_width = self._reg.gauge(
+            PREFILL_WIDE_WIDTH_METRIC,
+            'Tokens of the piece a prompt prefilled alone takes while its '
+            'tail holds one (the chunk where no wider piece pays)')
         self._attn_pages_live = self._reg.counter(
             ATTN_PAGES_LIVE_METRIC,
             "Pages of live rows' own contexts (ceil(length / page) a "
@@ -414,11 +437,20 @@ class StepProfiler:
         self._state_resets.inc(1)
         self._state_recompute.inc(recompute_tokens)
 
-    def note_prefill_pairs(self, n: int, tokens: int = 0) -> None:
+    def note_prefill_pairs(self, n: int, tokens: int = 0,
+                           wide: bool = False) -> None:
         """A prefill chunk's dispatch: its query-key pairs a layer and
-        its valid tokens. Host arithmetic."""
+        its valid tokens, counted again as a wide piece's where ``wide``.
+        Host arithmetic."""
         self._prefill_pairs.inc(n)
         self._prefill_tokens.inc(tokens)
+        if wide:
+            self._prefill_wide_pieces.inc(1)
+            self._prefill_wide_tokens.inc(tokens)
+
+    def note_wide_width(self, width: int) -> None:
+        """The width of a wide piece, once the engine has resolved it."""
+        self._prefill_wide_width.set(width)
 
     def note_decode_attn_pages(self, live: int, table: int) -> None:
         """Pages a decode dispatch's attention reads a layer (``live``:

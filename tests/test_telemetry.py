@@ -1155,6 +1155,11 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          'recurrent_layers', 'recurrent_state_bytes',
                          'state_resets_total',
                          'state_recompute_tokens_total',
+                         # pieces wider than the chunk a prompt alone
+                         # takes, their tokens and the width: zeros but
+                         # the width (the chunk) where no wider piece pays
+                         'prefill_wide_pieces_total',
+                         'prefill_wide_tokens_total', 'prefill_wide_width',
                          # the loop account: the engine thread's seconds
                          # by cause, and the turns that had work
                          'loop_turns_total'} | {
@@ -1165,6 +1170,8 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
     assert loop['loop_wait_work_seconds_total'] > 0.0    # parked
     assert loop['moe_layer_steps_total'] == 0
     assert loop['prefill_tokens_total'] > 0
+    assert loop['prefill_wide_pieces_total'] == 0
+    assert loop['prefill_wide_tokens_total'] == 0
     assert 0 < loop['pool_write_rows_live_total'] \
         < loop['pool_write_rows_offered_total']     # 1 live slot of 2
     assert loop['kv_cache_layers'] == 2             # tiny: 2 layers, 1 pass

@@ -785,3 +785,48 @@ def test_hybrid_chunk_batches_count_the_recurrent_mixers_terms(
 
     assert temp_bytes(8, 4) < 1.3e9
     assert temp_bytes(16, 4) > budget
+
+
+# ------------------------------------------- a lone prompt's wide piece
+@pytest.mark.parametrize('cell,table_p', [('longctx', 64), ('longgen', 16)])
+def test_wide_piece_holds_what_its_four_prompt_batch_holds(
+        one_chip, monkeypatch, cell, table_p):
+    """A 1024-token piece of one prompt at the longest context's page
+    bucket of each routed cell (8k tokens of GLM-4.7-Flash, 2k of Solar
+    Open 2) compiles for the chip, and its transients are those of the
+    4-prompt x 256 batch that ``_chunk_piece_bytes`` counts it as, give
+    or take the larger in-piece attention (0.80 against 0.77 GB and 0.82
+    against 0.62 GB, compiled for a described v5e): far inside the 2.2 GB
+    budget."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    if cell == 'longgen':
+        cfg, params, cache, rec = _solar_operands(one_chip, 32, 4100)
+    else:
+        cfg, rec = _glm_cfg(), None
+        params = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda key: llama.init_params(key, cfg),
+                           jax.random.PRNGKey(0)))
+        cache = _glm_cache(one_chip, cfg)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def prefill(params, cache, table, tokens, lengths, valid, want,
+                rec=None, slot_ids=None):
+        return paged.paged_prefill_chunk(
+            params, cache, table, tokens, lengths, valid, want, cfg,
+            rec=rec, slot_ids=slot_ids)
+
+    def temp_bytes(n, width):
+        state = {} if rec is None else dict(rec=rec, slot_ids=vec((n,)))
+        return jax.jit(prefill, donate_argnums=(1,),
+                       donate_argnames=('rec',)).lower(
+            params, cache, vec((n, table_p)), vec((n, width)), vec((n,)),
+            vec((n,)), vec((n,)), **state
+        ).compile().memory_analysis().temp_size_in_bytes
+
+    wide, batch = temp_bytes(1, 1024), temp_bytes(4, 256)
+    assert wide < batch + 0.3e9
+    assert wide < 0.5 * paged.PagedInferenceEngine._CHUNK_TRANSIENT_BUDGET
