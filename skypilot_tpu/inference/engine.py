@@ -286,11 +286,29 @@ def _refuse_recurrent(cfg, asked) -> None:
             f'{cfg.name} (mixer_pattern={cfg.mixer_pattern}) cannot be '
             f'combined with n_loops={cfg.n_loops}: a state a slot is '
             'kept per layer, not per (pass, layer)')
+    if cfg.recurrent_kind == 'mamba':
+        others = {
+            'quantize': 'quantize_params knows the dense GQA leaves only: '
+                        'not the Mamba mixer\'s projections, and no test '
+                        'holds quantized weights beside the float32 '
+                        'state to the reference',
+            'adapter_slots': 'the LoRA bank targets wq/wk/wv/wo and the '
+                             'dense FFN; the Mamba mixer has no such '
+                             'projection and no bank beside a state is '
+                             'held to the reference',
+            'mesh': 'the per-slot state is not yet placed over a mesh'}
+    else:
+        others = {
+            'quantize': 'quantize_params knows the dense GQA leaves only: '
+                        'not the recurrent mixer\'s projections nor the '
+                        'expert stacks',
+            'adapter_slots': 'the LoRA bank targets wq/wk/wv/wo and the '
+                             'dense FFN; the recurrent mixer and the '
+                             'routed FFN have neither',
+            'mesh': 'the per-slot state and the held experts are not yet '
+                    'placed over a mesh (no all-to-all)'}
     _refuse_first(cfg, f'mixer_pattern={cfg.mixer_pattern}', {
-        'quantize': (asked.get('quantize') is not None,
-                     'quantize_params knows the dense GQA leaves only: '
-                     'not the recurrent mixer\'s projections nor the '
-                     'expert stacks'),
+        'quantize': (asked.get('quantize') is not None, others['quantize']),
         'kv_cache_dtype': (asked.get('kv_cache_dtype', 'bf16') != 'bf16',
                            'the recurrent state is float32 and is not '
                            'quantized, and no test holds quantized rows '
@@ -300,12 +318,8 @@ def _refuse_recurrent(cfg, asked) -> None:
                         'recurrent state back, and no state before the '
                         'drafts is kept'),
         'adapter_slots': (bool(asked.get('adapter_slots')),
-                          'the LoRA bank targets wq/wk/wv/wo and the '
-                          'dense FFN; the recurrent mixer and the routed '
-                          'FFN have neither'),
-        'mesh': (asked.get('mesh') is not None,
-                 'the per-slot state and the held experts are not yet '
-                 'placed over a mesh (no all-to-all)'),
+                          others['adapter_slots']),
+        'mesh': (asked.get('mesh') is not None, others['mesh']),
         'decode_impl': (asked.get('decode_impl') == 'cross_layer',
                         'the fused-merge kernel has not been held to a '
                         'reference on a model whose cache layers are '

@@ -182,12 +182,17 @@ def _recurrent_layer(layer: Params, li, x: jax.Array, rec: RecurrentState,
     """One layer whose mixer keeps a state and no rows: row ``li`` of
     ``rec`` in, the state after these tokens written back in its place
     (``rec`` is a loop carry: in place). ``kernel`` (one token a slot):
-    the state is advanced where it lies by ``ops.kda``'s kernel, the
-    live slots' alone; else by XLA on every row it is given. Returns
-    ((x, rec), the layer's expert counters)."""
+    the state is advanced where it lies by the kind's state kernel
+    (``ops.kda`` / ``ops.mamba``), the live slots' alone; else by XLA on
+    every row it is given. Returns ((x, rec), the layer's expert
+    counters, or zeros for a dense FFN)."""
     tail = lax.dynamic_index_in_dim(rec.conv, li, 0, keepdims=False)
     if kernel:
-        from skypilot_tpu.ops.kda import recurrent_step_in_place
+        if 'mamba' in layer:
+            from skypilot_tpu.ops.mamba import (
+                step_in_place as recurrent_step_in_place)
+        else:
+            from skypilot_tpu.ops.kda import recurrent_step_in_place
         slots, advanced = x.shape[0], {}
         active = (jnp.ones((slots,), bool) if live is None
                   else jnp.broadcast_to(live, (slots, 1))[:, 0])
@@ -221,12 +226,20 @@ def _carrying_state(kv_layer_body, cfg: ModelConfig, positions: jax.Array,
     def body(carry, layer_and_idx):
         x, rec = carry
         layer, li = layer_and_idx
-        if 'kda' in layer:
+        if 'kda' in layer or 'mamba' in layer:
             return _recurrent_layer(layer, li, x, rec, cfg, positions, live,
                                     kernel=kernel)
         x, ys = kv_layer_body(x, layer_and_idx)
         return (x, rec), ys
     return body
+
+
+def _state_kernel_tiles(spec) -> bool:
+    """Whether a state's blocks tile the lanes for its kernel: a KDA
+    head's key and value widths, a Mamba layer's channels."""
+    if spec.kind == 'mamba':
+        return spec.v_dim % _LANES == 0
+    return spec.k_dim % _LANES == 0 and spec.v_dim % _LANES == 0
 
 
 def _rows_need_live(cfg: ModelConfig) -> bool:
@@ -762,16 +775,14 @@ def paged_decode_horizon(
                 layer_body, x, params, cfg)
         else:
             # 'pallas': the state kernel, where its blocks tile
-            # (ops/kda.py); else XLA.
-            state = cfg.state_spec
+            # (ops/kda.py, ops/mamba.py); else XLA.
             (x, rec), ys, _ = llama.run_loops(_carrying_state(
                 layer_body, cfg, positions, live,
                 kernel=(decode_impl == 'pallas'
-                        and state.k_dim % _LANES == 0
-                        and state.v_dim % _LANES == 0)),
+                        and _state_kernel_tiles(cfg.state_spec))),
                 (x, rec), params, cfg)
             (k_rows, v_rows), aux = ys['gqa']
-            aux = jnp.concatenate([aux, ys['kda']])
+            aux = jnp.concatenate([aux, ys[cfg.recurrent_kind]])
         ring_k = lax.dynamic_update_slice(
             ring_k, k_rows.astype(ring_k.dtype), (0, 0, i, 0, 0))
         ring_v = lax.dynamic_update_slice(
@@ -1807,6 +1818,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # Allocatable tokens (page 0 is the reserved trash page).
             'pool_token_capacity': (self.alloc.n_pages - 1) * self.page,
             # What the layers that cache no rows keep, beside the pool.
+            'recurrent_kind': self.cfg.recurrent_kind,
             'recurrent_layers': self.cfg.n_recurrent_layers,
             'recurrent_state_slot_bytes': self._state_slot_bytes,
             'recurrent_state_bytes': (self._state_slot_bytes
@@ -1929,6 +1941,11 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
     # decayed forms and the WY terms): the compile for a described v5e
     # has 131 MB a 256-token piece of 64 heads x 128 (compiler, PR 37).
     _RECURRENT_TOKEN_ARRAYS = 16
+    # ... of [d_inner] a token of a Mamba mixer's chunk (conv output,
+    # step size, write, scan output, gate): jamba2-3b's chunk programs
+    # grow by 72 MB a 256-token piece at 16 pages, 47 MB of it attention
+    # scores (compiled for a described v5e).
+    _MAMBA_TOKEN_ARRAYS = 5
 
     def _chunk_piece_bytes(self, pages: int,
                            width: Optional[int] = None) -> int:
@@ -1936,7 +1953,7 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
         to a chunk program's transients at the page bucket ``pages``: its
         attention scores [heads, width, pages x page]; routed experts'
         sorted rows (top-k copies of a token through gate, up and down);
-        a recurrent mixer's float32 terms a token."""
+        a recurrent mixer's float32 terms a token (of its kind)."""
         cfg = self.cfg
         width = width or self.chunk
         piece = pages * (cfg.n_heads * width * self.page
@@ -1946,8 +1963,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 6 * cfg.dim + 8 * cfg.moe_ffn_dim)
         if cfg.recurrent:
             spec = cfg.state_spec
-            piece += (width * spec.heads * spec.k_dim * 4
-                      * self._RECURRENT_TOKEN_ARRAYS)
+            piece += width * spec.token_width * 4 * (
+                self._MAMBA_TOKEN_ARRAYS if spec.kind == 'mamba'
+                else self._RECURRENT_TOKEN_ARRAYS)
         return int(piece)
 
     def _chunk_batch_cap(self, batch: List[int]) -> int:
@@ -2370,7 +2388,9 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
                 self.rec, = rec
         chunk_t1 = clock.monotonic()
         self.chunks_prefilled += 1
-        self._prof.note_prefill_pairs(pairs, tokens=chunk_tokens, wide=wide)
+        self._prof.note_prefill_pairs(
+            pairs, tokens=chunk_tokens, wide=wide,
+            scan=self.cfg.recurrent_kind == 'mamba')
         self._prof.note_pool_write(chunk_tokens, n * chunk_w)
         for i, slot in enumerate(batch):
             r = self._slots[slot]

@@ -31,16 +31,27 @@ class StateSpec(NamedTuple):
     """The state one sequence keeps in one recurrent layer, whatever its
     length: ``heads`` matrices of ``k_dim`` x ``v_dim`` float32, and the
     last ``conv_rows`` inputs of the short convolution, ``conv_dim``
-    channels each, in the model's dtype."""
+    channels each, in the model's dtype. ``kind`` is the layer kind
+    that keeps it: 'kda' (a key x value matrix a head) or 'mamba' (one
+    ``d_state`` x ``d_inner`` matrix: the selective scan's state with
+    its channels last, on the lanes)."""
     heads: int
     k_dim: int
     v_dim: int
     conv_rows: int
     conv_dim: int
+    kind: str = 'kda'
 
     def slot_bytes(self, dtype_bytes: int) -> int:
         return (self.heads * self.k_dim * self.v_dim * 4
                 + self.conv_rows * self.conv_dim * dtype_bytes)
+
+    @property
+    def token_width(self) -> int:
+        """float32 values of one token in one of the mixer's chunked
+        terms: a KDA layer's heads x key width, a Mamba layer's inner
+        channels."""
+        return self.v_dim if self.kind == 'mamba' else self.heads * self.k_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,7 +147,18 @@ class ModelConfig:
     # decay and output gates. ``use_rope`` False leaves the GQA layers
     # without rotary; ``attn_gate`` multiplies their attention output by
     # sigmoid(W_gate h) elementwise.
+    # 'mamba' (Mamba-1's selective state space, ``models/mamba.py``)
+    # keeps a [mamba_d_state, expand x dim] float32 state and the last
+    # ``mamba_d_conv - 1`` inputs of its causal conv; its step size runs
+    # through a ``mamba_dt_rank`` bottleneck. A model of 'mamba' layers
+    # has a dense FFN in every layer (``ffn_kind`` 'dense').
     mixer_pattern: tuple = ()
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
@@ -155,12 +177,19 @@ class ModelConfig:
         # a list out of a JSON file: hashable, as a jit static must be
         object.__setattr__(self, 'mixer_pattern', tuple(self.mixer_pattern))
         if self.mixer_pattern:
-            bad = set(self.mixer_pattern) - {'gqa', 'kda'}
-            if bad or self.n_layers % len(self.mixer_pattern):
+            kinds = set(self.mixer_pattern)
+            if (kinds - set(MIXER_KINDS)
+                    or len(kinds & set(RECURRENT_KINDS)) > 1
+                    or self.n_layers % len(self.mixer_pattern)):
                 raise ValueError(
                     f'{self.name}: mixer_pattern {self.mixer_pattern} must '
-                    "hold 'gqa' / 'kda' and divide n_layers "
-                    f'{self.n_layers}')
+                    "hold 'gqa' and at most one of 'kda' / 'mamba', and "
+                    f'divide n_layers {self.n_layers}')
+            if 'mamba' in kinds and (self.ffn_kind != 'dense'
+                                     or self.mamba_proj_bias):
+                raise ValueError(
+                    f"{self.name}: 'mamba' layers are built with a dense "
+                    'FFN and without projection biases')
         held, first = self.held_experts, self.first_held_expert
         if not 0 <= first <= first + held <= max(self.n_routed_experts,
                                                  held):
@@ -177,9 +206,22 @@ class ModelConfig:
                                      // len(self.mixer_pattern))
 
     @property
+    def recurrent_kind(self) -> Optional[str]:
+        """The one layer kind of the pattern that keeps a state (None:
+        every layer caches rows)."""
+        return next((k for k in RECURRENT_KINDS
+                     if k in self.mixer_pattern), None)
+
+    @property
     def n_recurrent_layers(self) -> int:
         """Layers that keep a fixed state a sequence and no cache rows."""
-        return self.layer_kinds.count('kda')
+        kinds = self.layer_kinds
+        return sum(kinds.count(k) for k in RECURRENT_KINDS)
+
+    @property
+    def mamba_inner(self) -> int:
+        """A Mamba layer's channels: ``mamba_expand`` x ``dim``."""
+        return self.mamba_expand * self.dim
 
     @property
     def recurrent(self) -> bool:
@@ -196,6 +238,10 @@ class ModelConfig:
         """What one sequence's state in one recurrent layer is made of."""
         if not self.recurrent:
             return None
+        if self.recurrent_kind == 'mamba':
+            return StateSpec(1, self.mamba_d_state, self.mamba_inner,
+                             self.mamba_d_conv - 1, self.mamba_inner,
+                             kind='mamba')
         return StateSpec(self.kda_heads, self.kda_head_dim,
                          self.kda_head_dim, self.kda_conv - 1,
                          3 * self.kda_heads * self.kda_head_dim)
@@ -261,8 +307,7 @@ class ModelConfig:
             from skypilot_tpu.models import latent_moe
             return latent_moe.num_params(self)
         if self.mixer_pattern:
-            from skypilot_tpu.models import kda
-            return kda.num_params(self)
+            return mixer_module(self).num_params(self)
         return sum(self._dense_param_split())
 
     def flops_per_token(self, training: bool = False) -> float:
@@ -274,8 +319,7 @@ class ModelConfig:
             from skypilot_tpu.models import latent_moe
             n = latent_moe.num_params(self, active_only=True)
         elif self.mixer_pattern:
-            from skypilot_tpu.models import kda
-            n = kda.num_params(self, active_only=True)
+            n = mixer_module(self).num_params(self, active_only=True)
         elif self.n_loops > 1:
             n += (self.n_loops - 1) * self._dense_param_split()[0]
         if self.is_moe:
@@ -284,6 +328,23 @@ class ModelConfig:
             dense_ffn = 3 * d * f * self.n_layers
             n = n - dense_ffn * self.n_experts + dense_ffn * self.n_experts_per_token
         return (6.0 if training else 2.0) * n
+
+
+# Every kind a ``mixer_pattern`` may name, and those that keep a state
+# a sequence in place of cache rows.
+MIXER_KINDS = ('gqa', 'kda', 'mamba')
+RECURRENT_KINDS = ('kda', 'mamba')
+
+
+def mixer_module(cfg: ModelConfig):
+    """The module that builds a model of a ``mixer_pattern``'s tree and
+    counts it: ``models/mamba.py`` for one of 'mamba' layers (dense
+    FFNs), else ``models/kda.py``."""
+    if 'mamba' in cfg.mixer_pattern:
+        from skypilot_tpu.models import mamba
+        return mamba
+    from skypilot_tpu.models import kda
+    return kda
 
 
 # --- Presets ---------------------------------------------------------------
@@ -405,11 +466,33 @@ TINY_SOLAR = _cfg(
     moe_ffn_dim=48, routed_scaling_factor=1.0, n_held_experts=4,
     first_held_expert=4)
 
+# ai21labs/AI21-Jamba2-3B (``jamba``) as published, whole: 28 layers,
+# attention where ``i % 14 == 7`` (layers 7 and 21: NoPE GQA, 20 query
+# heads on one KV head), Mamba-1 elsewhere, a dense SwiGLU FFN in every
+# layer (num_experts 1), tied embeddings
+# (perfbench/configs/jamba2-3b.json has the arithmetic).
+JAMBA2_3B = _cfg(
+    name='jamba2-3b', vocab_size=65536, dim=2560, n_layers=28, n_heads=20,
+    n_kv_heads=1, ffn_dim=8192, max_seq_len=262144, norm_eps=1e-6,
+    rope_theta=10000.0, tie_embeddings=True, use_rope=False,
+    mixer_pattern=('mamba',) * 7 + ('gqa',) + ('mamba',) * 6,
+    mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
+    mamba_conv_bias=True, mamba_proj_bias=False)
+
+# Two periods of two Mamba runs around one GQA layer; heads != KV heads,
+# d_state != dt_rank != taps, inner = 2 x dim.
+TINY_JAMBA = _cfg(
+    name='tiny-jamba', vocab_size=256, dim=64, n_layers=8, n_heads=4,
+    n_kv_heads=1, ffn_dim=160, max_seq_len=256, remat='none',
+    norm_eps=1e-6, tie_embeddings=True, use_rope=False,
+    mixer_pattern=('mamba', 'mamba', 'gqa', 'mamba'), mamba_d_state=8,
+    mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=12)
+
 PRESETS = {c.name: c for c in [
     LLAMA3_8B, LLAMA3_70B, LLAMA2_7B, LLAMA3_1B, MIXTRAL_8X7B,
     GEMMA_2B, GEMMA_7B, QWEN2_7B, TINY, TINY_MOE, TINY_GEMMA,
     TINY_QWEN, GLM_4_7_FLASH, TINY_GLM, OURO_2_6B, TINY_OURO,
-    SOLAR_OPEN2_250B, TINY_SOLAR]}
+    SOLAR_OPEN2_250B, TINY_SOLAR, JAMBA2_3B, TINY_JAMBA]}
 
 
 def get_config(name: str) -> ModelConfig:

@@ -45,6 +45,9 @@ Params = Dict[str, Any]
 
 # name of the stack that holds the layers of each kind
 STACKS = {'gqa': 'layers', 'kda': 'kda_layers'}
+# ... of every kind a pattern may name (``models/mamba.py`` holds the
+# 'mamba' stack)
+ALL_STACKS = dict(STACKS, mamba='mamba_layers')
 QK_NORM_EPS = 1e-6
 # The decay's draws (fla's KimiDeltaAttention): A uniform in [1, 16),
 # softplus(dt_bias) log-uniform in [0.001, 0.1).
@@ -259,43 +262,69 @@ def scan_periods(body, carry, params: Params, cfg: ModelConfig):
     li))`` gets ``li`` = the layer's index AMONG ITS KIND (its row of
     that kind's stacked cache or state). The stacks are scan inputs at
     both levels, [periods, layers a period, ...]. A stack's ``experts``
-    are not scanned (``llama.scan_layers``):
+    (where it has them) are not scanned (``llama.scan_layers``):
     the layer gets the whole stack and ``expert_layer``, its row of it.
+    A stack WITHOUT experts (dense FFNs) is not scanned at all: each
+    layer reads its leaves from the whole stack at its index. A
+    period's slice of a scanned stack is materialised as a copy where
+    there is more than one period (a Mamba stack's in_proj, 13 layers
+    x 52 MB, copied a period a decode step; compiler). A kind may make
+    several runs of a period (a traced body each).
     Returns (carry, {kind: that kind's per-layer outputs, layer-major})."""
     pattern = cfg.mixer_pattern
     n_periods = cfg.n_layers // len(pattern)
-    per = {kind: pattern.count(kind) for kind in STACKS}
+    stacks_of = {kind: name for kind, name in ALL_STACKS.items()
+                 if kind in pattern}
+    per = {kind: pattern.count(kind) for kind in stacks_of}
     runs = []                   # (kind, first among the period's, length)
     for i, kind in enumerate(pattern):
         if runs and runs[-1][0] == kind:
             runs[-1][2] += 1
         else:
             runs.append([kind, pattern[:i].count(kind), 1])
-    held = {kind: params[name]['experts'] for kind, name in STACKS.items()}
+    held = {kind: params[name].get('experts')
+            for kind, name in stacks_of.items()}
     scanned = {kind: jax.tree.map(
         lambda a, n=per[kind]: a.reshape((n_periods, n) + a.shape[1:]),
         {k: v for k, v in params[name].items() if k != 'experts'})
-        for kind, name in STACKS.items()}
+        for kind, name in stacks_of.items() if held[kind] is not None}
+    indexed = {kind: params[name] for kind, name in stacks_of.items()
+               if held[kind] is None}
 
     def period(carry, xs):
         stacks, p = xs
-        ys = {kind: [] for kind in STACKS}
+        ys = {kind: [] for kind in stacks_of}
         for kind, first, length in runs:
             base = p * per[kind] + first
 
-            def one(carry, layer_j, kind=kind, base=base):
-                layer, j = layer_j
-                li = base + j
-                return body(carry, (dict(layer, expert_layer=li,
-                                         experts=held[kind]), li))
+            if kind in indexed:
+                def one(carry, j, kind=kind, base=base):
+                    li = base + j
+                    return body(carry, (jax.tree.map(
+                        lambda a: lax.dynamic_index_in_dim(
+                            a, li, 0, keepdims=False), indexed[kind]), li))
 
-            run = jax.tree.map(lambda a: a[first:first + length],
-                               stacks[kind])
-            if length == 1:
-                carry, y = one(carry, (jax.tree.map(lambda a: a[0], run), 0))
-                y = jax.tree.map(lambda a: a[None], y)
+                if length == 1:
+                    carry, y = one(carry, 0)
+                else:
+                    carry, y = lax.scan(one, carry, jnp.arange(length))
             else:
-                carry, y = lax.scan(one, carry, (run, jnp.arange(length)))
+                def one(carry, layer_j, kind=kind, base=base):
+                    layer, j = layer_j
+                    li = base + j
+                    return body(carry, (dict(layer, expert_layer=li,
+                                             experts=held[kind]), li))
+
+                run = jax.tree.map(lambda a: a[first:first + length],
+                                   stacks[kind])
+                if length == 1:
+                    carry, y = one(carry, (jax.tree.map(lambda a: a[0], run),
+                                           0))
+                else:
+                    carry, y = lax.scan(one, carry,
+                                        (run, jnp.arange(length)))
+            if length == 1:
+                y = jax.tree.map(lambda a: a[None], y)
             ys[kind].append(y)
         return carry, {kind: jax.tree.map(
             lambda *a: jnp.concatenate(a), *v) for kind, v in ys.items()}
@@ -323,17 +352,29 @@ def gates(p: Params, h: jax.Array, cfg: ModelConfig):
     return g, beta, jax.nn.sigmoid(out + p['g_bias'])
 
 
-def short_conv(w: jax.Array, tail: jax.Array, x: jax.Array):
+def short_conv(w: jax.Array, tail: jax.Array, x: jax.Array,
+               bias: Optional[jax.Array] = None):
     """Causal depthwise convolution over time and SiLU. w [taps, c]
     (the last row multiplies the current token); tail [b, taps-1, c] the
-    inputs before x; x [b,s,c]. Returns (SiLU(conv) [b,s,c] float32, the
-    inputs [b, taps-1+s, c] the next tail is cut from)."""
+    inputs before x; x [b,s,c]; ``bias`` [c] added before the SiLU (None:
+    none). Returns (SiLU(conv) [b,s,c] float32, the inputs [b,
+    taps-1+s, c] the next tail is cut from)."""
     taps, s = w.shape[0], x.shape[1]
     seen = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     wf = w.astype(jnp.float32)
     y = sum(seen[:, j:j + s].astype(jnp.float32) * wf[j]
             for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y), seen
+
+
+def next_tail(seen: jax.Array, n_live: jax.Array, taps: int, dtype):
+    """The conv tail after a run: the last taps-1 inputs up to the last
+    live row (the old tail itself where no row is live). ``seen`` [b,
+    taps-1+s, c] is ``short_conv``'s second output."""
+    rows = n_live[:, None] + jnp.arange(taps - 1)[None, :]
+    return jnp.take_along_axis(seen, rows[..., None], axis=1).astype(dtype)
 
 
 def l2_normalise(x: jax.Array) -> jax.Array:
@@ -379,11 +420,7 @@ def mixer(p: Params, h: jax.Array, cfg: ModelConfig, rec=None,
         g = jnp.where(live[..., None, None], g, 0.0)
         beta = jnp.where(live[..., None], beta, 0.0)
         n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
-    # The tail after the run: the last taps-1 inputs up to the last live
-    # row (the old tail itself where no row is live).
-    rows = n_live[:, None] + jnp.arange(cfg.kda_conv - 1)[None, :]
-    tail = jnp.take_along_axis(seen, rows[..., None], axis=1).astype(
-        tail.dtype)
+    tail = next_tail(seen, n_live, cfg.kda_conv, tail.dtype)
     if s == 1:
         o, S = step_fn(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
         o = o[:, None]

@@ -46,8 +46,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         from skypilot_tpu.models import latent_moe
         return latent_moe.init_params(rng, cfg)
     if cfg.mixer_pattern:
-        from skypilot_tpu.models import kda
-        return kda.init_params(rng, cfg)
+        from skypilot_tpu.models.configs import mixer_module
+        return mixer_module(cfg).init_params(rng, cfg)
     d, hd = cfg.dim, cfg.head_dim
     n_h, n_kv, f, L = cfg.n_heads, cfg.n_kv_heads, cfg.ffn_dim, cfg.n_layers
     keys = jax.random.split(rng, 8)
@@ -119,8 +119,8 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         from skypilot_tpu.models import latent_moe
         return latent_moe.param_logical_axes(cfg)
     if cfg.mixer_pattern:
-        from skypilot_tpu.models import kda
-        return kda.param_logical_axes(cfg)
+        from skypilot_tpu.models.configs import mixer_module
+        return mixer_module(cfg).param_logical_axes(cfg)
     axes: Params = {
         'embed': ('vocab_in', 'embed'),
         'final_norm': ('norm',),
@@ -614,6 +614,19 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
             proj, new_rec = kda.mixer(layer['kda'], h, cfg, rec=rec,
                                       live=live, step_fn=attn_fn)
         return _routed_ffn_branch(layer, x + proj, cfg, live, new_rec)
+    if 'mamba' in layer:
+        # A Mamba mixer and a dense FFN; ``attn_fn`` is a one-token scan
+        # only where a state is handed in (the paged programs).
+        from skypilot_tpu.models import mamba
+        with jax.named_scope('mamba_mix'):
+            proj, new_rec = mamba.mixer(
+                layer['mamba'], h, cfg, rec=rec, live=live,
+                step_fn=attn_fn if rec is not None else None)
+        x = x + proj
+        h = rms_norm(x, layer['ffn_norm'], cfg.norm_eps)
+        with jax.named_scope('dense_ffn'):
+            x = x + _ffn(layer, h, cfg)
+        return x, new_rec, jnp.zeros((), jnp.float32)
     from skypilot_tpu.models.quantization import qeinsum
     lo = layer.get('lora') if isinstance(layer, dict) else None
     ml = layer.get('mlora') if isinstance(layer, dict) else None

@@ -39,6 +39,7 @@ _ARCH_FAMILY = {
     'Qwen2ForCausalLM': 'qwen2',
     'OuroForCausalLM': 'ouro',
     'SolarOpen2ForCausalLM': 'solar_open2',
+    'JambaForCausalLM': 'jamba',
 }
 
 
@@ -88,7 +89,32 @@ def config_from_hf(hf: Dict[str, Any],
                       hf.get('early_exit_threshold', 1.0)))
     if family == 'solar_open2':
         kw.update(_solar_open2_fields(hf))
+    if family == 'jamba':
+        kw.update(_jamba_fields(hf))
     return ModelConfig(**kw)
+
+
+def _jamba_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """``model_type: jamba``: attention at the layers ``i`` with ``i %
+    attn_layer_period == attn_layer_offset`` (``JambaConfig``'s rule),
+    Mamba-1 at the others; no positional encoding. A dense FFN in every
+    layer is the one built (``num_experts`` 1)."""
+    period, offset = hf['attn_layer_period'], hf['attn_layer_offset']
+    if hf['num_hidden_layers'] % period or not 0 <= offset < period:
+        raise ValueError(f'attention every {period} layers from {offset} '
+                         f'does not repeat over '
+                         f'{hf["num_hidden_layers"]} layers')
+    if hf.get('num_experts', 1) != 1 or hf.get('mamba_proj_bias'):
+        raise ValueError('jamba with routed experts or biased Mamba '
+                         'projections is not built')
+    return dict(
+        mixer_pattern=tuple('gqa' if i == offset else 'mamba'
+                            for i in range(period)),
+        use_rope=False, mamba_d_state=hf['mamba_d_state'],
+        mamba_d_conv=hf['mamba_d_conv'], mamba_expand=hf['mamba_expand'],
+        mamba_dt_rank=hf['mamba_dt_rank'],
+        mamba_conv_bias=bool(hf.get('mamba_conv_bias', True)),
+        mamba_proj_bias=False)
 
 
 def _solar_open2_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
@@ -677,8 +703,10 @@ def hf_config_dict(cfg: ModelConfig,
     arch = {'llama': 'LlamaForCausalLM', 'gemma': 'GemmaForCausalLM',
             'mixtral': 'MixtralForCausalLM',
             'qwen2': 'Qwen2ForCausalLM', 'ouro': 'OuroForCausalLM',
-            'solar_open2': 'SolarOpen2ForCausalLM'}
-    family = ('solar_open2' if cfg.mixer_pattern else
+            'solar_open2': 'SolarOpen2ForCausalLM',
+            'jamba': 'JambaForCausalLM'}
+    family = ('jamba' if 'mamba' in cfg.mixer_pattern else
+              'solar_open2' if cfg.mixer_pattern else
               'ouro' if cfg.n_loops > 1 else
               'mixtral' if cfg.is_moe else
               'gemma' if cfg.norm_plus_one else
@@ -727,6 +755,18 @@ def hf_config_dict(cfg: ModelConfig,
             routed_scaling_factor=cfg.routed_scaling_factor,
             n_held_experts=cfg.n_held_experts,
             first_held_expert=cfg.first_held_expert)
+    if family == 'jamba':
+        if cfg.mixer_pattern.count('gqa') != 1:
+            raise ValueError(f'{cfg.name}: jamba names one attention '
+                             'layer a period')
+        hf_cfg.update(
+            attn_layer_period=len(cfg.mixer_pattern),
+            attn_layer_offset=cfg.mixer_pattern.index('gqa'),
+            num_experts=1, num_experts_per_tok=1,
+            mamba_d_state=cfg.mamba_d_state, mamba_d_conv=cfg.mamba_d_conv,
+            mamba_expand=cfg.mamba_expand, mamba_dt_rank=cfg.mamba_dt_rank,
+            mamba_conv_bias=cfg.mamba_conv_bias,
+            mamba_proj_bias=cfg.mamba_proj_bias)
     return hf_cfg
 
 

@@ -1670,6 +1670,8 @@ class ModelServer:
                     profiler_lib.PREFILL_WIDE_TOKENS_METRIC),
                 'prefill_wide_width': self._counter_value(
                     profiler_lib.PREFILL_WIDE_WIDTH_METRIC),
+                'prefill_scan_tokens_total': self._counter_value(
+                    profiler_lib.PREFILL_SCAN_TOKENS_METRIC),
                 'kv_cache_layers': self._counter_value(
                     profiler_lib.KV_CACHE_LAYERS_METRIC),
                 'kv_token_bytes': self._counter_value(

@@ -85,6 +85,10 @@ PREFILL_TOKENS_METRIC = 'skytpu_prefill_tokens_total'
 PREFILL_WIDE_PIECES_METRIC = 'skytpu_prefill_wide_pieces_total'
 PREFILL_WIDE_TOKENS_METRIC = 'skytpu_prefill_wide_tokens_total'
 PREFILL_WIDE_WIDTH_METRIC = 'skytpu_prefill_wide_width'
+# Prompt tokens of enqueued prefill chunks that ran through a Mamba
+# layer's chunked selective scan (``ops/mamba.py``; zero for any other
+# model).
+PREFILL_SCAN_TOKENS_METRIC = 'skytpu_prefill_scan_tokens_total'
 # What one cached token is (gauges the engine sets once it is built): its
 # cache layers (layers x passes of a looped model) and its stored bytes
 # over all of them.
@@ -148,8 +152,8 @@ class NullProfiler:
         del recompute_tokens
 
     def note_prefill_pairs(self, n: int, tokens: int = 0,
-                           wide: bool = False) -> None:
-        del n, tokens, wide
+                           wide: bool = False, scan: bool = False) -> None:
+        del n, tokens, wide, scan
 
     def note_wide_width(self, width: int) -> None:
         del width
@@ -241,6 +245,10 @@ class StepProfiler:
             PREFILL_WIDE_TOKENS_METRIC,
             'Prompt tokens of those wide pieces (over the prefill tokens: '
             'the share prefilled wide)')
+        self._prefill_scan_tokens = self._reg.counter(
+            PREFILL_SCAN_TOKENS_METRIC,
+            "Prompt tokens of enqueued prefill chunks run through the Mamba "
+            "layers' chunked selective scan")
         self._prefill_wide_width = self._reg.gauge(
             PREFILL_WIDE_WIDTH_METRIC,
             'Tokens of the piece a prompt prefilled alone takes while its '
@@ -438,12 +446,14 @@ class StepProfiler:
         self._state_recompute.inc(recompute_tokens)
 
     def note_prefill_pairs(self, n: int, tokens: int = 0,
-                           wide: bool = False) -> None:
+                           wide: bool = False, scan: bool = False) -> None:
         """A prefill chunk's dispatch: its query-key pairs a layer and
-        its valid tokens, counted again as a wide piece's where ``wide``.
-        Host arithmetic."""
+        its valid tokens, counted again as a wide piece's where ``wide``
+        and as the selective scan's where ``scan``. Host arithmetic."""
         self._prefill_pairs.inc(n)
         self._prefill_tokens.inc(tokens)
+        if scan:
+            self._prefill_scan_tokens.inc(tokens)
         if wide:
             self._prefill_wide_pieces.inc(1)
             self._prefill_wide_tokens.inc(tokens)

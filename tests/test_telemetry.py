@@ -1160,6 +1160,9 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
                          # the width (the chunk) where no wider piece pays
                          'prefill_wide_pieces_total',
                          'prefill_wide_tokens_total', 'prefill_wide_width',
+                         # prompt tokens through the Mamba layers'
+                         # selective scan: zero for any other model
+                         'prefill_scan_tokens_total',
                          # the loop account: the engine thread's seconds
                          # by cause, and the turns that had work
                          'loop_turns_total'} | {
@@ -1172,6 +1175,7 @@ def test_ttft_stages_present_and_zero_before_traffic(stage_server):
     assert loop['prefill_tokens_total'] > 0
     assert loop['prefill_wide_pieces_total'] == 0
     assert loop['prefill_wide_tokens_total'] == 0
+    assert loop['prefill_scan_tokens_total'] == 0
     assert 0 < loop['pool_write_rows_live_total'] \
         < loop['pool_write_rows_offered_total']     # 1 live slot of 2
     assert loop['kv_cache_layers'] == 2             # tiny: 2 layers, 1 pass

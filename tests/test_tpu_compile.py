@@ -830,3 +830,151 @@ def test_wide_piece_holds_what_its_four_prompt_batch_holds(
     wide, batch = temp_bytes(1, 1024), temp_bytes(4, 256)
     assert wide < batch + 0.3e9
     assert wide < 0.5 * paged.PagedInferenceEngine._CHUNK_TRANSIENT_BUDGET
+
+
+# ----------------------------------- Mamba layers beside GQA layers
+def _jamba_operands(one_chip, slots, n_pages=4000):
+    cfg = configs.JAMBA2_3B
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda key: llama.init_params(key, cfg), jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(lambda: paged.PagedKVCache.create(
+        cfg, n_pages=n_pages, page_size=PAGE)))
+    rec = shapes(jax.eval_shape(
+        lambda: paged.RecurrentState.create(cfg, slots)))
+    assert cache.pool_k.shape == (2, n_pages, 1, PAGE, 128)
+    assert rec.state.shape == (26, slots, 1, 16, 5120)
+    assert rec.conv.shape == (26, slots, 3, 5120)
+    return cfg, params, cache, rec
+
+
+def _stack_slice_copies(text, cfg):
+    """Arrays of several layers of a stacked matrix that are not a whole
+    stack (26 Mamba layers, 2 GQA layers): the period scan reads a
+    dense-FFN stack where it lies. (A period's slice of a scanned stack was a 13-layer copy
+    of each leaf, [2, 13, 2560, 10240] and its kin, 2.1 GB of temp a
+    decode program.)"""
+    import re
+    d, f, i = cfg.dim, cfg.ffn_dim, cfg.mamba_inner
+    mats = '|'.join(f'{a},{b}' for a, b in ((d, 2 * i), (d, f), (f, d),
+                                            (i, d)))
+    return sorted({m.group(0) for m in re.finditer(
+        rf'bf16\[((?:\d+,)*)({mats})\]', text)
+        if m.group(1) not in ('', '1,', '2,', '26,')})   # the stacks
+
+
+def test_mamba_decode_program_advances_the_state_in_place(one_chip,
+                                                          monkeypatch):
+    """The whole ``paged_decode_horizon`` of the ``jamba2-3b.docreason``
+    cell at 64 slots: the donated state and conv tail (9,318,400 B a
+    slot, 596 MB) are aliased to the output; three kernels (the paged
+    GQA kernel over 20 query heads on one KV head, and the Mamba state
+    kernel in each of the period's two Mamba runs); the loops are the
+    horizon, the period, the runs of Mamba layers; no copy of a
+    stacked weight; 0.14 GB of temp."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    slots = 64
+    cfg, params, cache, rec = _jamba_operands(one_chip, slots)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, cache, table, tokens, lengths, active, rec):
+        return paged.paged_decode_horizon(
+            params, cache, table, tokens, lengths, cfg, horizon=8,
+            active=active, decode_impl='pallas', rec=rec)
+
+    compiled = jax.jit(decode, donate_argnames=('rec',)).lower(
+        params, cache, vec((slots, 16)), vec((slots,)), vec((slots,)),
+        vec((slots,), jnp.bool_), rec=rec).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == slots * 9_318_400
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert text.count('tpu_custom_call') == 3
+    assert 'mamba_recurrent_step' in text
+    assert _stack_slice_copies(text, cfg) == []
+
+
+def test_mamba_prefill_chunk_runs_the_scan_kernel(one_chip, monkeypatch):
+    """``paged_prefill_chunk`` of the same cell, 8 prompts x 256 tokens
+    over a 16-page bucket: the selective scan is the Pallas kernel (one
+    a traced Mamba run), pools and state are aliased to their outputs,
+    and the transients stay under 0.8 GB (0.57 GB)."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    slots, n, table_p, chunk = 64, 8, 16, 256
+    cfg, params, cache, rec = _jamba_operands(one_chip, slots)
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def prefill(params, cache, table, tokens, lengths, valid, want,
+                rec=None, slot_ids=None):
+        return paged.paged_prefill_chunk(
+            params, cache, table, tokens, lengths, valid, want, cfg,
+            rec=rec, slot_ids=slot_ids)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,),
+                       donate_argnames=('rec',)).lower(
+        params, cache, vec((n, table_p)), vec((n, chunk)), vec((n,)),
+        vec((n,)), vec((n,)), rec=rec, slot_ids=vec((n,))).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    held = 2 * cache.pool_k.size * 2 + slots * 9_318_400
+    assert mem.alias_size_in_bytes == held
+    assert mem.temp_size_in_bytes < 0.8e9
+    assert text.count('tpu_custom_call') == 2
+    assert 'mamba_scan' in text
+    assert _stack_slice_copies(text, cfg) == []
+
+
+def test_mamba_chunk_batches_count_the_mixers_terms(one_chip, monkeypatch):
+    """``_chunk_batch_cap`` at the ``docreason`` cell's sizes counts a
+    Mamba mixer's float32 terms, 5 x 5,120 values a token: 32 prompts a
+    chunk up to 8 pages of context, 16 up to 32, 8 at 64 (a 8k
+    context). The programs at those edges hold what the count says
+    (1.65, 1.85, 1.60 GB against 1.59, 1.93, 1.72 counted), and 32
+    prompts at 16 pages, which the count refuses, would hold 2.31 GB,
+    past the 2.2 GB budget."""
+    import types
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    engine_cls = paged.PagedInferenceEngine
+    slots, chunk = 64, 256
+    cfg, params, cache, rec = _jamba_operands(one_chip, slots)
+    eng = types.SimpleNamespace(
+        cfg=cfg, chunk=chunk, page=PAGE,
+        _ATTN_SCORE_BYTES=engine_cls._ATTN_SCORE_BYTES,
+        _RECURRENT_TOKEN_ARRAYS=engine_cls._RECURRENT_TOKEN_ARRAYS,
+        _MAMBA_TOKEN_ARRAYS=engine_cls._MAMBA_TOKEN_ARRAYS)
+    budget = engine_cls._CHUNK_TRANSIENT_BUDGET
+
+    def prompts_max(pages):
+        piece = engine_cls._chunk_piece_bytes(eng, pages)
+        return max(n for n in engine_cls._PREFILL_N_BUCKETS
+                   if n * piece <= budget)
+
+    assert {p: prompts_max(p) for p in (1, 8, 16, 32, 64)} == {
+        1: 32, 8: 32, 16: 16, 32: 16, 64: 8}
+
+    def vec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def prefill(params, cache, table, tokens, lengths, valid, want,
+                rec=None, slot_ids=None):
+        return paged.paged_prefill_chunk(
+            params, cache, table, tokens, lengths, valid, want, cfg,
+            rec=rec, slot_ids=slot_ids)
+
+    def temp_bytes(n, table_p):
+        return jax.jit(prefill, donate_argnums=(1,),
+                       donate_argnames=('rec',)).lower(
+            params, cache, vec((n, table_p)), vec((n, chunk)), vec((n,)),
+            vec((n,)), vec((n,)), rec=rec, slot_ids=vec((n,))
+        ).compile().memory_analysis().temp_size_in_bytes
+
+    for n, pages in ((32, 8), (16, 32), (8, 64)):
+        counted = n * engine_cls._chunk_piece_bytes(eng, pages)
+        assert temp_bytes(n, pages) < min(1.1 * counted, budget)
+    assert temp_bytes(32, 16) > budget
