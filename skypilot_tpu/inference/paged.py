@@ -178,24 +178,33 @@ class RecurrentState(NamedTuple):
 
 def _recurrent_layer(layer: Params, li, x: jax.Array, rec: RecurrentState,
                      cfg: ModelConfig, positions: jax.Array, live,
-                     kernel: bool = False):
+                     kernel: bool = False, params: Optional[Params] = None):
     """One layer whose mixer keeps a state and no rows: row ``li`` of
     ``rec`` in, the state after these tokens written back in its place
     (``rec`` is a loop carry: in place). ``kernel`` (one token a slot):
-    the state is advanced where it lies by the kind's state kernel
-    (``ops.kda`` / ``ops.mamba``), the live slots' alone; else by XLA on
-    every row it is given. Returns ((x, rec), the layer's expert
-    counters, or zeros for a dense FFN)."""
+    the state is advanced where it lies by the kind's kernel, the live
+    slots' alone: a KDA layer's recurrence (``ops.kda``), a Mamba layer's
+    whole mixer between its projections (``ops.mamba.decode_mix``, which
+    reads the Mamba stacks of ``params`` at ``li`` and keeps the conv
+    tail in place too); else by XLA on every row it is given. Returns
+    ((x, rec), the layer's expert counters, or zeros for a dense FFN)."""
+    if kernel and 'mamba' in layer:
+        from skypilot_tpu.models import mamba
+        active, advanced = _live_slots(live, x.shape[0]), {}
+
+        def mix(p, h):
+            y, advanced['state'], advanced['conv'] = mamba.decode_mixer(
+                p, params[mamba.STACKS['mamba']]['mamba'], h, li, rec.state,
+                rec.conv, active, cfg)
+            return y, None
+
+        x, _, aux = llama._layer_core(layer, x, cfg, positions, mix,
+                                      live=live, rec=rec)
+        return (x, RecurrentState(advanced['state'], advanced['conv'])), aux
     tail = lax.dynamic_index_in_dim(rec.conv, li, 0, keepdims=False)
     if kernel:
-        if 'mamba' in layer:
-            from skypilot_tpu.ops.mamba import (
-                step_in_place as recurrent_step_in_place)
-        else:
-            from skypilot_tpu.ops.kda import recurrent_step_in_place
-        slots, advanced = x.shape[0], {}
-        active = (jnp.ones((slots,), bool) if live is None
-                  else jnp.broadcast_to(live, (slots, 1))[:, 0])
+        from skypilot_tpu.ops.kda import recurrent_step_in_place
+        advanced, active = {}, _live_slots(live, x.shape[0])
 
         def step_fn(state, *qkvgb):
             del state                   # it stays in the stack
@@ -217,18 +226,27 @@ def _recurrent_layer(layer: Params, li, x: jax.Array, rec: RecurrentState,
     return (x, RecurrentState(state, conv)), aux
 
 
+def _live_slots(live, slots: int) -> jax.Array:
+    """[slots] bool: the slots whose row carries a token (``live``
+    [slots, 1], None = all)."""
+    return (jnp.ones((slots,), bool) if live is None
+            else jnp.broadcast_to(live, (slots, 1))[:, 0])
+
+
 def _carrying_state(kv_layer_body, cfg: ModelConfig, positions: jax.Array,
-                    live, kernel: bool = False):
+                    live, kernel: bool = False,
+                    params: Optional[Params] = None):
     """The layer body of a model with recurrent layers, from the body of
     a layer that caches rows: the carry is (x, every recurrent layer's
     state); a recurrent layer advances its row of the state, any other
-    runs ``kv_layer_body`` on x and hands the state through."""
+    runs ``kv_layer_body`` on x and hands the state through. ``params``:
+    the whole tree, where ``kernel`` reads a kind's stacks itself."""
     def body(carry, layer_and_idx):
         x, rec = carry
         layer, li = layer_and_idx
         if 'kda' in layer or 'mamba' in layer:
             return _recurrent_layer(layer, li, x, rec, cfg, positions, live,
-                                    kernel=kernel)
+                                    kernel=kernel, params=params)
         x, ys = kv_layer_body(x, layer_and_idx)
         return (x, rec), ys
     return body
@@ -240,6 +258,13 @@ def _state_kernel_tiles(spec) -> bool:
     if spec.kind == 'mamba':
         return spec.v_dim % _LANES == 0
     return spec.k_dim % _LANES == 0 and spec.v_dim % _LANES == 0
+
+
+def _decode_state_kernel(cfg: ModelConfig, decode_impl: str) -> bool:
+    """Whether the decode program advances the recurrent layers in their
+    kind's kernel ('pallas', where the blocks tile; ops/kda.py,
+    ops/mamba.py) rather than XLA."""
+    return decode_impl == 'pallas' and _state_kernel_tiles(cfg.state_spec)
 
 
 def _rows_need_live(cfg: ModelConfig) -> bool:
@@ -774,13 +799,10 @@ def paged_decode_horizon(
             x, ((k_rows, v_rows), aux), _ = llama.run_loops(
                 layer_body, x, params, cfg)
         else:
-            # 'pallas': the state kernel, where its blocks tile
-            # (ops/kda.py, ops/mamba.py); else XLA.
             (x, rec), ys, _ = llama.run_loops(_carrying_state(
                 layer_body, cfg, positions, live,
-                kernel=(decode_impl == 'pallas'
-                        and _state_kernel_tiles(cfg.state_spec))),
-                (x, rec), params, cfg)
+                kernel=_decode_state_kernel(cfg, decode_impl),
+                params=params), (x, rec), params, cfg)
             (k_rows, v_rows), aux = ys['gqa']
             aux = jnp.concatenate([aux, ys[cfg.recurrent_kind]])
         ring_k = lax.dynamic_update_slice(
@@ -1865,6 +1887,14 @@ class PagedInferenceEngine(SpeculativeMixin, _EngineBase):
             # (``kda.serving_layout``); None without such a stack.
             'kda_qkv': (list(self.params['kda_layers']['kda']['wqkv'].shape)
                         if 'kda_layers' in self.params else None),
+            # A Mamba layer's decode step: 'fused', the one kernel
+            # between its projections (``ops.mamba.decode_mix``), or
+            # 'xla'; None for a model without Mamba layers.
+            'mamba_decode': (
+                None if self.cfg.recurrent_kind != 'mamba'
+                else 'fused' if _decode_state_kernel(self.cfg,
+                                                     self.decode_impl)
+                else 'xla'),
             # First dispatch per jit static key (the profiler's compile
             # events): steady state over repeated shapes adds none.
             'jit_first_calls': len(compiles),

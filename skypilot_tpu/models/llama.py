@@ -615,13 +615,17 @@ def _layer_core(layer: Params, x: jax.Array, cfg: ModelConfig,
                                       live=live, step_fn=attn_fn)
         return _routed_ffn_branch(layer, x + proj, cfg, live, new_rec)
     if 'mamba' in layer:
-        # A Mamba mixer and a dense FFN; ``attn_fn`` is a one-token scan
-        # only where a state is handed in (the paged programs).
+        # A Mamba mixer and a dense FFN. Where a state is handed in (the
+        # paged programs), ``attn_fn`` (None: ``mamba.mixer``) is the
+        # mixer itself, ``(leaves, h) -> (y, state)``: the decode
+        # program's kernel.
         from skypilot_tpu.models import mamba
         with jax.named_scope('mamba_mix'):
-            proj, new_rec = mamba.mixer(
-                layer['mamba'], h, cfg, rec=rec, live=live,
-                step_fn=attn_fn if rec is not None else None)
+            if rec is not None and attn_fn is not None:
+                proj, new_rec = attn_fn(layer['mamba'], h)
+            else:
+                proj, new_rec = mamba.mixer(layer['mamba'], h, cfg, rec=rec,
+                                            live=live)
         x = x + proj
         h = rms_norm(x, layer['ffn_norm'], cfg.norm_eps)
         with jax.named_scope('dense_ffn'):

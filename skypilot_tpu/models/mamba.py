@@ -19,7 +19,9 @@ depthwise conv (``cfg.state_spec``). With ``h`` the normed layer input:
     out       = (y * SiLU(z)) W_out                  no bias
 
 The scan itself is ``ops/mamba.py``; projections, convolution, norms and
-gate are this file's ``mixer``, shared by every form.
+gate are this file's ``mixer`` in every form but the decode program's,
+``decode_mixer``, where one kernel (``ops.mamba.decode_mix``) takes
+everything between the two projections.
 
 The parameter tree keeps ``llama``'s top level (a tied model has no
 ``unembed``) and holds a stack a kind: ``layers`` (the 'gqa' layers) and
@@ -207,16 +209,14 @@ def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 
 
 def mixer(p: Params, h: jax.Array, cfg: ModelConfig, rec=None,
-          live: Optional[jax.Array] = None, step_fn=None):
+          live: Optional[jax.Array] = None):
     """h [b,s,d] normed -> (y [b,s,d], the state after the run). ``rec``
     = (S [b, 1, N, I] float32, conv tail [b, taps-1, I]) the state before
     it, None = a sequence's start (zeros). ``live`` [b,s] or [b,1] bool
     (None = all) marks the rows that carry a token, a prefix of the run:
-    the others move neither S nor the tail. ``step_fn`` (None:
-    ``ops.mamba.step``) is the one-token scan, ``(S, dt, u, B, C, A) ->
-    (y, S)``: a caller that keeps the state elsewhere (the decode
-    program's kernel over the stacked state) passes its own and an S it
-    does not read."""
+    the others move neither S nor the tail. Every form but the decode
+    program's kernel (``decode_mixer``): prefill, training, the XLA
+    fallback."""
     b, s, _ = h.shape
     I, N, R = cfg.mamba_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
     f32 = jnp.float32
@@ -246,14 +246,33 @@ def mixer(p: Params, h: jax.Array, cfg: ModelConfig, rec=None,
     u = dt * xc
     A = -jnp.exp(p['A_log'])
     if s == 1:
-        y, S = (step_fn or mamba_ops.step)(
-            None if S is None else S[:, 0], dt[:, 0], u[:, 0], B[:, 0],
-            C[:, 0], A)
+        y, S = mamba_ops.step(S[:, 0], dt[:, 0], u[:, 0], B[:, 0], C[:, 0],
+                              A)
         y = y[:, None]
     else:
         with jax.named_scope('mamba_scan'):
             y, S = mamba_ops.scan(S[:, 0], dt, u, B, C, A)
-    S = None if S is None else S[:, None]
+    S = S[:, None]
     y = (y + p['D'] * xc) * jax.nn.silu(z.astype(f32))
     return jnp.einsum('bsi,id->bsd', y.astype(h.dtype), p['out_proj']), \
         (S, tail)
+
+
+def decode_mixer(p: Params, stacks: Params, h: jax.Array, li,
+                 states: jax.Array, tails: jax.Array, active: jax.Array,
+                 cfg: ModelConfig):
+    """The decode program's one token a slot: ``mixer``'s mathematics
+    with the state and conv tail kept where they lie. h [slots, 1, d]
+    normed; ``p`` the layer's leaves (``in_proj`` and ``out_proj``, two
+    XLA dots); ``stacks`` every Mamba layer's leaves, which the kernel
+    ``ops.mamba.decode_mix`` reads at row ``li`` for everything between
+    the dots; states [layers, slots, 1, N, I] float32 and tails
+    [layers, slots, taps-1, I], advanced in place for the ``active``
+    [slots] slots alone. Returns (y [slots, 1, d], states, tails); a
+    dead slot's y is ``out_proj`` of zeros."""
+    xz = jnp.einsum('bd,dn->bn', h[:, 0], p['in_proj'])
+    states, tails, y = mamba_ops.decode_mix(
+        xz, stacks, states, tails, li, active, eps=cfg.norm_eps,
+        interpret=jax.default_backend() != 'tpu')
+    return (jnp.einsum('bi,id->bd', y, p['out_proj'])[:, None], states,
+            tails)

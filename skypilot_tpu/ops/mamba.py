@@ -19,10 +19,10 @@ Three forms of the same mathematics:
   writing every token's ``y`` and the final state. A naive XLA form
   would materialise [tokens, I, N] float32 terms (84 MB a 256-token
   piece of a 5,120-channel layer).
-- ``step_in_place``: one token a slot on the stacked state of every
-  slot, a Pallas kernel that reads and writes the live slots' state
-  where it lies (``ops/kda.py``'s ``recurrent_step_in_place`` is the
-  pattern).
+- ``decode_mix``: the decode program's one token a slot, a Pallas
+  kernel that takes the whole mixer between its two projections (conv,
+  ``x_proj``, norms, ``dt_proj``, softplus, the step, gate) and reads
+  and writes the live slots' state and conv tail where they lie.
 
 A token with ``dt = 0`` and ``u = 0`` leaves the state as it was
 (padding). Every product is float32 and elementwise.
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 
 SCAN_KERNEL_NAME = 'mamba_scan'
-STEP_KERNEL_NAME = 'mamba_recurrent_step'
+DECODE_KERNEL_NAME = 'mamba_decode_mix'
 _ROWS = 8               # tokens a scan-kernel loop iteration takes
 _LANE_BLOCK = 512       # channels a grid step / an inner pass holds
 
@@ -136,80 +136,274 @@ def scan(state, dt, u, B, C, A):
     return scan_xla(state, dt, u, B, C, A)
 
 
-def step_in_place(states: jax.Array, layer, dt: jax.Array, u: jax.Array,
-                  B: jax.Array, C: jax.Array, A: jax.Array,
-                  active: jax.Array, *, interpret: bool = False
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """``step`` on layer ``layer`` of the stacked state of every slot,
-    [layers, slots, 1, N, I] float32, for the slots that are ``active``
-    ([slots] bool): each live slot's state is read once and written
-    once, in place (the stack is aliased to the first output); a slot
-    that is not active is neither read nor written. dt, u [slots, I]; B,
-    C [slots, N]; A [N, I]. Returns (the stack, y [slots, I], zeros for
-    the slots that are not active).
 
-    Grid (slots,): a dead slot's blocks are mapped to those of the live
-    slot before it (the first live one where there is none), so the
-    pipeline sees an unchanged block index, moves nothing, and the body
-    is skipped. The layer rides the block index of the whole stack. The
-    body runs over ``_LANE_BLOCK`` channels at a time."""
+
+# The stacked leaves ``decode_mix`` reads at its layer, in operand order
+# ('conv_bias' where the layer has one).
+DECODE_LEAVES = ('conv', 'conv_bias', 'x_proj', 'dt_norm', 'b_norm',
+                 'c_norm', 'dt_proj', 'dt_bias', 'A_log', 'D')
+
+
+def decode_mix(xz: jax.Array, leaves, states: jax.Array, tails: jax.Array,
+               layer, active: jax.Array, *, eps: float,
+               interpret: bool = False
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A Mamba mixer's one token a slot between its two projections, as
+    one kernel: the causal conv over the tail and SiLU, ``x_proj``, the
+    three RMSNorms, ``dt_proj`` and softplus, ``step`` and the gate
+    ``(y + D x~) SiLU(z)``; the float32 math and the casts of
+    ``models/mamba.py``'s ``mixer``.
+
+    xz [slots, 2I] the in_proj output; ``leaves`` every Mamba layer's
+    leaves stacked [layers, ...] (those of ``DECODE_LEAVES`` are read,
+    at ``layer``, where they lie); states [layers, slots, 1, N, I] float32 and tails
+    [layers, slots, taps-1, I], both aliased in place; ``active``
+    [slots] bool. Returns (the state stack, the tail stack, the gated y
+    [slots, I] in xz's dtype, zeros for the slots that are not active).
+
+    Each live slot's state is read once and written once; a dead slot's
+    is neither read nor written. The tails lie tap-major on the TPU (a
+    [slots, I] plane a tap and layer: XLA's layout for [.., 3, I]), so
+    they move in tiles of ``_tail_rows`` slots: the tiles that hold a
+    live slot are read and written back, a dead slot's rows in them as
+    they came. ``x_proj`` and the B / C norms are read in their layouts
+    too ([R+2N, I] and [N, layers]): other views would be copies of the
+    stacks.
+
+    One grid step: the live tiles of the tails and the first live state
+    are on their way while A is formed; the conv, ``x_proj`` and
+    ``dt_proj`` run over every slot's row at once (a weight goes through
+    the MXU once, not once a slot); then the live slots' states,
+    double-buffered, each written back as the next one computes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    n_layers, slots, _, N, I = states.shape
+    f32 = jnp.float32
+    slots, I = xz.shape[0], xz.shape[1] // 2
+    N, T = states.shape[3], tails.shape[2]
+    R = leaves['dt_proj'].shape[1]
+    names = [k for k in DECODE_LEAVES if k in leaves]
+    views = dict(leaves, x_proj=jnp.swapaxes(leaves['x_proj'], 1, 2),
+                 b_norm=leaves['b_norm'].T, c_norm=leaves['c_norm'].T)
+    planes = jnp.swapaxes(tails, 1, 2)          # [layers, T, slots, I]
+    rows = _tail_rows(tails.dtype)
+    rows = rows if slots % rows == 0 else slots     # else one whole tile
     bi = _lane_block(I)
-    idx = jnp.where(active, jnp.arange(slots), -1)
-    before = lax.cummax(idx, axis=0)
-    remap = jnp.where(before >= 0, before, jnp.argmax(active)).astype(
-        jnp.int32)
-    rows = jnp.stack([dt, u], axis=1)                       # [slots, 2, I]
-    cols = jnp.stack([B, C], axis=-1)                       # [slots, N, 2]
+    chans = [slice(c0, c0 + bi) for c0 in range(0, I, bi)]
 
-    def kernel(layer_ref, remap_ref, live_ref, s_ref, rows_ref, cols_ref,
-               a_ref, s_out_ref, y_ref):
-        del layer_ref, remap_ref
+    def kernel(layer_ref, live_ref, x_ref, z_ref, *refs):
+        w = dict(zip(names, refs))
+        (s_hbm, t_hbm, y_ref, s_out, t_out, xc_v, dt_v, y_v, dbc_v, a_v,
+         mask_v, tail_v, s_buf, ids_ref, t_sem, s_sem) = refs[len(names):]
+        li = layer_ref[0]
 
-        @pl.when(live_ref[pl.program_id(0)] != 0)
-        def _():
-            b_col, c_col = cols_ref[0, :, 0:1], cols_ref[0, :, 1:2]
-            for c0 in range(0, I, bi):
-                ch = slice(c0, c0 + bi)
-                S = (jnp.exp(rows_ref[0, 0:1, ch] * a_ref[:, ch])
-                     * s_ref[0, 0, 0, :, ch]
-                     + rows_ref[0, 1:2, ch] * b_col)
-                s_out_ref[0, 0, 0, :, ch] = S
-                y_ref[0, :, ch] = jnp.sum(S * c_col, axis=0, keepdims=True)
+        def collect(slot, n):           # the live slots, ascending
+            ids_ref[n] = slot
+            return n + live_ref[slot]
 
-    def state_block(b, layer, remap, live):
-        del live
-        return (layer[0], remap[b], 0, 0, 0)
+        n = lax.fori_loop(0, slots, collect, jnp.int32(0))
 
-    def slot_block(b, layer, remap, live):
-        del layer, live
-        return (remap[b], 0, 0)
+        def vec(name, ch=slice(None)):      # the layer's row [1, width]
+            return w[name][pl.ds(li % 8, 1), ch]
 
-    new_states, y = pl.pallas_call(
+        def over_live(body, init=0):
+            return lax.fori_loop(0, n, body, init)
+
+        def tile_dmas(start, out=False):    # a tile's rows of each tap
+            hbm = t_out if out else t_hbm
+            window = pl.ds(pl.multiple_of(start, rows), rows)
+            pairs = [(tail_v.at[j, window], hbm.at[li, j, window])
+                     for j in range(T)]
+            return [pltpu.make_async_copy(*((a, b) if out else (b, a)),
+                                          t_sem.at[int(out)])
+                    for a, b in pairs]
+
+        def over_live_tiles(body):
+            """body(start) once for each tile holding a live slot (the
+            ids ascend); returns how many."""
+            def one(k, carry):
+                seen, last = carry
+                start = ids_ref[k] // rows * rows
+
+                @pl.when(start != last)
+                def _():
+                    body(start)
+                return seen + (start != last).astype(jnp.int32), start
+            return over_live(one, (jnp.int32(0), jnp.int32(-1)))[0]
+
+        def state_dma(k, buf, out=False):
+            hbm = s_out if out else s_hbm
+            src, dst = s_buf.at[buf], hbm.at[li, ids_ref[k]]
+            return pltpu.make_async_copy(*((src, dst) if out else (dst, src)),
+                                         s_sem.at[int(out), buf])
+
+        def start_all(dmas):
+            for dma in dmas:
+                dma.start()
+
+        tiles = over_live_tiles(lambda start: start_all(tile_dmas(start)))
+
+        @pl.when(n > 0)
+        def _first_state():
+            state_dma(0, 0).start()
+
+        mask_v[...] = jnp.zeros(mask_v.shape, f32)
+
+        def mark(k, carry):
+            mask_v[pl.ds(ids_ref[k], 1), :] = jnp.ones((1, mask_v.shape[1]),
+                                                       f32)
+            return carry
+
+        over_live(mark)
+        live = mask_v[:, 0:1] > 0                          # [slots, 1]
+        a_v[...] = -jnp.exp(w['A_log'][...])
+
+        def wait_tiles(count, out=False):
+            def one(i, carry):
+                for dma in tile_dmas(0, out):
+                    dma.wait()
+                return carry
+            lax.fori_loop(0, count, one, 0)
+
+        wait_tiles(tiles)
+        # The conv over every row (a dead tile's rows are left unread),
+        # and each live row's tail moved up by one token.
+        for ch in chans:
+            old = [tail_v[j, :, ch] for j in range(T)]
+            x = x_ref[:, ch]
+            taps = w['conv'][:, ch].astype(f32)             # [T + 1, bi]
+            acc = old[0].astype(f32) * taps[0:1]
+            for j in range(1, T):
+                acc = acc + old[j].astype(f32) * taps[j:j + 1]
+            acc = acc + x.astype(f32) * taps[T:T + 1]
+            if 'conv_bias' in w:
+                acc = acc + vec('conv_bias', ch)
+            xc_v[:, ch] = jax.nn.silu(acc)
+            for j, new in enumerate(old[1:] + [x.astype(tail_v.dtype)]):
+                tail_v[j, :, ch] = jnp.where(live, new, old[j])
+        over_live_tiles(lambda start: start_all(tile_dmas(start, out=True)))
+
+        # [d; B; C] = x~ W_x over every slot's row (W_x lies as its
+        # transpose); dt from d.
+        dbc = lax.dot_general(xc_v[...].astype(x_ref.dtype), w['x_proj'][...],
+                              (((1,), (1,)), ((), ())),
+                              preferred_element_type=f32)   # [slots, R+2N]
+        dbc_v[...] = dbc
+        d = dbc[:, :R]
+        d = (d * lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + eps)
+             * vec('dt_norm')).astype(x_ref.dtype)
+        for ch in chans:
+            dt = jnp.dot(d, w['dt_proj'][:, ch], preferred_element_type=f32)
+            dt_v[:, ch] = jax.nn.softplus(dt + vec('dt_bias', ch))
+
+        # B and C of a slot as columns [N, 1]: its row's lanes R.. and
+        # R+N.., picked out exactly (one term a sum), then normed.
+        def column(pick, a):
+            return jnp.sum(jnp.where(pick, a, 0.0), axis=1, keepdims=True)
+
+        lane = lax.broadcasted_iota(jnp.int32, (N, R + 2 * N), 1)
+        row = lax.broadcasted_iota(jnp.int32, (N, R + 2 * N), 0)
+        layer_lane = lax.broadcasted_iota(jnp.int32, w['b_norm'].shape, 1)
+        norm_cols = [column(layer_lane == li, w[k][...])
+                     for k in ('b_norm', 'c_norm')]
+
+        def update(k, carry):           # a live slot's state, in place
+            buf = k % 2
+            slot = ids_ref[k]
+
+            @pl.when(k + 1 < n)
+            def _next_state():
+                @pl.when(k >= 1)
+                def _buffer_free():
+                    state_dma(0, 1 - buf, out=True).wait()
+                state_dma(k + 1, 1 - buf).start()
+
+            dbc_row = dbc_v[pl.ds(slot, 1), :]
+            Bc, Cc = [
+                c * lax.rsqrt(jnp.mean(c * c, 0, keepdims=True) + eps) * wc
+                for c, wc in zip((column(lane == R + row, dbc_row),
+                                  column(lane == R + N + row, dbc_row)),
+                                 norm_cols)]
+            state_dma(k, buf).wait()
+            for ch in chans:
+                dt = dt_v[pl.ds(slot, 1), ch]               # [1, bi]
+                u = dt * xc_v[pl.ds(slot, 1), ch]
+                S = (jnp.exp(dt * a_v[:, ch]) * s_buf[buf, 0, :, ch]
+                     + u * Bc)
+                s_buf[buf, 0, :, ch] = S
+                y_v[pl.ds(slot, 1), ch] = jnp.sum(S * Cc, axis=0,
+                                                  keepdims=True)
+            state_dma(k, buf, out=True).start()
+            return carry
+
+        over_live(update)
+
+        @pl.when(n >= 2)
+        def _second_last():
+            state_dma(0, n % 2, out=True).wait()
+
+        @pl.when(n >= 1)
+        def _last():
+            state_dma(0, (n - 1) % 2, out=True).wait()
+
+        wait_tiles(tiles, out=True)
+        for ch in chans:
+            y = ((y_v[:, ch] + vec('D', ch) * xc_v[:, ch])
+                 * jax.nn.silu(z_ref[:, ch].astype(f32)))
+            y_ref[:, ch] = jnp.where(live, y, 0.0).astype(y_ref.dtype)
+
+    def at_layer(name):
+        a = views[name]
+        if name in ('b_norm', 'c_norm'):        # [N, layers], whole
+            return pl.BlockSpec(a.shape, lambda i, l, live: (0, 0))
+        if a.ndim == 2:         # a vector a layer: the 8 rows round it
+            return pl.BlockSpec((min(8, a.shape[0]), a.shape[1]),
+                                lambda i, l, live: (l[0] // 8, 0))
+        return pl.BlockSpec((None,) + a.shape[1:],
+                            lambda i, l, live, nd=a.ndim: (l[0],)
+                            + (0,) * (nd - 1))
+
+    def half(j):
+        return pl.BlockSpec((slots, I), lambda i, l, live: (0, j))
+
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    first = 4 + len(names)              # the state stack's operand index
+    y, states, planes = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,               # layer, remap, live
-            grid=(slots,),
-            in_specs=[
-                pl.BlockSpec((1, 1, 1, N, I), state_block),
-                pl.BlockSpec((1, 2, I), slot_block),
-                pl.BlockSpec((1, N, 2), slot_block),
-                pl.BlockSpec((N, I), lambda b, layer, remap, live: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, 1, N, I), state_block),
-                pl.BlockSpec((1, 1, I), slot_block),
+            num_scalar_prefetch=2,               # layer, live flags
+            grid=(1,),
+            in_specs=[half(0), half(1)] + [at_layer(k) for k in names]
+            + [any_spec, any_spec],
+            out_specs=[half(0), any_spec, any_spec],
+            scratch_shapes=[
+                pltpu.VMEM((slots, I), f32),                # x~
+                pltpu.VMEM((slots, I), f32),                # dt
+                pltpu.VMEM((slots, I), f32),                # C^T S
+                pltpu.VMEM((slots, R + 2 * N), f32),        # [d; B; C]
+                pltpu.VMEM((N, I), f32),                    # A
+                pltpu.VMEM((slots, 128), f32),              # live rows
+                pltpu.VMEM((T, slots, I), tails.dtype),     # tail planes
+                pltpu.VMEM((2, 1, N, I), f32),              # live states
+                pltpu.SMEM((slots,), jnp.int32),            # live ids
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct(states.shape, states.dtype),
-            jax.ShapeDtypeStruct((slots, 1, I), jnp.float32),
-        ],
-        input_output_aliases={3: 0},    # the state stack, in place
+        out_shape=[jax.ShapeDtypeStruct((slots, I), xz.dtype),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype),
+                   jax.ShapeDtypeStruct(planes.shape, planes.dtype)],
+        input_output_aliases={first: 1, first + 1: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
-        name=STEP_KERNEL_NAME,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), remap,
-      active.astype(jnp.int32), states, rows, cols, A)
-    return new_states, jnp.where(active[:, None], y[:, 0], 0.0)
+        name=DECODE_KERNEL_NAME,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), active.astype(jnp.int32),
+      xz, xz,
+      *[views[k] for k in names], states, planes)
+    return states, jnp.swapaxes(planes, 1, 2), y
+
+
+def _tail_rows(dtype) -> int:
+    """Slots a DMA of a tail plane moves at once: a tile's rows (8 of
+    32 bits: 16 of a bf16 plane)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)

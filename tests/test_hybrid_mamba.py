@@ -169,25 +169,50 @@ def test_chunked_scan_is_the_one_token_step(s):
         assert np.abs(S64.T @ C[0, t] - want[0, t]).max() < 1e-4
 
 
-@pytest.mark.parametrize('active', [(1, 0, 1, 1, 0), (0, 0, 1, 0, 0),
-                                    (1, 1, 1, 1, 1)])
-def test_state_kernel_is_the_step_on_live_slots(active):
-    rng = np.random.default_rng(0)
-    L, slots, N, I = 2, 5, 8, 1024
-    S = rng.normal(size=(L, slots, 1, N, I)).astype(np.float32)
-    _, dt, u, B, C, A = _scan_inputs(rng, slots, 1, N, I)
+@pytest.mark.parametrize('active', [
+    (1, 0, 1, 1, 0), (0, 0, 1, 0, 0), (1, 1, 1, 1, 1), (0, 0, 0, 0, 0),
+    # 24 slots in tail tiles of 8: live slots in the first and the
+    # last, none in the middle one
+    tuple(int(i in (1, 17, 23)) for i in range(24))])
+def test_decode_kernel_is_the_mixer_on_live_slots(active):
+    """``mamba.decode_mixer`` (the kernel ``ops.mamba.decode_mix``,
+    interpret mode) against ``mixer``'s one-token XLA form on the same
+    layer of a stack: the gated output, the new state and the new conv
+    tail of every live slot within float32 rounding; every other layer,
+    and a dead slot's state and tail, bit for bit; a dead slot's output
+    is ``out_proj`` of zeros."""
+    cfg, params = make('float32')
+    stacks = params['mamba_layers']['mamba']
+    n_layers, li = stacks['in_proj'].shape[0], 1
+    p = jax.tree.map(lambda a: a[li], stacks)
+    spec = cfg.state_spec
     live = np.array(active, bool)
-    new, y = jax.jit(lambda S, l, *a: mamba_ops.step_in_place(
-        S, l, *a, interpret=True))(jnp.asarray(S), jnp.int32(1), dt[:, 0],
-                                   u[:, 0], B[:, 0], C[:, 0], A,
-                                   jnp.asarray(live))
-    y_ref, S_ref = mamba_ops.step(jnp.asarray(S[1, :, 0]), dt[:, 0],
-                                  u[:, 0], B[:, 0], C[:, 0], A)
-    assert np.abs(new[1, :, 0] - np.where(live[:, None, None], S_ref,
-                                          S[1, :, 0])).max() < 1e-5
-    assert np.array_equal(new[0], S[0])         # the other layer
-    assert np.array_equal(new[1][~live], S[1][~live])
-    assert np.abs(np.where(live[:, None], y_ref, 0) - y).max() < 1e-4
+    slots = len(live)
+    rng = np.random.default_rng(slots)
+    states = rng.normal(size=(n_layers, slots, 1, spec.k_dim, spec.v_dim)
+                        ).astype(np.float32)
+    tails = rng.normal(size=(n_layers, slots, spec.conv_rows,
+                             spec.conv_dim)).astype(np.float32)
+    h = jnp.asarray(rng.normal(size=(slots, 1, cfg.dim)), jnp.float32)
+    y, new_states, new_tails = jax.jit(
+        lambda *a: mamba.decode_mixer(*a, cfg))(
+            p, stacks, h, li, jnp.asarray(states), jnp.asarray(tails),
+            jnp.asarray(live))
+    with jax.default_matmul_precision('highest'):
+        y_ref, (S_ref, tail_ref) = mamba.mixer(
+            p, h, cfg, rec=(jnp.asarray(states[li]), jnp.asarray(tails[li])),
+            live=jnp.asarray(live)[:, None])
+    y, new_states, new_tails = map(np.asarray, (y, new_states, new_tails))
+    assert np.abs(y[live] - np.asarray(y_ref)[live]).max(initial=0) < 1e-5
+    assert np.array_equal(y[~live], np.zeros_like(y[~live]))
+    assert np.abs(new_states[li][live]
+                  - np.asarray(S_ref)[live]).max(initial=0) < 1e-5
+    assert np.array_equal(new_tails[li][live], np.asarray(tail_ref)[live])
+    others = np.arange(n_layers) != li
+    assert np.array_equal(new_states[others], states[others])
+    assert np.array_equal(new_tails[others], tails[others])
+    assert np.array_equal(new_states[li][~live], states[li][~live])
+    assert np.array_equal(new_tails[li][~live], tails[li][~live])
 
 
 def test_padding_moves_neither_state_nor_conv_tail():
@@ -316,8 +341,9 @@ def serve_through_pages(cfg, params, prompts, n_new, horizon, slots,
 
 @pytest.mark.parametrize('dtype,lens,horizon,decode_impl', [
     ('float32', (37, 21), 1, 'gather'),     # chunks 16+16+5 and 16+5
-    ('float32', (37, 21), 8, 'pallas'),     # paged kernel + state kernel
+    ('float32', (37, 21), 8, 'pallas'),     # paged kernel + mixer kernel
     ('bfloat16', (37, 21), 8, 'gather'),
+    ('bfloat16', (37, 21), 8, 'pallas'),    # bf16 tails through the kernel
     ('float32', (1, 70), 4, 'gather'),
 ])
 def test_paged_prefill_then_decode_matches_reference(dtype, lens, horizon,
@@ -327,20 +353,21 @@ def test_paged_prefill_then_decode_matches_reference(dtype, lens, horizon,
     tenant (the first chunk starts from zeros), then decode through
     cache and state; every position's logits against the reference's
     full forward. 'pallas' takes the paged GQA kernel over a group of 4
-    query heads on the one KV head and the state kernel (interpret
-    mode): 128 channels tile the lanes."""
+    query heads on the one KV head and the Mamba decode kernel, the
+    whole mixer between its projections (interpret mode): 128 channels
+    tile the lanes."""
     cfg, params = make(dtype)
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lens]
     n_new = 17
     seen = []
-    real = mamba_ops.step_in_place
+    real = mamba_ops.decode_mix
 
     def spy(*a, **kw):
-        seen.append(a[0].shape)
+        seen.append(a[2].shape)
         return real(*a, **kw)
 
-    with mock.patch.object(mamba_ops, 'step_in_place', spy):
+    with mock.patch.object(mamba_ops, 'decode_mix', spy):
         out, got = serve_through_pages(cfg, params, prompts, n_new, horizon,
                                        slots=(2, 0), decode_impl=decode_impl)
     assert bool(seen) == (decode_impl == 'pallas')
@@ -412,6 +439,21 @@ def test_engine_serves_it_and_counts():
     assert moved[profiler.STATE_RECOMPUTE_METRIC] == 0
     assert moved[profiler.PREFILL_SCAN_TOKENS_METRIC] == sum(map(len,
                                                                  prompts))
+
+
+@pytest.mark.parametrize('preset,decode_impl,form', [
+    ('tiny-jamba', 'gather', 'xla'), ('tiny-jamba', 'pallas', 'fused'),
+    ('tiny', 'pallas', None)])
+def test_resolved_path_names_the_mamba_decode_form(preset, decode_impl,
+                                                   form):
+    """``resolved_path()['mamba_decode']``: which form the decode program
+    was built with, the one kernel between a Mamba layer's projections
+    or XLA; None for a model without Mamba layers."""
+    from skypilot_tpu.inference.paged import PagedInferenceEngine
+    cfg = dataclasses.replace(configs.get_config(preset), dtype=jnp.float32)
+    eng = PagedInferenceEngine(cfg, max_batch=2, max_seq=64, page_size=PAGE,
+                               chunk=CHUNK, decode_impl=decode_impl)
+    assert eng.resolved_path()['mamba_decode'] == form
 
 
 def test_a_slots_state_can_be_read_back():

@@ -872,10 +872,13 @@ def test_mamba_decode_program_advances_the_state_in_place(one_chip,
     """The whole ``paged_decode_horizon`` of the ``jamba2-3b.docreason``
     cell at 64 slots: the donated state and conv tail (9,318,400 B a
     slot, 596 MB) are aliased to the output; three kernels (the paged
-    GQA kernel over 20 query heads on one KV head, and the Mamba state
-    kernel in each of the period's two Mamba runs); the loops are the
-    horizon, the period, the runs of Mamba layers; no copy of a
-    stacked weight; 0.14 GB of temp."""
+    GQA kernel over 20 query heads on one KV head, and the Mamba decode
+    kernel, the whole mixer between its projections, in each of the
+    period's two Mamba runs); the loops are the horizon, the period, the
+    runs of Mamba layers; no copy of a stacked weight; no layer's conv
+    tail [64, 3, 5120] is cut out of the stack and written back into it
+    (the kernel moves the live slots' tiles where they lie); 2.3 MB of
+    temp (0.14 GB with those copies)."""
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     slots = 64
     cfg, params, cache, rec = _jamba_operands(one_chip, slots)
@@ -895,8 +898,11 @@ def test_mamba_decode_program_advances_the_state_in_place(one_chip,
     assert mem.alias_size_in_bytes == slots * 9_318_400
     assert mem.temp_size_in_bytes < 0.3e9
     assert text.count('tpu_custom_call') == 3
-    assert 'mamba_recurrent_step' in text
+    assert 'mamba_decode_mix' in text
     assert _stack_slice_copies(text, cfg) == []
+    import re
+    tail = rf'bf16\[(1,)?{slots},{cfg.mamba_d_conv - 1},{cfg.mamba_inner}\]'
+    assert not re.search(tail, text)
 
 
 def test_mamba_prefill_chunk_runs_the_scan_kernel(one_chip, monkeypatch):
